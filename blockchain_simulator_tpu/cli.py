@@ -207,10 +207,15 @@ def config_from_args(args) -> SimConfig:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
+    schedule = None  # what the jax engine resolves cfg.schedule to
+
     def emit(record, cfg=None, **kw):
         """Every result line leaves through here: one JSON line with the
         obs manifest attached (and, when $BLOCKSIM_RUNS_JSONL is set, the
-        same record appended there — utils/obs.py)."""
+        same record appended there — utils/obs.py).  A jax-engine line
+        names the program that ran: ``schedule`` is 'round' or 'tick'."""
+        if schedule is not None:
+            record["schedule"] = schedule
         print(json.dumps(obs.finalize(record, cfg, **kw)))
 
     try:
@@ -275,6 +280,14 @@ def main(argv=None) -> int:
                 m["wallclock_s"] = time.perf_counter() - t0
             emit(m, cfg)
         return 0
+
+    from blockchain_simulator_tpu.runner import use_round_schedule
+
+    try:
+        schedule = "round" if use_round_schedule(cfg) else "tick"
+    except ValueError as e:  # an ineligible explicit --schedule round
+        print(f"error: {e}", file=sys.stderr)
+        return 2
 
     if args.byz_sweep:
         from blockchain_simulator_tpu.parallel.sweep import run_byzantine_sweep

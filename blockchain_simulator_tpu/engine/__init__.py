@@ -3,8 +3,10 @@
 The engine (``engine.cpp``) is the framework's native, serial, per-message
 discrete-event simulator — the self-contained replacement for the ns-3
 dependency the upstream reference schedules into (SURVEY.md §7 L6).  It is
-compiled on demand with ``g++ -O2 -shared -fPIC`` (cached next to the source,
-rebuilt when the source is newer) and called through ctypes with a flat config
+compiled on demand with ``g++ -O2 -shared -fPIC`` (cached next to the source
+under a name that carries a hash of the source's CONTENTS — the library is
+git-ignored but travels with a copied working tree, and mtimes do not
+survive a copy) and called through ctypes with a flat config
 struct; results come back as a JSON metrics string with the same keys as the
 JAX backends' ``metrics()`` dicts, so differential tests compare them
 directly.
@@ -13,6 +15,7 @@ directly.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import pathlib
@@ -21,7 +24,6 @@ import tempfile
 
 _DIR = pathlib.Path(__file__).resolve().parent
 _SRC = _DIR / "engine.cpp"
-_LIB = _DIR / "_libengine.so"
 
 _PROTOCOLS = {"pbft": 0, "raft": 1, "paxos": 2}
 
@@ -64,8 +66,11 @@ class _CppCfg(ctypes.Structure):
 
 
 def build(force: bool = False) -> pathlib.Path:
-    """Compile the engine if missing or stale; returns the .so path."""
-    if force or not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
+    """Compile the engine unless the library built from exactly this
+    source is already there; returns the .so path."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    lib = _DIR / f"_libengine-{digest}.so"
+    if force or not lib.exists():
         # compile to a temp file and os.replace() so concurrent builders
         # (parallel pytest workers, two CLI invocations) never load a
         # partially written .so — replace is atomic within one directory
@@ -84,11 +89,14 @@ def build(force: bool = False) -> pathlib.Path:
                     f"{proc.stderr}"
                 )
             os.chmod(tmp, 0o755)  # mkstemp creates 0600; keep the .so loadable
-            os.replace(tmp, _LIB)
+            os.replace(tmp, lib)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-    return _LIB
+        for old in _DIR.glob("_libengine*.so"):  # libraries of other sources
+            if old != lib:
+                old.unlink(missing_ok=True)
+    return lib
 
 
 _lib_handle = None

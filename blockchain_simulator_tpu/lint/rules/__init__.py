@@ -12,7 +12,6 @@ from blockchain_simulator_tpu.lint.rules import (  # noqa: F401
     hardcoded_mesh_axis,
     host_sync_in_traced,
     module_scope_backend_touch,
-    probe_child_kill,
     prng_key_reuse,
     slow_cpu_lowering,
     static_arg_recompile_hazard,
@@ -24,7 +23,6 @@ ALL_RULES = [
     prng_key_reuse,
     module_scope_backend_touch,
     slow_cpu_lowering,
-    probe_child_kill,
     static_arg_recompile_hazard,
     unused_import,
     hardcoded_mesh_axis,

@@ -36,19 +36,20 @@ def _push(buf, t, lo: int, contrib, op: str):
 
     Two lowerings:
 
-    - **pallas** (TPU): one fused in-place kernel touching exactly the B
-      addressed ring slices (ops/ring_kernel.py) — the bandwidth floor.
-    - **DUS chain** (fallback): unrolled dynamic-slice / dynamic-update-slice
+    - **DUS chain** (default): unrolled dynamic-slice / dynamic-update-slice
       pairs over the (small, static) bucket axis.  A ``buf.at[idx_vec].add``
       would lower to XLA generic scatter, which TPUs execute catastrophically
       slowly — the round-3 ablation (tools/ablate.py) measured the scatter
-      form ~30x slower than this chain; the pallas kernel removes the chain's
-      remaining per-pair copy cost (round-4 measurement in
-      ARTIFACT_ring_kernel.json).
+      form ~30x slower than this chain.
+    - **pallas** (opt-in, TPU only): one fused in-place kernel touching
+      exactly the B addressed ring slices (ops/ring_kernel.py).  An explicit
+      ``BLOCKSIM_RING_KERNEL=pallas`` is honoured or raises — a backend
+      other than tpu or a ring that does not tile never quietly runs the
+      chain.
 
     Lowering selection is PROCESS-SCOPED: ``ring_kernel.enabled()`` reads
     ``BLOCKSIM_RING_KERNEL`` at trace time, and traced sim fns are cached by
-    config (runner.make_sim_fn / parallel.shard lru_caches), so flipping the
+    config (runner.make_sim_fn / parallel.shard registries), so flipping the
     env var mid-process keeps previously built fns on their old lowering.
     Set the variable before building sim fns (or clear the caches via
     ``make_sim_fn.cache_clear()``) — tools/ring_kernel_bench.py runs each
@@ -56,7 +57,7 @@ def _push(buf, t, lo: int, contrib, op: str):
     """
     from blockchain_simulator_tpu.ops import ring_kernel
 
-    if ring_kernel.enabled() and ring_kernel.pushable(buf, contrib):
+    if ring_kernel.enabled():
         return ring_kernel.fused_push(buf, t, lo, contrib, op)
     combine = jnp.add if op == "add" else jnp.maximum
     d = buf.shape[0]

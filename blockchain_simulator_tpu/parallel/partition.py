@@ -144,22 +144,15 @@ def _tag_arm(fn, mesh, arm):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: ``jax.shard_map`` + ``check_vma``
-    on current releases, ``jax.experimental.shard_map`` + ``check_rep`` on
-    0.4.x.  Replication checking is waived either way: delivery ops mix
+    """``jax.shard_map`` with replication checking waived: delivery ops mix
     gathered (unreplicated) and replicated values; correctness is covered
     by the sharded-vs-unsharded equivalence tests."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _named_shardings(mesh, specs):

@@ -949,6 +949,9 @@ class ScenarioServer:
                 # mesh descriptors below
                 "mesh": (_mesh_shape_dict(self.mesh)
                          if self.mesh is not None else None),
+                # the device this daemon serves on (platform, device_kind,
+                # device_count) — the same three the READY line carries
+                **(obs.device_info() or {}),
             }
             if self.replica is not None:
                 rec["replica"] = self.replica

@@ -341,17 +341,34 @@ def test_ring_kernel_untouched_slices_survive():
     np.testing.assert_array_equal(got[[2, 3]], buf0[[2, 3]] + 1)
 
 
-def test_ring_kernel_ineligible_shapes_fall_back():
+def test_ring_kernel_ineligible_shapes_are_refused():
     from blockchain_simulator_tpu.ops import ring_kernel
 
-    # L = 100 has no 128-multiple divisor -> DUS path
-    assert not ring_kernel.pushable(
-        jnp.zeros((5, 100), jnp.int32), jnp.zeros((2, 100), jnp.int32)
-    )
+    # L = 100 has no 128-multiple divisor
+    bad = (jnp.zeros((5, 100), jnp.int32), jnp.zeros((2, 100), jnp.int32))
+    assert not ring_kernel.pushable(*bad)
+    with pytest.raises(ValueError, match="cannot tile"):
+        ring_kernel.fused_push(bad[0], 0, 1, bad[1], "add", interpret=True)
     # B > D can never happen from ring_depth, but the guard must hold
     assert not ring_kernel.pushable(
         jnp.zeros((2, 128), jnp.int32), jnp.zeros((3, 128), jnp.int32)
     )
+
+
+def test_explicit_pallas_request_raises_off_tpu(monkeypatch):
+    # BLOCKSIM_RING_KERNEL=pallas is honoured or refused, never quietly
+    # replaced by the DUS chain
+    from blockchain_simulator_tpu.ops import ring
+
+    buf, contrib = jnp.zeros((5, 256), jnp.int32), jnp.ones((2, 256), jnp.int32)
+    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "pallas")
+    with pytest.raises(RuntimeError, match="needs the tpu backend"):
+        ring.ring_push_add(buf, 0, 1, contrib)
+    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "auto")
+    with pytest.raises(ValueError, match="expected 'dus' or 'pallas'"):
+        ring.ring_push_add(buf, 0, 1, contrib)
+    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "dus")
+    assert int(ring.ring_push_add(buf, 0, 1, contrib).sum()) == 2 * 256
 
 
 def test_ring_kernel_inside_scan_interpret():

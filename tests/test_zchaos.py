@@ -558,7 +558,7 @@ def test_health_supervised_retries_before_wedged():
     assert rec["verdict"] == "wedged"
     assert rec["attempts"] == 2
     assert rec["supervised"] is True
-    assert "abandoned_pid" in rec
+    assert "killed and reaped" in rec["error"]  # no probe child left behind
     assert time.monotonic() - t0 >= 0.05 * 2 + 0.05  # two probes + backoff
 
 

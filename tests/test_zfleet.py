@@ -392,7 +392,7 @@ def test_latest_verdict_filters_by_replica(tmp_path):
 
 
 def test_probe_backend_carries_replica_label():
-    rec = health.probe_backend(platform="cpu", replica="r7")
+    rec = health.probe_backend(replica="r7")
     assert rec["replica"] == "r7"
     assert rec["verdict"] == "healthy"
 

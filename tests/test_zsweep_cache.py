@@ -31,7 +31,6 @@ import sys
 import jax
 import pytest
 
-import bench
 from blockchain_simulator_tpu.models import base as base_model
 from blockchain_simulator_tpu.parallel.sweep import (
     run_byzantine_sweep,
@@ -284,33 +283,6 @@ def test_aot_cached_registry_hit_skips_recompile():
     assert c1 is c2 and built == [1]
 
 
-# ------------------------------------------------------- bench round grid ---
-
-
-def test_round_bucket_grid():
-    assert [bench._round_bucket(r) for r in (1, 2, 3, 10, 150, 200, 201)] == [
-        1, 2, 5, 10, 200, 200, 500,
-    ]
-    # the shipped defaults are already on the grid: behavior unchanged
-    assert bench._round_bucket(200) == 200
-    assert bench._round_bucket(2000) == 2000
-    assert bench._round_bucket(0) == 0
-
-
-def test_degraded_rounds_walks_grid_to_fit():
-    # prev attempt: 200 rounds, 2 s wall, 20 s compile
-    prev = (100.0, 200, 2.0, 20.0)
-    # plenty of budget: full 2000 never reaches here, next bucket down fits
-    assert bench._degraded_rounds(1e9, prev, 200, 2000) == 1000
-    # tight budget: only the smallest strictly-larger bucket fits
-    # projected(500) = 20 + 2*2*2.5 + 20 = 50
-    assert bench._degraded_rounds(51.0, prev, 200, 2000) == 500
-    # no budget for anything above the previous attempt
-    assert bench._degraded_rounds(10.0, prev, 200, 2000) is None
-    # nothing strictly between prev and want
-    assert bench._degraded_rounds(1e9, prev, 200, 500) is None
-
-
 # -------------------------------------------------- compare + CI plumbing ---
 
 
@@ -335,18 +307,18 @@ def test_bench_compare_never_gates_compile_s(tmp_path):
 
 @pytest.mark.slow
 def test_warm_bench_script_cold_vs_warm(tmp_path):
-    """tools/warm_bench.sh end-to-end at toy scale: two bench runs against
-    one persistent cache; the artifact records both compile_s and the warm
-    one improves (this is the lint.sh-chained CI shape of the acceptance
-    measurement; ARTIFACT_warm_bench.json is the committed 10k-scale run)."""
+    """tools/warm_bench.sh end-to-end at toy scale: two bench rehearsals
+    against one persistent cache placed with jax's own variable; the
+    artifact records both compile_s, the second run adds no cache entries
+    and — the first being cold — compiles faster (the lint.sh-chained CI
+    shape; unset, the cache is the fixed <repo>/.jax_cache)."""
     out = tmp_path / "warm.json"
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env.update({
         "WARM_BENCH_N": "128", "WARM_BENCH_ROUNDS": "10",
-        "WARM_BENCH_OUT": str(out),
-        "BLOCKSIM_COMPILE_CACHE": str(tmp_path / "exe"),
-        "BLOCKSIM_XLA_CACHE": str(tmp_path / "xla"),
+        "WARM_BENCH_OUT": str(out), "JAX_PLATFORMS": "cpu",
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla"),
     })
     proc = subprocess.run(
         ["bash", str(REPO / "tools" / "warm_bench.sh")],
@@ -354,5 +326,9 @@ def test_warm_bench_script_cold_vs_warm(tmp_path):
     )
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     rec = json.loads(out.read_text())
-    assert rec["cold"]["compile_s"] is not None
+    assert rec["cache_dir"] == str(tmp_path / "xla")
+    assert rec["first_run_was_cold"]
+    ents = rec["cache_entries"]
+    assert ents["before"] == 0 < ents["after_first"] == ents["after_second"]
     assert rec["warm"]["compile_s"] < rec["cold"]["compile_s"]
+    assert rec["device"]["platform"] == "cpu"

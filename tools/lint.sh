@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # CI gate: jaxlint (new findings vs LINT_BASELINE.json), jaxgraph (IR-level
 # audit + FLOP/byte budget gate vs GRAPH_BASELINE.json), and the
-# bench_compare perf-regression gate over the committed BENCH_*.json history.
+# bench_compare regression gate over BENCH_BASELINES.json + runs.jsonl.
 #
 # Exit 0 only when ALL pass:
 #   - `python -m blockchain_simulator_tpu.lint --format json` reports zero
@@ -37,20 +37,24 @@
 # perf history (*_findings metrics and the graph_* prefix are never gated
 # there — the budget gate lives in lint.graph itself).
 #
-# After both gates, tools/warm_bench.sh measures the cold-vs-warm compile
-# split of the CPU fallback bench against a persistent compile cache
+# After both gates, tools/warm_bench.sh checks the cold-vs-warm compile
+# split of two bench.py rehearsals against jax's persistent compile cache
 # (WARM_BENCH=0 skips; see the block below).
+#
+# Every stage is a CPU rehearsal: the chain claims no chip (the chip is
+# reached by sending `python chip_smoke.py` through the chip tool).
 #
 # Usage: tools/lint.sh [--threshold 0.5]
 set -u
 REPO="$(cd "$(dirname "$0")/.." && pwd)"
 cd "$REPO"
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 rc=0
 
 echo "== jaxlint =="
 python -m blockchain_simulator_tpu.lint \
-    blockchain_simulator_tpu tools bench.py --format json
+    blockchain_simulator_tpu tools bench.py chip_smoke.py --format json
 lint_rc=$?
 if [ "$lint_rc" -ne 0 ]; then
     echo "lint.sh: jaxlint FAILED (rc=$lint_rc)" >&2
@@ -315,12 +319,12 @@ if [ "$bench_rc" -ne 0 ]; then
     rc=1
 fi
 
-# Cold-vs-warm compile check (tools/warm_bench.sh): the CPU fallback bench
-# twice against one persistent compile cache; fails when the warm run's
-# compile_s does not improve.  Scaled down here (2000 nodes, 200 rounds —
-# ~1 min on the 2-core box) so the gate stays cheap; WARM_BENCH=0 skips
-# (the test-suite smoke does), and the full-scale artifact run is
-# `bash tools/warm_bench.sh` with its 10k defaults.
+# Cold-vs-warm compile check (tools/warm_bench.sh): two bench.py rehearsals
+# against jax's persistent compile cache — $JAX_COMPILATION_CACHE_DIR when
+# set, else the fixed <repo>/.jax_cache; fails when the second run adds
+# cache entries, or when the first run was cold and the second's compile_s
+# does not improve.  Scaled down here (2000 nodes, 200 rounds) so the gate
+# stays cheap; WARM_BENCH=0 skips (the test-suite smoke does).
 if [ "${WARM_BENCH:-1}" != "0" ]; then
     echo "== warm_bench =="
     WARM_BENCH_N="${WARM_BENCH_N:-2000}" \

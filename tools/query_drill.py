@@ -57,8 +57,9 @@ ARTIFACT = os.path.join(REPO, "ARTIFACT_query.json")
 def _force_platform(platform: str | None) -> None:
     if not platform:
         return
-    if "jax" not in _sys.modules:
-        os.environ.setdefault("JAX_PLATFORMS", platform)
+    # the drill's daemon/sweep children inherit its platform through their
+    # environment (the serve daemon has no platform flag of its own)
+    os.environ["JAX_PLATFORMS"] = platform
     import jax
 
     jax.config.update("jax_platforms", platform)
@@ -245,9 +246,8 @@ def kill9_leg(args, workdir: str) -> dict:
         time.sleep(0.01)
     killed = proc.poll() is None
     if killed:
-        # a CPU-pinned drill child on localhost, never a tunnel client —
-        # the wedge incident (KNOWN_ISSUES #3) does not apply
-        os.kill(proc.pid, signal.SIGKILL)  # jaxlint: disable=probe-child-kill
+        # the kill -9 IS the drill
+        os.kill(proc.pid, signal.SIGKILL)
     proc.wait(timeout=60)
     pre_keys = set(SweepJournal(journal_path).completed())
     rec["killed"] = killed

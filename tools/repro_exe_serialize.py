@@ -1,20 +1,22 @@
-"""Repro: XLA:CPU executable serialization on this container (jax 0.4.37).
+"""Repro: does executable serialization round-trip across processes here?
 
-KNOWN_ISSUES.md #0e records the measured verdict this script produces: does
-``jax.experimental.serialize_executable`` round-trip a compiled simulation
-executable across PROCESSES on the XLA:CPU backend, bit-equal, and how much
-compile wall does the deserialize path save?  The persistent layer of
+KNOWN_ISSUES.md #0e records the verdict this script produced on XLA:CPU:
+``jax.experimental.serialize_executable`` round-trips a compiled simulation
+executable across PROCESSES, bit-equal.  The persistent layer of
 ``utils/aotcache.py`` is gated on exactly this capability — if a jax upgrade
 breaks it, this script is the 60-second check (aotcache degrades to
-in-process-only caching either way; it never raises).
+in-process-only caching either way; it never raises).  Whether it holds for
+TPU executables is not known (ROADMAP S4): send this script through the
+chip tool to find out.
 
 Usage:
     JAX_PLATFORMS=cpu python tools/repro_exe_serialize.py
 
-Runs itself twice: the parent compiles + serializes + measures, then
-re-execs as a child that deserializes + runs + compares metrics.  Prints one
-JSON verdict line: {"serialize_ok", "bit_equal", "compile_s", "deserialize_s",
-"payload_bytes"}.
+The parent is stdlib-only and never touches jax — a chip belongs to one
+process at a time — so both halves are children, one after the other: one
+compiles + serializes + measures, the next deserializes + runs.  Prints one
+JSON verdict line: {"serialize_ok", "bit_equal", "platform", "compile_s",
+"deserialize_s", "payload_bytes"}.
 """
 
 from __future__ import annotations
@@ -38,10 +40,9 @@ def _metrics(final):
     return get_protocol("pbft").metrics(SimConfig(**CFG_KW), final)
 
 
-def child(path: str) -> None:
+def load_child(path: str) -> None:
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     # treedef unpickling resolves flax-struct state types by import
     from blockchain_simulator_tpu.models import pbft  # noqa: F401
     from jax.experimental.serialize_executable import deserialize_and_load
@@ -56,12 +57,12 @@ def child(path: str) -> None:
                      default=str))
 
 
-def main() -> int:
+def save_child(path: str) -> None:
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from blockchain_simulator_tpu.runner import make_sim_fn
     from blockchain_simulator_tpu.utils.config import SimConfig
+    from jax.experimental.serialize_executable import serialize
 
     sim = make_sim_fn(SimConfig(**CFG_KW))
     key = jax.random.key(SEED)
@@ -69,46 +70,56 @@ def main() -> int:
     compiled = sim.lower(key).compile()
     compile_s = time.perf_counter() - t0
     ref = _metrics(jax.block_until_ready(compiled(key)))
+    payload, in_tree, out_tree = serialize(compiled)
+    with open(path, "wb") as f:
+        pickle.dump((payload, in_tree, out_tree), f)
+    print(json.dumps({"compile_s": round(compile_s, 3),
+                      "payload_bytes": len(payload),
+                      "platform": jax.devices()[0].platform, "metrics": ref},
+                     default=str))
 
-    verdict = {"serialize_ok": False, "bit_equal": None,
-               "compile_s": round(compile_s, 3), "deserialize_s": None,
+
+def _run_child(mode: str, path: str) -> dict:
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), mode, path],
+        capture_output=True, text=True, timeout=600,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(f"{mode}: {proc.stderr[-1000:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def main() -> int:
+    verdict = {"serialize_ok": False, "bit_equal": None, "platform": None,
+               "compile_s": None, "deserialize_s": None,
                "payload_bytes": None}
-    path = None
+    fd, path = tempfile.mkstemp(suffix=".jaxexe")
+    os.close(fd)
     try:
-        from jax.experimental.serialize_executable import serialize
-
-        payload, in_tree, out_tree = serialize(compiled)
-        verdict["payload_bytes"] = len(payload)
-        fd, path = tempfile.mkstemp(suffix=".jaxexe")
-        with os.fdopen(fd, "wb") as f:
-            pickle.dump((payload, in_tree, out_tree), f)
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--child", path],
-            capture_output=True, text=True, timeout=300,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(proc.stderr[-1000:])
-        child_rec = json.loads(proc.stdout.strip().splitlines()[-1])
+        saved = _run_child("--save", path)
+        verdict.update({k: saved[k] for k in
+                        ("platform", "compile_s", "payload_bytes")})
+        loaded = _run_child("--load", path)
         verdict["serialize_ok"] = True
-        verdict["deserialize_s"] = child_rec["deserialize_s"]
-        verdict["bit_equal"] = all(
-            str(child_rec["metrics"][k]) == str(v) for k, v in ref.items()
-        )
+        verdict["deserialize_s"] = loaded["deserialize_s"]
+        verdict["bit_equal"] = loaded["metrics"] == saved["metrics"]
     except Exception as e:  # the verdict line IS the point — never traceback
         verdict["error"] = f"{type(e).__name__}: {e}"
     finally:
-        if path:
-            try:
-                os.unlink(path)
-            except OSError:
-                pass
+        try:
+            os.unlink(path)
+        except OSError:
+            pass
     print(json.dumps(verdict))
     return 0 if verdict["serialize_ok"] and verdict["bit_equal"] else 1
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        child(sys.argv[sys.argv.index("--child") + 1])
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if "--save" in sys.argv:
+        save_child(sys.argv[sys.argv.index("--save") + 1])
+    elif "--load" in sys.argv:
+        load_child(sys.argv[sys.argv.index("--load") + 1])
     else:
         sys.exit(main())

@@ -1,16 +1,19 @@
 """Measure the pallas fused ring push vs the DUS chain on the real chip.
 
-Times the full PBFT tick engine (the production consumer; round-3 ablation
-showed ring pushes at ~2.0 of 2.24 ms/tick at N=100k) with
+Times the full PBFT tick engine (the production consumer) with
 BLOCKSIM_RING_KERNEL=dus and =pallas, plus a push-only micro scan isolating
-the op.  Writes ARTIFACT_ring_kernel.json at the repo root.
+the op.  Writes chiprun_out/ring_kernel.json (the directory the chip tool
+brings back).
 
-Each measurement runs in a FRESH child process: round-4 observation — after
-the ~230 MB push-only micro scan, the next large program in the same process
-hit the KNOWN_ISSUES.md #2 "TPU device error" fault class, while the same
-program runs fine from a clean process.
+Each measurement runs in a FRESH child process, one after the other, from a
+parent that never touches jax: the lowering is chosen at trace time per
+process (ops/ring.py), and a chip belongs to one process at a time.
 
-Usage: python tools/ring_kernel_bench.py [N] [TICKS]
+N defaults to 102400, not 100000: an explicit pallas request raises on a
+ring whose flattened row is not a multiple of 128, and the PBFT ``vc`` ring
+is ``[D, N]``.
+
+Usage: python tools/ring_kernel_bench.py
        python tools/ring_kernel_bench.py --child micro|full  (internal)
 """
 
@@ -25,7 +28,7 @@ import json
 import subprocess
 import time
 
-N = int(_os.environ.get("RINGK_N", "100000"))
+N = int(_os.environ.get("RINGK_N", "102400"))
 TICKS = int(_os.environ.get("RINGK_TICKS", "2100"))
 
 
@@ -44,13 +47,12 @@ def child(which: str) -> None:
     import jax.numpy as jnp
 
     from blockchain_simulator_tpu import runner
-    from blockchain_simulator_tpu.utils.sync import force_sync
 
     if which == "full":
         sim = runner.make_sim_fn(_tick_cfg())
-        force_sync(sim(jax.random.key(1)))
+        jax.block_until_ready(sim(jax.random.key(1)))
         t0 = time.perf_counter()
-        force_sync(sim(jax.random.key(2)))
+        jax.block_until_ready(sim(jax.random.key(2)))
         wall = time.perf_counter() - t0
     else:  # push-only micro: the 3 PBFT add/max channel shapes at this N
         from blockchain_simulator_tpu.ops import ring
@@ -75,14 +77,15 @@ def child(which: str) -> None:
 
             return jax.lax.scan(body, bufs, jnp.arange(TICKS))[0]
 
-        force_sync(run(bufs))
+        jax.block_until_ready(run(bufs))
         t0 = time.perf_counter()
-        force_sync(run(bufs))
+        jax.block_until_ready(run(bufs))
         wall = time.perf_counter() - t0
     print(json.dumps({
         "wall_s": round(wall, 3),
         "us_per_tick": round(wall / TICKS * 1e6, 1),
-        "backend": jax.default_backend(),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
     }), flush=True)
 
 
@@ -120,9 +123,10 @@ def main() -> None:
             out["dus_full"]["wall_s"] / out["pallas_full"]["wall_s"], 2)
     except (TypeError, KeyError, ZeroDivisionError):
         pass
-    path = _os.path.join(_os.path.dirname(_os.path.dirname(
-        _os.path.abspath(__file__))), "ARTIFACT_ring_kernel.json")
-    with open(path, "w") as f:
+    out_dir = _os.path.join(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__))), "chiprun_out")
+    _os.makedirs(out_dir, exist_ok=True)
+    with open(_os.path.join(out_dir, "ring_kernel.json"), "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
 

@@ -1,22 +1,18 @@
 """Roofline analysis of the headline path (models/pbft_round.py).
 
-VERDICT r4 weak-#6: the repo had a roofline for the tick engine's ring pushes
-(ARTIFACT_ring_kernel.json: DUS chain ~75% of the HBM bound) but nothing for
-the round-blocked fast path that carries the 2222 rounds/s headline.  This
-tool answers: what fraction of a v5e's HBM bandwidth / vector FLOP peak does
-the fast path achieve, and how much headroom is left?
+What fraction of the chip's HBM bandwidth / vector FLOP peak does the
+round-blocked fast path achieve, and how much headroom is left?
 
 Method: XLA's own cost analysis of the compiled whole-run executable
 (``jit(sim).lower(key).compile().cost_analysis()`` -> flops, bytes accessed),
 divided by the number of simulated rounds, against the measured wall clock
-per round (same force_sync timing policy as bench.py).  Cost analysis is of
-the executable actually compiled for the backend this runs on — run it on
-the TPU for the headline numbers; the CPU fallback is labeled (fusion
-decisions differ, so CPU-derived bytes are an approximation of the TPU
-program's).
+per round (bench._measure's timing).  Cost analysis is of the executable
+actually compiled for the device this runs on, and the peaks are keyed by
+that device's ``device_kind`` (bench.HBM_BYTES_S): a device without a
+published peak — the CPU included — is an error, not a default.
 
-v5e single-chip peaks (public spec): 819 GB/s HBM BW, 197 TFLOP/s bf16 MXU.
-The round step is [N]-vector int32/f32 elementwise + PRNG work — no matmuls
+v5e single-chip peaks (Google Cloud "TPU v5e"): 819 GB/s HBM BW,
+197 TFLOP/s bf16 MXU.  The round step is [N]-vector int32/f32 elementwise + PRNG work — no matmuls
 — so the relevant ceilings are HBM bytes and VPU flops; we report HBM
 utilization (the binding one for streaming vector code) plus the raw flop
 rate for context.
@@ -29,7 +25,7 @@ numbers pair with ARTIFACT_tick_bench.json's dispatch-arm ratios: this
 tool prices ONE program against the hardware ceilings, tick_bench prices
 the dispatch arms against each other.
 
-Prints one JSON object; run in a fresh child process (KNOWN_ISSUES.md #2).
+Prints one JSON object.
 """
 
 from __future__ import annotations
@@ -42,18 +38,19 @@ import time
 N = int(os.environ.get("ROOFLINE_N", "100000"))
 ROUNDS = int(os.environ.get("ROOFLINE_ROUNDS", "2000"))
 SCHEDULE = os.environ.get("ROOFLINE_SCHEDULE", "round")
-V5E_BF16_FLOPS = 197e12
+BF16_FLOPS = {"TPU v5 lite": 197e12}  # Google Cloud "TPU v5e"
 
 
 def main() -> int:
     import jax
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     os.environ["BENCH_N"] = str(N)  # bench reads its N at import time
-    from bench import V5E_HBM_BYTES_S, _cfg, _measure
+    from bench import _cfg, _measure, hbm_bytes_s
+
+    dev = jax.devices()[0]
+    hbm_peak = hbm_bytes_s(dev.device_kind)
+    flop_peak = BF16_FLOPS[dev.device_kind]
 
     cfg = _cfg(ROUNDS)
     from blockchain_simulator_tpu.runner import make_sim_fn, use_round_schedule
@@ -77,8 +74,6 @@ def main() -> int:
     compiled = jax.jit(sim).lower(key).compile()
     lower_s = time.monotonic() - t0
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns one dict per device program
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
 
@@ -86,22 +81,23 @@ def main() -> int:
     per_round_s = wall / max(rounds_done, 1)
     bytes_per_round = bytes_acc / ROUNDS
     flops_per_round = flops / ROUNDS
-    hbm_util = (bytes_per_round / per_round_s) / V5E_HBM_BYTES_S
+    hbm_util = (bytes_per_round / per_round_s) / hbm_peak
     out = {
         "n": N,
         "rounds": ROUNDS,
         "schedule": SCHEDULE,
-        "backend": jax.default_backend(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "rounds_per_sec": round(value, 2),
         "per_round_us": round(per_round_s * 1e6, 1),
         "xla_bytes_accessed_per_round": round(bytes_per_round),
         "xla_flops_per_round": round(flops_per_round),
         "achieved_GBps": round(bytes_per_round / per_round_s / 1e9, 2),
         "achieved_GFLOPs": round(flops_per_round / per_round_s / 1e9, 2),
-        "v5e_hbm_peak_GBps": V5E_HBM_BYTES_S / 1e9,
+        "hbm_peak_GBps": hbm_peak / 1e9,
         "hbm_utilization": round(hbm_util, 4),
         "flop_utilization_vs_mxu_peak": round(
-            (flops_per_round / per_round_s) / V5E_BF16_FLOPS, 6
+            (flops_per_round / per_round_s) / flop_peak, 6
         ),
         "lower_compile_s": round(lower_s, 1),
         "measure_compile_s": round(compile_s, 1),

@@ -18,13 +18,12 @@ import time
 import jax
 import jax.numpy as jnp
 
-from blockchain_simulator_tpu.utils.sync import force_sync
 
 
 def timed(fn, *args):
-    force_sync(fn(*args))
+    jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
-    force_sync(fn(*args))
+    jax.block_until_ready(fn(*args))
     return time.perf_counter() - t0
 
 

@@ -60,8 +60,9 @@ PR6_FLOOR_RPS = 19.6
 def _force_platform(platform: str | None) -> None:
     if not platform:
         return
-    if "jax" not in _sys.modules:
-        os.environ.setdefault("JAX_PLATFORMS", platform)
+    # the drill's daemon/sweep children inherit its platform through their
+    # environment (the serve daemon has no platform flag of its own)
+    os.environ["JAX_PLATFORMS"] = platform
     import jax
 
     jax.config.update("jax_platforms", platform)

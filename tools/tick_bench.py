@@ -101,10 +101,10 @@ def _norm(rows):
 
 
 def _timed(fn):
-    from blockchain_simulator_tpu.utils.sync import force_sync
+    import jax
 
     t0 = time.perf_counter()
-    out = force_sync(fn())
+    out = jax.block_until_ready(fn())
     return out, time.perf_counter() - t0
 
 

@@ -74,6 +74,18 @@ _NEVER = np.iinfo(np.int32).max
 # replicated-spec and calls finalize after the scan)
 GLOBAL_FIELDS = ("slot_commits", "slot_commit_tick", "slot_propose_tick")
 
+# the phases of :func:`step` as ``jax.named_scope`` names: HLO metadata only
+# (nothing computed changes), so a profiler trace attributes device time to
+# a phase that keeps its name across refactors.  ops/ scopes nest inside.
+SCOPES = (
+    "pbft.tick.pop",
+    "pbft.tick.view_change",
+    "pbft.tick.pre_prepare",
+    "pbft.tick.prepare",
+    "pbft.tick.commit",
+    "pbft.tick.timers",
+)
+
 
 @struct.dataclass
 class PbftState:
@@ -266,441 +278,447 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
     queued = cfg.queued_links and ser > 0
     prop = cfg.link_delay_ms
 
-    # ---- pop this tick's arrivals; crashed nodes process nothing ------------
-    pp_t, pp = ring_pop(bufs.pp, t)
-    prep_t, prep_rt = ring_pop(bufs.prep_rt, t)
-    com_t, commit = ring_pop(bufs.commit, t)
-    vc_t, vc = ring_pop(bufs.vc, t)
-    am = state.alive.astype(jnp.int32)
-    pp_t, prep_t, com_t = pp_t * am[:, None], prep_t * am[:, None], com_t * am[:, None]
-    vc_t = vc_t * am
+    with jax.named_scope("pbft.tick.pop"):
+        # ---- pop this tick's arrivals; crashed nodes process nothing ------------
+        pp_t, pp = ring_pop(bufs.pp, t)
+        prep_t, prep_rt = ring_pop(bufs.prep_rt, t)
+        com_t, commit = ring_pop(bufs.commit, t)
+        vc_t, vc = ring_pop(bufs.vc, t)
+        am = state.alive.astype(jnp.int32)
+        pp_t, prep_t, com_t = pp_t * am[:, None], prep_t * am[:, None], com_t * am[:, None]
+        vc_t = vc_t * am
 
-    # queued mode: this tick's serial-link block deliveries (exact mode is
-    # enforced by runner._reject_cpp_only, so window == slot identity).  A
-    # destination can receive TWO blocks in one tick — a view change frees
-    # the new leader's links while an old-leader block is still backlogged —
-    # so every hit scatters into its own window (same-window collisions are
-    # impossible: exact mode keys windows by slot identity), matching the
-    # C++ engine delivering both events.
-    if queued:
-        hits = state.ppq_tick == t  # [N, Q]
-        vals = jnp.where(hits & state.alive[:, None], state.ppq_val, 0)
-        ppq_tick = jnp.where(hits, _NEVER, state.ppq_tick)
-        oh_arr = (
-            ((vals - 1) % w)[:, :, None] == windows[None, None, :]
-        ) & (vals > 0)[:, :, None]  # [N, Q, W]
-        pp_t = jnp.maximum(
-            pp_t, jnp.max(jnp.where(oh_arr, vals[:, :, None], 0), axis=1)
-        )
-    else:
-        ppq_tick = state.ppq_tick
+        # queued mode: this tick's serial-link block deliveries (exact mode is
+        # enforced by runner._reject_cpp_only, so window == slot identity).  A
+        # destination can receive TWO blocks in one tick — a view change frees
+        # the new leader's links while an old-leader block is still backlogged —
+        # so every hit scatters into its own window (same-window collisions are
+        # impossible: exact mode keys windows by slot identity), matching the
+        # C++ engine delivering both events.
+        if queued:
+            hits = state.ppq_tick == t  # [N, Q]
+            vals = jnp.where(hits & state.alive[:, None], state.ppq_val, 0)
+            ppq_tick = jnp.where(hits, _NEVER, state.ppq_tick)
+            oh_arr = (
+                ((vals - 1) % w)[:, :, None] == windows[None, None, :]
+            ) & (vals > 0)[:, :, None]  # [N, Q, W]
+            pp_t = jnp.maximum(
+                pp_t, jnp.max(jnp.where(oh_arr, vals[:, :, None], 0), axis=1)
+            )
+        else:
+            ppq_tick = state.ppq_tick
 
-    # ---- gossip decode (topology="gossip"): the block-carrying channels
-    # (PRE_PREPARE) and the control channel (VIEW_CHANGE) flood over the k-out
-    # digraph with a hop TTL; votes stay direct unicast — they are 4-byte
-    # packets, and flooding them would need per-sender dedup state (O(N^2)),
-    # defeating the sparse path.  Channel values carry encoded*H + hops_left
-    # (H = gossip_hops+1); a node processes each base value once (first
-    # sighting) but forwards any strictly better TTL copy, so a nearly-expired
-    # first arrival cannot truncate the flood (same scheme as models/paxos.py).
-    gossip = cfg.topology == "gossip"
-    # kregular gather overlay (topo/spec.py): every channel delivers DIRECT
-    # to the circulant in/out neighbor tables through the O(N*K) gather
-    # primitives (ops/gatherdeliv.py) — no relay, no dedup state, and at
-    # degree k = N-1 bit-equal to the dense/full-mesh arms below (the sorted
-    # full-overlay table is the identity, so the same keys draw the same
-    # tensors).  With k below the commit quorum a node can never hear enough
-    # votes — a stalling-but-valid scenario (KNOWN_ISSUES topo note).
-    kreg = cfg.topology == "kregular"
-    nbr_in_loc = nbr_out_loc = None
-    if kreg:
-        # topo_tables=None bakes the tables as trace constants (audit
-        # scale); the sharded programs pass them as operands instead.  In
-        # exchange mode the operands ARE this trace's rows already —
-        # ids=None skips the take that GSPMD would turn into a full-table
-        # all-gather (the retired table-regather debt)
-        nbr_in_loc, nbr_out_loc = gd.local_tables(
-            cfg, None if exchange is not None else ids, tables=topo_tables)
-    seen_pp, seen_vc = state.seen_pp, state.seen_vc
-    pp_fwd = vc_fwd = None
-    nbrs_loc = None
-    if gossip:
-        h_enc = cfg.gossip_hops + 1
-        nbrs_loc = jnp.take(
-            jnp.asarray(topology.kregular_out_neighbors(n, cfg.degree, cfg.seed)),
-            ids, axis=0,
-        )
-        pp_base, pp_hops = pp_t // h_enc, pp_t % h_enc
-        better = (pp_t > seen_pp) & state.alive[:, None]
-        new_base = (pp_base > seen_pp // h_enc) & state.alive[:, None]
-        seen_pp = jnp.maximum(seen_pp, pp_t * better)
-        pp_fwd = (pp_base * h_enc + jnp.maximum(pp_hops - 1, 0)) * (
-            better & (pp_hops > 0)
-        )
-        pp_t = pp_base * new_base  # first sighting processes (value = slot+1)
-        vc_base, vc_hops = vc_t // h_enc, vc_t % h_enc
-        vbetter = (vc_t > seen_vc) & state.alive
-        vnew = (vc_base > seen_vc // h_enc) & state.alive
-        seen_vc = jnp.maximum(seen_vc, vc_t * vbetter)
-        vc_fwd = (vc_base * h_enc + jnp.maximum(vc_hops - 1, 0)) * (
-            vbetter & (vc_hops > 0)
-        )
-        vc_t = vc_base * vnew
-
-    # ---- VIEW_CHANGE arrivals: adopt (v, leader) (pbft-node.cc:271-280) -----
-    has_vc = vc_t > 0
-    v = jnp.where(has_vc, (vc_t - 1) // n, state.v)
-    leader = jnp.where(has_vc, (vc_t - 1) % n, state.leader)
-    if queued:
-        # leadership rotated: the NEW leader's links are vote-only, hence
-        # free (votes never occupy the pipe — ser 0) in both engines; its
-        # busy registers start fresh.  VC arrivals all land before the next
-        # block tick (one-way hi <= interval, enforced by the runner), so
-        # the reset settles strictly between block sends.
-        any_vc = jnp.max(has_vc.astype(jnp.int32))
-        if axis is not None:
-            any_vc = jax.lax.pmax(any_vc, axis)
-        link_busy = jnp.where(any_vc > 0, 0, state.link_busy)
-    else:
-        link_busy = state.link_busy
-
-    # ---- PRE_PREPARE arrivals: evict stale tenant, store, broadcast PREPARE -
-    got_pp = pp_t > 0  # [N, W]  (any arrival re-broadcasts PREPARE — the
-    # reference PRE_PREPARE handler has no dedup, pbft-node.cc:193-211)
-    arr_sid = pp_t - 1  # announced slot id
-    new_tenant = got_pp & (arr_sid > state.slot_id)
-    slot_id = jnp.where(new_tenant, arr_sid, state.slot_id)
-    if exact:
-        # windows ARE slot identities — nothing is ever re-tenanted, so a
-        # learned tenant must not wipe the counters: votes can legitimately
-        # precede the PRE_PREPARE (gossip: direct-unicast COMMITs outrun the
-        # multi-hop block flood; drops: the pp may never come at all) and
-        # were already attributed to this window by identity
-        prepare_vote, commit_vote = state.prepare_vote, state.commit_vote
-        prep_sent, committed_w = state.prep_sent, state.committed_w
-    else:
-        # windowed mode: a higher slot id evicts the stale tenant's state
-        prepare_vote = jnp.where(new_tenant, 0, state.prepare_vote)
-        commit_vote = jnp.where(new_tenant, 0, state.commit_vote)
-        prep_sent = state.prep_sent & ~new_tenant
-        committed_w = state.committed_w & ~new_tenant
-    seen_hi = jnp.max(jnp.where(got_pp, arr_sid + 1, 0), axis=1)
-    next_n = jnp.maximum(state.next_n, seen_hi)
-
-    # PREPARE broadcast → short-circuited round-trip PREPARE_RES replies.
-    # Only honest, alive peers contribute SUCCESS votes (Byzantine nodes flip
-    # their votes to FAILED, which the counter ignores, pbft-node.cc:227).
-    voters = state.alive & state.honest
-    k_rt = chan_key(tkey, Channel.DELAY_ROUNDTRIP)
-    prep_active = got_pp.any(axis=1)
-    got_pp_i = got_pp.astype(jnp.int32)
-    if stat:
-        # fused sample-and-push (ops/delivery.push_roundtrip_reply_counts_
-        # stat): each reply bucket's chain math lands straight in its ring
-        # slice — bit-equal to the unfused sample → expand → ring_push_add
-        # compose, without the [B2, N, W] stacked intermediate.  The gated
-        # fallback returns the ring UNTOUCHED, which is what pushing an
-        # all-zero contribution produced.  The kregular overlay swaps ONLY
-        # the per-sender peer count — a gather over the out-table instead
-        # of total-minus-self — and rides the same fused chain on the same
-        # key (equal counts at k = N-1, hence bit-equal).
+        # ---- gossip decode (topology="gossip"): the block-carrying channels
+        # (PRE_PREPARE) and the control channel (VIEW_CHANGE) flood over the k-out
+        # digraph with a hop TTL; votes stay direct unicast — they are 4-byte
+        # packets, and flooding them would need per-sender dedup state (O(N^2)),
+        # defeating the sparse path.  Channel values carry encoded*H + hops_left
+        # (H = gossip_hops+1); a node processes each base value once (first
+        # sighting) but forwards any strictly better TTL copy, so a nearly-expired
+        # first arrival cannot truncate the flood (same scheme as models/paxos.py).
+        gossip = cfg.topology == "gossip"
+        # kregular gather overlay (topo/spec.py): every channel delivers DIRECT
+        # to the circulant in/out neighbor tables through the O(N*K) gather
+        # primitives (ops/gatherdeliv.py) — no relay, no dedup state, and at
+        # degree k = N-1 bit-equal to the dense/full-mesh arms below (the sorted
+        # full-overlay table is the identity, so the same keys draw the same
+        # tensors).  With k below the commit quorum a node can never hear enough
+        # votes — a stalling-but-valid scenario (KNOWN_ISSUES topo note).
+        kreg = cfg.topology == "kregular"
+        nbr_in_loc = nbr_out_loc = None
         if kreg:
-            n_peers = gd.out_counts(voters, nbr_out_loc, ids, axis, exchange)
-        else:
-            n_voters = voters.astype(jnp.int32).sum()
+            # topo_tables=None bakes the tables as trace constants (audit
+            # scale); the sharded programs pass them as operands instead.  In
+            # exchange mode the operands ARE this trace's rows already —
+            # ids=None skips the take that GSPMD would turn into a full-table
+            # all-gather (the retired table-regather debt)
+            nbr_in_loc, nbr_out_loc = gd.local_tables(
+                cfg, None if exchange is not None else ids, tables=topo_tables)
+        seen_pp, seen_vc = state.seen_pp, state.seen_vc
+        pp_fwd = vc_fwd = None
+        nbrs_loc = None
+        if gossip:
+            h_enc = cfg.gossip_hops + 1
+            nbrs_loc = jnp.take(
+                jnp.asarray(topology.kregular_out_neighbors(n, cfg.degree, cfg.seed)),
+                ids, axis=0,
+            )
+            pp_base, pp_hops = pp_t // h_enc, pp_t % h_enc
+            better = (pp_t > seen_pp) & state.alive[:, None]
+            new_base = (pp_base > seen_pp // h_enc) & state.alive[:, None]
+            seen_pp = jnp.maximum(seen_pp, pp_t * better)
+            pp_fwd = (pp_base * h_enc + jnp.maximum(pp_hops - 1, 0)) * (
+                better & (pp_hops > 0)
+            )
+            pp_t = pp_base * new_base  # first sighting processes (value = slot+1)
+            vc_base, vc_hops = vc_t // h_enc, vc_t % h_enc
+            vbetter = (vc_t > seen_vc) & state.alive
+            vnew = (vc_base > seen_vc // h_enc) & state.alive
+            seen_vc = jnp.maximum(seen_vc, vc_t * vbetter)
+            vc_fwd = (vc_base * h_enc + jnp.maximum(vc_hops - 1, 0)) * (
+                vbetter & (vc_hops > 0)
+            )
+            vc_t = vc_base * vnew
+
+    with jax.named_scope("pbft.tick.view_change"):
+        # ---- VIEW_CHANGE arrivals: adopt (v, leader) (pbft-node.cc:271-280) -----
+        has_vc = vc_t > 0
+        v = jnp.where(has_vc, (vc_t - 1) // n, state.v)
+        leader = jnp.where(has_vc, (vc_t - 1) % n, state.leader)
+        if queued:
+            # leadership rotated: the NEW leader's links are vote-only, hence
+            # free (votes never occupy the pipe — ser 0) in both engines; its
+            # busy registers start fresh.  VC arrivals all land before the next
+            # block tick (one-way hi <= interval, enforced by the runner), so
+            # the reset settles strictly between block sends.
+            any_vc = jnp.max(has_vc.astype(jnp.int32))
             if axis is not None:
-                n_voters = jax.lax.psum(n_voters, axis)
-            n_peers = n_voters - voters.astype(jnp.int32)
-        prep_rt = gated(
-            prep_active.any(),
-            lambda: dv.push_roundtrip_reply_counts_stat(
-                prep_rt, t, rt_lo, k_rt, prep_active,
-                n_peers, rt_probs, drop,
-                axis=axis, mode=smode,
-                # replies are per broadcast, i.e. per active (node, window)
-                expand=lambda c: c[:, None] * got_pp_i,
-            ),
-            prep_rt,
-            axis,
-        )
-    else:
-        rt_counts = gated(
-            prep_active.any(),
-            lambda: (
-                gd.roundtrip_reply_counts_kreg(
-                    k_rt, prep_active, nbr_out_loc, ids, lo, hi, drop,
-                    peer_mask=voters, axis=axis, impl=eimpl, xg=exchange,
-                ) if kreg else dv.roundtrip_reply_counts_dense(
-                    k_rt, prep_active, lo, hi, drop, peer_mask=voters,
-                    axis=axis, impl=eimpl,
-                )
-            ),
-            jnp.zeros((len(rt_probs), n_loc), jnp.int32),
-            axis,
-        )
-        # replies are per broadcast, i.e. per active (node, window)
-        prep_rt = ring_push_add(
-            prep_rt, t, rt_lo, rt_counts[:, :, None] * got_pp_i[None, :, :]
-        )
-
-    # ---- PREPARE_RES arrivals → prepare_vote → COMMIT broadcast -------------
-    pv = prepare_vote + prep_t
-    crossed_p = (prep_t > 0) & (pv >= cfg.pbft_prepare_need)  # pbft-node.cc:231
-    if clean:
-        crossed_p = crossed_p & ~prep_sent
-    prep_sent = prep_sent | crossed_p
-    prepare_vote = jnp.where(crossed_p, 0, pv)  # reset on threshold (quirk #4)
-
-    bt = cfg.pbft_block_interval_ms
-    is_block_tick = (t % bt == 0) & (t > 0)
-    commit_send = crossed_p & (state.alive & state.honest)[:, None]
-    commit_mat = commit_send.astype(jnp.int32)
-    if cfg.faults.byz_forge and cfg.faults.n_byzantine > 0:
-        # Active attack: Byzantine nodes flood COMMIT votes for the
-        # never-proposed last slot (exact mode: window == slot).  Under "n2"
-        # there is no per-sender dedup (quirk #2): every copy of every
-        # re-send lands in the accumulating counter, so f forgers cross any
-        # threshold eventually.  A "2f1" receiver counts at most one vote per
-        # sender *ever*, equivalent to the flood collapsing to a single send.
-        if cfg.quorum_rule == "2f1":
-            fire, copies = jnp.equal(t, bt), 1
+                any_vc = jax.lax.pmax(any_vc, axis)
+            link_busy = jnp.where(any_vc > 0, 0, state.link_busy)
         else:
-            fire, copies = is_block_tick, cfg.faults.byz_copies
-        forgers = (state.alive & ~state.honest).astype(jnp.int32) * jnp.int32(fire)
-        commit_mat = commit_mat.at[:, w - 1].add(forgers * copies)
-    k_cm = chan_key(tkey, Channel.DELAY_BCAST)
-    zeros_w = jnp.zeros((hi - lo, n_loc, w), jnp.int32)
-    if stat:
-        # fused chain-into-ring (see the prep_rt channel above); the
-        # kregular twin gathers the per-(receiver, slot) sender counts
-        # over the in-table instead of totals-minus-own
-        commit = gated(
-            (commit_mat > 0).any(),
-            lambda: (
-                gd.push_bcast_slots_stat_kreg(
-                    commit, t, lo, k_cm, commit_mat, nbr_in_loc, ids,
-                    ow_probs, drop, axis=axis, mode=smode, xg=exchange,
-                ) if kreg else dv.push_bcast_slots_stat(
-                    commit, t, lo, k_cm, commit_mat, ow_probs, drop,
+            link_busy = state.link_busy
+
+    with jax.named_scope("pbft.tick.pre_prepare"):
+        # ---- PRE_PREPARE arrivals: evict stale tenant, store, broadcast PREPARE -
+        got_pp = pp_t > 0  # [N, W]  (any arrival re-broadcasts PREPARE — the
+        # reference PRE_PREPARE handler has no dedup, pbft-node.cc:193-211)
+        arr_sid = pp_t - 1  # announced slot id
+        new_tenant = got_pp & (arr_sid > state.slot_id)
+        slot_id = jnp.where(new_tenant, arr_sid, state.slot_id)
+        if exact:
+            # windows ARE slot identities — nothing is ever re-tenanted, so a
+            # learned tenant must not wipe the counters: votes can legitimately
+            # precede the PRE_PREPARE (gossip: direct-unicast COMMITs outrun the
+            # multi-hop block flood; drops: the pp may never come at all) and
+            # were already attributed to this window by identity
+            prepare_vote, commit_vote = state.prepare_vote, state.commit_vote
+            prep_sent, committed_w = state.prep_sent, state.committed_w
+        else:
+            # windowed mode: a higher slot id evicts the stale tenant's state
+            prepare_vote = jnp.where(new_tenant, 0, state.prepare_vote)
+            commit_vote = jnp.where(new_tenant, 0, state.commit_vote)
+            prep_sent = state.prep_sent & ~new_tenant
+            committed_w = state.committed_w & ~new_tenant
+        seen_hi = jnp.max(jnp.where(got_pp, arr_sid + 1, 0), axis=1)
+        next_n = jnp.maximum(state.next_n, seen_hi)
+
+        # PREPARE broadcast → short-circuited round-trip PREPARE_RES replies.
+        # Only honest, alive peers contribute SUCCESS votes (Byzantine nodes flip
+        # their votes to FAILED, which the counter ignores, pbft-node.cc:227).
+        voters = state.alive & state.honest
+        k_rt = chan_key(tkey, Channel.DELAY_ROUNDTRIP)
+        prep_active = got_pp.any(axis=1)
+        got_pp_i = got_pp.astype(jnp.int32)
+        if stat:
+            # fused sample-and-push (ops/delivery.push_roundtrip_reply_counts_
+            # stat): each reply bucket's chain math lands straight in its ring
+            # slice — bit-equal to the unfused sample → expand → ring_push_add
+            # compose, without the [B2, N, W] stacked intermediate.  The gated
+            # fallback returns the ring UNTOUCHED, which is what pushing an
+            # all-zero contribution produced.  The kregular overlay swaps ONLY
+            # the per-sender peer count — a gather over the out-table instead
+            # of total-minus-self — and rides the same fused chain on the same
+            # key (equal counts at k = N-1, hence bit-equal).
+            if kreg:
+                n_peers = gd.out_counts(voters, nbr_out_loc, ids, axis, exchange)
+            else:
+                n_voters = voters.astype(jnp.int32).sum()
+                if axis is not None:
+                    n_voters = jax.lax.psum(n_voters, axis)
+                n_peers = n_voters - voters.astype(jnp.int32)
+            prep_rt = gated(
+                prep_active.any(),
+                lambda: dv.push_roundtrip_reply_counts_stat(
+                    prep_rt, t, rt_lo, k_rt, prep_active,
+                    n_peers, rt_probs, drop,
                     axis=axis, mode=smode,
-                )
-            ),
-            commit,
-            axis,
-        )
-    else:
-        cm_contrib = gated(
-            (commit_mat > 0).any(),
-            lambda: (
-                gd.bcast_slots_kreg(k_cm, commit_mat, nbr_in_loc, ids, lo,
-                                    hi, drop, axis=axis, impl=eimpl,
-                                    xg=exchange)
-                if kreg else
-                dv.bcast_slots_dense(k_cm, commit_mat, lo, hi, drop,
-                                     axis=axis, impl=eimpl)
-            ),
-            zeros_w,
-            axis,
-        )
-        commit = ring_push_add(commit, t, lo, cm_contrib)
+                    # replies are per broadcast, i.e. per active (node, window)
+                    expand=lambda c: c[:, None] * got_pp_i,
+                ),
+                prep_rt,
+                axis,
+            )
+        else:
+            rt_counts = gated(
+                prep_active.any(),
+                lambda: (
+                    gd.roundtrip_reply_counts_kreg(
+                        k_rt, prep_active, nbr_out_loc, ids, lo, hi, drop,
+                        peer_mask=voters, axis=axis, impl=eimpl, xg=exchange,
+                    ) if kreg else dv.roundtrip_reply_counts_dense(
+                        k_rt, prep_active, lo, hi, drop, peer_mask=voters,
+                        axis=axis, impl=eimpl,
+                    )
+                ),
+                jnp.zeros((len(rt_probs), n_loc), jnp.int32),
+                axis,
+            )
+            # replies are per broadcast, i.e. per active (node, window)
+            prep_rt = ring_push_add(
+                prep_rt, t, rt_lo, rt_counts[:, :, None] * got_pp_i[None, :, :]
+            )
 
-    # ---- COMMIT arrivals → commit_vote → finality ---------------------------
-    cv = commit_vote + com_t
-    crossed_c = (com_t > 0) & (cv >= cfg.pbft_commit_need)  # pbft-node.cc:248
-    if clean:
-        crossed_c = crossed_c & ~committed_w
-    commit_vote = jnp.where(crossed_c, 0, cv)
-    first_commit = crossed_c & ~committed_w
-    committed_w = committed_w | crossed_c
-    block_num = state.block_num + crossed_c.sum(axis=1)
-    # exact mode: an unknown tenant can only be window w itself (identity map)
-    eff_sid = jnp.where(slot_id >= 0, slot_id, windows[None, :] if exact else -1)
-    unattributed = state.unattributed + (first_commit & (eff_sid < 0)).sum(axis=1)
-    slot_commits, slot_commit_tick = _scatter_window_events(
-        state.slot_commits, state.slot_commit_tick, None,
-        first_commit, eff_sid, t, s,
-    )
+    with jax.named_scope("pbft.tick.prepare"):
+        # ---- PREPARE_RES arrivals → prepare_vote → COMMIT broadcast -------------
+        pv = prepare_vote + prep_t
+        crossed_p = (prep_t > 0) & (pv >= cfg.pbft_prepare_need)  # pbft-node.cc:231
+        if clean:
+            crossed_p = crossed_p & ~prep_sent
+        prep_sent = prep_sent | crossed_p
+        prepare_vote = jnp.where(crossed_p, 0, pv)  # reset on threshold (quirk #4)
 
-    # ---- timers: leader block broadcast every 50 ms (SendBlock) -------------
-    # stop at 40 rounds (pbft-node.cc:407). The reference's n_round is
-    # process-global (quirk #10); the per-node analog of global round progress
-    # is the sequence number next_n, so a post-view-change leader continues
-    # the count instead of restarting it.
-    send_block = (
-        is_block_tick
-        & (leader == ids)
-        & (next_n < min(cfg.pbft_max_rounds, s))
-        & state.alive
-    )
-    own_w = next_n % w
-    own_onehot = (windows[None, :] == own_w[:, None]) & send_block[:, None]
-    # the proposer learns its own window's tenant (it never hears its own
-    # PRE_PREPARE); in exact mode the counters survive for the same reason
-    # as at pp arrival above (identity windows — e.g. a post-view-change
-    # leader re-proposing an in-flight slot must not discard its votes)
-    slot_id = jnp.where(own_onehot, next_n[:, None], slot_id)
-    if not exact:
-        prepare_vote = jnp.where(own_onehot, 0, prepare_vote)
-        commit_vote = jnp.where(own_onehot, 0, commit_vote)
-        prep_sent = prep_sent & ~own_onehot
-        committed_w = committed_w & ~own_onehot
-    pp_val = own_onehot.astype(jnp.int32) * (next_n[:, None] + 1)
-    k_pp = chan_key(tkey, Channel.DELAY_BCAST2)
-    if queued:
-        # serial-pipe send (engine.cpp link_enqueue): the packet reaches the
-        # (leader -> j) link after its random scheduling delay d_j - prop,
-        # transmission starts when the link frees, occupies it for ser, then
-        # propagates.  A single block sender is guaranteed (no drops ->
-        # consistent leader beliefs; enforced by runner._reject_cpp_only),
-        # so sender-side scalars globalize with pmax.
-        val_sent = jnp.max(jnp.where(send_block, next_n + 1, 0))
-        sender = jnp.max(jnp.where(send_block, ids, -1))
-        if axis is not None:
-            val_sent = jax.lax.pmax(val_sent, axis)
-            sender = jax.lax.pmax(sender, axis)
-        dest = (val_sent > 0) & (ids != sender)  # crashed peers still get
-        # the packet (C++ bcast sends to all); they ignore it at pop time
-        d_j = jax.random.randint(
-            dv._shard_key(k_pp, axis), (n_loc,), lo, hi, jnp.int32
-        )
-        link_at = t + d_j - prop
-        start = jnp.maximum(link_at, link_busy)
-        delivery = start + ser + prop
-        link_busy = jnp.where(dest, start + ser, link_busy)
-        # enqueue into the first FREE slot (post-pop), never an occupied one:
-        # with the FIFO sized to min(max_rounds, max_slots) the occupancy —
-        # bounded by the serial-pipe backlog divided by ser, plus in-flight
-        # entries — can never fill it, so no undelivered block is ever
-        # silently clobbered (delivery matches on ppq_tick == t, so slot
-        # order is irrelevant)
-        q = ppq_tick.shape[1]
-        free = ppq_tick == _NEVER  # [N, Q]
-        first_free = jnp.argmax(free, axis=1)
-        oh_q = (
-            (jnp.arange(q)[None, :] == first_free[:, None])
-            & dest[:, None]
-            & free
-        )
-        ppq_tick = jnp.where(oh_q, delivery[:, None], ppq_tick)
-        ppq_val = jnp.where(oh_q, val_sent, state.ppq_val)
-    else:
-        ppq_val = state.ppq_val
-    if queued:
-        pass  # blocks already enqueued on the serial pipes; ring untouched
-    elif gossip:
-        # origin injection (TTL = gossip_hops) + this tick's relays, one
-        # flood push over the out-edges; every hop re-serializes the block
-        # (store-and-forward), hence the ser term on each leg
-        h_enc = cfg.gossip_hops + 1
-        origin_enc = (pp_val * h_enc + cfg.gossip_hops) * (pp_val > 0)
-        # the proposer must never process its own announcement (the reference
-        # leader never hears its own PRE_PREPARE); self-loop edges exist in
-        # the random digraph, so mark the origin's copy as already seen
-        seen_pp = jnp.maximum(seen_pp, origin_enc)
-        pp_out = jnp.maximum(origin_enc, pp_fwd)
-        pp_contrib = gated(
-            (pp_out > 0).any(),
-            lambda: dv.gossip_fwd(k_pp, pp_out, nbrs_loc, n, lo, hi, drop,
-                                  axis=axis, impl=eimpl),
-            zeros_w,
-            axis,
-        )
-    elif kreg:
-        pp_contrib = gated(
-            send_block.any(),
-            lambda: (
-                gd.bcast_window_value_max_stat_kreg(
-                    k_pp, pp_val, nbr_in_loc, ow_probs, drop, axis=axis,
-                    xg=exchange)
-                if stat else
-                gd.bcast_window_value_max_kreg(
-                    k_pp, pp_val, nbr_in_loc, ids, lo, hi, drop, axis=axis,
-                    impl=eimpl, xg=exchange)
-            ),
-            zeros_w,
-            axis,
-        )
-    elif stat:
-        pp_contrib = gated(
-            send_block.any(),
-            lambda: dv.bcast_window_value_max_stat(k_pp, pp_val, ow_probs, drop,
-                                                   axis=axis),
-            zeros_w,
-            axis,
-        )
-    else:
-        pp_contrib = gated(
-            send_block.any(),
-            lambda: dv.bcast_window_value_max_dense(k_pp, pp_val, lo, hi, drop,
-                                                    axis=axis, impl=eimpl),
-            zeros_w,
-            axis,
-        )
-    if not queued:
-        pp = ring_push_max(pp, t, lo + ser, pp_contrib)
-    rounds_sent = state.rounds_sent + send_block
-    (slot_propose_tick,) = _scatter_window_events(
-        None, None, state.slot_propose_tick,
-        own_onehot, jnp.where(own_onehot, next_n[:, None], -1), t, s,
-    )
-    next_n = next_n + send_block
-
-    # ---- random view change (P = 1/100 per leader round) --------------------
-    k_u = chan_key(tkey, Channel.VIEW_CHANGE)
-    if axis is not None:
-        k_u = jax.random.fold_in(k_u, jax.lax.axis_index(axis))
-    u = jax.random.randint(k_u, (n_loc,), 0, cfg.pbft_view_change_den)
-    trigger = send_block & (u < cfg.pbft_view_change_num)
-    new_leader = (leader + 1) % n  # rotation (pbft-node.cc:297)
-    new_v = v + 1
-    leader = jnp.where(trigger, new_leader, leader)
-    v = jnp.where(trigger, new_v, v)
-    view_changes = state.view_changes + trigger
-    enc = jnp.where(trigger, new_v * n + new_leader + 1, 0)
-    k_vc = chan_key(tkey, Channel.DELAY_REPLY)
-    zeros_flat = jnp.zeros((hi - lo, n_loc), jnp.int32)
-    if gossip:
-        h_enc = cfg.gossip_hops + 1
-        vc_origin = (enc * h_enc + cfg.gossip_hops) * (enc > 0)
-        seen_vc = jnp.maximum(seen_vc, vc_origin)  # self-loop guard
-        vc_out = jnp.maximum(vc_origin, vc_fwd)
-        vc_contrib = gated(
-            (vc_out > 0).any(),
-            lambda: dv.gossip_fwd(k_vc, vc_out[:, None], nbrs_loc, n, lo, hi,
-                                  drop, axis=axis, impl=eimpl)[:, :, 0],
-            zeros_flat,
-            axis,
-        )
-    elif kreg:
-        vc_contrib = gated(
-            trigger.any(),
-            lambda: (
-                gd.bcast_value_max_stat_kreg(k_vc, enc, nbr_in_loc, ow_probs,
-                                             drop, axis=axis, xg=exchange)
-                if stat else
-                gd.bcast_value_max_kreg(k_vc, trigger, enc, nbr_in_loc, ids,
-                                        lo, hi, drop, axis=axis, impl=eimpl,
+        bt = cfg.pbft_block_interval_ms
+        is_block_tick = (t % bt == 0) & (t > 0)
+        commit_send = crossed_p & (state.alive & state.honest)[:, None]
+        commit_mat = commit_send.astype(jnp.int32)
+        if cfg.faults.byz_forge and cfg.faults.n_byzantine > 0:
+            # Active attack: Byzantine nodes flood COMMIT votes for the
+            # never-proposed last slot (exact mode: window == slot).  Under "n2"
+            # there is no per-sender dedup (quirk #2): every copy of every
+            # re-send lands in the accumulating counter, so f forgers cross any
+            # threshold eventually.  A "2f1" receiver counts at most one vote per
+            # sender *ever*, equivalent to the flood collapsing to a single send.
+            if cfg.quorum_rule == "2f1":
+                fire, copies = jnp.equal(t, bt), 1
+            else:
+                fire, copies = is_block_tick, cfg.faults.byz_copies
+            forgers = (state.alive & ~state.honest).astype(jnp.int32) * jnp.int32(fire)
+            commit_mat = commit_mat.at[:, w - 1].add(forgers * copies)
+        k_cm = chan_key(tkey, Channel.DELAY_BCAST)
+        zeros_w = jnp.zeros((hi - lo, n_loc, w), jnp.int32)
+        if stat:
+            # fused chain-into-ring (see the prep_rt channel above); the
+            # kregular twin gathers the per-(receiver, slot) sender counts
+            # over the in-table instead of totals-minus-own
+            commit = gated(
+                (commit_mat > 0).any(),
+                lambda: (
+                    gd.push_bcast_slots_stat_kreg(
+                        commit, t, lo, k_cm, commit_mat, nbr_in_loc, ids,
+                        ow_probs, drop, axis=axis, mode=smode, xg=exchange,
+                    ) if kreg else dv.push_bcast_slots_stat(
+                        commit, t, lo, k_cm, commit_mat, ow_probs, drop,
+                        axis=axis, mode=smode,
+                    )
+                ),
+                commit,
+                axis,
+            )
+        else:
+            cm_contrib = gated(
+                (commit_mat > 0).any(),
+                lambda: (
+                    gd.bcast_slots_kreg(k_cm, commit_mat, nbr_in_loc, ids, lo,
+                                        hi, drop, axis=axis, impl=eimpl,
                                         xg=exchange)
-            ),
-            zeros_flat,
-            axis,
+                    if kreg else
+                    dv.bcast_slots_dense(k_cm, commit_mat, lo, hi, drop,
+                                         axis=axis, impl=eimpl)
+                ),
+                zeros_w,
+                axis,
+            )
+            commit = ring_push_add(commit, t, lo, cm_contrib)
+
+    with jax.named_scope("pbft.tick.commit"):
+        # ---- COMMIT arrivals → commit_vote → finality ---------------------------
+        cv = commit_vote + com_t
+        crossed_c = (com_t > 0) & (cv >= cfg.pbft_commit_need)  # pbft-node.cc:248
+        if clean:
+            crossed_c = crossed_c & ~committed_w
+        commit_vote = jnp.where(crossed_c, 0, cv)
+        first_commit = crossed_c & ~committed_w
+        committed_w = committed_w | crossed_c
+        block_num = state.block_num + crossed_c.sum(axis=1)
+        # exact mode: an unknown tenant can only be window w itself (identity map)
+        eff_sid = jnp.where(slot_id >= 0, slot_id, windows[None, :] if exact else -1)
+        unattributed = state.unattributed + (first_commit & (eff_sid < 0)).sum(axis=1)
+        slot_commits, slot_commit_tick = _scatter_window_events(
+            state.slot_commits, state.slot_commit_tick, None,
+            first_commit, eff_sid, t, s,
         )
-    elif stat:
-        vc_contrib = gated(
-            trigger.any(),
-            lambda: dv.bcast_value_max_stat(k_vc, enc, ow_probs, drop, axis=axis),
-            zeros_flat,
-            axis,
+
+    with jax.named_scope("pbft.tick.timers"):
+        # ---- timers: leader block broadcast every 50 ms (SendBlock) -------------
+        # stop at 40 rounds (pbft-node.cc:407). The reference's n_round is
+        # process-global (quirk #10); the per-node analog of global round progress
+        # is the sequence number next_n, so a post-view-change leader continues
+        # the count instead of restarting it.
+        send_block = (
+            is_block_tick
+            & (leader == ids)
+            & (next_n < min(cfg.pbft_max_rounds, s))
+            & state.alive
         )
-    else:
-        vc_contrib = gated(
-            trigger.any(),
-            lambda: dv.bcast_value_max_dense(k_vc, trigger, enc, lo, hi, drop,
-                                             axis=axis, impl=eimpl),
-            zeros_flat,
-            axis,
+        own_w = next_n % w
+        own_onehot = (windows[None, :] == own_w[:, None]) & send_block[:, None]
+        # the proposer learns its own window's tenant (it never hears its own
+        # PRE_PREPARE); in exact mode the counters survive for the same reason
+        # as at pp arrival above (identity windows — e.g. a post-view-change
+        # leader re-proposing an in-flight slot must not discard its votes)
+        slot_id = jnp.where(own_onehot, next_n[:, None], slot_id)
+        if not exact:
+            prepare_vote = jnp.where(own_onehot, 0, prepare_vote)
+            commit_vote = jnp.where(own_onehot, 0, commit_vote)
+            prep_sent = prep_sent & ~own_onehot
+            committed_w = committed_w & ~own_onehot
+        pp_val = own_onehot.astype(jnp.int32) * (next_n[:, None] + 1)
+        k_pp = chan_key(tkey, Channel.DELAY_BCAST2)
+        if queued:
+            # serial-pipe send (engine.cpp link_enqueue): the packet reaches the
+            # (leader -> j) link after its random scheduling delay d_j - prop,
+            # transmission starts when the link frees, occupies it for ser, then
+            # propagates.  A single block sender is guaranteed (no drops ->
+            # consistent leader beliefs; enforced by runner._reject_cpp_only),
+            # so sender-side scalars globalize with pmax.
+            val_sent = jnp.max(jnp.where(send_block, next_n + 1, 0))
+            sender = jnp.max(jnp.where(send_block, ids, -1))
+            if axis is not None:
+                val_sent = jax.lax.pmax(val_sent, axis)
+                sender = jax.lax.pmax(sender, axis)
+            dest = (val_sent > 0) & (ids != sender)  # crashed peers still get
+            # the packet (C++ bcast sends to all); they ignore it at pop time
+            d_j = jax.random.randint(
+                dv._shard_key(k_pp, axis), (n_loc,), lo, hi, jnp.int32
+            )
+            link_at = t + d_j - prop
+            start = jnp.maximum(link_at, link_busy)
+            delivery = start + ser + prop
+            link_busy = jnp.where(dest, start + ser, link_busy)
+            # enqueue into the first FREE slot (post-pop), never an occupied one:
+            # with the FIFO sized to min(max_rounds, max_slots) the occupancy —
+            # bounded by the serial-pipe backlog divided by ser, plus in-flight
+            # entries — can never fill it, so no undelivered block is ever
+            # silently clobbered (delivery matches on ppq_tick == t, so slot
+            # order is irrelevant)
+            q = ppq_tick.shape[1]
+            free = ppq_tick == _NEVER  # [N, Q]
+            first_free = jnp.argmax(free, axis=1)
+            oh_q = (
+                (jnp.arange(q)[None, :] == first_free[:, None])
+                & dest[:, None]
+                & free
+            )
+            ppq_tick = jnp.where(oh_q, delivery[:, None], ppq_tick)
+            ppq_val = jnp.where(oh_q, val_sent, state.ppq_val)
+        else:
+            ppq_val = state.ppq_val
+        if queued:
+            pass  # blocks already enqueued on the serial pipes; ring untouched
+        elif gossip:
+            # origin injection (TTL = gossip_hops) + this tick's relays, one
+            # flood push over the out-edges; every hop re-serializes the block
+            # (store-and-forward), hence the ser term on each leg
+            h_enc = cfg.gossip_hops + 1
+            origin_enc = (pp_val * h_enc + cfg.gossip_hops) * (pp_val > 0)
+            # the proposer must never process its own announcement (the reference
+            # leader never hears its own PRE_PREPARE); self-loop edges exist in
+            # the random digraph, so mark the origin's copy as already seen
+            seen_pp = jnp.maximum(seen_pp, origin_enc)
+            pp_out = jnp.maximum(origin_enc, pp_fwd)
+            pp_contrib = gated(
+                (pp_out > 0).any(),
+                lambda: dv.gossip_fwd(k_pp, pp_out, nbrs_loc, n, lo, hi, drop,
+                                      axis=axis, impl=eimpl),
+                zeros_w,
+                axis,
+            )
+        elif kreg:
+            pp_contrib = gated(
+                send_block.any(),
+                lambda: (
+                    gd.bcast_window_value_max_stat_kreg(
+                        k_pp, pp_val, nbr_in_loc, ow_probs, drop, axis=axis,
+                        xg=exchange)
+                    if stat else
+                    gd.bcast_window_value_max_kreg(
+                        k_pp, pp_val, nbr_in_loc, ids, lo, hi, drop, axis=axis,
+                        impl=eimpl, xg=exchange)
+                ),
+                zeros_w,
+                axis,
+            )
+        elif stat:
+            pp_contrib = gated(
+                send_block.any(),
+                lambda: dv.bcast_window_value_max_stat(k_pp, pp_val, ow_probs, drop,
+                                                       axis=axis),
+                zeros_w,
+                axis,
+            )
+        else:
+            pp_contrib = gated(
+                send_block.any(),
+                lambda: dv.bcast_window_value_max_dense(k_pp, pp_val, lo, hi, drop,
+                                                        axis=axis, impl=eimpl),
+                zeros_w,
+                axis,
+            )
+        if not queued:
+            pp = ring_push_max(pp, t, lo + ser, pp_contrib)
+        rounds_sent = state.rounds_sent + send_block
+        (slot_propose_tick,) = _scatter_window_events(
+            None, None, state.slot_propose_tick,
+            own_onehot, jnp.where(own_onehot, next_n[:, None], -1), t, s,
         )
-    vc = ring_push_max(vc, t, lo, vc_contrib)
+        next_n = next_n + send_block
+
+        # ---- random view change (P = 1/100 per leader round) --------------------
+        k_u = chan_key(tkey, Channel.VIEW_CHANGE)
+        if axis is not None:
+            k_u = jax.random.fold_in(k_u, jax.lax.axis_index(axis))
+        u = jax.random.randint(k_u, (n_loc,), 0, cfg.pbft_view_change_den)
+        trigger = send_block & (u < cfg.pbft_view_change_num)
+        new_leader = (leader + 1) % n  # rotation (pbft-node.cc:297)
+        new_v = v + 1
+        leader = jnp.where(trigger, new_leader, leader)
+        v = jnp.where(trigger, new_v, v)
+        view_changes = state.view_changes + trigger
+        enc = jnp.where(trigger, new_v * n + new_leader + 1, 0)
+        k_vc = chan_key(tkey, Channel.DELAY_REPLY)
+        zeros_flat = jnp.zeros((hi - lo, n_loc), jnp.int32)
+        if gossip:
+            h_enc = cfg.gossip_hops + 1
+            vc_origin = (enc * h_enc + cfg.gossip_hops) * (enc > 0)
+            seen_vc = jnp.maximum(seen_vc, vc_origin)  # self-loop guard
+            vc_out = jnp.maximum(vc_origin, vc_fwd)
+            vc_contrib = gated(
+                (vc_out > 0).any(),
+                lambda: dv.gossip_fwd(k_vc, vc_out[:, None], nbrs_loc, n, lo, hi,
+                                      drop, axis=axis, impl=eimpl)[:, :, 0],
+                zeros_flat,
+                axis,
+            )
+        elif kreg:
+            vc_contrib = gated(
+                trigger.any(),
+                lambda: (
+                    gd.bcast_value_max_stat_kreg(k_vc, enc, nbr_in_loc, ow_probs,
+                                                 drop, axis=axis, xg=exchange)
+                    if stat else
+                    gd.bcast_value_max_kreg(k_vc, trigger, enc, nbr_in_loc, ids,
+                                            lo, hi, drop, axis=axis, impl=eimpl,
+                                            xg=exchange)
+                ),
+                zeros_flat,
+                axis,
+            )
+        elif stat:
+            vc_contrib = gated(
+                trigger.any(),
+                lambda: dv.bcast_value_max_stat(k_vc, enc, ow_probs, drop, axis=axis),
+                zeros_flat,
+                axis,
+            )
+        else:
+            vc_contrib = gated(
+                trigger.any(),
+                lambda: dv.bcast_value_max_dense(k_vc, trigger, enc, lo, hi, drop,
+                                                 axis=axis, impl=eimpl),
+                zeros_flat,
+                axis,
+            )
+        vc = ring_push_max(vc, t, lo, vc_contrib)
 
     state = state.replace(
         seen_pp=seen_pp,

@@ -18,6 +18,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from blockchain_simulator_tpu.ops import scopes
+
+_names: list = []
+_scoped = scopes.scoped("ops.delay", _names)
+
 
 def uniform_probs(lo: int, hi: int) -> np.ndarray:
     """Bucket probabilities of U{lo..hi-1}, indexed 0..hi-lo-1 (offset lo)."""
@@ -44,6 +49,7 @@ def _rbg_key(key: jax.Array) -> jax.Array:
     return jax.random.wrap_key_data(jnp.tile(kd, 4)[:4], impl="rbg")
 
 
+@_scoped
 def sample_edge_delays(key: jax.Array, shape, lo: int, hi: int,
                        impl: str = "threefry") -> jax.Array:
     """One delay per edge, in [lo, hi).
@@ -131,6 +137,7 @@ def _fast_normal(key: jax.Array, shape) -> jax.Array:
     return (z.astype(jnp.float32) - 8.0) * 0.5
 
 
+@_scoped
 def binom(key: jax.Array, n: jax.Array, p: float, mode: str = "exact") -> jax.Array:
     """Binomial(n, p) draw (float32 out, same shape as ``n``).
 
@@ -145,6 +152,7 @@ def binom(key: jax.Array, n: jax.Array, p: float, mode: str = "exact") -> jax.Ar
     return jax.random.binomial(key, n, p)
 
 
+@_scoped
 def sample_bucket_counts(key: jax.Array, n: jax.Array, probs: np.ndarray,
                          mode: str = "exact") -> jax.Array:
     """Split ``n`` (int array, any shape) into bucket counts ~ Multinomial(n, probs).
@@ -189,6 +197,7 @@ def sample_bucket_counts(key: jax.Array, n: jax.Array, probs: np.ndarray,
     )
 
 
+@_scoped
 def bucket_count_chain(key: jax.Array, n: jax.Array, probs: np.ndarray,
                        mode: str = "exact"):
     """The conditional-binomial chain behind :func:`sample_bucket_counts`,
@@ -224,3 +233,7 @@ def bucket_count_chain(key: jax.Array, n: jax.Array, probs: np.ndarray,
         yield c
         remaining = remaining - c
         p_left -= pb
+
+
+# every scope above, by name (ops/scopes.py)
+SCOPES = tuple(_names)

@@ -27,12 +27,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from blockchain_simulator_tpu.ops import scopes
 from blockchain_simulator_tpu.ops.delay import (
     binom,
     bucket_count_chain,
     sample_bucket_counts,
     sample_edge_delays,
 )
+
+_names: list = []
+_scoped = scopes.scoped("ops.delivery", _names)
 
 
 def _shard_key(key, axis):
@@ -92,12 +96,14 @@ def _edge_hits(key, send, lo: int, hi: int, drop_prob: float = 0.0, axis=None,
 # --------------------------------------------------------------------------- #
 
 
+@_scoped
 def bcast_counts_dense(key, send, lo, hi, drop_prob=0.0, axis=None,
                        impl="threefry"):
     """Broadcast → per-receiver arrival counts.  Returns [B, N_loc]."""
     return _edge_hits(key, send, lo, hi, drop_prob, axis, impl=impl).sum(1)
 
 
+@_scoped
 def bcast_value_max_dense(key, send, value, lo, hi, drop_prob=0.0, axis=None,
                           impl="threefry"):
     """Broadcast of a per-sender value (>0; 0 = empty), max-combined at the
@@ -107,6 +113,7 @@ def bcast_value_max_dense(key, send, value, lo, hi, drop_prob=0.0, axis=None,
     return (hits * value_g.astype(jnp.int32)[None, :, None]).max(1)
 
 
+@_scoped
 def bcast_slots_dense(key, slot_mat, lo, hi, drop_prob=0.0, axis=None,
                       impl="threefry"):
     """Slot-keyed broadcast (e.g. PBFT messages carrying seq no n): sender i
@@ -126,6 +133,7 @@ def bcast_slots_dense(key, slot_mat, lo, hi, drop_prob=0.0, axis=None,
     return jnp.einsum("bij,is->bjs", hits, slot_g)
 
 
+@_scoped
 def bcast_window_value_max_dense(key, value_mat, lo, hi, drop_prob=0.0, axis=None,
                                  impl="threefry"):
     """Per-window value broadcast (PBFT PRE_PREPARE carrying the slot id):
@@ -143,6 +151,7 @@ def bcast_window_value_max_dense(key, value_mat, lo, hi, drop_prob=0.0, axis=Non
     return (hits[:, :, :, None] * value_g[None, :, None, :]).max(axis=1)
 
 
+@_scoped
 def bcast_window_value_max_stat(key, value_mat, probs: np.ndarray, drop_prob=0.0,
                                 axis=None):
     """Stat version of bcast_window_value_max_dense for few senders per
@@ -168,6 +177,7 @@ def bcast_window_value_max_stat(key, value_mat, probs: np.ndarray, drop_prob=0.0
     return (d[None] == _bucket_iota(0, nb, d.ndim)).astype(jnp.int32) * val[None]
 
 
+@_scoped
 def roundtrip_reply_counts_dense(
     key, send, lo, hi, drop_prob=0.0, peer_mask=None, axis=None,
     impl="threefry",
@@ -213,6 +223,7 @@ def roundtrip_reply_counts_dense(
     ).sum(2)
 
 
+@_scoped
 def unicast_reply_counts_dense(key, reply, lo, hi, drop_prob=0.0, axis=None,
                                impl="threefry"):
     """Route per-(replier, requester) reply counts back to each requester.
@@ -242,6 +253,7 @@ def unicast_reply_counts_dense(key, reply, lo, hi, drop_prob=0.0, axis=None,
     return lax.dynamic_slice_in_dim(out_g, start, n_loc, axis=1)
 
 
+@_scoped
 def bcast_matrix_dense(key, send, value, lo, hi, drop_prob=0.0, axis=None,
                        impl="threefry"):
     """Identity-preserving broadcast for request channels whose handling
@@ -259,6 +271,7 @@ def bcast_matrix_dense(key, send, value, lo, hi, drop_prob=0.0, axis=None,
 # --------------------------------------------------------------------------- #
 
 
+@_scoped
 def bcast_counts_stat(key, n_senders, is_sender, probs: np.ndarray, drop_prob=0.0, axis=None,
                       mode="exact"):
     """Full-mesh broadcast arrival counts without materializing edges.
@@ -295,6 +308,7 @@ def _slots_stat_m(key, slot_mat, drop_prob, axis, mode):
     return k, m
 
 
+@_scoped
 def bcast_slots_stat(key, slot_mat, probs: np.ndarray, drop_prob=0.0, axis=None,
                      mode="exact"):
     """Stat version of bcast_slots_dense: receiver j hears, per slot s,
@@ -304,6 +318,7 @@ def bcast_slots_stat(key, slot_mat, probs: np.ndarray, drop_prob=0.0, axis=None,
     return sample_bucket_counts(k, m, probs, mode)
 
 
+@_scoped
 def bcast_value_max_stat(key, value, probs: np.ndarray, drop_prob=0.0, axis=None):
     """Stat version of bcast_value_max_dense for ≤-a-few senders (e.g. PBFT
     VIEW_CHANGE from the leader): deliver the max announced value to every
@@ -343,6 +358,7 @@ def _roundtrip_stat_m(key, send, n_peers, drop_prob, axis, mode):
     return k, m
 
 
+@_scoped
 def roundtrip_reply_counts_stat(
     key, send, n_peers, rt_probs: np.ndarray, drop_prob=0.0, axis=None, mode="exact"
 ):
@@ -358,6 +374,7 @@ def roundtrip_reply_counts_stat(
 # --------------------------------------------------------------------------- #
 
 
+@_scoped
 def push_bucket_counts(buf, t, push_lo: int, key, m, probs: np.ndarray,
                        mode: str = "exact", expand=None):
     """Sample ``Multinomial(m, probs)`` bucket counts and combine each bucket
@@ -399,6 +416,7 @@ def push_bucket_counts(buf, t, push_lo: int, key, m, probs: np.ndarray,
     return buf
 
 
+@_scoped
 def push_bcast_slots_stat(buf, t, push_lo: int, key, slot_mat,
                           probs: np.ndarray, drop_prob=0.0, axis=None,
                           mode="exact"):
@@ -409,6 +427,7 @@ def push_bcast_slots_stat(buf, t, push_lo: int, key, slot_mat,
     return push_bucket_counts(buf, t, push_lo, k, m, probs, mode)
 
 
+@_scoped
 def push_roundtrip_reply_counts_stat(buf, t, push_lo: int, key, send, n_peers,
                                      rt_probs: np.ndarray, drop_prob=0.0,
                                      axis=None, mode="exact", expand=None):
@@ -424,6 +443,7 @@ def push_roundtrip_reply_counts_stat(buf, t, push_lo: int, key, send, n_peers,
 # --------------------------------------------------------------------------- #
 
 
+@_scoped
 def gossip_fwd(key, fwd_vals, nbrs_loc, n_glob, lo, hi, drop_prob=0.0, axis=None,
                fold=0x0D22, impl="threefry"):
     """TTL-flood forwarding: ``fwd_vals [N_loc, P]`` (>0 TTL-encoded values
@@ -454,3 +474,7 @@ def gossip_fwd(key, fwd_vals, nbrs_loc, n_glob, lo, hi, drop_prob=0.0, axis=None
         start = lax.axis_index(axis) * n_loc
         out = lax.dynamic_slice_in_dim(out, start, n_loc, axis=1)
     return out
+
+
+# every scope above, by name (ops/scopes.py)
+SCOPES = tuple(_names)

@@ -21,7 +21,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from blockchain_simulator_tpu.ops import scopes
 
+_names: list = []
+_scoped = scopes.scoped("ops.ring", _names)
+
+
+@_scoped
 def ring_pop(buf, t):
     """Read and clear the current tick's slice. Returns (slice, buf')."""
     idx = jnp.mod(t, buf.shape[0])
@@ -68,11 +74,17 @@ def _push(buf, t, lo: int, contrib, op: str):
     return buf
 
 
+@_scoped
 def ring_push_add(buf, t, lo: int, contrib):
     """Add ``contrib[b, ...]`` into slices ``t+lo+b``, b in [0, B)."""
     return _push(buf, t, lo, contrib, "add")
 
 
+@_scoped
 def ring_push_max(buf, t, lo: int, contrib):
     """Max-combine (for value channels where 0 == empty)."""
     return _push(buf, t, lo, contrib, "max")
+
+
+# every scope above, by name (ops/scopes.py)
+SCOPES = tuple(_names)

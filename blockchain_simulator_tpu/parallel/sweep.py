@@ -441,18 +441,26 @@ def run_seed_sweep(cfg: SimConfig, seeds, mesh=None):
             raise ValueError(
                 f"{len(seeds)} seeds not divisible by sweep axis size {n_sweep}"
             )
-    keys = jax.vmap(jax.random.key)(jnp.asarray(seeds, jnp.uint32))
-    finals = jax.block_until_ready(_batched_fn(cfg, mesh)(keys))
+    # the sweep layer's three host states, by name, on the profiler's clock
+    # (utils/telemetry.py): operands -> execute -> readback; ``rows`` is the
+    # count of results read back, ``lanes`` the batch axis dispatched
+    rows = len(seeds)
+    with telemetry.span("sweep.operands", rows=rows, lanes=rows):
+        batched = _batched_fn(cfg, mesh)
+        keys = jax.vmap(jax.random.key)(jnp.asarray(seeds, jnp.uint32))
+    with telemetry.span("sweep.execute", rows=rows, lanes=rows):
+        finals = jax.block_until_ready(batched(keys))
     out = []
-    for i, seed in enumerate(seeds):
-        final_i = jax.tree.map(lambda x: x[i], finals)
-        m = sim_metrics(cfg, final_i)
-        # observability routing: a finalized COPY of every sweep row goes to
-        # the optional runs.jsonl ($BLOCKSIM_RUNS_JSONL, utils/obs.py); the
-        # returned dicts stay pure metrics — tests compare them bit-for-bit
-        # against single runs
-        obs.record_run({"seed": int(seed), **m}, cfg)
-        out.append(m)
+    with telemetry.span("sweep.readback", rows=rows, lanes=rows):
+        for i, seed in enumerate(seeds):
+            final_i = jax.tree.map(lambda x: x[i], finals)
+            m = sim_metrics(cfg, final_i)
+            # observability routing: a finalized COPY of every sweep row goes
+            # to the optional runs.jsonl ($BLOCKSIM_RUNS_JSONL, utils/obs.py);
+            # the returned dicts stay pure metrics — tests compare them
+            # bit-for-bit against single runs
+            obs.record_run({"seed": int(seed), **m}, cfg)
+            out.append(m)
     return out
 
 
@@ -502,33 +510,33 @@ def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
     else:
         batched = (obsim_build.probed_batched_fn(canon, probe)
                    if probe is not None else dyn_batched_fn(canon))
-    keys = jax.vmap(jax.random.key)(
-        jnp.asarray([s for _, s in dispatch_points], jnp.uint32)
-    )
-    ops = [_dyn_operands(cfg, cfg.faults) for cfg, _ in dispatch_points]
-    nc = jnp.asarray([o[0] for o in ops], jnp.int32)
-    nb = jnp.asarray([o[1] for o in ops], jnp.int32)
-    # BLOCKSIM_PROFILE arms a jax.profiler capture around the executable
-    # run (utils/telemetry.py; free when disarmed).  A serve flush that
-    # routed here is already inside its own profile_region — the nested
-    # guard skips this one.
-    with telemetry.profile_region("sweep_dispatch"):
+    # the same three spans as run_seed_sweep (children of the sweep.chunk
+    # span where there is one; a batched serve flush carries them too)
+    n_lanes = len(dispatch_points)
+    rows = len(points) if n_out is None else min(n_out, len(points))
+    with telemetry.span("sweep.operands", rows=rows, lanes=n_lanes):
+        keys = jax.vmap(jax.random.key)(
+            jnp.asarray([s for _, s in dispatch_points], jnp.uint32)
+        )
+        ops = [_dyn_operands(cfg, cfg.faults) for cfg, _ in dispatch_points]
+        nc = jnp.asarray([o[0] for o in ops], jnp.int32)
+        nb = jnp.asarray([o[1] for o in ops], jnp.int32)
+    with telemetry.span("sweep.execute", rows=rows, lanes=n_lanes):
         outs = jax.block_until_ready(batched(keys, nc, nb))
     finals, probes = outs if probe is not None else (outs, None)
     out = []
-    if n_out is not None:
-        points = points[:n_out]
-    for i, (cfg_i, seed) in enumerate(points):
-        final_i = jax.tree.map(lambda x: x[i], finals)
-        m = sim_metrics(cfg_i, final_i)
-        if probe is not None:
-            from blockchain_simulator_tpu.obsim import host as obsim_host
+    with telemetry.span("sweep.readback", rows=rows, lanes=n_lanes):
+        for i, (cfg_i, seed) in enumerate(points[:rows]):
+            final_i = jax.tree.map(lambda x: x[i], finals)
+            m = sim_metrics(cfg_i, final_i)
+            if probe is not None:
+                from blockchain_simulator_tpu.obsim import host as obsim_host
 
-            m["probe"] = obsim_host.summarize_lane(cfg_i, probe, probes, i)
-            obsim_host.note_violations(m["probe"], cfg_i, int(seed))
-        if record:
-            obs.record_run({"seed": int(seed), **m}, cfg_i)
-        out.append(m)
+                m["probe"] = obsim_host.summarize_lane(cfg_i, probe, probes, i)
+                obsim_host.note_violations(m["probe"], cfg_i, int(seed))
+            if record:
+                obs.record_run({"seed": int(seed), **m}, cfg_i)
+            out.append(m)
     return out
 
 

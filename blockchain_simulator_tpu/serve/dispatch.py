@@ -80,43 +80,51 @@ def _solo_metrics(req):
     """Run one request through the solo executable; returns its metrics.
     The ``serve.solo_dispatch`` chaos point fires first with the request
     id, so a drill can poison exactly one request (chaos/inject.py).
-    Dispatch stamps (telemetry span synthesis, serve/server._answer) and
-    the ``$BLOCKSIM_PROFILE`` capture bracket the executable run — all
-    host-side, per the telemetry rule."""
+    Dispatch stamps (span synthesis at answer time, serve/server._answer)
+    bracket the whole attempt; inside it three spans name the host's
+    states — ``serve.dispatch.operands`` / ``.execute`` / ``.readback``,
+    children of the request's ``serve.dispatch`` span, whose id is
+    pre-minted here because the server only emits that span at answer
+    time.  All host-side, per the telemetry rule."""
     # stamp BEFORE the chaos point (and fire the point INSIDE the
     # try/finally): a poisoned request that raises at the injection
     # still records a near-zero dispatch-attempt span instead of
     # leaving a stale batched-dispatch stamp — or no stamp at all —
     # behind for the span synthesizer
     req.t_dispatch0 = time.monotonic()
+    req.trace_id = req.trace_id or telemetry.new_trace_id()
+    req.dispatch_span = telemetry.new_span_id()
+    ctx = telemetry.TraceContext(req.trace_id, req.dispatch_span)
     try:
         inject.chaos_point("serve.solo_dispatch", req_id=req.req_id)
-        with telemetry.profile_region("serve_solo"):
+        with telemetry.span("serve.dispatch.operands", ctx=ctx,
+                            id=req.req_id):
             keys, nc, nb = _operands([req])
+            args = (keys[0], nc[0], nb[0])
             if req.probe is not None:
                 # the armed solo twin (consobs-solo registry entry) —
                 # same operands, final state bit-equal under the exact
                 # sampler; the probe summary rides the metrics row
                 from blockchain_simulator_tpu.obsim import build as obsb
-                from blockchain_simulator_tpu.obsim import host as obsh
-                from blockchain_simulator_tpu.obsim import (
-                    schema as obs_schema,
-                )
 
-                final, probes = jax.block_until_ready(
-                    obsb.probed_solo_fn(req.canon, req.probe)(
-                        keys[0], nc[0], nb[0]
-                    )
-                )
-                m = sim_metrics(req.cfg, final)
-                m["probe"] = obs_schema.summarize(req.canon, req.probe,
-                                                  probes)
-                obsh.note_violations(m["probe"], req.cfg, req.seed)
-                return m
-            final = jax.block_until_ready(
-                _solo_fn(req.canon)(keys[0], nc[0], nb[0])
-            )
-        return sim_metrics(req.cfg, final)
+                sim = obsb.probed_solo_fn(req.canon, req.probe)
+            else:
+                sim = _solo_fn(req.canon)
+        with telemetry.span("serve.dispatch.execute", ctx=ctx,
+                            id=req.req_id):
+            out = jax.block_until_ready(sim(*args))
+        with telemetry.span("serve.dispatch.readback", ctx=ctx,
+                            id=req.req_id):
+            if req.probe is None:
+                return sim_metrics(req.cfg, out)
+            from blockchain_simulator_tpu.obsim import host as obsh
+            from blockchain_simulator_tpu.obsim import schema as obs_schema
+
+            final, probes = out
+            m = sim_metrics(req.cfg, final)
+            m["probe"] = obs_schema.summarize(req.canon, req.probe, probes)
+            obsh.note_violations(m["probe"], req.cfg, req.seed)
+            return m
     finally:
         req.t_dispatch1 = time.monotonic()
 
@@ -207,14 +215,13 @@ def run_batch(reqs, max_batch: int, force_solo: bool = False,
         # request access-log records; n_out skips pad-lane metrics
         d0 = time.monotonic()
         try:
-            with telemetry.profile_region("serve_flush"):
-                # the batcher groups on (canon, probe), so one flush is
-                # probe-homogeneous: reqs[0].probe speaks for every lane
-                rows = sweep.run_dyn_points(
-                    canon, [(r.cfg, r.seed) for r in lanes], record=False,
-                    n_out=len(reqs), mesh=mesh, journal=journal,
-                    probe=reqs[0].probe,
-                )
+            # the batcher groups on (canon, probe), so one flush is
+            # probe-homogeneous: reqs[0].probe speaks for every lane
+            rows = sweep.run_dyn_points(
+                canon, [(r.cfg, r.seed) for r in lanes], record=False,
+                n_out=len(reqs), mesh=mesh, journal=journal,
+                probe=reqs[0].probe,
+            )
         finally:
             d1 = time.monotonic()
             for req in reqs:

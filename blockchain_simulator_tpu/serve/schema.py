@@ -212,6 +212,10 @@ class ScenarioRequest:
     # so each query.step span can parent under the serve.request root the
     # server only emits at answer time (None = let emit() mint one)
     root_span: str | None = None
+    # pre-minted id of the serve.dispatch segment: a solo dispatch mints it
+    # so its operands/execute/readback spans (serve/dispatch.py, emitted
+    # mid-flush) parent under the segment emitted at answer time
+    dispatch_span: str | None = None
     t_admit: float = 0.0
     # lifecycle stamps (time.monotonic), filled as the request moves
     # batcher-side; the server synthesizes the segment spans (queue_wait /

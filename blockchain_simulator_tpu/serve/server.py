@@ -560,8 +560,15 @@ class ScenarioServer:
             max_wait = self.max_wait_ms / 1000.0
             timeout = None if not pending else max_wait / 4 if max_wait > 0 \
                 else 0.001
+            # the thread's state by name on the profiler's clock, twin only
+            # (utils/telemetry.py: a 6 ms poll must not evict the flight
+            # ring): idle = nothing pending, blocked on traffic; hold = a
+            # group in hand waiting out max_wait_ms; flush (_flush) is the
+            # third, and the three tile this loop
+            state = "serve.batcher.hold" if pending else "serve.batcher.idle"
             try:
-                item = self._arrivals.get(timeout=timeout)
+                with telemetry.span(state, record=False):
+                    item = self._arrivals.get(timeout=timeout)
             except queue.Empty:
                 item = None
             # drain everything already queued before deciding what is due:
@@ -727,8 +734,11 @@ class ScenarioServer:
             if not (a and b and b >= a):
                 continue
             if name is not None:
+                # a solo dispatch pre-minted its segment's id, so that its
+                # operands/execute/readback children hang off it
+                sid = req.dispatch_span if name == "serve.dispatch" else None
                 telemetry.emit(name, a, b, trace=tid, parent=root,
-                               id=req.req_id, **attrs)
+                               span_id=sid, id=req.req_id, **attrs)
             if hist is not None:
                 ms = (b - a) * 1000.0
                 self._hists[hist].observe(ms)
@@ -803,42 +813,51 @@ class ScenarioServer:
             if not allow:
                 force_solo = True
                 solo_reason = "breaker-solo"
-        results = dispatch.run_batch(
-            reqs, self.max_batch,
-            force_solo=force_solo, solo_reason=solo_reason, mesh=self.mesh,
-            journal=self._journal,
-        )
-        degraded = any(
-            resp.get("batch", {}).get("degraded") for _, resp in results
-        )
-        if breaker is not None and not force_solo:
-            with self._lock:
-                breaker.record(degraded, time.monotonic())
-        with self._lock:
-            self._stats["batches"] += 1
-            if degraded:
-                self._stats["degraded_batches"] += 1
-            b = len(live)
-            self._occupancy[b] = self._occupancy.get(b, 0) + 1
-            self._backoff = self.restart_backoff_s  # the loop is healthy
-        # run_batch answers in submission order, one response per request
-        for (req, fut), (_, resp) in zip(live, results):
-            if resp.get("kind") == schema.DispatchFailedError.kind:
-                # failed SOLO: poison.  Never into a batch again — future
-                # submissions of this id flush as singleton groups, and
-                # the WAL mark keeps the rule across restarts.
+        # the batcher's third state (see _batcher), with the counts at its
+        # boundary: requests in the flush, the padded bucket, the intended
+        # mode (a degrade shows in the answers' batch block, after the fact)
+        batched = len(reqs) >= 2 and not force_solo
+        with telemetry.span(
+                "serve.batcher.flush", record=False, size=len(reqs),
+                bucket=(dispatch.bucket_size(len(reqs), self.max_batch)
+                        if batched else 1),
+                mode="batched" if batched else solo_reason or "solo"):
+            results = dispatch.run_batch(
+                reqs, self.max_batch,
+                force_solo=force_solo, solo_reason=solo_reason, mesh=self.mesh,
+                journal=self._journal,
+            )
+            degraded = any(
+                resp.get("batch", {}).get("degraded") for _, resp in results
+            )
+            if breaker is not None and not force_solo:
                 with self._lock:
-                    fresh = req.req_id not in self._quarantine
-                    if fresh:
-                        self._quarantine.add(req.req_id)
-                        self._stats["quarantined"] += 1
-                if fresh and self._wal is not None:
-                    try:
-                        self._wal.append_quarantine(req.req_id)
-                    except OSError:
-                        pass
-            counter = "served" if resp.get("status") == "ok" else "errors"
-            self._answer(req, fut, resp, counter)
+                    breaker.record(degraded, time.monotonic())
+            with self._lock:
+                self._stats["batches"] += 1
+                if degraded:
+                    self._stats["degraded_batches"] += 1
+                b = len(live)
+                self._occupancy[b] = self._occupancy.get(b, 0) + 1
+                self._backoff = self.restart_backoff_s  # the loop is healthy
+            # run_batch answers in submission order, one response per request
+            for (req, fut), (_, resp) in zip(live, results):
+                if resp.get("kind") == schema.DispatchFailedError.kind:
+                    # failed SOLO: poison.  Never into a batch again — future
+                    # submissions of this id flush as singleton groups, and
+                    # the WAL mark keeps the rule across restarts.
+                    with self._lock:
+                        fresh = req.req_id not in self._quarantine
+                        if fresh:
+                            self._quarantine.add(req.req_id)
+                            self._stats["quarantined"] += 1
+                    if fresh and self._wal is not None:
+                        try:
+                            self._wal.append_quarantine(req.req_id)
+                        except OSError:
+                            pass
+                counter = "served" if resp.get("status") == "ok" else "errors"
+                self._answer(req, fut, resp, counter)
 
     # --------------------------------------------------------------- queries
     def _spawn_query(self, req, fut) -> None:

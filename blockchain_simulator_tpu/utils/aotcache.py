@@ -301,7 +301,8 @@ def enable_xla_cache() -> str:
     environment places it (jax picks that up itself; nothing is set here),
     else the fixed :data:`DEFAULT_XLA_CACHE`.  The entry-size/compile-time
     thresholds are zeroed either way, so a warm process compiles nothing
-    and a second identical run adds no entries.  Config-level only — never
+    and a second identical run adds no entries; HLO metadata (scope names,
+    source lines) is part of the key.  Config-level only — never
     touches a backend; call it before the first compile."""
     import jax
 
@@ -311,6 +312,13 @@ def enable_xla_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the program's jax.named_scope names are HLO metadata, which jax strips
+    # from the cache key by default: an executable cached by a build without
+    # them (or with other names) would then be loaded in place of this
+    # build's, and a profiler trace would show its names, not ours.  With
+    # the metadata in the key a cached executable carries the names of the
+    # source that asks for it.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

@@ -1,9 +1,9 @@
-"""Fleet-wide host-side telemetry: tracing, metrics, flight recorder, profiling.
+"""Fleet-wide host-side telemetry: tracing, metrics, flight recorder.
 
 PR 2's observability layer records what a *simulation* did (probe series,
 manifests, health verdicts).  This module records what the *system around
 the simulations* did — the serving fleet, the batcher, the sweep tier —
-as four host-side primitives every tier shares:
+as host-side primitives every tier shares:
 
 - **Request-scoped tracing.**  A :class:`TraceContext` (``trace_id`` +
   ``span_id``) is minted at router admission (serve/router.py) and at
@@ -14,13 +14,25 @@ as four host-side primitives every tier shares:
   utils/obs.py writer) when armed.  The serving span model (README
   "Telemetry"): ``router.request`` → ``router.send`` → ``serve.request``
   → {``serve.admit``, ``serve.queue_wait``, ``serve.batch_wait``,
-  ``serve.dispatch`` (pad-bucket attrs), ``serve.answer``} — segments
-  tile the request's wall clock, so a span tree accounts for the whole
-  p50 by construction.  :func:`spans_to_chrome_trace` exports spans (and,
-  via utils/trace.chrome_events, a sim probe series) onto ONE
-  Perfetto/Chrome-trace timeline.
-- **Metrics registry.**  Cheap thread-safe counters / gauges /
-  fixed-bucket histograms (:data:`metrics`), exposed as Prometheus text
+  ``serve.dispatch`` (pad-bucket attrs; children ``serve.dispatch.
+  operands`` / ``.execute`` / ``.readback``), ``serve.answer``} —
+  segments tile the request's wall clock, so a span tree accounts for the
+  whole p50 by construction.
+- **The same spans on the profiler's clock.**  This module is the ONE span
+  source: every :func:`span` also opens a ``jax.profiler.TraceAnnotation``
+  of the same name (its *twin*, carrying the span id), so inside any
+  profiler session (``cli --profile``, ``jax.profiler.start_trace``, the
+  benchmark's ``--trace 1``) the program's spans sit on the device
+  trace's timeline by name; with no session the twin is a no-op.  A record
+  and its twin come from one enter/exit, so any pair gives the offset
+  between ``time.monotonic()`` and the trace clock
+  (:func:`trace_clock_offset_ns`), which lays the spans synthesized at
+  answer time by :func:`emit` on that timeline too (:func:`on_trace_clock`).
+  ``span(..., record=False)`` is the twin alone: the batcher's thread
+  states (``serve.batcher.idle`` / ``.hold`` / ``.flush``) turn over every
+  few milliseconds and must not evict the flight ring's post-mortem.
+- **Metrics registry.**  Cheap thread-safe counters / fixed-bucket
+  histograms (:data:`metrics`), exposed as Prometheus text
   (``GET /metrics`` on the serve daemon and the fleet router) and as a
   compact snapshot on the run manifest (utils/obs.py).  Histogram
   percentiles power the ``/stats`` ``latency_ms`` blocks
@@ -30,18 +42,16 @@ as four host-side primitives every tier shares:
   shutdown, crash, supervisor degrade, or chaos invariant violation —
   when ``$BLOCKSIM_FLIGHT_DIR`` names a directory (unset = ring only,
   no file I/O).
-- **Profiling hooks.**  ``BLOCKSIM_PROFILE=<dir>`` arms
-  :func:`profile_region` — a ``jax.profiler.trace`` capture around
-  dispatch flushes (serve/dispatch.py) and sweep chunks
-  (parallel/sweep.py).  Disarmed it is one dict read and a predicted
-  branch, mirroring chaos/inject.py's pattern.
 
 HARD RULE (the host-sync-in-traced rule's telemetry corollary, enforced
-by tests/test_ztelemetry.py): every call into this module is host-side
-only.  Spans, counters and profile regions must never appear inside
-jitted/vmapped/scanned code — a span's ``time`` calls are host syncs, and
-traced code already has its own observability (utils/trace.py probe
-series).  Models and ops never import this module.
+by tests/test_zztelemetry.py): every call into this module is host-side
+only.  Spans and counters must never appear inside jitted/vmapped/scanned
+code — a span's ``time`` calls are host syncs.  Traced code names its own
+work with ``jax.named_scope`` directly (models/pbft.py, models/
+pbft_round.py, ops/scopes.py: HLO metadata, read from the same profiler
+trace) and has utils/trace.py probe series.  Models and ops never import
+this module; this module never imports jax (a router process stays
+jax-free: the twin is found through ``sys.modules``).
 
 Telemetry must never take down the thing it observes: every file write
 is swallowed on failure, and :func:`FlightRecorder.dump` with no armed
@@ -54,6 +64,8 @@ import contextlib
 import itertools
 import json
 import os
+import statistics
+import sys
 import threading
 import time
 import uuid
@@ -77,9 +89,6 @@ FLIGHT_ENV = "BLOCKSIM_FLIGHT_DIR"
 # must not fill the disk with them
 FLIGHT_KEEP_ENV = "BLOCKSIM_FLIGHT_KEEP"
 FLIGHT_KEEP_DEFAULT = 32
-
-# jax.profiler capture directory; unset = profile_region is free.
-PROFILE_ENV = "BLOCKSIM_PROFILE"
 
 TELEMETRY_SCHEMA = 1
 
@@ -219,32 +228,85 @@ def emit(name: str, t0: float, t1: float | None = None,
     return sid
 
 
+def _twin(name: str, attrs: dict):
+    """The profiler twin of a span, as a context manager: a
+    ``jax.profiler.TraceAnnotation`` named like the span, its attrs as the
+    event's stats.  jax is looked up, never imported (a process that has not
+    imported jax — the fleet router — has no profiler to annotate); outside
+    a profiler session the annotation is a no-op."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    try:
+        return jax.profiler.TraceAnnotation(
+            name, **{k: v for k, v in attrs.items() if v is not None})
+    except Exception:  # a failing profiler must never break the spanned code
+        return contextlib.nullcontext()
+
+
 @contextlib.contextmanager
-def span(name: str, ctx: TraceContext | None = None, **attrs):
+def span(name: str, ctx: TraceContext | None = None, record: bool = True,
+         **attrs):
     """Open/close one span around a block: child of ``ctx`` (or the
     thread's current context; a fresh trace when neither exists), set as
     the thread's current context inside the block — so nested spans and
     outbound HTTP headers (serve/router.py ``_http``) pick it up.  An
     escaping exception marks ``status="error"`` and re-raises.  Yields
-    the span's own :class:`TraceContext`."""
+    the span's own :class:`TraceContext`.
+
+    Every span has a twin in the profiler's trace (:func:`_twin`) that
+    carries ``<trace id>:<span id>`` as its ``span`` stat (the header form:
+    never all digits, so the profiler keeps it a string).  ``record=False``
+    is the twin ALONE — no record, no flight-ring entry, no context change,
+    yields None — for thread states that turn over every few milliseconds."""
+    if not record:
+        with _twin(name, attrs):
+            yield None
+        return
     parent = ctx if ctx is not None else current()
     tid = parent.trace_id if parent is not None else new_trace_id()
     sid = new_span_id()
     mine = TraceContext(tid, sid)
     prev = current()
     _tls.ctx = mine
-    t0 = time.monotonic()
     status = "ok"
+    t0 = t1 = time.monotonic()
     try:
-        yield mine
+        with _twin(name, {"span": mine.header(), **attrs}):
+            t0 = time.monotonic()
+            try:
+                yield mine
+            finally:
+                t1 = time.monotonic()
     except BaseException:
         status = "error"
         raise
     finally:
         _tls.ctx = prev
-        emit(name, t0, time.monotonic(), trace=tid,
+        emit(name, t0, t1, trace=tid,
              parent=parent.span_id if parent is not None else None,
              span_id=sid, status=status, **attrs)
+
+
+def trace_clock_offset_ns(records, twins: dict) -> float | None:
+    """The nanoseconds to add to a span record's ``ts`` (seconds, this
+    process's wall-anchored monotonic clock) to land on a profiler trace's
+    clock.  ``records`` are span records (a :func:`capture` buffer, the
+    flight ring, the span log); ``twins`` maps span id → the start, in the
+    trace's nanoseconds, of the annotation that carries it as its ``span``
+    stat.  Median over every pair found; None without one."""
+    diffs = [twins[r["id"]] - r["ts"] * 1e9 for r in records
+             if r.get("id") in twins]
+    return statistics.median(diffs) if diffs else None
+
+
+def on_trace_clock(rec: dict, offset_ns: float) -> tuple[float, float]:
+    """``(start_ns, end_ns)`` of a span record on the trace's clock: what
+    places the segments :func:`emit` synthesizes at answer time
+    (``serve.queue_wait``, ``serve.batch_wait``; they have no twin) on the
+    device trace's timeline."""
+    start = rec["ts"] * 1e9 + offset_ns
+    return start, start + rec["dur_ms"] * 1e6
 
 
 # --------------------------------------------------------------- metrics ---
@@ -273,22 +335,6 @@ class Counter:
     def inc(self, n: float = 1.0) -> None:
         with self._lock:
             self.value += n
-
-
-class Gauge:
-    """Last-write-wins gauge."""
-
-    __slots__ = ("name", "labels", "_lock", "value")
-
-    def __init__(self, name: str, labels: dict, lock: threading.Lock):
-        self.name = name
-        self.labels = labels
-        self._lock = lock
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        with self._lock:
-            self.value = float(v)
 
 
 class Histogram:
@@ -385,9 +431,6 @@ class MetricsRegistry:
     def counter(self, name: str, **labels) -> Counter:
         return self._get(Counter, name, labels)
 
-    def gauge(self, name: str, **labels) -> Gauge:
-        return self._get(Gauge, name, labels)
-
     def histogram(self, name: str, bounds=DEFAULT_MS_BUCKETS,
                   **labels) -> Histogram:
         return self._get(Histogram, name, labels, bounds=bounds)
@@ -428,19 +471,17 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
     def snapshot(self) -> dict:
-        """Compact JSON-able view: counters/gauges by ``name{labels}``,
+        """Compact JSON-able view: counters by ``name{labels}``,
         histograms as {count, sum, p50, p95, p99} — the flight-recorder
         dump and ARTIFACT_telemetry.json payload, and the delta source
         for chaos/invariants.check_telemetry."""
-        out: dict = {"counters": {}, "gauges": {}, "histograms": {}}
+        out: dict = {"counters": {}, "histograms": {}}
         with self._lock:
             instruments = list(self._instruments.values())
         for inst in instruments:
             key = inst.name + _label_str(inst.labels)
             if isinstance(inst, Counter):
                 out["counters"][key] = inst.value
-            elif isinstance(inst, Gauge):
-                out["gauges"][key] = inst.value
             else:
                 out["histograms"][key] = {
                     "count": inst.count, "sum": round(inst.sum, 3),
@@ -646,99 +687,3 @@ def reset() -> None:
     touch installed sinks or thread-local contexts."""
     metrics.reset()
     flight.reset()
-
-
-# -------------------------------------------------------------- profiling ---
-
-_profile_seq = itertools.count()
-_profile_active = threading.local()
-
-
-@contextlib.contextmanager
-def profile_region(name: str):
-    """``jax.profiler`` capture around one host-side region (a dispatch
-    flush, a sweep chunk) into ``$BLOCKSIM_PROFILE/<name>-<k>``.
-
-    Disarmed (env unset — the only state tests and serving see unless an
-    operator arms it): one dict read, zero jax imports.  Armed: one
-    capture directory per region instance, viewable in TensorBoard's
-    profile plugin or ui.perfetto.dev.  Nested regions (a serve flush
-    inside a profiled sweep chunk) skip the inner capture —
-    ``jax.profiler.trace`` does not nest.  Profiler failures are
-    swallowed: profiling must never take down the dispatch it measures.
-    """
-    d = os.environ.get(PROFILE_ENV)
-    if not d or getattr(_profile_active, "on", False):
-        yield
-        return
-    logdir = os.path.join(d, f"{name}-{next(_profile_seq)}")
-    try:
-        import jax
-
-        cm = jax.profiler.trace(logdir)
-        cm.__enter__()
-    except Exception:
-        yield
-        return
-    _profile_active.on = True
-    try:
-        yield
-    finally:
-        _profile_active.on = False
-        try:
-            cm.__exit__(None, None, None)
-        except Exception:
-            pass  # a failing profiler must never take down the dispatch
-
-
-# ----------------------------------------------------------- trace export ---
-
-
-def spans_to_chrome_trace(spans, path, series: dict | None = None,
-                          name: str = "telemetry") -> dict:
-    """Export span records (+ optionally one sim probe series) as a
-    single Chrome-trace/Perfetto JSON timeline.
-
-    Spans become complete events ("ph": "X") grouped one thread row per
-    trace (so a request's segment tree reads left-to-right on its own
-    row), timestamped on the shared wall clock.  ``series`` (a
-    utils/trace.py probe series dict) is overlaid through
-    ``trace.chrome_events`` as counter tracks in a second process — the
-    "serving spans and sim probe series on ONE timeline" recipe (README
-    "Telemetry").  Returns ``{"events", "path"}``."""
-    events: list[dict] = [
-        {"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
-         "args": {"name": name}},
-    ]
-    tids: dict[str, int] = {}
-    for rec in spans:
-        if rec.get("kind") != "span":
-            continue
-        trace_id = str(rec.get("trace"))
-        tid = tids.get(trace_id)
-        if tid is None:
-            tid = tids[trace_id] = len(tids) + 1
-            events.append({
-                "name": "thread_name", "ph": "M", "pid": 1, "tid": tid,
-                "args": {"name": f"trace {trace_id}"},
-            })
-        args = dict(rec.get("attrs") or {})
-        args["span_id"] = rec.get("id")
-        if rec.get("parent"):
-            args["parent"] = rec.get("parent")
-        if rec.get("status") != "ok":
-            args["status"] = rec.get("status")
-        events.append({
-            "name": rec.get("name"), "ph": "X", "pid": 1, "tid": tid,
-            "ts": int(float(rec.get("ts", 0.0)) * 1e6),
-            "dur": max(int(float(rec.get("dur_ms", 0.0)) * 1000.0), 1),
-            "args": args,
-        })
-    if series is not None:
-        from blockchain_simulator_tpu.utils import trace as trace_mod
-
-        events.extend(trace_mod.chrome_events(series, name="sim", pid=0))
-    doc = {"traceEvents": events, "displayTimeUnit": "ms"}
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return {"events": len(events), "path": str(path)}

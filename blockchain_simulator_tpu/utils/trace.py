@@ -431,10 +431,7 @@ MAX_COUNTER_SAMPLES = 2000
 def chrome_events(series: dict, name: str = "sim", pid: int = 0,
                   ) -> list[dict]:
     """The Chrome-trace event list of one probe series dict — the body of
-    :func:`to_chrome_trace`, exposed so utils/telemetry.py can overlay a
-    sim series and serving spans on ONE timeline
-    (``telemetry.spans_to_chrome_trace(series=...)``).  ``pid`` namespaces
-    the process row so the overlay's span process stays separate."""
+    :func:`to_chrome_trace`.  ``pid`` namespaces the process row."""
     ts_map = np.asarray(series["t"]) if "t" in series else None
     events: list[dict] = [
         {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,

@@ -66,13 +66,12 @@ def test_span_log_file_armed_by_env(tmp_path, monkeypatch):
 # ---------------------------------------------------------------- metrics
 
 
-def test_metrics_registry_counter_gauge_histogram_and_exposition():
+def test_metrics_registry_counter_histogram_and_exposition():
     reg = telemetry.MetricsRegistry()
     c = reg.counter("x_total", kind="a")
     assert reg.counter("x_total", kind="a") is c  # get-or-create identity
     c.inc()
     c.inc(2)
-    reg.gauge("g").set(7)
     h = reg.histogram("lat_ms")
     for v in (3, 7, 40, 900):
         h.observe(v)
@@ -122,13 +121,6 @@ def test_flight_recorder_ring_bounded_and_dump(tmp_path, monkeypatch):
     monkeypatch.setenv(telemetry.FLIGHT_ENV, str(tmp_path))
     path = fr.dump("shutdown")
     assert path and os.path.exists(path) and "shutdown" in path
-
-
-def test_profile_region_disarmed_is_free(monkeypatch):
-    monkeypatch.delenv(telemetry.PROFILE_ENV, raising=False)
-    with telemetry.profile_region("x"):
-        ran = True
-    assert ran
 
 
 # ----------------------------------------------------------- log rotation
@@ -394,29 +386,220 @@ def test_no_telemetry_call_site_in_traced_code():
                     "is host-side-telemetry-free by rule")
 
 
-def test_spans_to_chrome_trace_merges_series(tmp_path):
-    import numpy as np
+# ------------------------------------- spans on the profiler's clock; scopes
 
-    spans = [
-        {"kind": "span", "name": "serve.request", "trace": "t1",
-         "id": "aa", "parent": None, "ts": 100.0, "dur_ms": 12.5,
-         "status": "ok", "attrs": {"id": "r1"}},
-        {"kind": "span", "name": "serve.dispatch", "trace": "t1",
-         "id": "bb", "parent": "aa", "ts": 100.002, "dur_ms": 9.0,
-         "status": "ok"},
+
+def _host_events(trace_dir):
+    """Every host event of a captured trace as ``(name, start_ns, dur_ns,
+    stats, thread)`` (jax.profiler.ProfileData alone)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for i, line in enumerate(plane.lines):
+            out.extend((e.name, e.start_ns, e.duration_ns, dict(e.stats), i)
+                       for e in line.events
+                       if e.name.startswith(("t.", "serve.", "sweep.")))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """ONE short profiler session (tens of ms of work, every program warmed
+    before it) over: two recorded spans and an annotation-only one, two lone
+    requests through a ScenarioServer, one run_seed_sweep.  Returns the span
+    records, the flight ring's new entries and the trace's host events."""
+    import time
+
+    import jax
+
+    from blockchain_simulator_tpu.parallel.sweep import run_seed_sweep
+    from blockchain_simulator_tpu.serve import ScenarioServer
+    from blockchain_simulator_tpu.utils.config import SimConfig
+
+    cfg = SimConfig(protocol="pbft", n=8, sim_ms=200, stat_sampler="exact")
+    seeds = [11, 12, 13]
+    trace_dir = tmp_path_factory.mktemp("profile")
+    with ScenarioServer(max_batch=2, max_wait_ms=2.0) as srv:
+        srv.request(dict(TPL, seed=1), wait_s=300)  # warm: the solo program
+        run_seed_sweep(cfg, seeds)                  # warm: the 3-lane program
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        telemetry.flight.reset()
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            with telemetry.capture() as spans:
+                with telemetry.span("t.first", rows=3):
+                    time.sleep(0.004)
+                with telemetry.span("t.state", record=False, size=2):
+                    time.sleep(0.002)
+                with telemetry.span("t.second"):
+                    time.sleep(0.006)
+                for i in (2, 3):
+                    r = srv.request(dict(TPL, seed=i, id=f"tr-{i}"),
+                                    wait_s=300)
+                    assert r["status"] == "ok"
+                rows = run_seed_sweep(cfg, seeds)
+        finally:
+            jax.profiler.stop_trace()
+    assert len(rows) == len(seeds)
+    return {"spans": list(spans), "flight": telemetry.flight.snapshot(),
+            "events": _host_events(trace_dir), "seeds": seeds}
+
+
+def test_span_and_its_twin_agree_on_the_trace_clock(traced):
+    recs = {r["name"]: r for r in traced["spans"] if r["name"].startswith("t.")}
+    twins = {}
+    for name, start, dur, stats, _ in traced["events"]:
+        if "span" in stats:
+            trace_id, _, sid = str(stats["span"]).partition(":")
+            twins[sid] = (name, start, dur, trace_id)
+    # the twin carries the span's name, trace and attrs
+    name, _, _, trace_id = twins[recs["t.first"]["id"]]
+    assert name == "t.first" and trace_id == recs["t.first"]["trace"]
+    first = next(e for e in traced["events"] if e[0] == "t.first")
+    assert first[3]["rows"] == 3
+    # ONE conversion, from any pair, places every record on the trace clock
+    offset = telemetry.trace_clock_offset_ns(
+        [recs["t.first"]], {k: v[1] for k, v in twins.items()})
+    for rec in traced["spans"]:
+        if rec["id"] not in twins:
+            continue  # synthesized at answer time: no twin
+        a, b = telemetry.on_trace_clock(rec, offset)
+        _, start, dur, _ = twins[rec["id"]]
+        assert abs(a - start) < 1e6, rec["name"]          # within 1 ms
+        assert abs((b - a) - dur) < 1e6, rec["name"]
+    assert telemetry.trace_clock_offset_ns([], {}) is None
+
+
+def test_annotation_only_span_leaves_no_record(traced):
+    assert "t.state" in {e[0] for e in traced["events"]}  # the twin is there
+    assert "t.state" not in {r["name"] for r in traced["spans"]}
+    ring = {r.get("name") for r in traced["flight"]}
+    assert "t.first" in ring and "t.state" not in ring
+    assert not any(n and n.startswith("serve.batcher.") for n in ring)
+    state = next(e for e in traced["events"] if e[0] == "t.state")
+    assert state[3] == {"size": 2}  # no span id either
+
+
+def test_solo_dispatch_children_tile_their_parent(traced):
+    spans = traced["spans"]
+    parents = [s for s in spans if s["name"] == "serve.dispatch"
+               and s["attrs"]["id"].startswith("tr-")]
+    assert len(parents) == 2
+    for p in parents:
+        kids = [s for s in spans if s.get("parent") == p["id"]
+                and s["trace"] == p["trace"]]
+        assert [k["name"] for k in kids] == [
+            "serve.dispatch.operands", "serve.dispatch.execute",
+            "serve.dispatch.readback"]
+        total = sum(k["dur_ms"] for k in kids)
+        assert p["dur_ms"] * 0.85 <= total <= p["dur_ms"] * 1.02 + 0.05
+        # the segment stays a child of the request root: the root-level
+        # tiling is untouched
+        root = next(s for s in spans if s["id"] == p["parent"])
+        assert root["name"] == "serve.request"
+
+
+def test_batcher_states_tile_the_batcher_thread(traced):
+    states = sorted((e for e in traced["events"]
+                     if e[0].startswith("serve.batcher.")),
+                    key=lambda e: e[1])
+    assert {e[0] for e in states} == {
+        "serve.batcher.idle", "serve.batcher.hold", "serve.batcher.flush"}
+    assert len({e[4] for e in states}) == 1  # one thread
+    for a, b in zip(states, states[1:]):
+        assert a[1] + a[2] <= b[1] + 1e3  # in sequence, never nested
+    covered = sum(e[2] for e in states)
+    span = states[-1][1] + states[-1][2] - states[0][1]
+    assert covered >= 0.97 * span
+    flushes = [e for e in states if e[0] == "serve.batcher.flush"]
+    assert len(flushes) == 2
+    assert all(e[3] == {"size": 1, "bucket": 1, "mode": "solo"}
+               for e in flushes)
+    # a flush holds its request's dispatch
+    execs = [e for e in traced["events"]
+             if e[0] == "serve.dispatch.execute"]
+    assert len(execs) == 2
+    for f, x in zip(flushes, sorted(execs, key=lambda e: e[1])):
+        assert f[1] <= x[1] and x[1] + x[2] <= f[1] + f[2]
+
+
+def test_seed_sweep_emits_operands_execute_readback_with_rows(traced):
+    n = len(traced["seeds"])
+    for rec, name in zip(
+            [s for s in traced["spans"] if s["name"].startswith("sweep.")],
+            ["sweep.operands", "sweep.execute", "sweep.readback"]):
+        assert rec["name"] == name
+        assert rec["attrs"] == {"rows": n, "lanes": n}
+    twins = [e for e in traced["events"] if e[0].startswith("sweep.")]
+    assert [e[0] for e in sorted(twins, key=lambda e: e[1])] == [
+        "sweep.operands", "sweep.execute", "sweep.readback"]
+    assert all(e[3]["rows"] == n for e in twins)
+
+
+def _all_scopes():
+    from blockchain_simulator_tpu.models import pbft, pbft_round
+    from blockchain_simulator_tpu.ops import delay, delivery, ring
+
+    return (pbft_round.SCOPES + pbft.SCOPES + delivery.SCOPES
+            + delay.SCOPES + ring.SCOPES)
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    """The op_name metadata of the engines' lowered programs (nothing is
+    compiled or run): the pbft tick engine on per-edge, stat and gossip
+    delivery, the pbft round engine, and the raft and paxos tick engines
+    for the delivery ops only they call; one op no engine calls is lowered
+    alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from blockchain_simulator_tpu import runner
+    from blockchain_simulator_tpu.ops import delay, delivery
+    from blockchain_simulator_tpu.utils.config import SimConfig
+
+    cfgs = [
+        SimConfig(protocol="pbft", n=8, sim_ms=200),
+        SimConfig(protocol="pbft", n=8, sim_ms=200, delivery="stat",
+                  schedule="tick"),
+        SimConfig(protocol="pbft", n=8, sim_ms=200, delivery="stat",
+                  schedule="round", model_serialization=False),
+        SimConfig(protocol="pbft", n=16, sim_ms=200, topology="gossip",
+                  degree=4),
+        SimConfig(protocol="raft", n=8, sim_ms=200),
+        SimConfig(protocol="raft", n=8, sim_ms=200, delivery="stat",
+                  schedule="tick"),
+        SimConfig(protocol="paxos", n=8, sim_ms=200),
     ]
-    series = {"commits": np.asarray([0, 1, 2, 2])}
-    out = tmp_path / "trace.json"
-    rec = telemetry.spans_to_chrome_trace(spans, str(out), series=series)
-    doc = json.loads(out.read_text())
-    evs = doc["traceEvents"]
-    xs = [e for e in evs if e.get("ph") == "X"]
-    assert {e["name"] for e in xs} == {"serve.request", "serve.dispatch"}
-    # both rows of one trace share a tid; the series rides pid 0
-    assert len({e["tid"] for e in xs}) == 1
-    assert any(e.get("ph") == "C" and e["pid"] == 0 for e in evs)
-    assert any(e.get("ph") == "i" for e in evs)  # commit instants
-    assert rec["events"] == len(evs)
+    texts = [jax.jit(runner.make_sim_fn(c)).lower(jax.random.key(0))
+             .as_text(debug_info=True) for c in cfgs]
+    probs = delay.uniform_probs(3, 6)
+    texts.append(jax.jit(
+        lambda k, m: delivery.bcast_slots_stat(k, m, probs)
+    ).lower(jax.random.key(0), jnp.ones((8, 4), jnp.int32))
+        .as_text(debug_info=True))
+    return texts
+
+
+@pytest.mark.parametrize("scope", _all_scopes())
+def test_lowered_programs_carry_the_scope(scope, lowered_programs):
+    """Every name in the SCOPES tuples is on the op_name path of some
+    operation of a lowered program (HLO metadata: nothing computed
+    changes), as a whole path component."""
+    assert any(f"{scope}/" in text for text in lowered_programs), scope
+    if scope.startswith("pbft.tick."):
+        assert f"{scope}/" in lowered_programs[0]
+    if scope.startswith("pbft.round."):
+        assert f"{scope}/" in lowered_programs[2]
 
 
 def test_telemetry_report_quick_cli(tmp_path):

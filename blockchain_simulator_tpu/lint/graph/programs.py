@@ -340,6 +340,7 @@ def build_catalog() -> list[ProgramSpec]:
             import jax
 
             from blockchain_simulator_tpu import runner
+            from blockchain_simulator_tpu.models.base import lane_vmap
 
             cfg = cfgs[base_arm]
             cfg = cfg.with_(faults=_dc.replace(cfg.faults, **fc_kw))
@@ -347,7 +348,7 @@ def build_catalog() -> list[ProgramSpec]:
             # must come out identical, which is exactly what the
             # registry-key-divergence rule asserts.  Per-call jit is fine:
             # audit builds trace once and never execute.
-            fn = jax.jit(jax.vmap(runner.make_dyn_sim_fn(cfg)))  # jaxlint: disable=static-arg-recompile-hazard
+            fn = jax.jit(lane_vmap(runner.make_dyn_sim_fn(cfg)))  # jaxlint: disable=static-arg-recompile-hazard
             return fn, (_keys_sds(2), _i32_sds((2,)), _i32_sds((2,)))
 
         return ProgramSpec(name, "sweep-batched-dynf", build,

@@ -54,13 +54,56 @@ def sim_metrics(cfg, final) -> dict:
     return get_protocol(cfg.protocol).metrics(cfg, final)
 
 
+# the batch axis of the single-device vmapped programs (seed sweeps, fault
+# sweeps, the server's buckets), bound by :func:`lane_vmap` alone.  Mesh
+# arms and the shard vmaps of models/mixed.py leave their batch axis unnamed.
+LANES_AXIS = "lanes"
+
+# :func:`gated`'s own work under a lane batch, as a ``jax.named_scope`` (HLO
+# metadata, ops/scopes.py): the predicate's lane reduction and the per-lane
+# select of the taken arm.
+GATE_SCOPE = "ops.gate.any_lane"
+SCOPES = (GATE_SCOPE,)
+
+
+def lane_vmap(fn):
+    """``jax.vmap(fn)`` with the lane axis named, so that :func:`gated`,
+    traced inside, can see that it runs under a lane batch."""
+    return jax.vmap(fn, axis_name=LANES_AXIS)
+
+
+def _under_lanes() -> bool:
+    try:
+        jax.lax.axis_size(LANES_AXIS)
+    except NameError:
+        return False
+    return True
+
+
 def gated(pred, fn, zeros, axis=None):
     """Skip a delivery computation when no sender is active this tick.
     Sharded, the predicate must be globally agreed (the branch contains
-    collectives), so it is pmax-reduced over the mesh axis first."""
+    collectives), so it is pmax-reduced over the mesh axis first.
+
+    Under a lane batch (:func:`lane_vmap`) a cond on a per-lane predicate
+    would lower to a select, both arms run for every lane on every tick.
+    There the branch is taken on "any lane active", which is unbatched, so
+    the cond stays a cond; inside the taken arm each lane keeps ``fn()``
+    where its own predicate holds and ``zeros`` elsewhere, which is what the
+    select gave.  A tick on which no lane is active touches nothing."""
     if axis is not None:
         pred = jax.lax.pmax(pred.astype(jnp.int32), axis) > 0
-    return jax.lax.cond(pred, fn, lambda: zeros)
+    if not _under_lanes():
+        return jax.lax.cond(pred, fn, lambda: zeros)
+    with jax.named_scope(GATE_SCOPE):
+        any_lane = jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
+
+    def taken():
+        out = fn()
+        with jax.named_scope(GATE_SCOPE):
+            return jax.tree.map(lambda o, z: jnp.where(pred, o, z), out, zeros)
+
+    return jax.lax.cond(any_lane, taken, lambda: zeros)
 
 
 def fault_masks(cfg, n: int):

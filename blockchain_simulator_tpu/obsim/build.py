@@ -182,7 +182,7 @@ def probed_batched_fn(cfg, pcfg: schema.ProbeConfig, multi_seed: bool = False):
     fn = make_probed_dyn_sim_fn(cfg, pcfg)
     if multi_seed:
         return jax.jit(partition.seq_map(fn))
-    return jax.jit(jax.vmap(fn))
+    return jax.jit(base_model.lane_vmap(fn))
 
 
 @aotcache.cached_factory("consobs-mesh")

@@ -50,7 +50,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from blockchain_simulator_tpu.chaos import inject
-from blockchain_simulator_tpu.models.base import canonical_fault_cfg, sim_metrics
+from blockchain_simulator_tpu.models.base import (
+    canonical_fault_cfg,
+    lane_vmap,
+    sim_metrics,
+)
 from blockchain_simulator_tpu.parallel import journal as journal_mod
 from blockchain_simulator_tpu.parallel import partition
 from blockchain_simulator_tpu.parallel.mesh import NODES_AXIS, SWEEP_AXIS
@@ -71,9 +75,11 @@ def _batched_fn(cfg: SimConfig, mesh=None):
     """Jitted ``batched(keys) -> finals`` for one (cfg, mesh): registry-
     cached so repeated sweeps of one config reuse the compiled program
     instead of building a fresh jit wrapper per call (jaxlint
-    static-arg-recompile-hazard; runner.make_sim_fn convention)."""
+    static-arg-recompile-hazard; runner.make_sim_fn convention).  On one
+    device the batch is a lane batch (models/base.lane_vmap: ``gated``
+    stays a conditional); over a mesh it is not."""
     if mesh is None:
-        return jax.jit(jax.vmap(make_sim_fn(cfg)))
+        return jax.jit(lane_vmap(make_sim_fn(cfg)))
     from blockchain_simulator_tpu.parallel.shard import make_sharded_sim_fn
 
     return jax.jit(
@@ -88,8 +94,10 @@ def dyn_batched_fn(cfg: SimConfig):
     canonical; one registry entry per fault structure).  Public: the
     scenario server's micro-batched dispatch (serve/dispatch.py) rides the
     same registry entry as the sweeps, so a sweep warms the server and
-    vice versa."""
-    return jax.jit(jax.vmap(make_dyn_sim_fn(cfg)))
+    vice versa.  A lane batch (models/base.lane_vmap): lanes differ in
+    fault level, so ``gated`` takes an arm when any lane is active and
+    selects per lane inside it."""
+    return jax.jit(lane_vmap(make_dyn_sim_fn(cfg)))
 
 
 # back-compat alias (pre-serve name; lint/graph/programs.py and external
@@ -434,7 +442,11 @@ def run_seed_sweep(cfg: SimConfig, seeds, mesh=None):
     # that vmap lowers to a select: both branches run for the whole batch,
     # so a batched round-schedule raft sweep costs about one tick-engine
     # pass (the fallback branch continues the prefix carry, it does not
-    # restart), never more.
+    # restart), never more.  The tick engines' gated deliveries are NOT
+    # such a select on one device: the batch binds the lane axis
+    # (models/base.lane_vmap) and ``gated`` branches on "any lane active",
+    # so a tick on which no lane broadcasts skips the arm as a lone run
+    # does.  Over a mesh the batch axis stays unnamed and they are selects.
     if mesh is not None:
         n_sweep = mesh.shape[SWEEP_AXIS]
         if len(seeds) % n_sweep != 0:

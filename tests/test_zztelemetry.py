@@ -546,11 +546,11 @@ def test_seed_sweep_emits_operands_execute_readback_with_rows(traced):
 
 
 def _all_scopes():
-    from blockchain_simulator_tpu.models import pbft, pbft_round
+    from blockchain_simulator_tpu.models import base, pbft, pbft_round
     from blockchain_simulator_tpu.ops import delay, delivery, ring
 
     return (pbft_round.SCOPES + pbft.SCOPES + delivery.SCOPES
-            + delay.SCOPES + ring.SCOPES)
+            + delay.SCOPES + ring.SCOPES + base.SCOPES)
 
 
 @pytest.fixture(scope="module")
@@ -559,7 +559,8 @@ def lowered_programs():
     compiled or run): the pbft tick engine on per-edge, stat and gossip
     delivery, the pbft round engine, and the raft and paxos tick engines
     for the delivery ops only they call; one op no engine calls is lowered
-    alone."""
+    alone; and the lane-batched pbft tick program, where ``gated`` does
+    work of its own."""
     import jax
     import jax.numpy as jnp
 
@@ -587,6 +588,11 @@ def lowered_programs():
         lambda k, m: delivery.bcast_slots_stat(k, m, probs)
     ).lower(jax.random.key(0), jnp.ones((8, 4), jnp.int32))
         .as_text(debug_info=True))
+    from blockchain_simulator_tpu.parallel import sweep
+
+    texts.append(sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
+        jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
+    ).as_text(debug_info=True))
     return texts
 
 

@@ -54,9 +54,9 @@ def sim_metrics(cfg, final) -> dict:
     return get_protocol(cfg.protocol).metrics(cfg, final)
 
 
-# the batch axis of the single-device vmapped programs (seed sweeps, fault
-# sweeps, the server's buckets), bound by :func:`lane_vmap` alone.  Mesh
-# arms and the shard vmaps of models/mixed.py leave their batch axis unnamed.
+# the batch axis of the single-device vmapped programs (seed and fault sweeps,
+# the server's buckets) and of the raft shards in models/mixed.step, bound by
+# :func:`lane_vmap` alone.  Mesh arms leave their batch axis unnamed.
 LANES_AXIS = "lanes"
 
 # :func:`gated`'s own work under a lane batch, as a ``jax.named_scope`` (HLO

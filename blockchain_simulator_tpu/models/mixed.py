@@ -4,13 +4,17 @@
 heartbeat replication *internally*, while a cross-shard PBFT instance over the
 ``S`` shard representatives finalizes global blocks.  This is the hierarchical
 composition named in BASELINE.json ("256 Raft shards × 1k nodes with
-cross-shard PBFT finality") — a capability with no reference counterpart (the
-reference runs exactly one protocol per compiled binary, SURVEY.md §1).
+cross-shard PBFT finality") — a capability with no upstream counterpart
+(upstream runs exactly one protocol per compiled binary, SURVEY.md §1); its
+plain reference is the per-message ``benchmark/reference/mixed_engine.py``
+(tests/test_zzmixed_reference.py, tests/test_zzmixed_cell.py).
 
 Composition is pure function reuse, the payoff of the protocol-backend API
-(models/base.py): the Raft backend's ``step`` is ``jax.vmap``-ed over the
-shard axis (every leaf ``[m, ...]`` → ``[S, m, ...]``, per-shard PRNG streams
-via ``fold_in(shard)``), and the PBFT backend runs unchanged over ``S``
+(models/base.py): the Raft backend's ``step`` is vmapped over the shard
+axis (every leaf ``[m, ...]`` → ``[S, m, ...]``, per-shard PRNG streams via
+``fold_in(shard)``) as a lane batch (``base.lane_vmap``: its ``gated``
+deliveries branch on "any shard active" instead of lowering to selects),
+and the PBFT backend runs unchanged over ``S``
 virtual nodes whose ``alive`` mask is recomputed *every tick* as "shard has an
 elected leader" — a shard only participates in cross-shard consensus while
 its Raft layer is healthy.  Faults (crash/Byzantine/drop) apply within each
@@ -34,8 +38,42 @@ import numpy as np
 from flax import struct
 
 from blockchain_simulator_tpu.models import pbft, raft
+from blockchain_simulator_tpu.models.base import lane_vmap
 from blockchain_simulator_tpu.utils import prng
 from blockchain_simulator_tpu.utils.config import FaultConfig
+
+
+# where a run's device time goes, as ``jax.named_scope`` names (HLO metadata
+# only — see models/pbft.SCOPES).  ``mixed.tick.*`` are the parts of one
+# :func:`step` and sit under ``mixed.prefix`` (the election-phase scan) or
+# ``mixed.fallback`` (the per-tick continuation when a shard failed the
+# handoff: zero device time on a sound run, which is how a trace shows which
+# arm of ``scan_fast``'s cond ran); ``raft.tick.*`` / ``pbft.tick.*`` / ``ops.*``
+# nest inside under their own names.
+SCOPES = (
+    "mixed.prefix",
+    "mixed.tick.raft_shards",
+    "mixed.tick.membership",
+    "mixed.tick.finality",
+    "mixed.handoff",
+    "mixed.steady.raft_hb",
+    "mixed.steady.finality",
+    "mixed.fallback",
+)
+
+# what :func:`metrics` reports of WHEN things happened, in ticks (= ms): the
+# last shard's election, the last raft commit past its leader's election,
+# the first global proposal, the last global commit, and the global view
+# changes (a run with one is not held to an undisturbed reference's global
+# times).  The plain reference (benchmark/reference/mixed_engine.py) yields
+# the same keys; a caller that holds a run to it asks for this tuple first.
+MILESTONES = (
+    "leader_elected_ms_max",
+    "raft_commit_tail_ms_max",
+    "global_first_propose_ms",
+    "global_last_commit_ms",
+    "global_view_changes",
+)
 
 
 @struct.dataclass
@@ -54,10 +92,11 @@ def sub_configs(cfg):
     """(raft_cfg for one m-node shard, pbft_cfg over S representatives).
 
     The RAFT sub-config resolves ``stat_sampler="auto"`` at the PARENT scale
-    (cfg.n = S·m), not the shard size: under the shard vmap, ``gated()``
-    branches lower to select — every shard pays the sampler on every tick —
-    and the auto heuristic's n >= 4096 cutoff is about total per-tick
-    sampler work.  At config-5 scale (256k rows) this swaps the ~40-pass
+    (cfg.n = S·m), not the shard size: under the shard batch a taken
+    ``gated()`` arm runs for every shard (``step`` branches on "any shard
+    active" and selects per shard inside the arm), and the auto
+    heuristic's n >= 4096 cutoff is about total per-tick sampler work.
+    At config-5 scale (256k rows) this swaps the ~40-pass
     BTRS exact binomial for the ~6-pass normal approximation in all 256
     shards (the approximation error is O(1/sqrt(count)) per bucket —
     negligible at 1k-node shards), a severalfold cut in the per-tick cost
@@ -105,21 +144,27 @@ def step(cfg, state: MixedState, bufs: MixedBufs, t, tkey):
     rcfg, pcfg = sub_configs(cfg)
     s_loc = state.raft.block_num.shape[0]  # local shard rows
     base = 0 if axis is None else jax.lax.axis_index(axis) * s_loc
-    shard_keys = jax.vmap(lambda i: jax.random.fold_in(tkey, 0x0C0C + base + i))(
-        jnp.arange(s_loc)
-    )
-    r_state, r_bufs = jax.vmap(
-        functools.partial(raft.step, rcfg, t=t)
-    )(state.raft, bufs.raft, tkey=shard_keys)
-    # cross-shard membership: a representative is alive iff its shard
-    # currently has an elected, alive leader
-    has_leader = (r_state.is_leader & r_state.alive).any(axis=1)
-    if axis is not None:
-        has_leader = jax.lax.all_gather(has_leader, axis, tiled=True)
-    p_state = state.pbft.replace(alive=has_leader)
-    p_state, p_bufs = pbft.step(
-        pcfg, p_state, bufs.pbft, t, jax.random.fold_in(tkey, 0x9B9B)
-    )
+    with jax.named_scope("mixed.tick.raft_shards"):
+        shard_keys = jax.vmap(
+            lambda i: jax.random.fold_in(tkey, 0x0C0C + base + i)
+        )(jnp.arange(s_loc))
+        # the shard batch is a lane batch: raft.step's gated deliveries
+        # branch on "any shard active" (models/base.gated) instead of
+        # lowering to selects that every shard pays on every tick
+        r_state, r_bufs = lane_vmap(
+            functools.partial(raft.step, rcfg, t=t)
+        )(state.raft, bufs.raft, tkey=shard_keys)
+    with jax.named_scope("mixed.tick.membership"):
+        # cross-shard membership: a representative is alive iff its shard
+        # currently has an elected, alive leader
+        has_leader = (r_state.is_leader & r_state.alive).any(axis=1)
+        if axis is not None:
+            has_leader = jax.lax.all_gather(has_leader, axis, tiled=True)
+        p_state = state.pbft.replace(alive=has_leader)
+    with jax.named_scope("mixed.tick.finality"):
+        p_state, p_bufs = pbft.step(
+            pcfg, p_state, bufs.pbft, t, jax.random.fold_in(tkey, 0x9B9B)
+        )
     return MixedState(raft=r_state, pbft=p_state), MixedBufs(raft=r_bufs, pbft=p_bufs)
 
 
@@ -155,11 +200,15 @@ def prefix_handoff(cfg, state, bufs, key):
         st, bf = step(cfg, st, bf, t, prng.tick_key(key, t))
         return (st, bf), ()
 
-    carry, _ = jax.lax.scan(tick_body, (state, bufs), jnp.arange(t_e))
-    ok_s, h_s = jax.vmap(lambda st: raft_hb.handoff(rcfg, st))(carry[0].raft)
-    bad = (~ok_s).sum()
-    if axis is not None:
-        bad = jax.lax.psum(bad, axis)
+    with jax.named_scope("mixed.prefix"):
+        carry, _ = jax.lax.scan(tick_body, (state, bufs), jnp.arange(t_e))
+    with jax.named_scope("mixed.handoff"):
+        ok_s, h_s = jax.vmap(
+            lambda st: raft_hb.handoff(rcfg, st)
+        )(carry[0].raft)
+        bad = (~ok_s).sum()
+        if axis is not None:
+            bad = jax.lax.psum(bad, axis)
     return carry, bad == 0, h_s
 
 
@@ -182,21 +231,22 @@ def fast_finish(cfg, carry, h_s, key, with_probe: bool = False):
     # per-shard steady-scan streams key on the GLOBAL shard id, so the
     # sharded run is bit-identical to the single-device run (the same
     # convention as step's per-tick shard keys)
-    hb_keys = jax.vmap(
-        lambda i: jax.random.fold_in(key, 0x4BB7 + base + i)
-    )(jnp.arange(s_loc))
-    if with_probe:
-        res, raft_ys = jax.vmap(
-            lambda k, hh: raft_hb.steady_scan(rcfg, k, hh, with_probe=True)
-        )(hb_keys, h_s)
-    else:
-        res = jax.vmap(
-            lambda k, hh: raft_hb.steady_scan(rcfg, k, hh)
-        )(hb_keys, h_s)
-        raft_ys = None
-    raft_final = jax.vmap(
-        lambda rst, hh, r: raft_hb.materialize(rcfg, rst, hh, r)
-    )(st.raft, h_s, res)
+    with jax.named_scope("mixed.steady.raft_hb"):
+        hb_keys = jax.vmap(
+            lambda i: jax.random.fold_in(key, 0x4BB7 + base + i)
+        )(jnp.arange(s_loc))
+        if with_probe:
+            res, raft_ys = jax.vmap(
+                lambda k, hh: raft_hb.steady_scan(rcfg, k, hh, with_probe=True)
+            )(hb_keys, h_s)
+        else:
+            res = jax.vmap(
+                lambda k, hh: raft_hb.steady_scan(rcfg, k, hh)
+            )(hb_keys, h_s)
+            raft_ys = None
+        raft_final = jax.vmap(
+            lambda rst, hh, r: raft_hb.materialize(rcfg, rst, hh, r)
+        )(st.raft, h_s, res)
     ones = jnp.ones((s,), bool)
 
     def p_body(pcarry, t):
@@ -214,10 +264,11 @@ def fast_finish(cfg, carry, h_s, key, with_probe: bool = False):
         )
         return (ps, pb), ys
 
-    (p_state, _), pbft_ys = jax.lax.scan(
-        p_body, (st.pbft, bf.pbft),
-        t_e + jnp.arange(max(cfg.ticks - t_e, 0)),
-    )
+    with jax.named_scope("mixed.steady.finality"):
+        (p_state, _), pbft_ys = jax.lax.scan(
+            p_body, (st.pbft, bf.pbft),
+            t_e + jnp.arange(max(cfg.ticks - t_e, 0)),
+        )
     final = MixedState(raft=raft_final, pbft=p_state)
     return (final, (raft_ys, pbft_ys)) if with_probe else final
 
@@ -258,6 +309,7 @@ def scan_fast(cfg, state: MixedState, bufs: MixedBufs, key):
     def fast_branch(carry):
         return fast_finish(cfg, carry, h_s, key)
 
+    @jax.named_scope("mixed.fallback")
     def tick_branch(carry):
         (st, _), _ = jax.lax.scan(
             tick_body, carry, t_e + jnp.arange(max(cfg.ticks - t_e, 0))
@@ -281,7 +333,24 @@ def metrics(cfg, state: MixedState) -> dict:
     shard_blocks = np.where(
         has_leader, block_num[np.arange(s), lead_idx], 0
     )
+    # MILESTONES: times that do not depend on the random stream beyond a
+    # tick or two.  Per shard the last block's commit past its leader's
+    # election is the proposal delay + the heartbeat schedule + one ack
+    # round trip; -1 where no shard has one
+    lead_tick = leader_tick[np.arange(s), lead_idx]
+    block_tick = np.asarray(state.raft.block_tick)[np.arange(s), lead_idx]
+    done = has_leader & (shard_blocks > 0)
+    tails = block_tick.max(axis=1)[done] - lead_tick[done]
     pm = pbft.metrics(pcfg, state.pbft)
+    proposed = np.asarray(state.pbft.slot_propose_tick)
+    proposed = proposed[proposed < pbft._NEVER]
+    milestones = dict(zip(MILESTONES, (
+        float(lead_tick[has_leader].max()) if has_leader.any() else -1.0,
+        float(tails.max()) if tails.size else -1.0,
+        float(proposed.min()) if proposed.size else -1.0,
+        pm["last_commit_ms"],
+        pm["view_changes"],
+    ), strict=True))
     return {
         "protocol": "mixed",
         "n": cfg.n,
@@ -294,4 +363,5 @@ def metrics(cfg, state: MixedState) -> dict:
         "global_rounds_sent": pm["rounds_sent"],
         "global_mean_ttf_ms": pm["mean_time_to_finality_ms"],
         "agreement_ok": pm["agreement_ok"],
+        **milestones,
     }

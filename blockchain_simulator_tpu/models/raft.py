@@ -79,6 +79,19 @@ from blockchain_simulator_tpu.utils.prng import Channel, chan_key
 # traces.
 DISARM = np.int32(1 << 30)
 
+# the phases of :func:`step` as ``jax.named_scope`` names, after its own
+# section comments (HLO metadata only — see models/pbft.SCOPES); ops/ scopes
+# nest inside, and under models/mixed.py these sit below ``mixed.tick.*``
+SCOPES = (
+    "raft.tick.pop",
+    "raft.tick.heartbeat_rx",
+    "raft.tick.vote_rx",
+    "raft.tick.vote_reply_rx",
+    "raft.tick.ack_rx",
+    "raft.tick.timer_vote",
+    "raft.tick.timer_heartbeat",
+)
+
 
 @struct.dataclass
 class RaftState:
@@ -237,698 +250,705 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
     # is never busy and queued == constant-latency, so the plain path runs
     queued = cfg.queued_links and ser > 0
 
-    # ---- pop arrivals; crashed nodes process nothing ------------------------
-    vreq_t, vreq = ring_pop(bufs.vreq, t)
-    ok_t, vres_ok = ring_pop(bufs.vres_ok, t)
-    no_t, vres_no = ring_pop(bufs.vres_no, t)
-    plain_t, hb_plain = ring_pop(bufs.hb_plain, t)
-    prop_t, hb_prop = ring_pop(bufs.hb_prop, t)
-    hbok_t, hb_ok = ring_pop(bufs.hb_ok, t)
-    hbbad_t, hb_bad = ring_pop(bufs.hb_bad, t)
-    am = state.alive.astype(jnp.int32)
-    ok_t, no_t = ok_t * am, no_t * am
-    plain_t, prop_t = plain_t * am, prop_t * am
-    hbok_t, hbbad_t = hbok_t * am, hbbad_t * am
-    hbtot_t = hbok_t + hbbad_t
-    if stat:
-        vreq_t = vreq_t * am
-    else:
-        vreq_t = vreq_t * am[:, None]
-
-    # ---- gossip decode (topology="gossip"): the three broadcast channels
-    # (VOTE_REQ, plain HEARTBEAT, proposal HEARTBEAT) flood over the k-out
-    # digraph with a hop TTL; replies (votes, proposal acks) stay direct
-    # unicast to the decoded originator — the same overlay as models/paxos.py.
-    # Flood values are time-monotone encodings (dedup by per-channel ``seen``
-    # register): vreq (t+1)*n + cand + 1; plain hb t+1; proposal
-    # (t+1)*(n+1) + leader + 1 (the +1 keeps 0 = empty).  A node processes
-    # each base value once (first sighting) but forwards any strictly better
-    # TTL copy, so a nearly-expired first arrival cannot truncate the flood.
-    gossip = cfg.topology == "gossip"
-    # kregular gather overlay (topo/spec.py + ops/gatherdeliv.py): every
-    # channel delivers DIRECT over the circulant in/out tables — broadcasts
-    # reach out-neighbors, replies gather back requester-side through the
-    # inslot cross-index (scatter-free) — O(N*K) per tick, bit-equal to the
-    # dense arms at degree k = N-1.  A candidate only ever hears its
-    # in-neighbors' votes, so elections need k >= majority_need - 1 to be
-    # winnable (stalling below that is a valid modeled outcome).
-    kreg = cfg.topology == "kregular"
-    nbr_in_loc = nbr_out_loc = inslot_loc = None
-    if kreg:
-        # exchange mode: operands are already this trace's rows (ids=None
-        # pass-through — re-taking a sharded operand would regather it)
-        nbr_in_loc, nbr_out_loc, inslot_loc = gd.local_tables(
-            cfg, None if exchange is not None else ids, inslot=True,
-            tables=topo_tables)
-    seen_vreq, seen_hb, seen_prop = state.seen_vreq, state.seen_hb, state.seen_prop
-    vreq_fwd = hb_fwd = prop_fwd = None
-    nbrs_loc = None
-    if gossip:
-        h_enc = cfg.gossip_hops + 1
-        nbrs_loc = jnp.take(
-            jnp.asarray(topology.kregular_out_neighbors(n, cfg.degree, cfg.seed)),
-            ids, axis=0,
-        )
-
-        def _decode(arr, seen):
-            base, hops = arr // h_enc, arr % h_enc
-            new = (base > seen // h_enc) & state.alive
-            better = (arr > seen) & state.alive
-            seen = jnp.maximum(seen, arr * better)
-            fwd = (base * h_enc + jnp.maximum(hops - 1, 0)) * (better & (hops > 0))
-            return base * new, seen, fwd
-
-        vreq_t, seen_vreq, vreq_fwd = _decode(vreq_t, seen_vreq)
-        plain_t, seen_hb, hb_fwd = _decode(plain_t, seen_hb)
-        prop_t, seen_prop, prop_fwd = _decode(prop_t, seen_prop)
-
-    # ---- heartbeat arrivals (follower side, raft-node.cc:170-193) -----------
-    got_hb = (plain_t > 0) | (prop_t > 0)
-    if gossip:
-        # proposal value = the leader id riding the flood encoding
-        m_value = jnp.where(prop_t > 0, (prop_t - 1) % (n + 1), state.m_value)
-    else:
-        m_value = jnp.where(prop_t > 0, prop_t - 1, state.m_value)
-    if clean:
-        # re-arm the election timer: real failure detection
-        k_e = chan_key(tkey, Channel.ELECTION)
-        if axis is not None:
-            k_e = jax.random.fold_in(k_e, jax.lax.axis_index(axis))
-        rearm = t + jax.random.randint(
-            k_e, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
-            dtype=jnp.int32,
-        )
-        election_deadline = jnp.where(got_hb, rearm, state.election_deadline)
-    else:
-        # quirk #5: Simulator::Cancel with the re-arm commented out
-        # (raft-node.cc:177-178) — one heartbeat pacifies a follower forever
-        election_deadline = jnp.where(got_hb, DISARM, state.election_deadline)
-
-    # ---- gossip proposal acks: a follower acks the proposal when the flood
-    # lands (direct unicast to the decoded leader); replaces the full-mesh
-    # short-circuited round trip, which has no meaning over multi-hop paths
-    if gossip:
-        got_prop = prop_t > 0
-        ack_to = jnp.where(got_prop, (prop_t - 1) % (n + 1), n)  # n = drop
-        k_ack = chan_key(tkey, Channel.DELAY_REPLY2)
-
-        def _ack_counts(wire):
-            c = jnp.zeros((n,), jnp.int32).at[ack_to].add(
-                wire.astype(jnp.int32), mode="drop"
-            )
-            if axis is not None:
-                c = jax.lax.psum(c, axis)
-                start = jax.lax.axis_index(axis) * n_loc
-                c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
-            return c
-
-        def _push_acks():
-            # fused chain-into-ring (ops/delivery.push_bucket_counts):
-            # bit-equal to the former stacked sample → ring_push_add pair
-            # (same keys, same chain, same adds), minus the [2, B, N]
-            # intermediate; the gated fallback leaves the rings untouched,
-            # which is what pushing all-zero contributions produced
-            mok = _ack_counts(got_prop & state.honest & state.alive)
-            mbad = _ack_counts(got_prop & ~state.honest & state.alive)
-            if drop > 0.0:
-                kd = jax.random.fold_in(k_ack, 0x0D18)
-                mok = jnp.round(delay_ops.binom(
-                    kd, mok, 1.0 - drop, smode)).astype(jnp.int32)
-                mbad = jnp.round(delay_ops.binom(
-                    jax.random.fold_in(kd, 1), mbad, 1.0 - drop,
-                    smode)).astype(jnp.int32)
-            return (
-                dv.push_bucket_counts(
-                    hb_ok, t, lo, jax.random.fold_in(k_ack, 1), mok,
-                    ow_probs, smode),
-                dv.push_bucket_counts(
-                    hb_bad, t, lo, jax.random.fold_in(k_ack, 2), mbad,
-                    ow_probs, smode),
-            )
-
-        hb_ok, hb_bad = gated(
-            got_prop.any(), _push_acks, (hb_ok, hb_bad), axis,
-        )
-
-    # ---- vote requests (acceptor side, raft-node.cc:154-167) ---------------
-    can_grant = ~state.has_voted & state.alive
-    my_base = state.my_base
-    if stat:
-        # full mesh: vreq_t[i] = max candidate id + 1 seen this tick (the
-        # stat broadcast reaches the sender too — drop the self-request);
-        # gossip: the candidate id rides the flood encoding
-        grant_to = (vreq_t - 1) % n if gossip else vreq_t - 1
-        has_req = (vreq_t > 0) & (grant_to != ids)
-        if gossip:
-            # term-style release: the dedup register admits only strictly
-            # newer elections (see the my_base field comment), so every
-            # processed request is a grant — the permanent latch would
-            # deadlock the storm
-            grant = has_req & state.alive
-            # granting a vote resets the election timeout (standard Raft):
-            # during the candidacy storm every node keeps re-arming, so no
-            # timer fires into the winner's first heartbeat window and the
-            # post-storm leader is not spuriously deposed
-            k_gr = chan_key(tkey, Channel.ELECTION + 300)
-            if axis is not None:
-                k_gr = jax.random.fold_in(k_gr, jax.lax.axis_index(axis))
-            rearm_gr = t + jax.random.randint(
-                k_gr, (n_loc,), cfg.raft_election_lo_ms,
-                cfg.raft_election_hi_ms, dtype=jnp.int32,
-            )
-            election_deadline = jnp.where(grant, rearm_gr, election_deadline)
-        else:
-            grant = has_req & can_grant
-        deny = has_req & ~grant
-        has_voted = state.has_voted | grant
-        # Byzantine receivers flip their replies (grant<->deny on the wire)
-        ok_wire = (grant & state.honest) | (deny & ~state.honest)
-        no_wire = (deny & state.honest) | (grant & ~state.honest)
-        # per-candidate reply counts, multinomially spread: a global
-        # scatter-add on the full mesh; the overlay routes them
-        # requester-side instead — candidate c gathers its out-neighbors'
-        # wires and keeps those addressed to it (ops/gatherdeliv.
-        # reply_counts_by_target_kreg: equal counts at k = N-1, and the
-        # kregular program stays scatter-free, KNOWN_ISSUES #0i)
-        def reply_counts(wire):
-            if kreg:
-                return gd.reply_counts_by_target_kreg(
-                    wire, grant_to, nbr_out_loc, ids, axis, exchange
-                )
-            c = jnp.zeros((n,), jnp.int32).at[grant_to].add(
-                wire.astype(jnp.int32), mode="drop"
-            )
-            if axis is not None:
-                c = jax.lax.psum(c, axis)
-                start = jax.lax.axis_index(axis) * n_loc
-                c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
-            return c
-
-        any_req = has_req.any()
-        k_vr = chan_key(tkey, Channel.DELAY_REPLY)
-
-        def push_replies():
-            # fused chain-into-ring — see the gossip ack block above
-            mok = reply_counts(ok_wire)
-            mno = reply_counts(no_wire)
-            if drop > 0.0:
-                kd = jax.random.fold_in(k_vr, 0x0D17)
-                mok = jnp.round(delay_ops.binom(
-                    kd, mok, 1.0 - drop, smode)).astype(jnp.int32)
-                mno = jnp.round(delay_ops.binom(
-                    jax.random.fold_in(kd, 1), mno, 1.0 - drop,
-                    smode)).astype(jnp.int32)
-            return (
-                dv.push_bucket_counts(
-                    vres_ok, t, lo, jax.random.fold_in(k_vr, 7), mok,
-                    ow_probs, smode),
-                dv.push_bucket_counts(
-                    vres_no, t, lo, jax.random.fold_in(k_vr, 8), mno,
-                    ow_probs, smode),
-            )
-
-        vres_ok, vres_no = gated(
-            any_req, push_replies, (vres_ok, vres_no), axis,
-        )
-    else:
-        # vreq_t[i, j] = 1 iff candidate j's request reaches i this tick.
-        # Concurrent same-tick requests: the vote goes to the lowest candidate
-        # id (the reference grants in serial arrival order; within one tick the
-        # order is undefined, so we fix a deterministic choice).
-        has_req = vreq_t > 0
-        any_req = has_req.any(axis=1)
-        first = jnp.argmax(has_req, axis=1)  # lowest j with a request
-        grant_mask = (
-            jax.nn.one_hot(first, vreq_t.shape[1], dtype=jnp.int32)
-            * (any_req & can_grant).astype(jnp.int32)[:, None]
-        )
-        deny_mask = has_req.astype(jnp.int32) - grant_mask
-        has_voted = state.has_voted | (any_req & can_grant)
-        hn = state.honest.astype(jnp.int32)[:, None]
-        ok_wire = grant_mask * hn + deny_mask * (1 - hn)
-        no_wire = deny_mask * hn + grant_mask * (1 - hn)
-        k_vr = chan_key(tkey, Channel.DELAY_REPLY)
-        if kreg:
-            # slot-indexed wires route back requester-side through the
-            # inslot cross-index gather — no scatter, same keys/folds as
-            # the dense unicast (bit-equal at k = N-1)
-            def _unicast(kk, wire):
-                return gd.unicast_reply_counts_kreg(
-                    kk, wire, nbr_in_loc, nbr_out_loc, inslot_loc, ids,
-                    lo, hi, drop, axis=axis, impl=eimpl, xg=exchange)
-        else:
-            def _unicast(kk, wire):
-                return dv.unicast_reply_counts_dense(
-                    kk, wire, lo, hi, drop, axis=axis, impl=eimpl)
-        both = gated(
-            any_req.any(),
-            lambda: jnp.stack([
-                _unicast(jax.random.fold_in(k_vr, 7), ok_wire),
-                _unicast(jax.random.fold_in(k_vr, 8), no_wire),
-            ]),
-            jnp.zeros((2, hi - lo, n_loc), jnp.int32),
-            axis,
-        )
-        vres_ok = ring_push_add(vres_ok, t, lo, both[0])
-        vres_no = ring_push_add(vres_no, t, lo, both[1])
-
-    # ---- vote responses (candidate side, raft-node.cc:196-232) --------------
-    vs = state.vote_success + ok_t * (~state.is_leader)
-    vf = state.vote_failed + no_t * (~state.is_leader)
-    win = ~state.is_leader & (ok_t > 0) & (vs + 1 >= cfg.majority_need) & state.alive
-    lose = ~win & (no_t > 0) & (vf >= cfg.raft_lose_need) & ~state.is_leader
-    vote_success = jnp.where(win | lose, 0, vs)
-    vote_failed = jnp.where(win | lose, 0, vf)
-    # winner: cancel own timer, first heartbeat NOW, proposals in +1 s
-    is_leader = state.is_leader | win
-    election_deadline = jnp.where(win, DISARM, election_deadline)
-    next_hb = jnp.where(win, jnp.int32(t), state.next_hb)
-    proposal_tick = jnp.where(
-        win, jnp.int32(t) + cfg.raft_proposal_delay_ms, state.proposal_tick
-    )
-    leader_tick = jnp.where(win & (state.leader_tick < 0), jnp.int32(t),
-                            state.leader_tick)
-    # loser: majority denied — release the vote latch and retry on the timer
-    has_voted = has_voted & ~lose
-    if queued:
-        # leadership changed: the new leader's links are vote-only, hence
-        # free, in both engines (votes never occupy the pipe); its busy
-        # registers start fresh.  Already-scheduled deliveries from the old
-        # leader keep their ring slots, exactly like the C++ engine's
-        # in-flight events.
-        lead_prev = jnp.max(jnp.where(state.is_leader & state.alive, ids, -1))
-        lead_new = jnp.max(jnp.where(is_leader & state.alive, ids, -1))
-        if axis is not None:
-            lead_prev = jax.lax.pmax(lead_prev, axis)
-            lead_new = jax.lax.pmax(lead_new, axis)
-        link_busy = jnp.where(lead_new != lead_prev, 0, state.link_busy)
-    else:
-        link_busy = state.link_busy
-
-    # ---- gossip: leader step-down on a newer election (see my_base) ---------
-    if gossip:
-        newest = seen_vreq // h_enc
-        resign = is_leader & (newest > state.my_base) & state.alive
-        is_leader = is_leader & ~resign
-        next_hb = jnp.where(resign, DISARM, next_hb)
-        proposal_tick = jnp.where(resign, DISARM, proposal_tick)
-        # back to follower: re-arm the election timer (clean fidelity —
-        # gossip requires it) so the node can detect the new leader failing
-        k_rs = chan_key(tkey, Channel.ELECTION + 200)
-        if axis is not None:
-            k_rs = jax.random.fold_in(k_rs, jax.lax.axis_index(axis))
-        rearm_rs = t + jax.random.randint(
-            k_rs, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
-            dtype=jnp.int32,
-        )
-        election_deadline = jnp.where(resign, rearm_rs, election_deadline)
-    else:
-        resign = jnp.zeros((n_loc,), bool)
-    # a resigned leader abandons its open ack window: in-flight acks keep
-    # arriving at the ex-leader (unicast), and without this a later
-    # re-election could latch a phantom commit from pre-resignation acks
-    hb_succ_in = jnp.where(resign, 0, state.hb_succ)
-    hb_cnt_in = jnp.where(resign, 0, state.hb_cnt)
-    hb_open_in = state.hb_open & ~resign
-
-    # ---- proposal acks (leader side, raft-node.cc:234-251) ------------------
-    hs = hb_succ_in + hbok_t
-    hc = hb_cnt_in + hbtot_t
-    if clean:
-        commit = hb_open_in & (hs + 1 >= cfg.majority_need) & is_leader
-        hb_open = hb_open_in & ~commit
-        hb_succ, hb_cnt = hs, hc
-    else:
-        # reference: the check runs only at exactly N-1 responses in
-        done = (hbtot_t > 0) & (hc == n - 1)
-        commit = done & (hs + 1 >= cfg.majority_need)
-        hb_succ = jnp.where(done, 0, hs)
-        hb_cnt = jnp.where(done, 0, hc)
-        hb_open = hb_open_in
-    blk = jnp.clip(state.block_num, 0, cfg.raft_max_blocks - 1)
-    block_tick = jnp.where(
-        (jax.nn.one_hot(blk, cfg.raft_max_blocks, dtype=bool)
-         & commit[:, None] & (state.block_num < cfg.raft_max_blocks)[:, None]),
-        jnp.int32(t),
-        state.block_tick,
-    )
-    block_num = state.block_num + commit
-    # blockNum >= 50 cancels the heartbeat (raft-node.cc:248-251).  Gossip
-    # divergence: completion must NOT silence the failure detector — with
-    # term-style vote release, heartbeat silence triggers a fresh election
-    # whose winner re-replicates from scratch (per-leader counters, no
-    # shared log); the completed leader keeps the 4-byte control heartbeat
-    # and simply stops proposing (add_change_value already cleared).
-    if not gossip:
-        next_hb = jnp.where(block_num >= cfg.raft_max_blocks, DISARM, next_hb)
-
-    # ---- timer: sendVote (raft-node.cc:392-401) -----------------------------
-    fire = (
-        (jnp.int32(t) >= election_deadline)
-        & (election_deadline != DISARM)
-        & ~is_leader
-        & state.alive
-    )
-    has_voted = has_voted | fire  # self-vote latch
-    if gossip:
-        # fresh election: restart the reply count (stale replies from the
-        # previous election drained long ago — reply horizon << timeout)
-        vote_success = jnp.where(fire, 0, vote_success)
-        vote_failed = jnp.where(fire, 0, vote_failed)
-    k_e2 = chan_key(tkey, Channel.ELECTION + 100)
-    if axis is not None:
-        k_e2 = jax.random.fold_in(k_e2, jax.lax.axis_index(axis))
-    rearm2 = t + jax.random.randint(
-        k_e2, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
-        dtype=jnp.int32,
-    )
-    election_deadline = jnp.where(fire, rearm2, election_deadline)
-    elections = state.elections + fire
-    k_vq = chan_key(tkey, Channel.DELAY_BCAST)
-    if gossip:
-        # flood origin: full TTL, marked seen so the self-loop copy is inert
-        base_v = ((jnp.int32(t) + 1) * n + ids + 1) * fire.astype(jnp.int32)
-        origin_v = (base_v * h_enc + cfg.gossip_hops) * (base_v > 0)
-        seen_vreq = jnp.maximum(seen_vreq, origin_v)
-        # the candidate backs its own (newest) election
-        my_base = jnp.maximum(my_base, base_v)
-        out_v = jnp.maximum(origin_v, vreq_fwd)
-        vq_contrib = gated(
-            (out_v > 0).any(),
-            lambda: dv.gossip_fwd(k_vq, out_v[:, None], nbrs_loc, n, lo, hi,
-                                  drop, axis=axis, impl=eimpl)[:, :, 0],
-            zeros_flat,
-            axis,
-        )
-        vreq = ring_push_max(vreq, t, lo, vq_contrib)
-    elif stat:
-        vq_contrib = gated(
-            fire.any(),
-            lambda: (
-                gd.bcast_value_max_stat_kreg(
-                    k_vq, (ids + 1) * fire.astype(jnp.int32), nbr_in_loc,
-                    ow_probs, drop, axis=axis, xg=exchange)
-                if kreg else
-                dv.bcast_value_max_stat(
-                    k_vq, (ids + 1) * fire.astype(jnp.int32), ow_probs, drop,
-                    axis=axis)
-            ),
-            zeros_flat,
-            axis,
-        )
-        vreq = ring_push_max(vreq, t, lo, vq_contrib)
-    elif kreg:
-        vq_contrib = gated(
-            fire.any(),
-            lambda: gd.bcast_matrix_kreg(
-                k_vq, fire, fire.astype(jnp.int32), nbr_in_loc, ids, lo, hi,
-                drop, axis=axis, impl=eimpl, xg=exchange),
-            jnp.zeros((hi - lo, n_loc, cfg.degree + 1), jnp.int32),
-            axis,
-        )
-        vreq = ring_push_max(vreq, t, lo, vq_contrib)
-    else:
-        vq_contrib = gated(
-            fire.any(),
-            lambda: dv.bcast_matrix_dense(
-                k_vq, fire, fire.astype(jnp.int32), lo, hi, drop, axis=axis,
-                impl=eimpl),
-            jnp.zeros((hi - lo, n_loc, n), jnp.int32),
-            axis,
-        )
-        vreq = ring_push_max(vreq, t, lo, vq_contrib)
-
-    # ---- timer: sendHeartBeat (raft-node.cc:405-433) ------------------------
-    hb_fire = (
-        is_leader & (jnp.int32(t) >= next_hb) & (next_hb != DISARM) & state.alive
-    )
-    # setProposal fires exactly once (raft-node.cc:216,431-433) — round==50
-    # clears add_change_value for good, so the trigger must not re-fire
-    set_prop = (jnp.int32(t) >= proposal_tick) & (proposal_tick != DISARM)
-    add_change_value = (state.add_change_value | set_prop) & ~resign
-    proposal_tick = jnp.where(set_prop, DISARM, proposal_tick)
-    prop_send = hb_fire & add_change_value
-    # Full mesh: either/or, like the reference (raft-node.cc:405-433).
-    # Gossip: the leader ALWAYS floods the 4-byte plain heartbeat — a 20 KB
-    # proposal store-and-forwards ~hops*(delay+ser) (~460 ms at defaults),
-    # far beyond the 150-300 ms election window, so using the block channel
-    # as the failure detector deposes a healthy leader every proposal phase;
-    # separating the control heartbeat from block dissemination is the
-    # documented gossip divergence.
-    plain_send = hb_fire if gossip else (hb_fire & ~add_change_value)
-    next_hb = jnp.where(hb_fire, next_hb + cfg.raft_heartbeat_ms, next_hb)
-    # SendTX: round++; at round==50 stop adding proposals (raft-node.cc:361-365)
-    round_ = state.round + prop_send
-    add_change_value = add_change_value & ~(
-        prop_send & (round_ >= cfg.raft_max_rounds)
-    )
-    # new proposal round opens the ack window
-    hb_succ = jnp.where(prop_send, 0, hb_succ) if clean else hb_succ
-    hb_cnt = jnp.where(prop_send, 0, hb_cnt) if clean else hb_cnt
-    hb_open = (hb_open | prop_send) if clean else hb_open
-
-    k_hb = chan_key(tkey, Channel.DELAY_BCAST2)
-    if queued:
-        # serial-pipe send (engine.cpp link_enqueue): the packet reaches the
-        # (leader -> j) link after its scheduling delay d_j - prop, transmits
-        # when the link frees (proposals occupy it for ser; 4-byte plain
-        # heartbeats queue behind but occupy nothing), then propagates.
-        # Deliveries land on the rings at dynamic per-destination offsets —
-        # bounded by the (ser - hb) * rounds backlog that config.ring_depth
-        # reserves — via scatter (fidelity-mode path; scatter cost is
-        # irrelevant at the n=8-ish scales queued fidelity runs at).
-        prop_ms = cfg.link_delay_ms
-        prop_val = jnp.max(jnp.where(prop_send, ids + 1, 0))
-        plain_on = jnp.max(plain_send.astype(jnp.int32))
-        sender = jnp.max(jnp.where(prop_send | plain_send, ids, -1))
-        if axis is not None:
-            prop_val = jax.lax.pmax(prop_val, axis)
-            plain_on = jax.lax.pmax(plain_on, axis)
-            sender = jax.lax.pmax(sender, axis)
-        any_send = (prop_val > 0) | (plain_on > 0)
-        dest = any_send & (ids != sender)  # crashed peers still reserve the
-        # pipe (C++ run_loop kind-2: reservation is sender-side)
-        d_j = jax.random.randint(
-            dv._shard_key(jax.random.fold_in(k_hb, 7), axis), (n_loc,), lo,
-            hi, jnp.int32,
-        )
-        ser_s = jnp.where(prop_val > 0, ser, 0)
-        start = jnp.maximum(t + d_j - prop_ms, link_busy)
-        delivery = start + ser_s + prop_ms
-        link_busy = jnp.where(dest, start + ser_s, link_busy)
-        dd = hb_prop.shape[0]
-        cols = jnp.arange(n_loc)
-        didx = jnp.where(dest, delivery % dd, dd)  # dd = out-of-bounds drop
-        hb_prop = hb_prop.at[didx, cols].max(
-            jnp.where(dest, prop_val, 0), mode="drop")
-        hb_plain = hb_plain.at[didx, cols].add(
-            (dest & (plain_on > 0)).astype(jnp.int32), mode="drop")
-    elif gossip:
-        # plain heartbeats: tiny control messages, flooded with the tick as
-        # the monotone base (concurrent leaders dedup to one — got_hb only
-        # pacifies timers); proposals carry the 20 KB block, so every hop
-        # re-serializes (store-and-forward), hence ser on each leg
-        base_h = (jnp.int32(t) + 1) * plain_send.astype(jnp.int32)
-        origin_h = (base_h * h_enc + cfg.gossip_hops) * (base_h > 0)
-        seen_hb = jnp.maximum(seen_hb, origin_h)
-        out_h = jnp.maximum(origin_h, hb_fwd)
-        plain_contrib = gated(
-            (out_h > 0).any(),
-            lambda: dv.gossip_fwd(
-                jax.random.fold_in(k_hb, 2), out_h[:, None], nbrs_loc, n, lo,
-                hi, drop, axis=axis, impl=eimpl)[:, :, 0],
-            zeros_flat,
-            axis,
-        )
-        hb_plain = ring_push_max(hb_plain, t, lo, plain_contrib)
-        base_p = (
-            (jnp.int32(t) + 1) * (n + 1) + ids + 1
-        ) * prop_send.astype(jnp.int32)
-        origin_p = (base_p * h_enc + cfg.gossip_hops) * (base_p > 0)
-        seen_prop = jnp.maximum(seen_prop, origin_p)
-        out_p = jnp.maximum(origin_p, prop_fwd)
-        prop_contrib = gated(
-            (out_p > 0).any(),
-            lambda: dv.gossip_fwd(
-                jax.random.fold_in(k_hb, 3), out_p[:, None], nbrs_loc, n, lo,
-                hi, drop, axis=axis, impl=eimpl)[:, :, 0],
-            zeros_flat,
-            axis,
-        )
-        hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
-    elif kreg:
+    with jax.named_scope("raft.tick.pop"):
+        # ---- pop arrivals; crashed nodes process nothing ------------------------
+        vreq_t, vreq = ring_pop(bufs.vreq, t)
+        ok_t, vres_ok = ring_pop(bufs.vres_ok, t)
+        no_t, vres_no = ring_pop(bufs.vres_no, t)
+        plain_t, hb_plain = ring_pop(bufs.hb_plain, t)
+        prop_t, hb_prop = ring_pop(bufs.hb_prop, t)
+        hbok_t, hb_ok = ring_pop(bufs.hb_ok, t)
+        hbbad_t, hb_bad = ring_pop(bufs.hb_bad, t)
+        am = state.alive.astype(jnp.int32)
+        ok_t, no_t = ok_t * am, no_t * am
+        plain_t, prop_t = plain_t * am, prop_t * am
+        hbok_t, hbbad_t = hbok_t * am, hbbad_t * am
+        hbtot_t = hbok_t + hbbad_t
         if stat:
-            plain_contrib = gated(
-                plain_send.any(),
-                # mode stays exact for the same O(1)-sender reason as the
-                # full-mesh stat arm below
-                lambda: gd.bcast_counts_stat_kreg(
-                    k_hb, plain_send, nbr_in_loc, ids, ow_probs, drop,
-                    axis=axis, mode="exact", xg=exchange),
-                zeros_flat,
-                axis,
-            )
-            prop_contrib = gated(
-                prop_send.any(),
-                lambda: gd.bcast_value_max_stat_kreg(
-                    jax.random.fold_in(k_hb, 1),
-                    (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
-                    ow_probs, drop, axis=axis, xg=exchange),
-                zeros_flat,
-                axis,
-            )
+            vreq_t = vreq_t * am
         else:
-            plain_contrib = gated(
-                plain_send.any(),
-                lambda: gd.bcast_counts_kreg(
-                    k_hb, plain_send, nbr_in_loc, ids, lo, hi, drop,
-                    axis=axis, impl=eimpl, xg=exchange),
-                zeros_flat,
-                axis,
-            )
-            prop_contrib = gated(
-                prop_send.any(),
-                lambda: gd.bcast_value_max_kreg(
-                    jax.random.fold_in(k_hb, 1), prop_send,
-                    (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
-                    ids, lo, hi, drop, axis=axis, impl=eimpl, xg=exchange),
-                zeros_flat,
-                axis,
-            )
-    elif stat:
-        plain_contrib = gated(
-            plain_send.any(),
-            lambda: dv.bcast_counts_stat(
-                k_hb,
-                _psum_scalar(plain_send.astype(jnp.int32).sum(), axis),
-                # mode stays exact here: this channel has O(1) senders (the
-                # leader), and the Gaussian binomial approximation is biased
-                # for count-1 draws (~9% on a p=1/3 bucket); the sampler-cost
-                # argument for "normal" only applies to O(N)-count channels
-                plain_send, ow_probs, drop, axis=axis, mode="exact"),
-            zeros_flat,
-            axis,
-        )
-        prop_contrib = gated(
-            prop_send.any(),
-            lambda: dv.bcast_value_max_stat(
-                jax.random.fold_in(k_hb, 1),
-                (ids + 1) * prop_send.astype(jnp.int32), ow_probs, drop,
-                axis=axis),
-            zeros_flat,
-            axis,
-        )
-    else:
-        plain_contrib = gated(
-            plain_send.any(),
-            lambda: dv.bcast_counts_dense(k_hb, plain_send, lo, hi, drop,
-                                          axis=axis, impl=eimpl),
-            zeros_flat,
-            axis,
-        )
-        prop_contrib = gated(
-            prop_send.any(),
-            lambda: dv.bcast_value_max_dense(
-                jax.random.fold_in(k_hb, 1), prop_send,
-                (ids + 1) * prop_send.astype(jnp.int32), lo, hi, drop,
-                axis=axis, impl=eimpl),
-            zeros_flat,
-            axis,
-        )
-    if not gossip and not queued:
-        hb_plain = ring_push_add(hb_plain, t, lo, plain_contrib)
-        hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
+            vreq_t = vreq_t * am[:, None]
 
-    # proposal acks: follower state never affects the SUCCESS reply
-    # (raft-node.cc:170-193), so the round trip is short-circuited; Byzantine
-    # followers flip to FAILED.  The SUCCESS (honest) and FAILED (Byzantine)
-    # channels cover *disjoint* peer sets, so their independent delay draws
-    # cover disjoint edges — each ack lands in exactly one channel at one tick,
-    # and the leader's total count is their sum.  (Gossip acks are generated
-    # at flood arrival instead — see the gossip block above.)
-    k_rt = chan_key(tkey, Channel.DELAY_ROUNDTRIP)
-    voters = state.alive & state.honest
-    liars = state.alive & ~state.honest
-    if gossip:
-        pass
-    elif queued:
-        # the follower's ack is a 4-byte reply over the (follower -> leader)
-        # link, which is never busy (followers send no blocks): it departs at
-        # the proposal's queued DELIVERY tick and lands one one-way delay
-        # later.  Ack ticks are per-destination, the receiver is the single
-        # leader row: bucket them into a [D] histogram (psum'd across shards)
-        # and add it into the leader's ring column on the owning shard.
-        d2 = jax.random.randint(
-            dv._shard_key(jax.random.fold_in(k_rt, 9), axis), (n_loc,), lo,
-            hi, jnp.int32,
+        # ---- gossip decode (topology="gossip"): the three broadcast channels
+        # (VOTE_REQ, plain HEARTBEAT, proposal HEARTBEAT) flood over the k-out
+        # digraph with a hop TTL; replies (votes, proposal acks) stay direct
+        # unicast to the decoded originator — the same overlay as models/paxos.py.
+        # Flood values are time-monotone encodings (dedup by per-channel ``seen``
+        # register): vreq (t+1)*n + cand + 1; plain hb t+1; proposal
+        # (t+1)*(n+1) + leader + 1 (the +1 keeps 0 = empty).  A node processes
+        # each base value once (first sighting) but forwards any strictly better
+        # TTL copy, so a nearly-expired first arrival cannot truncate the flood.
+        gossip = cfg.topology == "gossip"
+        # kregular gather overlay (topo/spec.py + ops/gatherdeliv.py): every
+        # channel delivers DIRECT over the circulant in/out tables — broadcasts
+        # reach out-neighbors, replies gather back requester-side through the
+        # inslot cross-index (scatter-free) — O(N*K) per tick, bit-equal to the
+        # dense arms at degree k = N-1.  A candidate only ever hears its
+        # in-neighbors' votes, so elections need k >= majority_need - 1 to be
+        # winnable (stalling below that is a valid modeled outcome).
+        kreg = cfg.topology == "kregular"
+        nbr_in_loc = nbr_out_loc = inslot_loc = None
+        if kreg:
+            # exchange mode: operands are already this trace's rows (ids=None
+            # pass-through — re-taking a sharded operand would regather it)
+            nbr_in_loc, nbr_out_loc, inslot_loc = gd.local_tables(
+                cfg, None if exchange is not None else ids, inslot=True,
+                tables=topo_tables)
+        seen_vreq, seen_hb, seen_prop = state.seen_vreq, state.seen_hb, state.seen_prop
+        vreq_fwd = hb_fwd = prop_fwd = None
+        nbrs_loc = None
+        if gossip:
+            h_enc = cfg.gossip_hops + 1
+            nbrs_loc = jnp.take(
+                jnp.asarray(topology.kregular_out_neighbors(n, cfg.degree, cfg.seed)),
+                ids, axis=0,
+            )
+
+            def _decode(arr, seen):
+                base, hops = arr // h_enc, arr % h_enc
+                new = (base > seen // h_enc) & state.alive
+                better = (arr > seen) & state.alive
+                seen = jnp.maximum(seen, arr * better)
+                fwd = (base * h_enc + jnp.maximum(hops - 1, 0)) * (better & (hops > 0))
+                return base * new, seen, fwd
+
+            vreq_t, seen_vreq, vreq_fwd = _decode(vreq_t, seen_vreq)
+            plain_t, seen_hb, hb_fwd = _decode(plain_t, seen_hb)
+            prop_t, seen_prop, prop_fwd = _decode(prop_t, seen_prop)
+
+    with jax.named_scope("raft.tick.heartbeat_rx"):
+        # ---- heartbeat arrivals (follower side, raft-node.cc:170-193) -----------
+        got_hb = (plain_t > 0) | (prop_t > 0)
+        if gossip:
+            # proposal value = the leader id riding the flood encoding
+            m_value = jnp.where(prop_t > 0, (prop_t - 1) % (n + 1), state.m_value)
+        else:
+            m_value = jnp.where(prop_t > 0, prop_t - 1, state.m_value)
+        if clean:
+            # re-arm the election timer: real failure detection
+            k_e = chan_key(tkey, Channel.ELECTION)
+            if axis is not None:
+                k_e = jax.random.fold_in(k_e, jax.lax.axis_index(axis))
+            rearm = t + jax.random.randint(
+                k_e, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
+                dtype=jnp.int32,
+            )
+            election_deadline = jnp.where(got_hb, rearm, state.election_deadline)
+        else:
+            # quirk #5: Simulator::Cancel with the re-arm commented out
+            # (raft-node.cc:177-178) — one heartbeat pacifies a follower forever
+            election_deadline = jnp.where(got_hb, DISARM, state.election_deadline)
+
+        # ---- gossip proposal acks: a follower acks the proposal when the flood
+        # lands (direct unicast to the decoded leader); replaces the full-mesh
+        # short-circuited round trip, which has no meaning over multi-hop paths
+        if gossip:
+            got_prop = prop_t > 0
+            ack_to = jnp.where(got_prop, (prop_t - 1) % (n + 1), n)  # n = drop
+            k_ack = chan_key(tkey, Channel.DELAY_REPLY2)
+
+            def _ack_counts(wire):
+                c = jnp.zeros((n,), jnp.int32).at[ack_to].add(
+                    wire.astype(jnp.int32), mode="drop"
+                )
+                if axis is not None:
+                    c = jax.lax.psum(c, axis)
+                    start = jax.lax.axis_index(axis) * n_loc
+                    c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
+                return c
+
+            def _push_acks():
+                # fused chain-into-ring (ops/delivery.push_bucket_counts):
+                # bit-equal to the former stacked sample → ring_push_add pair
+                # (same keys, same chain, same adds), minus the [2, B, N]
+                # intermediate; the gated fallback leaves the rings untouched,
+                # which is what pushing all-zero contributions produced
+                mok = _ack_counts(got_prop & state.honest & state.alive)
+                mbad = _ack_counts(got_prop & ~state.honest & state.alive)
+                if drop > 0.0:
+                    kd = jax.random.fold_in(k_ack, 0x0D18)
+                    mok = jnp.round(delay_ops.binom(
+                        kd, mok, 1.0 - drop, smode)).astype(jnp.int32)
+                    mbad = jnp.round(delay_ops.binom(
+                        jax.random.fold_in(kd, 1), mbad, 1.0 - drop,
+                        smode)).astype(jnp.int32)
+                return (
+                    dv.push_bucket_counts(
+                        hb_ok, t, lo, jax.random.fold_in(k_ack, 1), mok,
+                        ow_probs, smode),
+                    dv.push_bucket_counts(
+                        hb_bad, t, lo, jax.random.fold_in(k_ack, 2), mbad,
+                        ow_probs, smode),
+                )
+
+            hb_ok, hb_bad = gated(
+                got_prop.any(), _push_acks, (hb_ok, hb_bad), axis,
+            )
+
+    with jax.named_scope("raft.tick.vote_rx"):
+        # ---- vote requests (acceptor side, raft-node.cc:154-167) ---------------
+        can_grant = ~state.has_voted & state.alive
+        my_base = state.my_base
+        if stat:
+            # full mesh: vreq_t[i] = max candidate id + 1 seen this tick (the
+            # stat broadcast reaches the sender too — drop the self-request);
+            # gossip: the candidate id rides the flood encoding
+            grant_to = (vreq_t - 1) % n if gossip else vreq_t - 1
+            has_req = (vreq_t > 0) & (grant_to != ids)
+            if gossip:
+                # term-style release: the dedup register admits only strictly
+                # newer elections (see the my_base field comment), so every
+                # processed request is a grant — the permanent latch would
+                # deadlock the storm
+                grant = has_req & state.alive
+                # granting a vote resets the election timeout (standard Raft):
+                # during the candidacy storm every node keeps re-arming, so no
+                # timer fires into the winner's first heartbeat window and the
+                # post-storm leader is not spuriously deposed
+                k_gr = chan_key(tkey, Channel.ELECTION + 300)
+                if axis is not None:
+                    k_gr = jax.random.fold_in(k_gr, jax.lax.axis_index(axis))
+                rearm_gr = t + jax.random.randint(
+                    k_gr, (n_loc,), cfg.raft_election_lo_ms,
+                    cfg.raft_election_hi_ms, dtype=jnp.int32,
+                )
+                election_deadline = jnp.where(grant, rearm_gr, election_deadline)
+            else:
+                grant = has_req & can_grant
+            deny = has_req & ~grant
+            has_voted = state.has_voted | grant
+            # Byzantine receivers flip their replies (grant<->deny on the wire)
+            ok_wire = (grant & state.honest) | (deny & ~state.honest)
+            no_wire = (deny & state.honest) | (grant & ~state.honest)
+            # per-candidate reply counts, multinomially spread: a global
+            # scatter-add on the full mesh; the overlay routes them
+            # requester-side instead — candidate c gathers its out-neighbors'
+            # wires and keeps those addressed to it (ops/gatherdeliv.
+            # reply_counts_by_target_kreg: equal counts at k = N-1, and the
+            # kregular program stays scatter-free, KNOWN_ISSUES #0i)
+            def reply_counts(wire):
+                if kreg:
+                    return gd.reply_counts_by_target_kreg(
+                        wire, grant_to, nbr_out_loc, ids, axis, exchange
+                    )
+                c = jnp.zeros((n,), jnp.int32).at[grant_to].add(
+                    wire.astype(jnp.int32), mode="drop"
+                )
+                if axis is not None:
+                    c = jax.lax.psum(c, axis)
+                    start = jax.lax.axis_index(axis) * n_loc
+                    c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
+                return c
+
+            any_req = has_req.any()
+            k_vr = chan_key(tkey, Channel.DELAY_REPLY)
+
+            def push_replies():
+                # fused chain-into-ring — see the gossip ack block above
+                mok = reply_counts(ok_wire)
+                mno = reply_counts(no_wire)
+                if drop > 0.0:
+                    kd = jax.random.fold_in(k_vr, 0x0D17)
+                    mok = jnp.round(delay_ops.binom(
+                        kd, mok, 1.0 - drop, smode)).astype(jnp.int32)
+                    mno = jnp.round(delay_ops.binom(
+                        jax.random.fold_in(kd, 1), mno, 1.0 - drop,
+                        smode)).astype(jnp.int32)
+                return (
+                    dv.push_bucket_counts(
+                        vres_ok, t, lo, jax.random.fold_in(k_vr, 7), mok,
+                        ow_probs, smode),
+                    dv.push_bucket_counts(
+                        vres_no, t, lo, jax.random.fold_in(k_vr, 8), mno,
+                        ow_probs, smode),
+                )
+
+            vres_ok, vres_no = gated(
+                any_req, push_replies, (vres_ok, vres_no), axis,
+            )
+        else:
+            # vreq_t[i, j] = 1 iff candidate j's request reaches i this tick.
+            # Concurrent same-tick requests: the vote goes to the lowest candidate
+            # id (the reference grants in serial arrival order; within one tick the
+            # order is undefined, so we fix a deterministic choice).
+            has_req = vreq_t > 0
+            any_req = has_req.any(axis=1)
+            first = jnp.argmax(has_req, axis=1)  # lowest j with a request
+            grant_mask = (
+                jax.nn.one_hot(first, vreq_t.shape[1], dtype=jnp.int32)
+                * (any_req & can_grant).astype(jnp.int32)[:, None]
+            )
+            deny_mask = has_req.astype(jnp.int32) - grant_mask
+            has_voted = state.has_voted | (any_req & can_grant)
+            hn = state.honest.astype(jnp.int32)[:, None]
+            ok_wire = grant_mask * hn + deny_mask * (1 - hn)
+            no_wire = deny_mask * hn + grant_mask * (1 - hn)
+            k_vr = chan_key(tkey, Channel.DELAY_REPLY)
+            if kreg:
+                # slot-indexed wires route back requester-side through the
+                # inslot cross-index gather — no scatter, same keys/folds as
+                # the dense unicast (bit-equal at k = N-1)
+                def _unicast(kk, wire):
+                    return gd.unicast_reply_counts_kreg(
+                        kk, wire, nbr_in_loc, nbr_out_loc, inslot_loc, ids,
+                        lo, hi, drop, axis=axis, impl=eimpl, xg=exchange)
+            else:
+                def _unicast(kk, wire):
+                    return dv.unicast_reply_counts_dense(
+                        kk, wire, lo, hi, drop, axis=axis, impl=eimpl)
+            both = gated(
+                any_req.any(),
+                lambda: jnp.stack([
+                    _unicast(jax.random.fold_in(k_vr, 7), ok_wire),
+                    _unicast(jax.random.fold_in(k_vr, 8), no_wire),
+                ]),
+                jnp.zeros((2, hi - lo, n_loc), jnp.int32),
+                axis,
+            )
+            vres_ok = ring_push_add(vres_ok, t, lo, both[0])
+            vres_no = ring_push_add(vres_no, t, lo, both[1])
+
+    with jax.named_scope("raft.tick.vote_reply_rx"):
+        # ---- vote responses (candidate side, raft-node.cc:196-232) --------------
+        vs = state.vote_success + ok_t * (~state.is_leader)
+        vf = state.vote_failed + no_t * (~state.is_leader)
+        win = ~state.is_leader & (ok_t > 0) & (vs + 1 >= cfg.majority_need) & state.alive
+        lose = ~win & (no_t > 0) & (vf >= cfg.raft_lose_need) & ~state.is_leader
+        vote_success = jnp.where(win | lose, 0, vs)
+        vote_failed = jnp.where(win | lose, 0, vf)
+        # winner: cancel own timer, first heartbeat NOW, proposals in +1 s
+        is_leader = state.is_leader | win
+        election_deadline = jnp.where(win, DISARM, election_deadline)
+        next_hb = jnp.where(win, jnp.int32(t), state.next_hb)
+        proposal_tick = jnp.where(
+            win, jnp.int32(t) + cfg.raft_proposal_delay_ms, state.proposal_tick
         )
-        ack_arr = delivery + d2
-        prop_on = prop_val > 0
-        okd = dest & prop_on & voters
-        badd = dest & prop_on & liars
-        dd = hb_ok.shape[0]
-        hist_ok = jnp.zeros((dd,), jnp.int32).at[
-            jnp.where(okd, ack_arr % dd, dd)].add(1, mode="drop")
-        hist_bad = jnp.zeros((dd,), jnp.int32).at[
-            jnp.where(badd, ack_arr % dd, dd)].add(1, mode="drop")
+        leader_tick = jnp.where(win & (state.leader_tick < 0), jnp.int32(t),
+                                state.leader_tick)
+        # loser: majority denied — release the vote latch and retry on the timer
+        has_voted = has_voted & ~lose
+        if queued:
+            # leadership changed: the new leader's links are vote-only, hence
+            # free, in both engines (votes never occupy the pipe); its busy
+            # registers start fresh.  Already-scheduled deliveries from the old
+            # leader keep their ring slots, exactly like the C++ engine's
+            # in-flight events.
+            lead_prev = jnp.max(jnp.where(state.is_leader & state.alive, ids, -1))
+            lead_new = jnp.max(jnp.where(is_leader & state.alive, ids, -1))
+            if axis is not None:
+                lead_prev = jax.lax.pmax(lead_prev, axis)
+                lead_new = jax.lax.pmax(lead_new, axis)
+            link_busy = jnp.where(lead_new != lead_prev, 0, state.link_busy)
+        else:
+            link_busy = state.link_busy
+
+        # ---- gossip: leader step-down on a newer election (see my_base) ---------
+        if gossip:
+            newest = seen_vreq // h_enc
+            resign = is_leader & (newest > state.my_base) & state.alive
+            is_leader = is_leader & ~resign
+            next_hb = jnp.where(resign, DISARM, next_hb)
+            proposal_tick = jnp.where(resign, DISARM, proposal_tick)
+            # back to follower: re-arm the election timer (clean fidelity —
+            # gossip requires it) so the node can detect the new leader failing
+            k_rs = chan_key(tkey, Channel.ELECTION + 200)
+            if axis is not None:
+                k_rs = jax.random.fold_in(k_rs, jax.lax.axis_index(axis))
+            rearm_rs = t + jax.random.randint(
+                k_rs, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
+                dtype=jnp.int32,
+            )
+            election_deadline = jnp.where(resign, rearm_rs, election_deadline)
+        else:
+            resign = jnp.zeros((n_loc,), bool)
+        # a resigned leader abandons its open ack window: in-flight acks keep
+        # arriving at the ex-leader (unicast), and without this a later
+        # re-election could latch a phantom commit from pre-resignation acks
+        hb_succ_in = jnp.where(resign, 0, state.hb_succ)
+        hb_cnt_in = jnp.where(resign, 0, state.hb_cnt)
+        hb_open_in = state.hb_open & ~resign
+
+    with jax.named_scope("raft.tick.ack_rx"):
+        # ---- proposal acks (leader side, raft-node.cc:234-251) ------------------
+        hs = hb_succ_in + hbok_t
+        hc = hb_cnt_in + hbtot_t
+        if clean:
+            commit = hb_open_in & (hs + 1 >= cfg.majority_need) & is_leader
+            hb_open = hb_open_in & ~commit
+            hb_succ, hb_cnt = hs, hc
+        else:
+            # reference: the check runs only at exactly N-1 responses in
+            done = (hbtot_t > 0) & (hc == n - 1)
+            commit = done & (hs + 1 >= cfg.majority_need)
+            hb_succ = jnp.where(done, 0, hs)
+            hb_cnt = jnp.where(done, 0, hc)
+            hb_open = hb_open_in
+        blk = jnp.clip(state.block_num, 0, cfg.raft_max_blocks - 1)
+        block_tick = jnp.where(
+            (jax.nn.one_hot(blk, cfg.raft_max_blocks, dtype=bool)
+             & commit[:, None] & (state.block_num < cfg.raft_max_blocks)[:, None]),
+            jnp.int32(t),
+            state.block_tick,
+        )
+        block_num = state.block_num + commit
+        # blockNum >= 50 cancels the heartbeat (raft-node.cc:248-251).  Gossip
+        # divergence: completion must NOT silence the failure detector — with
+        # term-style vote release, heartbeat silence triggers a fresh election
+        # whose winner re-replicates from scratch (per-leader counters, no
+        # shared log); the completed leader keeps the 4-byte control heartbeat
+        # and simply stops proposing (add_change_value already cleared).
+        if not gossip:
+            next_hb = jnp.where(block_num >= cfg.raft_max_blocks, DISARM, next_hb)
+
+    with jax.named_scope("raft.tick.timer_vote"):
+        # ---- timer: sendVote (raft-node.cc:392-401) -----------------------------
+        fire = (
+            (jnp.int32(t) >= election_deadline)
+            & (election_deadline != DISARM)
+            & ~is_leader
+            & state.alive
+        )
+        has_voted = has_voted | fire  # self-vote latch
+        if gossip:
+            # fresh election: restart the reply count (stale replies from the
+            # previous election drained long ago — reply horizon << timeout)
+            vote_success = jnp.where(fire, 0, vote_success)
+            vote_failed = jnp.where(fire, 0, vote_failed)
+        k_e2 = chan_key(tkey, Channel.ELECTION + 100)
         if axis is not None:
-            hist_ok = jax.lax.psum(hist_ok, axis)
-            hist_bad = jax.lax.psum(hist_bad, axis)
-        col = sender - ids[0]
-        owned = prop_on & (col >= 0) & (col < n_loc)
-        col_c = jnp.clip(col, 0, n_loc - 1)
-        hb_ok = hb_ok.at[:, col_c].add(jnp.where(owned, hist_ok, 0))
-        hb_bad = hb_bad.at[:, col_c].add(jnp.where(owned, hist_bad, 0))
-    elif stat:
-        # fused chain-into-ring (ops/delivery.push_roundtrip_reply_counts_
-        # stat) — bit-equal to the former sample → ring_push_add compose.
-        # The kregular overlay swaps only the per-sender peer counts for
-        # out-table gathers (equal at k = N-1, same keys/chain).
-        if kreg:
-            ok_peers = gd.out_counts(voters, nbr_out_loc, ids, axis, exchange)
-            bad_peers = gd.out_counts(liars, nbr_out_loc, ids, axis, exchange)
+            k_e2 = jax.random.fold_in(k_e2, jax.lax.axis_index(axis))
+        rearm2 = t + jax.random.randint(
+            k_e2, (n_loc,), cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
+            dtype=jnp.int32,
+        )
+        election_deadline = jnp.where(fire, rearm2, election_deadline)
+        elections = state.elections + fire
+        k_vq = chan_key(tkey, Channel.DELAY_BCAST)
+        if gossip:
+            # flood origin: full TTL, marked seen so the self-loop copy is inert
+            base_v = ((jnp.int32(t) + 1) * n + ids + 1) * fire.astype(jnp.int32)
+            origin_v = (base_v * h_enc + cfg.gossip_hops) * (base_v > 0)
+            seen_vreq = jnp.maximum(seen_vreq, origin_v)
+            # the candidate backs its own (newest) election
+            my_base = jnp.maximum(my_base, base_v)
+            out_v = jnp.maximum(origin_v, vreq_fwd)
+            vq_contrib = gated(
+                (out_v > 0).any(),
+                lambda: dv.gossip_fwd(k_vq, out_v[:, None], nbrs_loc, n, lo, hi,
+                                      drop, axis=axis, impl=eimpl)[:, :, 0],
+                zeros_flat,
+                axis,
+            )
+            vreq = ring_push_max(vreq, t, lo, vq_contrib)
+        elif stat:
+            vq_contrib = gated(
+                fire.any(),
+                lambda: (
+                    gd.bcast_value_max_stat_kreg(
+                        k_vq, (ids + 1) * fire.astype(jnp.int32), nbr_in_loc,
+                        ow_probs, drop, axis=axis, xg=exchange)
+                    if kreg else
+                    dv.bcast_value_max_stat(
+                        k_vq, (ids + 1) * fire.astype(jnp.int32), ow_probs, drop,
+                        axis=axis)
+                ),
+                zeros_flat,
+                axis,
+            )
+            vreq = ring_push_max(vreq, t, lo, vq_contrib)
+        elif kreg:
+            vq_contrib = gated(
+                fire.any(),
+                lambda: gd.bcast_matrix_kreg(
+                    k_vq, fire, fire.astype(jnp.int32), nbr_in_loc, ids, lo, hi,
+                    drop, axis=axis, impl=eimpl, xg=exchange),
+                jnp.zeros((hi - lo, n_loc, cfg.degree + 1), jnp.int32),
+                axis,
+            )
+            vreq = ring_push_max(vreq, t, lo, vq_contrib)
         else:
-            n_voters = _psum_scalar(voters.astype(jnp.int32).sum(), axis)
-            n_liars = _psum_scalar(liars.astype(jnp.int32).sum(), axis)
-            ok_peers = n_voters - voters.astype(jnp.int32)
-            bad_peers = n_liars - liars.astype(jnp.int32)
-        hb_ok, hb_bad = gated(
-            prop_send.any(),
-            lambda: (
-                dv.push_roundtrip_reply_counts_stat(
-                    hb_ok, t, rt_lo + ser, k_rt, prop_send,
-                    ok_peers, rt_probs, drop,
-                    axis=axis, mode=smode),
-                dv.push_roundtrip_reply_counts_stat(
-                    hb_bad, t, rt_lo + ser, jax.random.fold_in(k_rt, 1),
-                    prop_send, bad_peers, rt_probs,
-                    drop, axis=axis, mode=smode),
-            ),
-            (hb_ok, hb_bad),
-            axis,
+            vq_contrib = gated(
+                fire.any(),
+                lambda: dv.bcast_matrix_dense(
+                    k_vq, fire, fire.astype(jnp.int32), lo, hi, drop, axis=axis,
+                    impl=eimpl),
+                jnp.zeros((hi - lo, n_loc, n), jnp.int32),
+                axis,
+            )
+            vreq = ring_push_max(vreq, t, lo, vq_contrib)
+
+    with jax.named_scope("raft.tick.timer_heartbeat"):
+        # ---- timer: sendHeartBeat (raft-node.cc:405-433) ------------------------
+        hb_fire = (
+            is_leader & (jnp.int32(t) >= next_hb) & (next_hb != DISARM) & state.alive
         )
-    else:
-        if kreg:
-            def _rt(kk, peers):
-                return gd.roundtrip_reply_counts_kreg(
-                    kk, prop_send, nbr_out_loc, ids, lo, hi, drop,
-                    peer_mask=peers, axis=axis, impl=eimpl, xg=exchange)
+        # setProposal fires exactly once (raft-node.cc:216,431-433) — round==50
+        # clears add_change_value for good, so the trigger must not re-fire
+        set_prop = (jnp.int32(t) >= proposal_tick) & (proposal_tick != DISARM)
+        add_change_value = (state.add_change_value | set_prop) & ~resign
+        proposal_tick = jnp.where(set_prop, DISARM, proposal_tick)
+        prop_send = hb_fire & add_change_value
+        # Full mesh: either/or, like the reference (raft-node.cc:405-433).
+        # Gossip: the leader ALWAYS floods the 4-byte plain heartbeat — a 20 KB
+        # proposal store-and-forwards ~hops*(delay+ser) (~460 ms at defaults),
+        # far beyond the 150-300 ms election window, so using the block channel
+        # as the failure detector deposes a healthy leader every proposal phase;
+        # separating the control heartbeat from block dissemination is the
+        # documented gossip divergence.
+        plain_send = hb_fire if gossip else (hb_fire & ~add_change_value)
+        next_hb = jnp.where(hb_fire, next_hb + cfg.raft_heartbeat_ms, next_hb)
+        # SendTX: round++; at round==50 stop adding proposals (raft-node.cc:361-365)
+        round_ = state.round + prop_send
+        add_change_value = add_change_value & ~(
+            prop_send & (round_ >= cfg.raft_max_rounds)
+        )
+        # new proposal round opens the ack window
+        hb_succ = jnp.where(prop_send, 0, hb_succ) if clean else hb_succ
+        hb_cnt = jnp.where(prop_send, 0, hb_cnt) if clean else hb_cnt
+        hb_open = (hb_open | prop_send) if clean else hb_open
+
+        k_hb = chan_key(tkey, Channel.DELAY_BCAST2)
+        if queued:
+            # serial-pipe send (engine.cpp link_enqueue): the packet reaches the
+            # (leader -> j) link after its scheduling delay d_j - prop, transmits
+            # when the link frees (proposals occupy it for ser; 4-byte plain
+            # heartbeats queue behind but occupy nothing), then propagates.
+            # Deliveries land on the rings at dynamic per-destination offsets —
+            # bounded by the (ser - hb) * rounds backlog that config.ring_depth
+            # reserves — via scatter (fidelity-mode path; scatter cost is
+            # irrelevant at the n=8-ish scales queued fidelity runs at).
+            prop_ms = cfg.link_delay_ms
+            prop_val = jnp.max(jnp.where(prop_send, ids + 1, 0))
+            plain_on = jnp.max(plain_send.astype(jnp.int32))
+            sender = jnp.max(jnp.where(prop_send | plain_send, ids, -1))
+            if axis is not None:
+                prop_val = jax.lax.pmax(prop_val, axis)
+                plain_on = jax.lax.pmax(plain_on, axis)
+                sender = jax.lax.pmax(sender, axis)
+            any_send = (prop_val > 0) | (plain_on > 0)
+            dest = any_send & (ids != sender)  # crashed peers still reserve the
+            # pipe (C++ run_loop kind-2: reservation is sender-side)
+            d_j = jax.random.randint(
+                dv._shard_key(jax.random.fold_in(k_hb, 7), axis), (n_loc,), lo,
+                hi, jnp.int32,
+            )
+            ser_s = jnp.where(prop_val > 0, ser, 0)
+            start = jnp.maximum(t + d_j - prop_ms, link_busy)
+            delivery = start + ser_s + prop_ms
+            link_busy = jnp.where(dest, start + ser_s, link_busy)
+            dd = hb_prop.shape[0]
+            cols = jnp.arange(n_loc)
+            didx = jnp.where(dest, delivery % dd, dd)  # dd = out-of-bounds drop
+            hb_prop = hb_prop.at[didx, cols].max(
+                jnp.where(dest, prop_val, 0), mode="drop")
+            hb_plain = hb_plain.at[didx, cols].add(
+                (dest & (plain_on > 0)).astype(jnp.int32), mode="drop")
+        elif gossip:
+            # plain heartbeats: tiny control messages, flooded with the tick as
+            # the monotone base (concurrent leaders dedup to one — got_hb only
+            # pacifies timers); proposals carry the 20 KB block, so every hop
+            # re-serializes (store-and-forward), hence ser on each leg
+            base_h = (jnp.int32(t) + 1) * plain_send.astype(jnp.int32)
+            origin_h = (base_h * h_enc + cfg.gossip_hops) * (base_h > 0)
+            seen_hb = jnp.maximum(seen_hb, origin_h)
+            out_h = jnp.maximum(origin_h, hb_fwd)
+            plain_contrib = gated(
+                (out_h > 0).any(),
+                lambda: dv.gossip_fwd(
+                    jax.random.fold_in(k_hb, 2), out_h[:, None], nbrs_loc, n, lo,
+                    hi, drop, axis=axis, impl=eimpl)[:, :, 0],
+                zeros_flat,
+                axis,
+            )
+            hb_plain = ring_push_max(hb_plain, t, lo, plain_contrib)
+            base_p = (
+                (jnp.int32(t) + 1) * (n + 1) + ids + 1
+            ) * prop_send.astype(jnp.int32)
+            origin_p = (base_p * h_enc + cfg.gossip_hops) * (base_p > 0)
+            seen_prop = jnp.maximum(seen_prop, origin_p)
+            out_p = jnp.maximum(origin_p, prop_fwd)
+            prop_contrib = gated(
+                (out_p > 0).any(),
+                lambda: dv.gossip_fwd(
+                    jax.random.fold_in(k_hb, 3), out_p[:, None], nbrs_loc, n, lo,
+                    hi, drop, axis=axis, impl=eimpl)[:, :, 0],
+                zeros_flat,
+                axis,
+            )
+            hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
+        elif kreg:
+            if stat:
+                plain_contrib = gated(
+                    plain_send.any(),
+                    # mode stays exact for the same O(1)-sender reason as the
+                    # full-mesh stat arm below
+                    lambda: gd.bcast_counts_stat_kreg(
+                        k_hb, plain_send, nbr_in_loc, ids, ow_probs, drop,
+                        axis=axis, mode="exact", xg=exchange),
+                    zeros_flat,
+                    axis,
+                )
+                prop_contrib = gated(
+                    prop_send.any(),
+                    lambda: gd.bcast_value_max_stat_kreg(
+                        jax.random.fold_in(k_hb, 1),
+                        (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
+                        ow_probs, drop, axis=axis, xg=exchange),
+                    zeros_flat,
+                    axis,
+                )
+            else:
+                plain_contrib = gated(
+                    plain_send.any(),
+                    lambda: gd.bcast_counts_kreg(
+                        k_hb, plain_send, nbr_in_loc, ids, lo, hi, drop,
+                        axis=axis, impl=eimpl, xg=exchange),
+                    zeros_flat,
+                    axis,
+                )
+                prop_contrib = gated(
+                    prop_send.any(),
+                    lambda: gd.bcast_value_max_kreg(
+                        jax.random.fold_in(k_hb, 1), prop_send,
+                        (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
+                        ids, lo, hi, drop, axis=axis, impl=eimpl, xg=exchange),
+                    zeros_flat,
+                    axis,
+                )
+        elif stat:
+            plain_contrib = gated(
+                plain_send.any(),
+                lambda: dv.bcast_counts_stat(
+                    k_hb,
+                    _psum_scalar(plain_send.astype(jnp.int32).sum(), axis),
+                    # mode stays exact here: this channel has O(1) senders (the
+                    # leader), and the Gaussian binomial approximation is biased
+                    # for count-1 draws (~9% on a p=1/3 bucket); the sampler-cost
+                    # argument for "normal" only applies to O(N)-count channels
+                    plain_send, ow_probs, drop, axis=axis, mode="exact"),
+                zeros_flat,
+                axis,
+            )
+            prop_contrib = gated(
+                prop_send.any(),
+                lambda: dv.bcast_value_max_stat(
+                    jax.random.fold_in(k_hb, 1),
+                    (ids + 1) * prop_send.astype(jnp.int32), ow_probs, drop,
+                    axis=axis),
+                zeros_flat,
+                axis,
+            )
         else:
-            def _rt(kk, peers):
-                return dv.roundtrip_reply_counts_dense(
-                    kk, prop_send, lo, hi, drop, peer_mask=peers, axis=axis,
-                    impl=eimpl)
-        ok_counts = gated(
-            prop_send.any(), lambda: _rt(k_rt, voters), zeros_rt, axis,
-        )
-        bad_counts = gated(
-            prop_send.any(),
-            lambda: _rt(jax.random.fold_in(k_rt, 1), liars),
-            zeros_rt,
-            axis,
-        )
-        hb_ok = ring_push_add(hb_ok, t, rt_lo + ser, ok_counts)
-        hb_bad = ring_push_add(hb_bad, t, rt_lo + ser, bad_counts)
+            plain_contrib = gated(
+                plain_send.any(),
+                lambda: dv.bcast_counts_dense(k_hb, plain_send, lo, hi, drop,
+                                              axis=axis, impl=eimpl),
+                zeros_flat,
+                axis,
+            )
+            prop_contrib = gated(
+                prop_send.any(),
+                lambda: dv.bcast_value_max_dense(
+                    jax.random.fold_in(k_hb, 1), prop_send,
+                    (ids + 1) * prop_send.astype(jnp.int32), lo, hi, drop,
+                    axis=axis, impl=eimpl),
+                zeros_flat,
+                axis,
+            )
+        if not gossip and not queued:
+            hb_plain = ring_push_add(hb_plain, t, lo, plain_contrib)
+            hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
+
+        # proposal acks: follower state never affects the SUCCESS reply
+        # (raft-node.cc:170-193), so the round trip is short-circuited; Byzantine
+        # followers flip to FAILED.  The SUCCESS (honest) and FAILED (Byzantine)
+        # channels cover *disjoint* peer sets, so their independent delay draws
+        # cover disjoint edges — each ack lands in exactly one channel at one tick,
+        # and the leader's total count is their sum.  (Gossip acks are generated
+        # at flood arrival instead — see the gossip block above.)
+        k_rt = chan_key(tkey, Channel.DELAY_ROUNDTRIP)
+        voters = state.alive & state.honest
+        liars = state.alive & ~state.honest
+        if gossip:
+            pass
+        elif queued:
+            # the follower's ack is a 4-byte reply over the (follower -> leader)
+            # link, which is never busy (followers send no blocks): it departs at
+            # the proposal's queued DELIVERY tick and lands one one-way delay
+            # later.  Ack ticks are per-destination, the receiver is the single
+            # leader row: bucket them into a [D] histogram (psum'd across shards)
+            # and add it into the leader's ring column on the owning shard.
+            d2 = jax.random.randint(
+                dv._shard_key(jax.random.fold_in(k_rt, 9), axis), (n_loc,), lo,
+                hi, jnp.int32,
+            )
+            ack_arr = delivery + d2
+            prop_on = prop_val > 0
+            okd = dest & prop_on & voters
+            badd = dest & prop_on & liars
+            dd = hb_ok.shape[0]
+            hist_ok = jnp.zeros((dd,), jnp.int32).at[
+                jnp.where(okd, ack_arr % dd, dd)].add(1, mode="drop")
+            hist_bad = jnp.zeros((dd,), jnp.int32).at[
+                jnp.where(badd, ack_arr % dd, dd)].add(1, mode="drop")
+            if axis is not None:
+                hist_ok = jax.lax.psum(hist_ok, axis)
+                hist_bad = jax.lax.psum(hist_bad, axis)
+            col = sender - ids[0]
+            owned = prop_on & (col >= 0) & (col < n_loc)
+            col_c = jnp.clip(col, 0, n_loc - 1)
+            hb_ok = hb_ok.at[:, col_c].add(jnp.where(owned, hist_ok, 0))
+            hb_bad = hb_bad.at[:, col_c].add(jnp.where(owned, hist_bad, 0))
+        elif stat:
+            # fused chain-into-ring (ops/delivery.push_roundtrip_reply_counts_
+            # stat) — bit-equal to the former sample → ring_push_add compose.
+            # The kregular overlay swaps only the per-sender peer counts for
+            # out-table gathers (equal at k = N-1, same keys/chain).
+            if kreg:
+                ok_peers = gd.out_counts(voters, nbr_out_loc, ids, axis, exchange)
+                bad_peers = gd.out_counts(liars, nbr_out_loc, ids, axis, exchange)
+            else:
+                n_voters = _psum_scalar(voters.astype(jnp.int32).sum(), axis)
+                n_liars = _psum_scalar(liars.astype(jnp.int32).sum(), axis)
+                ok_peers = n_voters - voters.astype(jnp.int32)
+                bad_peers = n_liars - liars.astype(jnp.int32)
+            hb_ok, hb_bad = gated(
+                prop_send.any(),
+                lambda: (
+                    dv.push_roundtrip_reply_counts_stat(
+                        hb_ok, t, rt_lo + ser, k_rt, prop_send,
+                        ok_peers, rt_probs, drop,
+                        axis=axis, mode=smode),
+                    dv.push_roundtrip_reply_counts_stat(
+                        hb_bad, t, rt_lo + ser, jax.random.fold_in(k_rt, 1),
+                        prop_send, bad_peers, rt_probs,
+                        drop, axis=axis, mode=smode),
+                ),
+                (hb_ok, hb_bad),
+                axis,
+            )
+        else:
+            if kreg:
+                def _rt(kk, peers):
+                    return gd.roundtrip_reply_counts_kreg(
+                        kk, prop_send, nbr_out_loc, ids, lo, hi, drop,
+                        peer_mask=peers, axis=axis, impl=eimpl, xg=exchange)
+            else:
+                def _rt(kk, peers):
+                    return dv.roundtrip_reply_counts_dense(
+                        kk, prop_send, lo, hi, drop, peer_mask=peers, axis=axis,
+                        impl=eimpl)
+            ok_counts = gated(
+                prop_send.any(), lambda: _rt(k_rt, voters), zeros_rt, axis,
+            )
+            bad_counts = gated(
+                prop_send.any(),
+                lambda: _rt(jax.random.fold_in(k_rt, 1), liars),
+                zeros_rt,
+                axis,
+            )
+            hb_ok = ring_push_add(hb_ok, t, rt_lo + ser, ok_counts)
+            hb_bad = ring_push_add(hb_bad, t, rt_lo + ser, bad_counts)
 
     state = state.replace(
         is_leader=is_leader,

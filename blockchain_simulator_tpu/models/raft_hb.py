@@ -86,6 +86,10 @@ from blockchain_simulator_tpu.utils.prng import Channel, chan_key
 
 DISARM = raft_tick.DISARM
 
+# one heartbeat step of :func:`steady_scan` as a ``jax.named_scope`` name
+# (HLO metadata only — see models/pbft.SCOPES)
+SCOPES = ("raft.hb.step",)
+
 
 def prefix_ticks(cfg) -> int:
     """Static election-phase length: the last possible first-attempt election
@@ -228,6 +232,7 @@ def steady_scan(cfg, key, h: Handoff, with_probe: bool = False):
     smode = cfg.eff_stat_sampler
     need = cfg.majority_need
 
+    @jax.named_scope("raft.hb.step")
     def hb_body(carry, k):
         pend, hs, open_, bn, rnd, add_on, stopped, bt = carry
         t_k = h.hb0 + k * hb

@@ -90,6 +90,11 @@ def test_mixed_fast_path_matches_tick_engine():
     assert not use_round_schedule(CFG)  # edge delivery stays per-tick
     m_fast = run_simulation(STAT)
     m_tick = run_simulation(STAT.with_(schedule="tick"))
+    # raft commit TICKS carry the +/-1 bucket-quantile jitter of the two
+    # engines' independent draws (raft_hb's milestone contract); every
+    # other key is equal
+    tail = "raft_commit_tail_ms_max"
+    assert abs(m_fast.pop(tail) - m_tick.pop(tail)) <= 1
     assert m_fast == m_tick
     assert m_fast["global_blocks_final"] == 40
     assert m_fast["shards_with_leader"] == 8

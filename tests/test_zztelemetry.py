@@ -546,11 +546,13 @@ def test_seed_sweep_emits_operands_execute_readback_with_rows(traced):
 
 
 def _all_scopes():
-    from blockchain_simulator_tpu.models import base, pbft, pbft_round
+    from blockchain_simulator_tpu.models import (base, mixed, pbft,
+                                                 pbft_round, raft, raft_hb)
     from blockchain_simulator_tpu.ops import delay, delivery, ring
 
     return (pbft_round.SCOPES + pbft.SCOPES + delivery.SCOPES
-            + delay.SCOPES + ring.SCOPES + base.SCOPES)
+            + delay.SCOPES + ring.SCOPES + base.SCOPES
+            + mixed.SCOPES + raft.SCOPES + raft_hb.SCOPES)
 
 
 @pytest.fixture(scope="module")
@@ -559,8 +561,10 @@ def lowered_programs():
     compiled or run): the pbft tick engine on per-edge, stat and gossip
     delivery, the pbft round engine, and the raft and paxos tick engines
     for the delivery ops only they call; one op no engine calls is lowered
-    alone; and the lane-batched pbft tick program, where ``gated`` does
-    work of its own."""
+    alone; the lane-batched pbft tick program, where ``gated`` does work
+    of its own; and last a small mixed program on its fast path (both arms
+    of its cond are lowered), which holds the raft and pbft tick engines
+    under ``mixed.*``."""
     import jax
     import jax.numpy as jnp
 
@@ -593,6 +597,10 @@ def lowered_programs():
     texts.append(sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
         jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
     ).as_text(debug_info=True))
+    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
+        protocol="mixed", n=24, mixed_shards=4, sim_ms=400, delivery="stat",
+        model_serialization=False))).lower(jax.random.key(0))
+        .as_text(debug_info=True))
     return texts
 
 
@@ -606,6 +614,11 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
         assert f"{scope}/" in lowered_programs[0]
     if scope.startswith("pbft.round."):
         assert f"{scope}/" in lowered_programs[2]
+    if scope.startswith(("mixed.", "raft.", "pbft.tick.")):
+        # nested, not renamed: raft.tick.* (inside the shard batch's
+        # ``vmap(...)`` wrapper) and pbft.tick.* sit under mixed.*
+        mixed_text = lowered_programs[-1]
+        assert f"{scope}/" in mixed_text or f"({scope})/" in mixed_text
 
 
 def test_telemetry_report_quick_cli(tmp_path):

@@ -40,13 +40,13 @@ def get_protocol(name: str):
 
 
 def sim_metrics(cfg, final) -> dict:
-    """Host-side metrics for ONE final state, topology-aware: the committee
-    path's final is a stacked [C, ...] pytree whose metrics are the
-    two-level aggregate (topo/committee.py); every other topology is the
-    flat protocol's own surface.  The one metrics door runner, sweeps and
-    the scenario server share — call sites must not reach for
-    ``get_protocol(cfg.protocol).metrics`` directly once a topology can
-    reshape the final state."""
+    """Host-side metrics for ONE final state, topology-aware: a committee
+    final is a stacked [C, ...] pytree read as the two-level aggregate
+    (topo/committee.py), any other is the flat protocol's own surface.  The
+    one metrics door of runner, sweeps and server (none calls a protocol's
+    ``metrics`` itself).  ``final`` is a lone device state, each read blocking,
+    or a sweep row: host arrays in the protocol's ``METRIC_FIELDS``, all that
+    ``metrics`` promises to read, None elsewhere (parallel/sweep._readback)."""
     if cfg.topology == "committee":
         from blockchain_simulator_tpu.topo import committee
 

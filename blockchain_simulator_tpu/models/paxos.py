@@ -648,3 +648,11 @@ def metrics(cfg, state: PaxosState) -> dict:
         "gave_up": int(np.asarray(state.gave_up).sum()),
         "agreement_ok": bool(agreement),
     }
+
+
+# the state fields :func:`metrics` reads, and the only ones (see
+# pbft.METRIC_FIELDS; parallel/sweep._readback fetches these leaves alone)
+METRIC_FIELDS = (
+    "alive", "command", "commit_tick", "exec_tick", "gave_up", "is_commit",
+    "proposal", "ticket",
+)

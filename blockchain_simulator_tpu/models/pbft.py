@@ -796,3 +796,12 @@ def metrics(cfg, state: PbftState) -> dict:
         # remain observable are forged/unattributed commits, reported above
         "agreement_ok": bool(forged_commits == 0 and unattributed == 0),
     }
+
+
+# the state fields :func:`metrics` reads, and the only ones: a batched
+# dispatch fetches these leaves alone (parallel/sweep._readback) and hands
+# ``metrics`` a state whose other fields are None
+METRIC_FIELDS = (
+    "alive", "next_n", "rounds_sent", "block_num", "unattributed",
+    "view_changes", "slot_commits", "slot_commit_tick", "slot_propose_tick",
+)

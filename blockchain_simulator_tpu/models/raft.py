@@ -1021,3 +1021,11 @@ def metrics(cfg, state: RaftState) -> dict:
             lead < 0 or (stored.size == 0) or (stored == lead).all()
         ),
     }
+
+
+# the state fields :func:`metrics` reads, and the only ones (see
+# pbft.METRIC_FIELDS; parallel/sweep._readback fetches these leaves alone)
+METRIC_FIELDS = (
+    "alive", "block_num", "block_tick", "elections", "is_leader",
+    "leader_tick", "m_value", "round",
+)

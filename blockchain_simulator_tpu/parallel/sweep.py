@@ -15,15 +15,14 @@ structure (models/base.canonical_fault_cfg) and compiles once per group.
 Results are bit-equal to the per-point static path (pinned in
 tests/test_zsweep_cache.py); the mixed shard sim keeps the static path.
 
-Bit-equality caveat: under ``stat_sampler="exact"`` (and the whole edge
-path) equality is exact — integer draws whose arithmetic is identical in
-both programs.  The ``"normal"`` CLT sampler (auto at n >= 4096) has a
-float path that XLA may arrange differently in the two compiled programs:
-with the SAME keys, one message can land one delay bucket over, moving a
-commit tail by ±1 tick (measured once across a 22-point 10k sweep,
-``tools/sweep_cache_bench.py`` notes) — the same jitter class
-models/pbft_round.py documents vs the tick engine; counts and milestones
-are unaffected.
+Bit-equality caveat: under ``stat_sampler="exact"`` (and the whole edge path)
+equality is exact — integer draws whose arithmetic is identical in both
+programs.  The ``"normal"`` CLT sampler (auto at n >= 4096) has a float path
+that XLA may arrange differently in the two compiled programs: with the SAME
+keys, one message can land one delay bucket over, moving a commit tail by ±1
+tick (measured once across a 22-point 10k sweep, ``tools/sweep_cache_bench.py``
+notes) — the same jitter class models/pbft_round.py documents vs the tick
+engine; counts and milestones are unaffected.
 
 Durability: every dynamic-operand sweep accepts ``journal=`` (a
 parallel/journal.SweepJournal) — execution then chunks one fault level
@@ -43,6 +42,7 @@ configs differing only in counts must trace to ONE jaxpr fingerprint, or
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -436,17 +436,17 @@ def multi_seed_fn(cfg: SimConfig, n_seeds: int):
 
 def run_seed_sweep(cfg: SimConfig, seeds, mesh=None):
     """Run ``len(seeds)`` simulations of one config in a single vmapped
-    program; returns a list of per-seed metrics dicts."""
+    program; returns a list of per-seed metrics dicts, read back in ONE
+    device->host fetch of the leaves ``metrics`` reads (:func:`_readback`)."""
     # Every schedule is fully traceable — including round-schedule raft,
     # whose checked handoff is a lax.cond (models/raft_hb.scan_from_init)
     # that vmap lowers to a select: both branches run for the whole batch,
     # so a batched round-schedule raft sweep costs about one tick-engine
-    # pass (the fallback branch continues the prefix carry, it does not
-    # restart), never more.  The tick engines' gated deliveries are NOT
-    # such a select on one device: the batch binds the lane axis
-    # (models/base.lane_vmap) and ``gated`` branches on "any lane active",
-    # so a tick on which no lane broadcasts skips the arm as a lone run
-    # does.  Over a mesh the batch axis stays unnamed and they are selects.
+    # pass (the fallback continues the prefix carry), never more.  The tick
+    # engines' gated deliveries are NOT such a select on one device: the
+    # batch binds the lane axis (models/base.lane_vmap) and ``gated``
+    # branches on "any lane active", so a tick on which no lane broadcasts
+    # skips the arm as a lone run does.  Over a mesh they are selects.
     if mesh is not None:
         n_sweep = mesh.shape[SWEEP_AXIS]
         if len(seeds) % n_sweep != 0:
@@ -463,14 +463,13 @@ def run_seed_sweep(cfg: SimConfig, seeds, mesh=None):
     with telemetry.span("sweep.execute", rows=rows, lanes=rows):
         finals = jax.block_until_ready(batched(keys))
     out = []
-    with telemetry.span("sweep.readback", rows=rows, lanes=rows):
-        for i, seed in enumerate(seeds):
-            final_i = jax.tree.map(lambda x: x[i], finals)
-            m = sim_metrics(cfg, final_i)
+    with _readback(cfg, finals, rows) as states:
+        for seed, state in zip(seeds, states):
+            m = sim_metrics(cfg, state)
             # observability routing: a finalized COPY of every sweep row goes
             # to the optional runs.jsonl ($BLOCKSIM_RUNS_JSONL, utils/obs.py);
             # the returned dicts stay pure metrics — tests compare them
-            # bit-for-bit against single runs
+            # bit-for-bit against single runs; nothing below touches the device
             obs.record_run({"seed": int(seed), **m}, cfg)
             out.append(m)
     return out
@@ -493,7 +492,8 @@ def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
     ``probe`` (an obsim/schema.ProbeConfig) swaps in the armed twin of
     the same arm (obsim/build.py ``consobs-*`` registry entries) and
     attaches a per-row ``"probe"`` summary; monitor violations trip the
-    flight recorder host-side (obsim/host.note_violations)."""
+    flight recorder host-side (obsim/host.note_violations).  Rows are read
+    back as in :func:`run_seed_sweep`: one fetch (:func:`_readback`)."""
     points = list(points)
     # the batched-dispatch chaos point: the drills inject raise/hang/slow
     # here — the exact exception path a real backend fault takes through
@@ -537,10 +537,10 @@ def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
         outs = jax.block_until_ready(batched(keys, nc, nb))
     finals, probes = outs if probe is not None else (outs, None)
     out = []
-    with telemetry.span("sweep.readback", rows=rows, lanes=n_lanes):
-        for i, (cfg_i, seed) in enumerate(points[:rows]):
-            final_i = jax.tree.map(lambda x: x[i], finals)
-            m = sim_metrics(cfg_i, final_i)
+    # one field set for all: the points share canon's protocol and topology
+    with _readback(canon, finals, rows) as states:
+        for i, ((cfg_i, seed), state) in enumerate(zip(points, states)):
+            m = sim_metrics(cfg_i, state)
             if probe is not None:
                 from blockchain_simulator_tpu.obsim import host as obsim_host
 
@@ -944,3 +944,46 @@ def run_byzantine_sweep(cfg: SimConfig, f_values=None, seeds=(0,), forge=True,
         for seed, m in zip(seeds, res[fc]):
             out.append({"f": int(f), "seed": int(seed), **m})
     return out
+
+
+@contextlib.contextmanager
+def _readback(cfg: SimConfig, finals, rows: int):
+    """The ``sweep.readback`` span of one batched dispatch; yields the final
+    states of its first ``rows`` lanes as HOST states, in lane order.
+
+    ONE ``jax.device_get`` brings over the leaves the protocol's ``metrics``
+    reads, its module's ``METRIC_FIELDS`` (every field where a module
+    declares none, as models/mixed), each copy started before the first is
+    awaited.  Row ``i`` is then the finals' own state type with
+    ``host[f][i]`` (a numpy view) in the fetched fields and None in the
+    others, which is all ``sim_metrics`` asks for.  A committee final is
+    stacked ``[B, C, ...]`` and takes the inner protocol's fields;
+    topo/committee.metrics slices numpy per committee.
+
+    A slice per leaf and row on the device, with ``metrics`` blocking on
+    every read, is (leaves + fields) x rows round trips for the same bytes,
+    and was half of a 32-lane dispatch at n=1024 on the chip (PERF.md
+    section 6, PR 29).  The ``[N, W]`` tables never cross the host link.
+    Padded lanes (a server bucket's or a mesh's duplicates of the last
+    point) do, unread: cutting them off on the device is an eager program
+    per leaf shape, compiled at the first partial bucket, inside a serving
+    window.  The span's ``leaves`` and ``bytes`` attrs say what was
+    fetched."""
+    from blockchain_simulator_tpu.models.base import get_protocol
+
+    names = [f.name for f in dataclasses.fields(finals)]
+    fields = getattr(get_protocol(cfg.protocol), "METRIC_FIELDS", names)
+    picked = {f: getattr(finals, f) for f in fields}
+    leaves = jax.tree.leaves(picked)
+    with telemetry.span(
+        "sweep.readback", rows=rows, lanes=leaves[0].shape[0],
+        leaves=len(leaves), bytes=sum(x.nbytes for x in leaves),
+    ):
+        host = jax.device_get(picked)
+        yield [
+            type(finals)(**{
+                f: jax.tree.map(lambda x: x[i], host[f]) if f in host else None
+                for f in names
+            })
+            for i in range(rows)
+        ]

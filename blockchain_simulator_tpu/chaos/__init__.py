@@ -2,10 +2,10 @@
 
 The repo simulates Byzantine and crash faults *inside* the consensus
 models; this package applies the same discipline to the framework around
-them — the scenario server, the batched dispatch primitive, the
-persistent compile cache, the health gate.  Production code carries
-named chaos points (:func:`~blockchain_simulator_tpu.chaos.inject.
-chaos_point`) that are free when disarmed; a seeded
+them — the scenario server, the batched dispatch primitive, the health
+gate.  Production code carries named chaos points
+(:func:`~blockchain_simulator_tpu.chaos.inject.chaos_point`) that are
+free when disarmed; a seeded
 :class:`~blockchain_simulator_tpu.chaos.inject.ChaosController` arms
 them with counted, reproducible faults (raise, hang, slow, poison), and
 :mod:`~blockchain_simulator_tpu.chaos.invariants` checks that the stack
@@ -18,7 +18,7 @@ kept its accounting promises while the faults flew:
 - **registry stats monotone** — cache counters never run backwards.
 
 ``tools/chaos_drill.py`` scripts the scenarios (dispatch-fail/hang,
-cache-corrupt, health-flap, batcher-kill, queue-storm, poison-request,
+health-flap, batcher-kill, queue-storm, poison-request,
 crash-restart) and pins that each runs identically twice under one chaos
 seed; README "Chaos drills" is the operator doc.
 

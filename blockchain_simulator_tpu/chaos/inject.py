@@ -19,7 +19,7 @@ Determinism: actions trigger on **counted** firings, never wall-clock or
 probability — ``fail_next(site, n=3)`` fails exactly the next three
 firings of that site.  The controller's seeded ``rng`` exists for the
 *scenario scripts* (tools/chaos_drill.py) to draw request mixes and
-corruption offsets reproducibly; the hook layer itself is count-exact so
+flap patterns reproducibly; the hook layer itself is count-exact so
 one chaos seed replays one fault schedule bit-for-bit.
 
 Registered sites (grep ``chaos_point(`` for ground truth):

@@ -15,9 +15,8 @@ module turns that promise into a checkable function the drill
 2. **No lost manifest lines** — every terminal outcome (including each
    replayed request) has at least one runs.jsonl record carrying its id.
 3. **Registry stats monotone** — executable-registry counters
-   (hits/misses/evictions/disk_*/corrupt_healed) never decrease across
-   the scenario: a fault may add misses or heals, it may not rewind
-   history.
+   (hits/misses/evictions) never decrease across the scenario: a fault
+   may add misses, it may not rewind history.
 
 Violations are returned as human-readable strings (empty list = clean);
 the drill sums them into the ``chaos_invariant_violations`` metric.
@@ -28,10 +27,7 @@ from __future__ import annotations
 from blockchain_simulator_tpu.utils import obs
 
 # Counters that must never decrease across a scenario (invariant 3).
-MONOTONE_KEYS = (
-    "hits", "misses", "evictions", "disk_hits", "disk_misses",
-    "disk_saves", "disk_errors", "corrupt_healed",
-)
+MONOTONE_KEYS = ("hits", "misses", "evictions")
 
 # Span attrs that are deterministic under a scripted scenario and so may
 # ride the normalized span tree (everything else — durations, batch

@@ -18,9 +18,6 @@ Scenario catalog (tools/chaos_drill.py runs all; tests pick):
   opens after the threshold, solo-only mode, half-open probe re-closes;
 - ``dispatch-hang``   batched dispatch hangs/slows → queued requests
   behind the hang expire into typed 504s, slow traffic still answers;
-- ``cache-corrupt``   a persistent-cache entry is bit-flipped on disk →
-  checksum detects, self-heal (delete/recompile/rewrite) counts
-  ``corrupt_healed``, the next load is a clean disk hit;
 - ``health-flap``     a seed-driven sick/healthy verdict pattern →
   admission 503s exactly while sick, serves exactly while healthy;
 - ``batcher-kill``    the batcher thread dies mid-loop → the supervisor
@@ -171,71 +168,6 @@ def scenario_dispatch_hang(ctl, workdir, quick):
         violations.append(f"hang outcomes wrong: {ledger.kinds()}")
     return {"ledger": ledger, "stats": stats, "violations": violations,
             "extra": {"hang_s": hang_s}}
-
-
-def scenario_cache_corrupt(ctl, workdir, quick):
-    """A persistent-cache entry is bit-flipped on disk: the checksum
-    catches it BEFORE deserialization, the entry self-heals (delete →
-    recompile → rewrite, ``corrupt_healed`` counted) and the next load is
-    a clean disk hit with a bit-equal result."""
-    import jax
-    import jax.numpy as jnp
-
-    cache_dir = os.path.join(workdir, "compile_cache")
-    prev = os.environ.get(aotcache.PERSIST_ENV)
-    os.environ[aotcache.PERSIST_ENV] = cache_dir
-    violations = []
-    try:
-        args = (jnp.arange(16, dtype=jnp.int32),)
-
-        def build():
-            return jax.jit(lambda x: (x * 2 + 1).sum())
-
-        s0 = aotcache.registry.stats()
-        c1, i1 = aotcache.aot_compile("chaos-probe", build(), args)
-        v1 = int(c1(*args))
-        entries = sorted(os.listdir(cache_dir))
-        if len(entries) != 1:
-            # the save itself failed (disk full?): report, don't crash —
-            # a drill must always end in an invariant verdict
-            violations.append(f"expected 1 cache entry, found {entries}")
-            return {"ledger": None, "stats": None,
-                    "violations": violations,
-                    "extra": {"sources": [i1["source"]], "value": v1,
-                              "healed": 0}}
-        path = os.path.join(cache_dir, entries[0])
-        size = os.path.getsize(path)
-        # flip one bit in the body (the checksummed blob dominates the
-        # file; the offset is seed-driven, the detection is not)
-        offset = ctl.rng.randrange(size // 5, size - 1)
-        with open(path, "r+b") as f:
-            f.seek(offset)
-            byte = f.read(1)
-            f.seek(offset)
-            f.write(bytes([byte[0] ^ 0x40]))
-        c2, i2 = aotcache.aot_compile("chaos-probe", build(), args)
-        v2 = int(c2(*args))
-        c3, i3 = aotcache.aot_compile("chaos-probe", build(), args)
-        v3 = int(c3(*args))
-        s1 = aotcache.registry.stats()
-        healed = s1["corrupt_healed"] - s0["corrupt_healed"]
-        if healed != 1:
-            violations.append(f"corrupt_healed moved by {healed}, not 1")
-        if i2["source"] != "compile":
-            violations.append("corrupt entry was served from disk")
-        if i3["source"] != "disk":
-            violations.append("healed entry did not reload from disk")
-        if not (v1 == v2 == v3):
-            violations.append(f"values diverged: {v1} {v2} {v3}")
-        extra = {"sources": [i1["source"], i2["source"], i3["source"]],
-                 "value": v1, "healed": healed}
-    finally:
-        if prev is None:
-            os.environ.pop(aotcache.PERSIST_ENV, None)
-        else:
-            os.environ[aotcache.PERSIST_ENV] = prev
-    return {"ledger": None, "stats": None, "violations": violations,
-            "extra": extra}
 
 
 def scenario_health_flap(ctl, workdir, quick):
@@ -736,7 +668,6 @@ def scenario_query_kill9(ctl, workdir, quick):
 SCENARIOS = {
     "dispatch-fail": scenario_dispatch_fail,
     "dispatch-hang": scenario_dispatch_hang,
-    "cache-corrupt": scenario_cache_corrupt,
     "health-flap": scenario_health_flap,
     "batcher-kill": scenario_batcher_kill,
     "queue-storm": scenario_queue_storm,

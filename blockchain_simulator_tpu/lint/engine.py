@@ -362,7 +362,7 @@ def prune_baseline(
 
 def _default_paths() -> list[str]:
     out = [os.path.join(REPO_ROOT, "blockchain_simulator_tpu")]
-    for extra in ("tools", "bench.py"):
+    for extra in ("tools", "bench.py", "chip_smoke.py"):
         p = os.path.join(REPO_ROOT, extra)
         if os.path.exists(p):
             out.append(p)
@@ -401,7 +401,7 @@ def main(argv=None) -> int:
     )
     p.add_argument("paths", nargs="*",
                    help="files/dirs to lint (default: the package + tools "
-                        "+ bench.py)")
+                        "+ bench.py + chip_smoke.py)")
     p.add_argument("--format", choices=("text", "json"), default="text")
     p.add_argument("--baseline", default=None,
                    help=f"baseline file (default: {BASELINE_NAME} at the "

@@ -15,9 +15,9 @@ check IR-level contracts AST rules can only approximate:
   (``host-callback-in-program``);
 - no 64-bit dtypes and no weak-type drift across program boundaries
   (``f64-in-program``, ``weak-type-boundary``);
-- no large constants baked into the jaxpr — they bloat
-  ``$BLOCKSIM_COMPILE_CACHE`` payloads and defeat the one-executable-per-
-  fault-structure contract (``large-jaxpr-constant``);
+- no large constants baked into the jaxpr — they bloat the executable
+  and defeat the one-executable-per-fault-structure contract
+  (``large-jaxpr-constant``);
 - confirmed-slow CPU lowerings found post-trace, replacing the AST
   ``slow-cpu-lowering`` allowlist guesswork with ground truth
   (``slow-lowering-confirmed``);

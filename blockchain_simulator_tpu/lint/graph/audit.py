@@ -32,9 +32,9 @@ BASELINE_NAME = "GRAPH_BASELINE.json"
 REPO_ROOT = prog_mod.REPO_ROOT
 
 # Constants below this many bytes are normal trace residue (fault masks,
-# iota seeds); at or above it they bloat every serialized
-# $BLOCKSIM_COMPILE_CACHE entry and — when derived from per-point values a
-# sweep varies — defeat the one-executable-per-fault-structure contract.
+# iota seeds); at or above it they bloat the executable (and its compile-
+# cache entry) and — when derived from per-point values a sweep varies —
+# defeat the one-executable-per-fault-structure contract.
 LARGE_CONST_BYTES = 1 << 16  # 64 KiB
 
 # Budget growth beyond this fraction of the pinned value fails the gate.
@@ -73,7 +73,7 @@ RULE_SUMMARIES = {
     ),
     "large-jaxpr-constant": (
         f"constant >= {LARGE_CONST_BYTES} bytes baked into the jaxpr "
-        "(bloats $BLOCKSIM_COMPILE_CACHE payloads; should be an operand)"
+        "(bloats the executable; should be an operand)"
     ),
     "slow-lowering-confirmed": (
         "scatter/sort/cum* primitive confirmed in the traced IR (the "

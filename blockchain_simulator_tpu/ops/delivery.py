@@ -391,21 +391,7 @@ def push_bucket_counts(buf, t, push_lo: int, key, m, probs: np.ndarray,
     the unfused form: same keys (delay.bucket_count_chain yields exactly
     what sample_bucket_counts stacks), same integer adds, same bucket
     order.  ``expand`` (optional) maps a bucket's int32 counts to its ring
-    contribution (e.g. broadcasting per-window activity masks).
-
-    When the pallas ring kernel is armed (``BLOCKSIM_RING_KERNEL``,
-    ops/ring_kernel.py) the unfused compose runs instead, so the kernel
-    keeps seeing whole stacked contributions."""
-    from blockchain_simulator_tpu.ops import ring_kernel
-    from blockchain_simulator_tpu.ops.ring import ring_push_add
-
-    if ring_kernel.enabled():
-        cnt = sample_bucket_counts(key, m, probs, mode)
-        contrib = (
-            cnt if expand is None
-            else jnp.stack([expand(cnt[b]) for b in range(cnt.shape[0])])
-        )
-        return ring_push_add(buf, t, push_lo, contrib)
+    contribution (e.g. broadcasting per-window activity masks)."""
     d = buf.shape[0]
     for b, c in enumerate(bucket_count_chain(key, m, probs, mode)):
         cb = c.astype(jnp.int32)

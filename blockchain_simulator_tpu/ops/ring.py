@@ -40,31 +40,12 @@ def ring_pop(buf, t):
 def _push(buf, t, lo: int, contrib, op: str):
     """Combine ``contrib[b, ...]`` into slices ``t+lo+b``, b in [0, B).
 
-    Two lowerings:
-
-    - **DUS chain** (default): unrolled dynamic-slice / dynamic-update-slice
-      pairs over the (small, static) bucket axis.  A ``buf.at[idx_vec].add``
-      would lower to XLA generic scatter, which TPUs execute catastrophically
-      slowly — the round-3 ablation (tools/ablate.py) measured the scatter
-      form ~30x slower than this chain.
-    - **pallas** (opt-in, TPU only): one fused in-place kernel touching
-      exactly the B addressed ring slices (ops/ring_kernel.py).  An explicit
-      ``BLOCKSIM_RING_KERNEL=pallas`` is honoured or raises — a backend
-      other than tpu or a ring that does not tile never quietly runs the
-      chain.
-
-    Lowering selection is PROCESS-SCOPED: ``ring_kernel.enabled()`` reads
-    ``BLOCKSIM_RING_KERNEL`` at trace time, and traced sim fns are cached by
-    config (runner.make_sim_fn / parallel.shard registries), so flipping the
-    env var mid-process keeps previously built fns on their old lowering.
-    Set the variable before building sim fns (or clear the caches via
-    ``make_sim_fn.cache_clear()``) — tools/ring_kernel_bench.py runs each
-    mode in a fresh child process for exactly this reason.
+    A DUS chain: unrolled dynamic-slice / dynamic-update-slice pairs over
+    the (small, static) bucket axis.  A ``buf.at[idx_vec].add`` would lower
+    to XLA generic scatter, which TPUs execute catastrophically slowly —
+    the round-3 ablation (tools/ablate.py) measured the scatter form ~30x
+    slower than this chain.
     """
-    from blockchain_simulator_tpu.ops import ring_kernel
-
-    if ring_kernel.enabled():
-        return ring_kernel.fused_push(buf, t, lo, contrib, op)
     combine = jnp.add if op == "add" else jnp.maximum
     d = buf.shape[0]
     for b in range(contrib.shape[0]):

@@ -7,12 +7,11 @@ simulated quorum protocols preach (PAPERS.md 2007.12637):
 
 - :class:`ReplicaProc` — one replica = one ``python -m
   blockchain_simulator_tpu.serve`` daemon subprocess with its own WAL in
-  the fleet directory, all replicas sharing one persistent compile cache
-  (``$BLOCKSIM_COMPILE_CACHE``, KNOWN_ISSUES.md #0e) so the fleet warms
-  from a single set of serialized executables.
+  the fleet directory; the replicas share jax's compile cache
+  (``aotcache.enable_xla_cache``, serve/__main__.py), so replica 2..N
+  warm from what replica 1 compiled.
 - :class:`FleetManager` — spawn/monitor/kill/restart N replicas under one
-  fleet directory (``<fleet_dir>/wal/<replica>.wal`` + shared
-  ``<fleet_dir>/compile_cache``).
+  fleet directory (``<fleet_dir>/wal/<replica>.wal``).
 - **WAL lease claims** (:func:`claim_wal`) — on replica death a router
   lease-claims the dead WAL through an atomic claim file so its
   admitted-but-unanswered requests are replayed on a live peer **exactly
@@ -65,11 +64,6 @@ from blockchain_simulator_tpu.serve.wal import WriteAheadLog
 from blockchain_simulator_tpu.utils import obs
 
 CLAIM_SCHEMA = 1
-
-# The shared persistent-compile-cache env the replicas warm from
-# (utils/aotcache.py; KNOWN_ISSUES.md #0e: serialized executables
-# round-trip cross-process on XLA:CPU).
-PERSIST_ENV = "BLOCKSIM_COMPILE_CACHE"
 
 
 # --------------------------------------------------------------- claims ---
@@ -384,13 +378,9 @@ class ReplicaProc:
 
 class FleetManager:
     """N replicas under one fleet directory: WALs in ``<dir>/wal/``, each
-    replica's stderr in ``<dir>/logs/``, one
-    shared persistent compile cache in ``<dir>/compile_cache`` (unless the
-    caller already points ``$BLOCKSIM_COMPILE_CACHE`` elsewhere — the
-    bench shares one cache across fleet SIZES that way)."""
+    replica's stderr in ``<dir>/logs/``."""
 
-    def __init__(self, n_replicas: int, fleet_dir: str,
-                 shared_cache: bool = True, **replica_kw):
+    def __init__(self, n_replicas: int, fleet_dir: str, **replica_kw):
         if n_replicas < 1:
             raise ValueError("n_replicas must be >= 1")
         self.fleet_dir = str(fleet_dir)
@@ -399,9 +389,6 @@ class FleetManager:
         log_dir = os.path.join(self.fleet_dir, "logs")
         os.makedirs(log_dir, exist_ok=True)
         env = dict(replica_kw.pop("env", None) or {})
-        if shared_cache and PERSIST_ENV not in os.environ \
-                and PERSIST_ENV not in env:
-            env[PERSIST_ENV] = os.path.join(self.fleet_dir, "compile_cache")
         self.replica_kw = replica_kw
         self.replicas: list[ReplicaProc] = [
             ReplicaProc(f"replica-{i}",
@@ -415,8 +402,8 @@ class FleetManager:
 
     def start(self, timeout_s: float = 300.0) -> list[dict]:
         """Start every replica sequentially (on the 1-core box parallel
-        cold starts just thrash; the shared cache makes replica 2..N warm
-        from replica 1's serialized executables anyway)."""
+        cold starts just thrash; jax's compile cache makes replica 2..N
+        warm from what replica 1 compiled anyway)."""
         return [r.start(timeout_s) for r in self.replicas]
 
     def restart(self, replica_id: str, timeout_s: float = 300.0) -> dict:

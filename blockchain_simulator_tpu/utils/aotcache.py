@@ -1,10 +1,9 @@
-"""Unified executable registry + persistent AOT compile caching.
+"""Executable registry, AOT staging and jax's compile cache.
 
 Compilation dominates end-to-end wall on every sweep-shaped workload this
-repo cares about: the committed ``BENCH_*.json`` history shows ``compile_s``
-at 20-23 s against ~18 s of run wall, and a 0..33 Byzantine f-sweep used to
-pay one full XLA compile PER FAULT LEVEL for seconds of actual simulation.
-This module is the one place compiled programs live:
+repo cares about: a 0..33 Byzantine f-sweep used to pay one full XLA compile
+PER FAULT LEVEL for seconds of actual simulation.  This module is the one
+place compiled programs live:
 
 - **In-process registry** (:class:`ExecutableRegistry`, module singleton
   :data:`registry`): a single keyed LRU store that subsumes the scattered
@@ -14,49 +13,33 @@ This module is the one place compiled programs live:
   ``static-arg-recompile-hazard`` rule recognizes it as a sanctioned cache
   decorator, same as ``functools.lru_cache``.  Hit/miss/eviction stats are
   exported into every run manifest (``utils/obs.py`` ``cache`` block).
-- **AOT staging** (:func:`aot_compile`): explicit
-  ``jit(f).lower(*args).compile()`` with the executable's own cost analysis
-  attached — the compile-vs-run split every timing surface wants, without a
+- **AOT staging** (:func:`aot_compile`, memoized by :func:`aot_cached`):
+  explicit ``jit(f).lower(*args).compile()`` with the executable's own cost
+  analysis attached — the compile-vs-run split bench.py wants, without a
   throwaway first execution.
-- **Persistent on-disk layer**: with ``$BLOCKSIM_COMPILE_CACHE`` set,
-  :func:`aot_compile` round-trips executables through
-  ``jax.experimental.serialize_executable`` (proven on XLA:CPU only —
-  bit-equal metrics across processes, KNOWN_ISSUES.md #0e, repro:
-  ``tools/repro_exe_serialize.py``; its disk key knows neither
-  ``device_kind`` nor the libtpu version, so it is left unset on the chip).
-  Independently, :func:`enable_xla_cache` turns on jax's own compilation
-  cache for every entry point (cli, serve, bench.py, chip_smoke.py's
-  children): where ``$JAX_COMPILATION_CACHE_DIR`` says when it is set,
-  else at the fixed ``<repo>/.jax_cache`` — ``aot_compile``'s
-  ``.lower().compile()`` passes through it too.
+- **The one persistent cache is jax's own** (:func:`enable_xla_cache`),
+  turned on by every entry point (cli, serve, bench.py, the benchmark,
+  chip_smoke.py's children): where ``$JAX_COMPILATION_CACHE_DIR`` says
+  when it is set, else at the fixed ``<repo>/.jax_cache``.  Every
+  ``.lower().compile()`` passes through it, ``aot_compile``'s included; an
+  unreadable entry is jax's to handle (it warns and compiles,
+  tests/test_zsweep_cache.py).
 
-Design constraints:
-
-- **Never touch a backend at import** (jaxlint module-scope-backend-touch):
-  a chip belongs to one process at a time, so a parent that touches jax at
-  import cannot launch chip children.  This module does not even import
-  jax at module scope — ``utils/obs.py`` imports it from jax-free parents.
-- **Corrupt or stale disk entries must never take down a run**: every
-  persistent-layer failure falls back to a fresh compile and is counted in
-  the stats instead of raised.  Entries carry a content checksum verified
-  BEFORE deserialization; a failed check self-heals (detect -> delete ->
-  recompile -> rewrite) and counts ``corrupt_healed`` in the stats and in
-  every manifest ``cache`` block — the chaos cache-corrupt drill
-  (tools/chaos_drill.py) flips real bits to prove it.
+Design constraint: **never touch a backend at import** (jaxlint
+module-scope-backend-touch).  A chip belongs to one process at a time, so a
+parent that touches jax at import cannot launch chip children.  This module
+does not even import jax at module scope — ``utils/obs.py`` imports it from
+jax-free parents.
 """
 
 from __future__ import annotations
 
 import collections
 import functools
-import hashlib
 import os
-import pickle
 import threading
 import time
 
-# Persistent serialized-executable directory (unset = in-process only).
-PERSIST_ENV = "BLOCKSIM_COMPILE_CACHE"
 # jax's own compilation cache is placed from OUTSIDE with jax's own variable
 # (jax reads it itself — this module then sets no directory in code);
 # unset, every entry point shares one fixed path inside the checkout.  The
@@ -65,32 +48,6 @@ XLA_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 DEFAULT_XLA_CACHE = os.path.join(_REPO, ".jax_cache")
-
-# Bump when the on-disk entry layout changes: stale-format entries are
-# treated as misses, never parsed.  v2 added the content checksum: the
-# serialized body is hashed at write time and verified BEFORE deserialize,
-# so a bit-flipped entry (KNOWN_ISSUES.md #0e's corruption folklore) is
-# detected, deleted, recompiled and rewritten — counted as
-# ``corrupt_healed`` — instead of feeding garbage to the deserializer or
-# silently degrading to a compile with no trace of why.
-_DISK_FORMAT = 2
-
-
-class _CorruptEntry(Exception):
-    """A persistent-cache entry that failed the content checksum (or could
-    not even be parsed): bit rot, a torn write, or outside interference —
-    the self-heal path's trigger, never surfaced to callers."""
-
-
-def _dist_version(name: str) -> str | None:
-    """Installed package version without importing the package (the
-    utils/obs.py convention)."""
-    try:
-        import importlib.metadata
-
-        return importlib.metadata.version(name)
-    except Exception:
-        return None
 
 
 def _mesh_desc(args: tuple, kwargs: tuple) -> str | None:
@@ -143,11 +100,6 @@ class ExecutableRegistry:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        self.disk_hits = 0
-        self.disk_misses = 0
-        self.disk_saves = 0
-        self.disk_errors = 0
-        self.corrupt_healed = 0
         self.last_key: str | None = None
         self.last_mesh: str | None = None
 
@@ -206,13 +158,7 @@ class ExecutableRegistry:
                 "misses": self.misses,
                 "evictions": self.evictions,
                 "entries": len(self._entries),
-                "disk_hits": self.disk_hits,
-                "disk_misses": self.disk_misses,
-                "disk_saves": self.disk_saves,
-                "disk_errors": self.disk_errors,
-                "corrupt_healed": self.corrupt_healed,
                 "last_key": self.last_key,
-                "persistent_dir": persistent_dir(),
             }
 
     def stats_snapshot(self) -> dict:
@@ -251,8 +197,6 @@ class ExecutableRegistry:
                 "misses": self.misses,
                 "key": self.last_key,
                 "mesh": self.last_mesh,
-                "corrupt_healed": self.corrupt_healed,
-                "persistent_dir": persistent_dir(),
             }
 
 
@@ -287,12 +231,7 @@ def cached_factory(name: str):
     return deco
 
 
-# ------------------------------------------------------- persistent layer ---
-
-
-def persistent_dir() -> str | None:
-    """Serialized-executable directory ($BLOCKSIM_COMPILE_CACHE), or None."""
-    return os.environ.get(PERSIST_ENV) or None
+# ---------------------------------------------------- jax's compile cache ---
 
 
 def enable_xla_cache() -> str:
@@ -322,161 +261,15 @@ def enable_xla_cache() -> str:
     return path
 
 
-def _disk_key(name: str, cfg, example_args, extra) -> str:
-    """Content hash of everything that must match for a serialized
-    executable to be valid: factory name, canonical config, input avals,
-    jax/jaxlib versions, backend, device count."""
-    import dataclasses
-    import json
-
-    import jax
-
-    from blockchain_simulator_tpu.utils import obs
-
-    avals = [
-        f"{getattr(a, 'shape', None)}:{getattr(a, 'dtype', None)}"
-        for a in jax.tree.leaves(example_args)
-    ]
-    blob = json.dumps(
-        {
-            "format": _DISK_FORMAT,
-            "name": name,
-            "cfg": obs.config_hash(cfg) if dataclasses.is_dataclass(cfg) else str(cfg),
-            "avals": avals,
-            "extra": repr(extra),
-            "jax": _dist_version("jax"),
-            "jaxlib": _dist_version("jaxlib"),
-            "backend": jax.default_backend(),
-            "n_devices": len(jax.devices()),
-        },
-        sort_keys=True,
-    )
-    return hashlib.sha256(blob.encode()).hexdigest()[:24]
-
-
-def _model_modules(cfg) -> None:
-    """Import the model modules whose flax-struct pytree types appear in a
-    serialized executable's in/out treedefs — unpickling a treedef resolves
-    them by type, so they must be importable first."""
-    from blockchain_simulator_tpu.models.base import get_protocol
-
-    proto = getattr(cfg, "protocol", None)
-    if proto is None:
-        return
-    get_protocol(proto)
-    if proto == "pbft":
-        from blockchain_simulator_tpu.models import pbft_round  # noqa: F401
-    elif proto in ("raft", "mixed"):
-        from blockchain_simulator_tpu.models import raft_hb  # noqa: F401
-
-
-def _load_entry(path: str):
-    """Parse + checksum-verify one on-disk entry; returns ``(payload,
-    in_tree, out_tree)`` ready for ``deserialize_and_load``.  Raises
-    :class:`_CorruptEntry` on bit rot (unparseable container, checksum
-    mismatch, or a body that fails to parse despite its checksum) and
-    ``ValueError`` on a clean-but-stale format version — the two are
-    counted differently (``corrupt_healed`` vs ``disk_errors``) because
-    only the first means the bytes changed under us."""
-    try:
-        with open(path, "rb") as f:
-            rec = pickle.load(f)
-        fmt = rec[0]
-    except Exception as e:
-        raise _CorruptEntry(f"unparseable entry: {e}") from e
-    if fmt != _DISK_FORMAT:
-        raise ValueError(f"stale cache format {fmt}")
-    try:
-        _, digest, blob = rec
-    except Exception as e:
-        raise _CorruptEntry(f"malformed v{_DISK_FORMAT} entry: {e}") from e
-    if hashlib.sha256(blob).hexdigest() != digest:
-        raise _CorruptEntry("content checksum mismatch")
-    try:
-        return pickle.loads(blob)
-    except Exception as e:
-        # the checksum matched, so the WRITER produced a bad body — still
-        # a heal (delete + recompile + rewrite), never a crash
-        raise _CorruptEntry(f"checksummed body failed to parse: {e}") from e
-
-
-def aot_compile(name: str, jitted, example_args: tuple, cfg=None, extra=None):
+def aot_compile(jitted, example_args: tuple):
     """AOT-stage ``jitted`` for ``example_args``: returns ``(compiled,
-    info)`` where ``info`` = ``{"source": "disk"|"compile",
-    "compile_s": float, "cost": {"flops", "bytes"} | None}``.
-
-    With ``$BLOCKSIM_COMPILE_CACHE`` set, tries
-    ``jax.experimental.serialize_executable`` round-trips first (load) and
-    last (save); any disk-layer failure degrades to a fresh compile and a
-    counter bump, never an exception.  The in-process :data:`registry` is
-    the first-level cache — wrap call sites in :func:`cached_factory` (or
-    call :func:`aot_cached`) so repeat invocations skip this entirely.
-    """
-    import jax
-
-    info: dict = {"source": "compile", "compile_s": None, "cost": None}
-    pdir = persistent_dir()
-    path = None
-    if pdir:
-        try:
-            os.makedirs(pdir, exist_ok=True)
-            path = os.path.join(
-                pdir, f"{name}-{_disk_key(name, cfg, example_args, extra)}.jaxexe"
-            )
-        except Exception:
-            registry.disk_errors += 1
-            path = None
+    info)`` where ``info`` = ``{"compile_s": float, "cost": {"flops",
+    "bytes"} | None}``.  Call it through :func:`aot_cached` (or from a
+    :func:`cached_factory`) so repeat invocations skip it entirely."""
     t0 = time.perf_counter()
-    if path and os.path.exists(path):
-        try:
-            from jax.experimental.serialize_executable import (
-                deserialize_and_load,
-            )
-
-            if cfg is not None:
-                _model_modules(cfg)
-            payload, in_tree, out_tree = _load_entry(path)
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
-            registry.disk_hits += 1
-            info["source"] = "disk"
-            info["compile_s"] = time.perf_counter() - t0
-            info["cost"] = _cost(compiled)
-            return compiled, info
-        except _CorruptEntry:
-            # the self-heal cycle: detect -> delete -> recompile (below)
-            # -> rewrite (the save path overwrites).  Counted so a flaky
-            # disk is visible in every manifest instead of masquerading
-            # as an unexplained slow compile.
-            registry.corrupt_healed += 1
-            try:
-                os.unlink(path)
-            except OSError:
-                pass
-        except Exception:
-            # stale-format/foreign/undeserializable entry: recompile (and
-            # overwrite below) — the bytes were intact, the entry was not
-            # usable here
-            registry.disk_errors += 1
-    elif path:
-        registry.disk_misses += 1
     compiled = jitted.lower(*example_args).compile()
-    info["compile_s"] = time.perf_counter() - t0
-    info["cost"] = _cost(compiled)
-    if path:
-        try:
-            from jax.experimental.serialize_executable import serialize
-
-            payload, in_tree, out_tree = serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree))
-            digest = hashlib.sha256(blob).hexdigest()
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "wb") as f:
-                pickle.dump((_DISK_FORMAT, digest, blob), f)
-            os.replace(tmp, path)  # atomic: readers never see a torn entry
-            registry.disk_saves += 1
-        except Exception:
-            registry.disk_errors += 1
-    return compiled, info
+    return compiled, {"compile_s": time.perf_counter() - t0,
+                      "cost": cost_of(compiled)}
 
 
 def cost_of(staged) -> dict | None:
@@ -497,15 +290,12 @@ def cost_of(staged) -> dict | None:
         return None
 
 
-_cost = cost_of  # internal alias kept for the aot_compile call sites below
-
-
 def aot_cached(name: str, jitted_factory, example_args: tuple, cfg=None, extra=None):
     """Registry-memoized :func:`aot_compile`: one entry per (name, cfg,
     extra, input avals).  ``jitted_factory()`` is only called on a miss.
     Returns ``(compiled, info)`` — ``info`` is the build-time record (a
-    registry hit returns the original record with ``source`` unchanged and
-    ``compile_s`` as paid at build time)."""
+    registry hit returns the original record, ``compile_s`` as paid at
+    build time)."""
     import jax
 
     shapes = tuple(
@@ -516,7 +306,5 @@ def aot_cached(name: str, jitted_factory, example_args: tuple, cfg=None, extra=N
         f"aot:{name}",
         (cfg, extra, shapes),
         {},
-        lambda *_a, **_k: aot_compile(
-            name, jitted_factory(), example_args, cfg=cfg, extra=extra
-        ),
+        lambda *_a, **_k: aot_compile(jitted_factory(), example_args),
     )

@@ -296,94 +296,49 @@ def test_push_bcast_slots_stat_bit_equal_compose():
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
 
 
-# --- pallas fused ring push (ops/ring_kernel.py) ---------------------------
+# --- the ring push (ops/ring._push: the DUS chain) --------------------------
 
 
-def _dus_push(buf, t, lo, contrib, op):
-    import numpy as _np
-
-    out = _np.array(buf)
+def _np_push(buf, t, lo, contrib, op):
+    out = np.array(buf)
     d = out.shape[0]
     for b in range(contrib.shape[0]):
         idx = (t + lo + b) % d
-        c = _np.asarray(contrib[b])
-        out[idx] = out[idx] + c if op == "add" else _np.maximum(out[idx], c)
+        out[idx] = (out[idx] + contrib[b] if op == "add"
+                    else np.maximum(out[idx], contrib[b]))
     return out
 
 
+@pytest.mark.parametrize("where", ["eager", "scan"])
 @pytest.mark.parametrize("op", ["add", "max"])
-def test_ring_kernel_matches_dus(op):
-    from blockchain_simulator_tpu.ops import ring_kernel
-
-    rng = np.random.default_rng(7)
-    d, b, rest = 7, 3, (4, 128)  # L = 512 tiles as one 128-multiple block
-    buf0 = rng.integers(0, 1000, (d, *rest), dtype=np.int32)
-    contrib = rng.integers(0, 1000, (b, *rest), dtype=np.int32)
-    assert ring_kernel.pushable(jnp.asarray(buf0), jnp.asarray(contrib))
-    for t in (0, 4, 5, 6, 123):  # incl. wraparound: t+lo+b crossing d
-        got = ring_kernel.fused_push(
-            jnp.asarray(buf0), jnp.int32(t), 2, jnp.asarray(contrib), op,
-            interpret=True,
-        )
-        np.testing.assert_array_equal(np.asarray(got), _dus_push(buf0, t, 2, contrib, op))
-
-
-def test_ring_kernel_untouched_slices_survive():
-    from blockchain_simulator_tpu.ops import ring_kernel
-
-    buf0 = np.arange(6 * 256, dtype=np.int32).reshape(6, 256)
-    contrib = np.ones((2, 256), np.int32)
-    got = np.asarray(ring_kernel.fused_push(
-        jnp.asarray(buf0), jnp.int32(1), 1, jnp.asarray(contrib), "add",
-        interpret=True,
-    ))
-    np.testing.assert_array_equal(got[[0, 1, 4, 5]], buf0[[0, 1, 4, 5]])
-    np.testing.assert_array_equal(got[[2, 3]], buf0[[2, 3]] + 1)
-
-
-def test_ring_kernel_ineligible_shapes_are_refused():
-    from blockchain_simulator_tpu.ops import ring_kernel
-
-    # L = 100 has no 128-multiple divisor
-    bad = (jnp.zeros((5, 100), jnp.int32), jnp.zeros((2, 100), jnp.int32))
-    assert not ring_kernel.pushable(*bad)
-    with pytest.raises(ValueError, match="cannot tile"):
-        ring_kernel.fused_push(bad[0], 0, 1, bad[1], "add", interpret=True)
-    # B > D can never happen from ring_depth, but the guard must hold
-    assert not ring_kernel.pushable(
-        jnp.zeros((2, 128), jnp.int32), jnp.zeros((3, 128), jnp.int32)
-    )
-
-
-def test_explicit_pallas_request_raises_off_tpu(monkeypatch):
-    # BLOCKSIM_RING_KERNEL=pallas is honoured or refused, never quietly
-    # replaced by the DUS chain
+def test_ring_push_matches_numpy_model(op, where):
     from blockchain_simulator_tpu.ops import ring
 
-    buf, contrib = jnp.zeros((5, 256), jnp.int32), jnp.ones((2, 256), jnp.int32)
-    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "pallas")
-    with pytest.raises(RuntimeError, match="needs the tpu backend"):
-        ring.ring_push_add(buf, 0, 1, contrib)
-    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "auto")
-    with pytest.raises(ValueError, match="expected 'dus' or 'pallas'"):
-        ring.ring_push_add(buf, 0, 1, contrib)
-    monkeypatch.setenv("BLOCKSIM_RING_KERNEL", "dus")
-    assert int(ring.ring_push_add(buf, 0, 1, contrib).sum()) == 2 * 256
+    push = ring.ring_push_add if op == "add" else ring.ring_push_max
+    rng = np.random.default_rng(7)
+    d, b, lo, l = 5, 3, 2, 256
+    buf0 = rng.integers(0, 1000, (d, l), dtype=np.int32)
+    ticks = (0, 1, 2, 3, 4, 123)  # t+lo+b crosses d from t=1: wrap-around
+    contribs = rng.integers(0, 1000, (len(ticks), b, l), dtype=np.int32)
+    if where == "eager":  # every push on the same ring
+        befores = [buf0] * len(ticks)
+        afters = [
+            np.asarray(push(jnp.asarray(buf0), jnp.int32(t), lo,
+                            jnp.asarray(c)))
+            for t, c in zip(ticks, contribs)
+        ]
+    else:  # the production call site: pushes on a scan-carried ring
+        def body(buf, x):
+            new = push(buf, x[0], lo, x[1])
+            return new, new
 
-
-def test_ring_kernel_inside_scan_interpret():
-    # the production call site: pushes on a scan-carried ring
-    from blockchain_simulator_tpu.ops import ring_kernel
-
-    d, b, l = 5, 2, 256
-    buf0 = jnp.zeros((d, l), jnp.int32)
-    contrib = jnp.ones((b, l), jnp.int32)
-
-    def body(buf, t):
-        return ring_kernel.fused_push(buf, t, 1, contrib, "add",
-                                      interpret=True), ()
-
-    out, _ = jax.lax.scan(body, buf0, jnp.arange(10))
-    # every tick adds 1 to two slices; over 10 ticks each slice is hit
-    # 10*b/d = 4 times on average; total mass must be exactly 10*b*l
-    assert int(out.sum()) == 10 * b * l
+        _, ys = jax.lax.scan(
+            body, jnp.asarray(buf0),
+            (jnp.asarray(ticks, jnp.int32), jnp.asarray(contribs)))
+        afters = list(np.asarray(ys))
+        befores = [buf0] + afters[:-1]
+    for t, c, before, after in zip(ticks, contribs, befores, afters):
+        np.testing.assert_array_equal(after, _np_push(before, t, lo, c, op))
+        rest = sorted(set(range(d)) - {(t + lo + i) % d for i in range(b)})
+        assert len(rest) == d - b
+        np.testing.assert_array_equal(after[rest], before[rest])

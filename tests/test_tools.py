@@ -417,14 +417,18 @@ def test_lint_sh_chains_both_gates(tmp_path):
         # mesh-sharded overlay programs (~1 min each) — covered by
         # tests/test_zztopo.py and tests/test_zzshardtopo.py.
         # CONSOBS=0: the consensus-obs report compiles armed/disarmed
-        # twins (~2 min) — covered by tests/test_zzobsim.py.  Together
+        # twins (~2 min) — covered by tests/test_zzobsim.py.
+        # GATHER=0 / QUERY=0: the gather-locality smoke compiles the
+        # overlay program under both layouts on 8 devices and the query
+        # drill SIGKILLs a real subprocess — covered by
+        # tests/test_zzexchange.py and tests/test_zzquery.py.  Together
         # those stages outgrew this smoke's 240 s budget; the chain
         # itself is pinned by the script-contract asserts below.
         env={**os.environ, "BLOCKSIM_RUNS_JSONL": str(runs),
              "WARM_BENCH": "0", "GRAPH": "0", "COMMS": "0", "SERVE": "0",
              "CHAOS": "0", "MESH_SWEEP": "0", "FLEET": "0", "RESUME": "0",
              "TICK": "0", "TELEM": "0", "TOPO": "0", "SHARD_TOPO": "0",
-             "CONSOBS": "0"},
+             "CONSOBS": "0", "GATHER": "0", "QUERY": "0"},
     )
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "jaxlint" in proc.stdout and "no regression" in proc.stdout
@@ -449,6 +453,10 @@ def test_lint_sh_chains_both_gates(tmp_path):
     assert '"${TICK:-1}"' in script
     assert "tools/telemetry_report.py --quick" in script
     assert '"${TELEM:-1}"' in script
+    assert "tools/gather_locality_bench.py --quick" in script
+    assert '"${GATHER:-1}"' in script
+    assert "tools/query_drill.py --quick" in script
+    assert '"${QUERY:-1}"' in script
     recs = [json.loads(ln) for ln in runs.read_text().strip().splitlines()]
     lint_recs = [r for r in recs if r.get("metric") == "jaxlint_new_findings"]
     assert lint_recs and lint_recs[-1]["value"] == 0

@@ -1,5 +1,5 @@
 """Chaos engineering for the serving stack (chaos/, serve/wal.py, the
-hardened ScenarioServer, aotcache self-heal, health probe retries).
+hardened ScenarioServer, registry counters, health probe retries).
 
 Late-alphabet file on purpose: the scenario-level tests compile the
 shared pbft n=8 exact-sampler template (the same TPL tests/test_zserve.py
@@ -144,7 +144,7 @@ def test_checker_flags_missing_access_log_lines(tmp_path):
 
 
 def test_registry_monotone():
-    before = {"hits": 5, "misses": 2, "corrupt_healed": 0}
+    before = {"hits": 5, "misses": 2, "evictions": 0}
     assert invariants.registry_monotone(before, dict(before, hits=9)) == []
     v = invariants.registry_monotone(before, dict(before, misses=1))
     assert v and "misses" in v[0]
@@ -276,12 +276,6 @@ def test_scenario_dispatch_hang_timeouts_are_typed():
     assert rep["outcomes"]["stuck-c"] == ["timeout"]
     assert rep["outcomes"]["hung-a"] == ["ok"]
     assert rep["counts"]["timeouts"] == 2
-
-
-def test_scenario_cache_corrupt_self_heals():
-    rep = _run_clean("cache-corrupt")
-    assert rep["sources"] == ["compile", "compile", "disk"]
-    assert rep["healed"] == 1
 
 
 def test_scenario_health_flap_matches_pattern():
@@ -484,66 +478,6 @@ def test_registry_eviction_vs_inflight_builds_thread_storm(monkeypatch):
     # bounded regardless, which is the storm's actual contract
     assert stats["entries"] <= reg.maxsize
     assert stats["evictions"] > 0             # the LRU churned under fire
-
-
-# ------------------------------------------------- aotcache self-heal ------
-
-def test_aotcache_checksum_corruption_self_heals(tmp_path, monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    monkeypatch.setenv(aotcache.PERSIST_ENV, str(tmp_path / "cache"))
-    args = (jnp.arange(8, dtype=jnp.int32),)
-
-    def build():
-        return jax.jit(lambda x: (x + 3).sum())
-
-    s0 = aotcache.registry.stats()
-    c1, i1 = aotcache.aot_compile("zchaos-heal", build(), args)
-    assert i1["source"] == "compile"
-    (entry,) = list((tmp_path / "cache").iterdir())
-    size = entry.stat().st_size
-    with open(entry, "r+b") as f:
-        f.seek(size // 2)
-        byte = f.read(1)
-        f.seek(size // 2)
-        f.write(bytes([byte[0] ^ 0xFF]))
-    c2, i2 = aotcache.aot_compile("zchaos-heal", build(), args)
-    assert i2["source"] == "compile"  # healed: recompiled, rewrote
-    c3, i3 = aotcache.aot_compile("zchaos-heal", build(), args)
-    assert i3["source"] == "disk"     # the rewritten entry verifies clean
-    s1 = aotcache.registry.stats()
-    assert s1["corrupt_healed"] - s0["corrupt_healed"] == 1
-    assert s1["disk_hits"] - s0["disk_hits"] == 1
-    assert int(c1(*args)) == int(c2(*args)) == int(c3(*args))
-    # the counter is part of every stats surface (the satellite contract)
-    assert "corrupt_healed" in aotcache.registry.stats_snapshot()
-    assert "corrupt_healed" in aotcache.registry.manifest()
-
-
-def test_aotcache_stale_format_counts_disk_error_not_heal(tmp_path,
-                                                          monkeypatch):
-    import pickle
-
-    import jax
-    import jax.numpy as jnp
-
-    monkeypatch.setenv(aotcache.PERSIST_ENV, str(tmp_path / "cache"))
-    args = (jnp.arange(8, dtype=jnp.int32),)
-
-    def build():
-        return jax.jit(lambda x: (x * 5).sum())
-
-    aotcache.aot_compile("zchaos-stale", build(), args)
-    (entry,) = list((tmp_path / "cache").iterdir())
-    with open(entry, "wb") as f:  # a clean but old-format entry
-        pickle.dump((1, b"payload", None, None), f)
-    s0 = aotcache.registry.stats()
-    _, info = aotcache.aot_compile("zchaos-stale", build(), args)
-    s1 = aotcache.registry.stats()
-    assert info["source"] == "compile"
-    assert s1["disk_errors"] - s0["disk_errors"] == 1
-    assert s1["corrupt_healed"] == s0["corrupt_healed"]
 
 
 # ------------------------------------------------- health probe retry ------

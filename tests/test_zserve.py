@@ -308,8 +308,7 @@ def test_solo_dispatch_failure_is_typed_not_fatal(monkeypatch):
 
 def test_registry_stats_snapshot():
     snap = aotcache.registry.stats_snapshot()
-    for k in ("hits", "misses", "evictions", "persistent_dir",
-              "by_factory"):
+    for k in ("hits", "misses", "evictions", "by_factory"):
         assert k in snap
     assert sum(snap["by_factory"].values()) == snap["entries"]
 

@@ -92,7 +92,7 @@ def test_f64_fires_under_x64_and_clean_in_default_mode():
             jax.ShapeDtypeStruct((4,), jnp.dtype("float64")),
         )
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         res = audit_one(build)
     assert "f64-in-program" in rules_fired(res), res.findings
 

@@ -10,9 +10,9 @@ CAVEAT (round-4 finding, KNOWN_ISSUES.md #5): ablation-by-removal
 OVERSTATES the removed piece's cost.  Patching the ring pushes out also
 lets XLA dead-code-eliminate the samplers and delivery math whose only
 consumers they were, so the "no_push" delta (~2.0 ms/tick) bundled most of
-the sampling pipeline into the pushes.  Isolation measurement
-(tools/ring_kernel_bench.py) puts the pushes alone at ~128 us/tick (~75%
-of the HBM bandwidth bound).  Read deltas here as "this stage AND its
+the sampling pipeline into the pushes.  An isolation measurement (a
+push-only scan, KNOWN_ISSUES.md #5) put the pushes alone at ~128 us/tick
+(~75% of the HBM bandwidth bound).  Read deltas here as "this stage AND its
 exclusive producers", not as the stage's own cost.
 
 Usage: python tools/ablate.py [N] [TICKS]

@@ -8,18 +8,16 @@ Three legs, one artifact:
   invariant-clean (chaos/invariants.check_fleet) and byte-equal across
   the two runs — the fleet extension of tools/chaos_drill.py's contract;
 - **replica kill -9 leg** (full runs) — a REAL 2-replica subprocess fleet
-  (serve/fleet.py FleetManager, shared persistent compile cache) takes
-  SIGKILL on the replica holding admitted-but-unanswered requests
-  mid-traffic; the router lease-claims the dead WAL and replays every
+  (serve/fleet.py FleetManager) takes SIGKILL on the replica holding
+  admitted-but-unanswered requests mid-traffic; the router lease-claims the dead WAL and replays every
   pending id on the peer exactly once, answers bit-equal (exact sampler)
   to uninterrupted references, and the restarted replica replays ZERO
   (the handoff's done-records retired its backlog);
 - **traffic-shaped scaling bench** (full runs) — a seeded generator
   synthesizes million-user-shaped load phases (overdriven capacity,
   diurnal ramp, burst, hot/cold scenario skew, adversarial group mix —
-  the runs.jsonl access-log schema end to end) against 1/2/4 replicas
-  sharing one compile cache, charting req/s vs replica count and the
-  per-phase latency envelope; ``--mesh-sweep N`` adds a 1-replica
+  the runs.jsonl access-log schema end to end) against 1/2/4 replicas,
+  charting req/s vs replica count and the per-phase latency envelope; ``--mesh-sweep N`` adds a 1-replica
   mesh-dispatch comparison leg so the daemon default is measured, not
   guessed (ROADMAP item 1 follow-on).
 
@@ -233,51 +231,41 @@ def micro_bench(seed: int) -> dict:
 
 def scaling_leg(seed: int, replica_counts, fleet_root: str,
                 mesh_sweep: int = 0) -> dict:
-    """Subprocess fleets at 1/2/4 replicas sharing ONE persistent compile
-    cache (KNOWN_ISSUES #0e: later fleets — and replicas 2..N of each —
-    warm from serialized executables), each driven through the full
-    traffic-shaped phase set."""
-    from blockchain_simulator_tpu.serve.fleet import PERSIST_ENV, FleetManager
+    """Subprocess fleets at 1/2/4 replicas, each driven through the full
+    traffic-shaped phase set (later fleets — and replicas 2..N of each —
+    warm from jax's compile cache, serve/__main__.py)."""
+    from blockchain_simulator_tpu.serve.fleet import FleetManager
     from blockchain_simulator_tpu.serve.router import FleetRouter
 
-    cache_dir = os.path.join(fleet_root, "compile_cache")
-    prev_cache = os.environ.get(PERSIST_ENV)
-    os.environ[PERSIST_ENV] = cache_dir
     scaling: dict = {}
-    try:
-        legs = [(str(n), n, 0) for n in replica_counts]
-        if mesh_sweep and mesh_sweep > 1:
-            legs.append((f"1+mesh{mesh_sweep}", 1, mesh_sweep))
-        for label, n, mesh in legs:
-            fleet_dir = os.path.join(fleet_root, f"fleet-{label}")
-            mgr = FleetManager(n, fleet_dir, max_batch=8, max_wait_ms=10.0,
-                               max_queue=256, mesh_sweep=mesh, prewarm=HOT)
-            t0 = time.monotonic()
-            mgr.start()
-            start_s = time.monotonic() - t0
-            router = FleetRouter(mgr.replicas, owner="bench-router",
-                                 probe_interval_s=0.5)
-            rec: dict = {"replicas": n, "mesh_sweep": mesh or None,
-                         "start_s": round(start_s, 2), "phases": {}}
-            try:
-                for i in range(2 * n):  # touch every replica once, warm
-                    router.request(dict(HOT, seed=i, id=f"warm-{label}-{i}"),
-                                   wait_s=300)
-                for shape, count, peak in PHASES:
-                    rec["phases"][shape] = run_phase(
-                        router, shape, seed, count, peak)
-                    print(json.dumps({"fleet": label, "phase": shape,
-                                      **rec["phases"][shape]}), flush=True)
-                rec["capacity_rps"] = rec["phases"]["capacity"]["served_rps"]
-            finally:
-                router.close()
-                mgr.close()
-            scaling[label] = rec
-    finally:
-        if prev_cache is None:
-            os.environ.pop(PERSIST_ENV, None)
-        else:
-            os.environ[PERSIST_ENV] = prev_cache
+    legs = [(str(n), n, 0) for n in replica_counts]
+    if mesh_sweep and mesh_sweep > 1:
+        legs.append((f"1+mesh{mesh_sweep}", 1, mesh_sweep))
+    for label, n, mesh in legs:
+        fleet_dir = os.path.join(fleet_root, f"fleet-{label}")
+        mgr = FleetManager(n, fleet_dir, max_batch=8, max_wait_ms=10.0,
+                           max_queue=256, mesh_sweep=mesh, prewarm=HOT)
+        t0 = time.monotonic()
+        mgr.start()
+        start_s = time.monotonic() - t0
+        router = FleetRouter(mgr.replicas, owner="bench-router",
+                             probe_interval_s=0.5)
+        rec: dict = {"replicas": n, "mesh_sweep": mesh or None,
+                     "start_s": round(start_s, 2), "phases": {}}
+        try:
+            for i in range(2 * n):  # touch every replica once, warm
+                router.request(dict(HOT, seed=i, id=f"warm-{label}-{i}"),
+                               wait_s=300)
+            for shape, count, peak in PHASES:
+                rec["phases"][shape] = run_phase(
+                    router, shape, seed, count, peak)
+                print(json.dumps({"fleet": label, "phase": shape,
+                                  **rec["phases"][shape]}), flush=True)
+            rec["capacity_rps"] = rec["phases"]["capacity"]["served_rps"]
+        finally:
+            router.close()
+            mgr.close()
+        scaling[label] = rec
     return scaling
 
 

@@ -90,7 +90,7 @@ def _measure(cfg, batch: int):
     import jax
     import jax.numpy as jnp
 
-    from blockchain_simulator_tpu.models.base import get_protocol
+    from blockchain_simulator_tpu.models.base import get_protocol, lane_vmap
     from blockchain_simulator_tpu.runner import make_sim_fn
     from blockchain_simulator_tpu.utils import aotcache
 
@@ -99,7 +99,7 @@ def _measure(cfg, batch: int):
         # not a per-call recompile: the lambda only runs on a registry MISS
         # (aot_cached memoizes per (cfg, batch, avals)), so the vmap wrapper
         # and its compile happen at most once per config
-        build = lambda: jax.jit(jax.vmap(sim))  # jaxlint: disable=static-arg-recompile-hazard
+        build = lambda: jax.jit(lane_vmap(sim))  # jaxlint: disable=static-arg-recompile-hazard
         keys = lambda base: jax.vmap(jax.random.key)(
             jnp.arange(batch, dtype=jnp.uint32) + base
         )

@@ -56,14 +56,22 @@ def sim_metrics(cfg, final) -> dict:
 
 # the batch axis of the single-device vmapped programs (seed and fault sweeps,
 # the server's buckets) and of the raft shards in models/mixed.step, bound by
-# :func:`lane_vmap` alone.  Mesh arms leave their batch axis unnamed.
+# :func:`lane_vmap` alone.
 LANES_AXIS = "lanes"
+
+# the batch axis of the vmaps that cannot branch, bound by :func:`select_vmap`
+# alone: the mesh arms' batches (parallel/sweep.py, obsim/build.py), whose
+# per-lane predicate makes every cond a select of both arms.
+SELECT_AXIS = "select_lanes"
 
 # :func:`gated`'s own work under a lane batch, as a ``jax.named_scope`` (HLO
 # metadata, ops/scopes.py): the predicate's lane reduction and the per-lane
 # select of the taken arm.
 GATE_SCOPE = "ops.gate.any_lane"
-SCOPES = (GATE_SCOPE,)
+# the taken arm of :func:`gated_push`: the device events under it are the
+# ticks on which a channel's push ran.
+PUSH_SCOPE = "ops.gate.push_taken"
+SCOPES = (GATE_SCOPE, PUSH_SCOPE)
 
 
 def lane_vmap(fn):
@@ -72,9 +80,16 @@ def lane_vmap(fn):
     return jax.vmap(fn, axis_name=LANES_AXIS)
 
 
-def _under_lanes() -> bool:
+def select_vmap(fn, **kwargs):
+    """``jax.vmap(fn, **kwargs)`` for a batch whose conds lower to selects,
+    named so that :func:`gated_push`, traced inside, keeps the ring out of
+    them."""
+    return jax.vmap(fn, axis_name=SELECT_AXIS, **kwargs)
+
+
+def _under(axis_name: str) -> bool:
     try:
-        jax.lax.axis_size(LANES_AXIS)
+        jax.lax.axis_size(axis_name)
     except NameError:
         return False
     return True
@@ -90,10 +105,16 @@ def gated(pred, fn, zeros, axis=None):
     There the branch is taken on "any lane active", which is unbatched, so
     the cond stays a cond; inside the taken arm each lane keeps ``fn()``
     where its own predicate holds and ``zeros`` elsewhere, which is what the
-    select gave.  A tick on which no lane is active touches nothing."""
+    select gave.  A tick on which no lane is active touches nothing.
+
+    ``zeros`` is an operand of that select and of the ``cond``, so it is
+    never a ring: at the edge path's ``[lanes, D, N, W]`` one pass over a
+    ring costs more than a whole tick, and XLA:TPU copies a ring that
+    crosses a ``conditional``.  What is pushed into a ring goes through
+    :func:`gated_push`, as every engine call site does."""
     if axis is not None:
         pred = jax.lax.pmax(pred.astype(jnp.int32), axis) > 0
-    if not _under_lanes():
+    if not _under(LANES_AXIS):
         return jax.lax.cond(pred, fn, lambda: zeros)
     with jax.named_scope(GATE_SCOPE):
         any_lane = jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
@@ -104,6 +125,74 @@ def gated(pred, fn, zeros, axis=None):
             return jax.tree.map(lambda o, z: jnp.where(pred, o, z), out, zeros)
 
     return jax.lax.cond(any_lane, taken, lambda: zeros)
+
+
+def gated_push(pred, fn, zeros, bufs, push, axis=None):
+    """``push(bufs, gated(pred, fn, zeros, axis))`` with the push inside the
+    gate's branch: a tick with no sender leaves ``bufs`` (one ring or a
+    tuple of rings) untouched, which is what pushing ``zeros`` (the identity
+    of an add-ring, and of a max-ring whose empty value is 0) produced, and
+    saves the read-modify-write of every delay bucket's slice.
+
+    The branch is :func:`gated`'s: on ``pred``, reduced over the lanes under
+    :func:`lane_vmap`.  The per-lane select
+    stays on the contribution; the ring crosses the branch untouched and is
+    never an operand of a select.  Under :func:`select_vmap`, where no
+    branch survives, and in a program sharded over ``axis``, whose arms
+    hold collectives (XLA:CPU runs a nested loop's collectives concurrently
+    with the tick's own and aborts, KNOWN_ISSUES #0b'), the push keeps the
+    parent's form outside the loop.  A call site whose
+    push computes its own contribution (the stat arms' fused
+    chain-into-ring) passes ``tuple`` and ``()``: there a lane without a
+    sender must push zeros, as it does by drawing from zero counts.
+
+    The branch is a ``while`` of at most one trip, not a ``cond``: XLA:TPU
+    updates a ``while``'s carry in place, but around a ``conditional`` with
+    a ring among its operands it copied whole rings on every tick (PERF.md
+    section 6, PR 31: three ``[32, 152, 1024, 64]`` copies a tick).  A
+    ``while`` body has a hazard of its own, which the carry below answers:
+    whatever in it does not depend on the carry is hoisted out of the loop,
+    and so runs on every tick."""
+    if axis is not None or _under(SELECT_AXIS):
+        # the parent's programs: a gated contribution and an unconditional
+        # push, or the fused push behind the gate with its ring as ``zeros``
+        if jax.tree.leaves(zeros):
+            return push(bufs, gated(pred, fn, zeros, axis))
+        return gated(pred, lambda: push(bufs, fn()), bufs, axis)
+    lanes = _under(LANES_AXIS)
+    any_lane = pred
+    if lanes:
+        with jax.named_scope(GATE_SCOPE):
+            any_lane = jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
+    trips = any_lane.astype(jnp.int32)
+
+    def taken(bufs):
+        with jax.named_scope(PUSH_SCOPE):
+            out = fn()
+            if lanes:
+                with jax.named_scope(GATE_SCOPE):
+                    out = jax.tree.map(
+                        lambda o, z: jnp.where(pred, o, z), out, zeros)
+            return push(bufs, out)
+
+    # everything the arm closes over rides the loop's carry, behind a barrier
+    # with the trip counter: loop-invariant code motion would otherwise lift
+    # the whole arm but its last adds into the tick (measured: the samplers of
+    # mixed256x1k.solo ran on every tick, 75.1 -> 14.0 rounds/s)
+    arm = jax.make_jaxpr(taken)(bufs)
+    consts = [jnp.asarray(c) for c in arm.consts]
+    treedef = jax.tree.structure(bufs)
+
+    def body(carry):
+        i, consts, leaves = carry
+        i, consts = jax.lax.optimization_barrier((i, consts))
+        leaves = jax.core.eval_jaxpr(arm.jaxpr, consts, *leaves)
+        return i + 1, consts, leaves
+
+    leaves = jax.lax.while_loop(
+        lambda c: c[0] < trips, body,
+        (jnp.int32(0), consts, jax.tree.leaves(bufs)))[2]
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def fault_masks(cfg, n: int):

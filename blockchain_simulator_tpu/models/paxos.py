@@ -89,7 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from blockchain_simulator_tpu.models.base import fault_masks, gated
+from blockchain_simulator_tpu.models.base import fault_masks, gated_push
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import topology
@@ -383,16 +383,19 @@ def step(cfg, state: PaxosState, bufs: PaxosBufs, t, tkey, *,
     k_r = chan_key(tkey, Channel.DELAY_REPLY)
     zeros_ok = jnp.zeros((nb, n_loc, 3), jnp.int32)
     zeros_cmd = jnp.zeros((nb, n_loc), jnp.int32)
-    ok_c, no_c, cmd_c = gated(
+    resp_ok, resp_no, resp_cmd = gated_push(
         any_req,
         lambda: _reply_contribs(k_r, ok_w, no_w, cmd_wire, lo, hi, drop, axis,
                                 ids, p, impl=eimpl),
         (zeros_ok, zeros_ok, zeros_cmd),
+        (resp_ok, resp_no, resp_cmd),
+        lambda rings, c: (
+            ring_push_add(rings[0], t, lo, c[0]),
+            ring_push_add(rings[1], t, lo, c[1]),
+            ring_push_max(rings[2], t, lo, c[2]),
+        ),
         axis,
     )
-    resp_ok = ring_push_add(resp_ok, t, lo, ok_c)
-    resp_no = ring_push_add(resp_no, t, lo, no_c)
-    resp_cmd = ring_push_max(resp_cmd, t, lo, cmd_c)
 
     # ---- proposer FSM: response counting ------------------------------------
     adopt_val = jnp.maximum(state.adopt_val, cmd_t)
@@ -537,45 +540,51 @@ def step(cfg, state: PaxosState, bufs: PaxosBufs, t, tkey, *,
     cm_val = (state.ticket * c_enc + state.proposal + 1) * adv1.astype(jnp.int32)
     zeros_req = jnp.zeros((nb, n_loc, p), jnp.int32)
     channels = (
-        (tk_val, Channel.DELAY_BCAST),
-        (pp_val, Channel.DELAY_BCAST2),
-        (cm_val, Channel.DELAY_BCAST3),
+        (tk_val, Channel.DELAY_BCAST, req_ticket),
+        (pp_val, Channel.DELAY_BCAST2, req_propose),
+        (cm_val, Channel.DELAY_BCAST3, req_commit),
     )
-    contribs = []
+    pushed = []
+
+    def push_req(buf, contrib):
+        return ring_push_max(buf, t, lo, contrib)
+
     if gossip:
         # a proposer's own send is the flood origin: full TTL, own column,
         # marked seen so the loopback copy is not re-forwarded
         own = (ids[:, None] == jnp.arange(p)[None, :]).astype(jnp.int32)
-        for ci, (val, chan) in enumerate(channels):
+        for ci, (val, chan, ring) in enumerate(channels):
             init_mat = val[:, None] * own
             init_enc = (init_mat * h_enc + cfg.gossip_hops) * (init_mat > 0)
             # the origin marks its own full-TTL copy seen, so no loopback
             # copy (necessarily fewer hops) is ever re-forwarded
             seen_req = seen_req.at[:, ci, :].max(init_enc)
             enc = jnp.maximum(fwd_vals[ci], init_enc)
-            contribs.append(gated(
+            pushed.append(gated_push(
                 (enc > 0).any(),
                 lambda e=enc, c=chan: _gossip_fwd_contrib(
                     chan_key(tkey, c), e, nbrs_loc, n, lo, hi, drop, axis,
                     impl=eimpl,
                 ),
                 zeros_req,
+                ring,
+                push_req,
                 axis,
             ))
     else:
-        for val, chan in channels:
-            contribs.append(gated(
+        for val, chan, ring in channels:
+            pushed.append(gated_push(
                 (val > 0).any(),
                 lambda v=val, c=chan: _req_contrib(
                     chan_key(tkey, c), v, lo, hi, drop, axis, ids, p, ref_skip,
                     impl=eimpl, inmask=inmask,
                 ),
                 zeros_req,
+                ring,
+                push_req,
                 axis,
             ))
-    req_ticket = ring_push_max(req_ticket, t, lo, contribs[0])
-    req_propose = ring_push_max(req_propose, t, lo, contribs[1])
-    req_commit = ring_push_max(req_commit, t, lo, contribs[2])
+    req_ticket, req_propose, req_commit = pushed
 
     state = state.replace(
         t_max=t_max,

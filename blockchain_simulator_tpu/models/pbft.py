@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from blockchain_simulator_tpu.models.base import fault_masks, gated
+from blockchain_simulator_tpu.models.base import fault_masks, gated_push
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import gatherdeliv as gd
@@ -413,9 +413,10 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # fused sample-and-push (ops/delivery.push_roundtrip_reply_counts_
             # stat): each reply bucket's chain math lands straight in its ring
             # slice — bit-equal to the unfused sample → expand → ring_push_add
-            # compose, without the [B2, N, W] stacked intermediate.  The gated
-            # fallback returns the ring UNTOUCHED, which is what pushing an
-            # all-zero contribution produced.  The kregular overlay swaps ONLY
+            # compose, without the [B2, N, W] stacked intermediate.  There is
+            # no separate contribution (``()``): the gate skips the whole
+            # push, and under a lane batch a lane without a sender adds an
+            # all-zero draw, which leaves its ring as it was.  The kregular overlay swaps ONLY
             # the per-sender peer count — a gather over the out-table instead
             # of total-minus-self — and rides the same fused chain on the same
             # key (equal counts at k = N-1, hence bit-equal).
@@ -426,20 +427,22 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 if axis is not None:
                     n_voters = jax.lax.psum(n_voters, axis)
                 n_peers = n_voters - voters.astype(jnp.int32)
-            prep_rt = gated(
+            prep_rt = gated_push(
                 prep_active.any(),
-                lambda: dv.push_roundtrip_reply_counts_stat(
-                    prep_rt, t, rt_lo, k_rt, prep_active,
+                tuple,
+                (),
+                prep_rt,
+                lambda buf, _: dv.push_roundtrip_reply_counts_stat(
+                    buf, t, rt_lo, k_rt, prep_active,
                     n_peers, rt_probs, drop,
                     axis=axis, mode=smode,
                     # replies are per broadcast, i.e. per active (node, window)
                     expand=lambda c: c[:, None] * got_pp_i,
                 ),
-                prep_rt,
                 axis,
             )
         else:
-            rt_counts = gated(
+            prep_rt = gated_push(
                 prep_active.any(),
                 lambda: (
                     gd.roundtrip_reply_counts_kreg(
@@ -451,11 +454,12 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                     )
                 ),
                 jnp.zeros((len(rt_probs), n_loc), jnp.int32),
+                prep_rt,
+                # replies are per broadcast, i.e. per active (node, window)
+                lambda buf, rt_counts: ring_push_add(
+                    buf, t, rt_lo, rt_counts[:, :, None] * got_pp_i[None, :, :]
+                ),
                 axis,
-            )
-            # replies are per broadcast, i.e. per active (node, window)
-            prep_rt = ring_push_add(
-                prep_rt, t, rt_lo, rt_counts[:, :, None] * got_pp_i[None, :, :]
             )
 
     with jax.named_scope("pbft.tick.prepare"):
@@ -490,22 +494,24 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # fused chain-into-ring (see the prep_rt channel above); the
             # kregular twin gathers the per-(receiver, slot) sender counts
             # over the in-table instead of totals-minus-own
-            commit = gated(
+            commit = gated_push(
                 (commit_mat > 0).any(),
-                lambda: (
+                tuple,
+                (),
+                commit,
+                lambda buf, _: (
                     gd.push_bcast_slots_stat_kreg(
-                        commit, t, lo, k_cm, commit_mat, nbr_in_loc, ids,
+                        buf, t, lo, k_cm, commit_mat, nbr_in_loc, ids,
                         ow_probs, drop, axis=axis, mode=smode, xg=exchange,
                     ) if kreg else dv.push_bcast_slots_stat(
-                        commit, t, lo, k_cm, commit_mat, ow_probs, drop,
+                        buf, t, lo, k_cm, commit_mat, ow_probs, drop,
                         axis=axis, mode=smode,
                     )
                 ),
-                commit,
                 axis,
             )
         else:
-            cm_contrib = gated(
+            commit = gated_push(
                 (commit_mat > 0).any(),
                 lambda: (
                     gd.bcast_slots_kreg(k_cm, commit_mat, nbr_in_loc, ids, lo,
@@ -516,9 +522,10 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                                          axis=axis, impl=eimpl)
                 ),
                 zeros_w,
+                commit,
+                lambda buf, c: ring_push_add(buf, t, lo, c),
                 axis,
             )
-            commit = ring_push_add(commit, t, lo, cm_contrib)
 
     with jax.named_scope("pbft.tick.commit"):
         # ---- COMMIT arrivals → commit_vote → finality ---------------------------
@@ -603,6 +610,10 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             ppq_val = jnp.where(oh_q, val_sent, state.ppq_val)
         else:
             ppq_val = state.ppq_val
+
+        def push_pp(buf, contrib):
+            return ring_push_max(buf, t, lo + ser, contrib)
+
         if queued:
             pass  # blocks already enqueued on the serial pipes; ring untouched
         elif gossip:
@@ -616,15 +627,17 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # the random digraph, so mark the origin's copy as already seen
             seen_pp = jnp.maximum(seen_pp, origin_enc)
             pp_out = jnp.maximum(origin_enc, pp_fwd)
-            pp_contrib = gated(
+            pp = gated_push(
                 (pp_out > 0).any(),
                 lambda: dv.gossip_fwd(k_pp, pp_out, nbrs_loc, n, lo, hi, drop,
                                       axis=axis, impl=eimpl),
                 zeros_w,
+                pp,
+                push_pp,
                 axis,
             )
         elif kreg:
-            pp_contrib = gated(
+            pp = gated_push(
                 send_block.any(),
                 lambda: (
                     gd.bcast_window_value_max_stat_kreg(
@@ -636,26 +649,30 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                         impl=eimpl, xg=exchange)
                 ),
                 zeros_w,
+                pp,
+                push_pp,
                 axis,
             )
         elif stat:
-            pp_contrib = gated(
+            pp = gated_push(
                 send_block.any(),
                 lambda: dv.bcast_window_value_max_stat(k_pp, pp_val, ow_probs, drop,
                                                        axis=axis),
                 zeros_w,
+                pp,
+                push_pp,
                 axis,
             )
         else:
-            pp_contrib = gated(
+            pp = gated_push(
                 send_block.any(),
                 lambda: dv.bcast_window_value_max_dense(k_pp, pp_val, lo, hi, drop,
                                                         axis=axis, impl=eimpl),
                 zeros_w,
+                pp,
+                push_pp,
                 axis,
             )
-        if not queued:
-            pp = ring_push_max(pp, t, lo + ser, pp_contrib)
         rounds_sent = state.rounds_sent + send_block
         (slot_propose_tick,) = _scatter_window_events(
             None, None, state.slot_propose_tick,
@@ -677,20 +694,26 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
         enc = jnp.where(trigger, new_v * n + new_leader + 1, 0)
         k_vc = chan_key(tkey, Channel.DELAY_REPLY)
         zeros_flat = jnp.zeros((hi - lo, n_loc), jnp.int32)
+
+        def push_vc(buf, contrib):
+            return ring_push_max(buf, t, lo, contrib)
+
         if gossip:
             h_enc = cfg.gossip_hops + 1
             vc_origin = (enc * h_enc + cfg.gossip_hops) * (enc > 0)
             seen_vc = jnp.maximum(seen_vc, vc_origin)  # self-loop guard
             vc_out = jnp.maximum(vc_origin, vc_fwd)
-            vc_contrib = gated(
+            vc = gated_push(
                 (vc_out > 0).any(),
                 lambda: dv.gossip_fwd(k_vc, vc_out[:, None], nbrs_loc, n, lo, hi,
                                       drop, axis=axis, impl=eimpl)[:, :, 0],
                 zeros_flat,
+                vc,
+                push_vc,
                 axis,
             )
         elif kreg:
-            vc_contrib = gated(
+            vc = gated_push(
                 trigger.any(),
                 lambda: (
                     gd.bcast_value_max_stat_kreg(k_vc, enc, nbr_in_loc, ow_probs,
@@ -701,24 +724,29 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                                             xg=exchange)
                 ),
                 zeros_flat,
+                vc,
+                push_vc,
                 axis,
             )
         elif stat:
-            vc_contrib = gated(
+            vc = gated_push(
                 trigger.any(),
                 lambda: dv.bcast_value_max_stat(k_vc, enc, ow_probs, drop, axis=axis),
                 zeros_flat,
+                vc,
+                push_vc,
                 axis,
             )
         else:
-            vc_contrib = gated(
+            vc = gated_push(
                 trigger.any(),
                 lambda: dv.bcast_value_max_dense(k_vc, trigger, enc, lo, hi, drop,
                                                  axis=axis, impl=eimpl),
                 zeros_flat,
+                vc,
+                push_vc,
                 axis,
             )
-        vc = ring_push_max(vc, t, lo, vc_contrib)
 
     state = state.replace(
         seen_pp=seen_pp,

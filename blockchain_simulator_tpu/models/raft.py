@@ -63,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from blockchain_simulator_tpu.models.base import fault_masks, gated
+from blockchain_simulator_tpu.models.base import fault_masks, gated_push
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import gatherdeliv as gd
@@ -357,12 +357,13 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                     c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
                 return c
 
-            def _push_acks():
+            def _push_acks(rings, _):
                 # fused chain-into-ring (ops/delivery.push_bucket_counts):
                 # bit-equal to the former stacked sample → ring_push_add pair
                 # (same keys, same chain, same adds), minus the [2, B, N]
-                # intermediate; the gated fallback leaves the rings untouched,
-                # which is what pushing all-zero contributions produced
+                # intermediate; there is no separate contribution: the gate
+                # skips the whole push, and a lane without a sender adds
+                # all-zero counts, which leave its rings as they were
                 mok = _ack_counts(got_prop & state.honest & state.alive)
                 mbad = _ack_counts(got_prop & ~state.honest & state.alive)
                 if drop > 0.0:
@@ -374,15 +375,15 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                         smode)).astype(jnp.int32)
                 return (
                     dv.push_bucket_counts(
-                        hb_ok, t, lo, jax.random.fold_in(k_ack, 1), mok,
+                        rings[0], t, lo, jax.random.fold_in(k_ack, 1), mok,
                         ow_probs, smode),
                     dv.push_bucket_counts(
-                        hb_bad, t, lo, jax.random.fold_in(k_ack, 2), mbad,
+                        rings[1], t, lo, jax.random.fold_in(k_ack, 2), mbad,
                         ow_probs, smode),
                 )
 
-            hb_ok, hb_bad = gated(
-                got_prop.any(), _push_acks, (hb_ok, hb_bad), axis,
+            hb_ok, hb_bad = gated_push(
+                got_prop.any(), tuple, (), (hb_ok, hb_bad), _push_acks, axis,
             )
 
     with jax.named_scope("raft.tick.vote_rx"):
@@ -443,7 +444,7 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             any_req = has_req.any()
             k_vr = chan_key(tkey, Channel.DELAY_REPLY)
 
-            def push_replies():
+            def push_replies(rings, _):
                 # fused chain-into-ring — see the gossip ack block above
                 mok = reply_counts(ok_wire)
                 mno = reply_counts(no_wire)
@@ -456,15 +457,15 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                         smode)).astype(jnp.int32)
                 return (
                     dv.push_bucket_counts(
-                        vres_ok, t, lo, jax.random.fold_in(k_vr, 7), mok,
+                        rings[0], t, lo, jax.random.fold_in(k_vr, 7), mok,
                         ow_probs, smode),
                     dv.push_bucket_counts(
-                        vres_no, t, lo, jax.random.fold_in(k_vr, 8), mno,
+                        rings[1], t, lo, jax.random.fold_in(k_vr, 8), mno,
                         ow_probs, smode),
                 )
 
-            vres_ok, vres_no = gated(
-                any_req, push_replies, (vres_ok, vres_no), axis,
+            vres_ok, vres_no = gated_push(
+                any_req, tuple, (), (vres_ok, vres_no), push_replies, axis,
             )
         else:
             # vreq_t[i, j] = 1 iff candidate j's request reaches i this tick.
@@ -496,17 +497,20 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 def _unicast(kk, wire):
                     return dv.unicast_reply_counts_dense(
                         kk, wire, lo, hi, drop, axis=axis, impl=eimpl)
-            both = gated(
+            vres_ok, vres_no = gated_push(
                 any_req.any(),
                 lambda: jnp.stack([
                     _unicast(jax.random.fold_in(k_vr, 7), ok_wire),
                     _unicast(jax.random.fold_in(k_vr, 8), no_wire),
                 ]),
                 jnp.zeros((2, hi - lo, n_loc), jnp.int32),
+                (vres_ok, vres_no),
+                lambda rings, both: (
+                    ring_push_add(rings[0], t, lo, both[0]),
+                    ring_push_add(rings[1], t, lo, both[1]),
+                ),
                 axis,
             )
-            vres_ok = ring_push_add(vres_ok, t, lo, both[0])
-            vres_no = ring_push_add(vres_no, t, lo, both[1])
 
     with jax.named_scope("raft.tick.vote_reply_rx"):
         # ---- vote responses (candidate side, raft-node.cc:196-232) --------------
@@ -624,6 +628,10 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         election_deadline = jnp.where(fire, rearm2, election_deadline)
         elections = state.elections + fire
         k_vq = chan_key(tkey, Channel.DELAY_BCAST)
+
+        def push_vreq(buf, contrib):
+            return ring_push_max(buf, t, lo, contrib)
+
         if gossip:
             # flood origin: full TTL, marked seen so the self-loop copy is inert
             base_v = ((jnp.int32(t) + 1) * n + ids + 1) * fire.astype(jnp.int32)
@@ -632,16 +640,17 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             # the candidate backs its own (newest) election
             my_base = jnp.maximum(my_base, base_v)
             out_v = jnp.maximum(origin_v, vreq_fwd)
-            vq_contrib = gated(
+            vreq = gated_push(
                 (out_v > 0).any(),
                 lambda: dv.gossip_fwd(k_vq, out_v[:, None], nbrs_loc, n, lo, hi,
                                       drop, axis=axis, impl=eimpl)[:, :, 0],
                 zeros_flat,
+                vreq,
+                push_vreq,
                 axis,
             )
-            vreq = ring_push_max(vreq, t, lo, vq_contrib)
         elif stat:
-            vq_contrib = gated(
+            vreq = gated_push(
                 fire.any(),
                 lambda: (
                     gd.bcast_value_max_stat_kreg(
@@ -653,29 +662,32 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                         axis=axis)
                 ),
                 zeros_flat,
+                vreq,
+                push_vreq,
                 axis,
             )
-            vreq = ring_push_max(vreq, t, lo, vq_contrib)
         elif kreg:
-            vq_contrib = gated(
+            vreq = gated_push(
                 fire.any(),
                 lambda: gd.bcast_matrix_kreg(
                     k_vq, fire, fire.astype(jnp.int32), nbr_in_loc, ids, lo, hi,
                     drop, axis=axis, impl=eimpl, xg=exchange),
                 jnp.zeros((hi - lo, n_loc, cfg.degree + 1), jnp.int32),
+                vreq,
+                push_vreq,
                 axis,
             )
-            vreq = ring_push_max(vreq, t, lo, vq_contrib)
         else:
-            vq_contrib = gated(
+            vreq = gated_push(
                 fire.any(),
                 lambda: dv.bcast_matrix_dense(
                     k_vq, fire, fire.astype(jnp.int32), lo, hi, drop, axis=axis,
                     impl=eimpl),
                 jnp.zeros((hi - lo, n_loc, n), jnp.int32),
+                vreq,
+                push_vreq,
                 axis,
             )
-            vreq = ring_push_max(vreq, t, lo, vq_contrib)
 
     with jax.named_scope("raft.tick.timer_heartbeat"):
         # ---- timer: sendHeartBeat (raft-node.cc:405-433) ------------------------
@@ -708,6 +720,15 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         hb_open = (hb_open | prop_send) if clean else hb_open
 
         k_hb = chan_key(tkey, Channel.DELAY_BCAST2)
+
+        def push_plain(buf, contrib):
+            # the gossip flood carries a value, the direct arms a count
+            push = ring_push_max if gossip else ring_push_add
+            return push(buf, t, lo, contrib)
+
+        def push_prop(buf, contrib):
+            return ring_push_max(buf, t, lo + ser, contrib)
+
         if queued:
             # serial-pipe send (engine.cpp link_enqueue): the packet reaches the
             # (leader -> j) link after its scheduling delay d_j - prop, transmits
@@ -752,33 +773,35 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             origin_h = (base_h * h_enc + cfg.gossip_hops) * (base_h > 0)
             seen_hb = jnp.maximum(seen_hb, origin_h)
             out_h = jnp.maximum(origin_h, hb_fwd)
-            plain_contrib = gated(
+            hb_plain = gated_push(
                 (out_h > 0).any(),
                 lambda: dv.gossip_fwd(
                     jax.random.fold_in(k_hb, 2), out_h[:, None], nbrs_loc, n, lo,
                     hi, drop, axis=axis, impl=eimpl)[:, :, 0],
                 zeros_flat,
+                hb_plain,
+                push_plain,
                 axis,
             )
-            hb_plain = ring_push_max(hb_plain, t, lo, plain_contrib)
             base_p = (
                 (jnp.int32(t) + 1) * (n + 1) + ids + 1
             ) * prop_send.astype(jnp.int32)
             origin_p = (base_p * h_enc + cfg.gossip_hops) * (base_p > 0)
             seen_prop = jnp.maximum(seen_prop, origin_p)
             out_p = jnp.maximum(origin_p, prop_fwd)
-            prop_contrib = gated(
+            hb_prop = gated_push(
                 (out_p > 0).any(),
                 lambda: dv.gossip_fwd(
                     jax.random.fold_in(k_hb, 3), out_p[:, None], nbrs_loc, n, lo,
                     hi, drop, axis=axis, impl=eimpl)[:, :, 0],
                 zeros_flat,
+                hb_prop,
+                push_prop,
                 axis,
             )
-            hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
         elif kreg:
             if stat:
-                plain_contrib = gated(
+                hb_plain = gated_push(
                     plain_send.any(),
                     # mode stays exact for the same O(1)-sender reason as the
                     # full-mesh stat arm below
@@ -786,37 +809,45 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                         k_hb, plain_send, nbr_in_loc, ids, ow_probs, drop,
                         axis=axis, mode="exact", xg=exchange),
                     zeros_flat,
+                    hb_plain,
+                    push_plain,
                     axis,
                 )
-                prop_contrib = gated(
+                hb_prop = gated_push(
                     prop_send.any(),
                     lambda: gd.bcast_value_max_stat_kreg(
                         jax.random.fold_in(k_hb, 1),
                         (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
                         ow_probs, drop, axis=axis, xg=exchange),
                     zeros_flat,
+                    hb_prop,
+                    push_prop,
                     axis,
                 )
             else:
-                plain_contrib = gated(
+                hb_plain = gated_push(
                     plain_send.any(),
                     lambda: gd.bcast_counts_kreg(
                         k_hb, plain_send, nbr_in_loc, ids, lo, hi, drop,
                         axis=axis, impl=eimpl, xg=exchange),
                     zeros_flat,
+                    hb_plain,
+                    push_plain,
                     axis,
                 )
-                prop_contrib = gated(
+                hb_prop = gated_push(
                     prop_send.any(),
                     lambda: gd.bcast_value_max_kreg(
                         jax.random.fold_in(k_hb, 1), prop_send,
                         (ids + 1) * prop_send.astype(jnp.int32), nbr_in_loc,
                         ids, lo, hi, drop, axis=axis, impl=eimpl, xg=exchange),
                     zeros_flat,
+                    hb_prop,
+                    push_prop,
                     axis,
                 )
         elif stat:
-            plain_contrib = gated(
+            hb_plain = gated_push(
                 plain_send.any(),
                 lambda: dv.bcast_counts_stat(
                     k_hb,
@@ -827,37 +858,42 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                     # argument for "normal" only applies to O(N)-count channels
                     plain_send, ow_probs, drop, axis=axis, mode="exact"),
                 zeros_flat,
+                hb_plain,
+                push_plain,
                 axis,
             )
-            prop_contrib = gated(
+            hb_prop = gated_push(
                 prop_send.any(),
                 lambda: dv.bcast_value_max_stat(
                     jax.random.fold_in(k_hb, 1),
                     (ids + 1) * prop_send.astype(jnp.int32), ow_probs, drop,
                     axis=axis),
                 zeros_flat,
+                hb_prop,
+                push_prop,
                 axis,
             )
         else:
-            plain_contrib = gated(
+            hb_plain = gated_push(
                 plain_send.any(),
                 lambda: dv.bcast_counts_dense(k_hb, plain_send, lo, hi, drop,
                                               axis=axis, impl=eimpl),
                 zeros_flat,
+                hb_plain,
+                push_plain,
                 axis,
             )
-            prop_contrib = gated(
+            hb_prop = gated_push(
                 prop_send.any(),
                 lambda: dv.bcast_value_max_dense(
                     jax.random.fold_in(k_hb, 1), prop_send,
                     (ids + 1) * prop_send.astype(jnp.int32), lo, hi, drop,
                     axis=axis, impl=eimpl),
                 zeros_flat,
+                hb_prop,
+                push_prop,
                 axis,
             )
-        if not gossip and not queued:
-            hb_plain = ring_push_add(hb_plain, t, lo, plain_contrib)
-            hb_prop = ring_push_max(hb_prop, t, lo + ser, prop_contrib)
 
         # proposal acks: follower state never affects the SUCCESS reply
         # (raft-node.cc:170-193), so the round trip is short-circuited; Byzantine
@@ -912,19 +948,21 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 n_liars = _psum_scalar(liars.astype(jnp.int32).sum(), axis)
                 ok_peers = n_voters - voters.astype(jnp.int32)
                 bad_peers = n_liars - liars.astype(jnp.int32)
-            hb_ok, hb_bad = gated(
+            hb_ok, hb_bad = gated_push(
                 prop_send.any(),
-                lambda: (
+                tuple,
+                (),
+                (hb_ok, hb_bad),
+                lambda rings, _: (
                     dv.push_roundtrip_reply_counts_stat(
-                        hb_ok, t, rt_lo + ser, k_rt, prop_send,
+                        rings[0], t, rt_lo + ser, k_rt, prop_send,
                         ok_peers, rt_probs, drop,
                         axis=axis, mode=smode),
                     dv.push_roundtrip_reply_counts_stat(
-                        hb_bad, t, rt_lo + ser, jax.random.fold_in(k_rt, 1),
+                        rings[1], t, rt_lo + ser, jax.random.fold_in(k_rt, 1),
                         prop_send, bad_peers, rt_probs,
                         drop, axis=axis, mode=smode),
                 ),
-                (hb_ok, hb_bad),
                 axis,
             )
         else:
@@ -938,17 +976,21 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                     return dv.roundtrip_reply_counts_dense(
                         kk, prop_send, lo, hi, drop, peer_mask=peers, axis=axis,
                         impl=eimpl)
-            ok_counts = gated(
-                prop_send.any(), lambda: _rt(k_rt, voters), zeros_rt, axis,
+            def push_ack(buf, counts):
+                return ring_push_add(buf, t, rt_lo + ser, counts)
+
+            hb_ok = gated_push(
+                prop_send.any(), lambda: _rt(k_rt, voters), zeros_rt, hb_ok,
+                push_ack, axis,
             )
-            bad_counts = gated(
+            hb_bad = gated_push(
                 prop_send.any(),
                 lambda: _rt(jax.random.fold_in(k_rt, 1), liars),
                 zeros_rt,
+                hb_bad,
+                push_ack,
                 axis,
             )
-            hb_ok = ring_push_add(hb_ok, t, rt_lo + ser, ok_counts)
-            hb_bad = ring_push_add(hb_bad, t, rt_lo + ser, bad_counts)
 
     state = state.replace(
         is_leader=is_leader,

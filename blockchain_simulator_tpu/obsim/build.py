@@ -204,7 +204,7 @@ def probed_mesh_fn(cfg, pcfg: schema.ProbeConfig, mesh):
     if partition.mesh_size(mesh) == 1:
         return probed_batched_fn(cfg, pcfg)
     if int(dict(mesh.shape).get(NODES_AXIS, 1)) > 1:
-        batched = jax.vmap(fn)
+        batched = base_model.select_vmap(fn)
         b = max(partition.sweep_axis_size(mesh), 1)
         keys_sds = jax.eval_shape(
             lambda: jax.vmap(jax.random.key)(jnp.arange(b, dtype=jnp.uint32))

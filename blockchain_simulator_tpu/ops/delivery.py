@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from blockchain_simulator_tpu.ops import scopes
+from blockchain_simulator_tpu.ops.ring import node_minor
 from blockchain_simulator_tpu.ops.delay import (
     binom,
     bucket_count_chain,
@@ -393,6 +394,7 @@ def push_bucket_counts(buf, t, push_lo: int, key, m, probs: np.ndarray,
     order.  ``expand`` (optional) maps a bucket's int32 counts to its ring
     contribution (e.g. broadcasting per-window activity masks)."""
     d = buf.shape[0]
+    buf = node_minor(buf)
     for b, c in enumerate(bucket_count_chain(key, m, probs, mode)):
         cb = c.astype(jnp.int32)
         contrib = cb if expand is None else expand(cb)

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from blockchain_simulator_tpu.ops import scopes
 
@@ -27,14 +28,38 @@ _names: list = []
 _scoped = scopes.scoped("ops.ring", _names)
 
 
+# a TPU tile's lane width: a minor dimension narrower than this is padded to it
+_LANES = 128
+
+
+def node_minor(buf):
+    """Pin a ``[D, N, W]`` ring whose rows are narrower than a lane tile to
+    the node-minor layout.  XLA:TPU picks that layout itself where it sees
+    the whole tick, but lays out a computation called from control flow (the
+    gate of models/base.gated_push) in isolation, where it fell back to the
+    W-minor default for one ring: twice the bytes (W = 64 padded to 128) and
+    a transposing copy of the whole ring on every tick (PERF.md section 6,
+    PR 31).  With every ring op asking for the one layout, none is left to
+    guess."""
+    if buf.ndim == 3 and buf.shape[2] < _LANES <= buf.shape[1]:
+        return with_layout_constraint(buf, Layout(major_to_minor=(0, 2, 1)))
+    return buf
+
+
 @_scoped
 def ring_pop(buf, t):
-    """Read and clear the current tick's slice. Returns (slice, buf')."""
+    """Read and clear the current tick's slice. Returns (slice, buf').
+
+    The barrier makes the slice a value of its own before the ring goes on:
+    a consumer that fused the read of the OLD ring into itself, phases later,
+    kept the old ring alive across the in-place pushes and cost a copy of
+    the whole ring on every tick (PERF.md section 6, PR 31)."""
     idx = jnp.mod(t, buf.shape[0])
+    buf = node_minor(buf)
     cur = jax.lax.dynamic_index_in_dim(buf, idx, 0, keepdims=False)
-    return cur, jax.lax.dynamic_update_index_in_dim(
+    return jax.lax.optimization_barrier((cur, jax.lax.dynamic_update_index_in_dim(
         buf, jnp.zeros_like(cur), idx, 0
-    )
+    )))
 
 
 def _push(buf, t, lo: int, contrib, op: str):
@@ -45,9 +70,17 @@ def _push(buf, t, lo: int, contrib, op: str):
     to XLA generic scatter, which TPUs execute catastrophically slowly —
     the round-3 ablation (tools/ablate.py) measured the scatter form ~30x
     slower than this chain.
+
+    Every bucket read-modify-writes one slice of the ring whatever
+    ``contrib`` holds, so a call site whose contribution comes out of a gate
+    pushes through models/base.gated_push, inside the gate's branch, and a
+    tick with no sender touches no slice.  A ring is never the ``zeros`` of
+    models/base.gated: that is an operand of a select and of a
+    ``conditional``, and either costs passes over the whole ring.
     """
     combine = jnp.add if op == "add" else jnp.maximum
     d = buf.shape[0]
+    buf = node_minor(buf)
     for b in range(contrib.shape[0]):
         idx = jnp.mod(t + lo + b, d)
         cur = jax.lax.dynamic_index_in_dim(buf, idx, 0, keepdims=False)

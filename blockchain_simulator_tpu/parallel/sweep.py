@@ -53,6 +53,7 @@ from blockchain_simulator_tpu.chaos import inject
 from blockchain_simulator_tpu.models.base import (
     canonical_fault_cfg,
     lane_vmap,
+    select_vmap,
     sim_metrics,
 )
 from blockchain_simulator_tpu.parallel import journal as journal_mod
@@ -77,13 +78,14 @@ def _batched_fn(cfg: SimConfig, mesh=None):
     instead of building a fresh jit wrapper per call (jaxlint
     static-arg-recompile-hazard; runner.make_sim_fn convention).  On one
     device the batch is a lane batch (models/base.lane_vmap: ``gated``
-    stays a conditional); over a mesh it is not."""
+    stays a conditional); over a mesh it is not, every cond is a select
+    (models/base.select_vmap: ``gated_push`` keeps the ring out of it)."""
     if mesh is None:
         return jax.jit(lane_vmap(make_sim_fn(cfg)))
     from blockchain_simulator_tpu.parallel.shard import make_sharded_sim_fn
 
     return jax.jit(
-        jax.vmap(make_sharded_sim_fn(cfg, mesh), spmd_axis_name=SWEEP_AXIS)
+        select_vmap(make_sharded_sim_fn(cfg, mesh), spmd_axis_name=SWEEP_AXIS)
     )
 
 
@@ -128,7 +130,8 @@ def mesh_dyn_batched_fn(cfg: SimConfig, mesh):
     - **nodes axis > 1**: the explicit-sharding pjit arm — batch over
       ``sweep``, each lane's node dim over ``nodes``
       (partition.batched_out_shardings), XLA GSPMD partitioning the scan:
-      the "node axis optionally sharded for large n" option.
+      the "node axis optionally sharded for large n" option.  A
+      ``select_vmap``: its conds are selects (KNOWN_ISSUES #0b).
 
     Callers must pad the batch to a multiple of the sweep axis size
     (partition.pad_points; run_dyn_points does).  Bit-equality to the
@@ -139,7 +142,7 @@ def mesh_dyn_batched_fn(cfg: SimConfig, mesh):
     if partition.mesh_size(mesh) == 1:
         return dyn_batched_fn(cfg)
     if int(dict(mesh.shape).get(NODES_AXIS, 1)) > 1:
-        batched = jax.vmap(fn)
+        batched = select_vmap(fn)
         b = max(partition.sweep_axis_size(mesh), 1)
         keys_sds = jax.eval_shape(
             lambda: jax.vmap(jax.random.key)(jnp.arange(b, dtype=jnp.uint32))

@@ -1,6 +1,7 @@
-"""``models/base.gated`` under a lane batch (``lane_vmap``): the gate branches
-on "any lane active" and stays a conditional, every lane's row is its solo
-run's, and no program without the lane axis moves."""
+"""``models/base.gated`` and ``gated_push`` under a lane batch (``lane_vmap``):
+the gate branches on "any lane active" and stays a branch, a ring is pushed
+inside it and never selected, every lane's row is its solo run's, and no
+program without the lane axis moves."""
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +37,30 @@ def _plain_gated(pred, fn, zeros, axis=None):
     return jax.lax.cond(pred, fn, lambda: zeros)
 
 
-def _everywhere(monkeypatch, fn):
+def _plain_push_lone(monkeypatch):
+    """``gated_push`` of a program that binds no batch axis: the helper
+    itself with its eyes closed to every axis (the loop of at most one
+    trip around fn and push, under the helper's scope)."""
+    def push(*args, **kwargs):
+        with monkeypatch.context() as m:
+            m.setattr(base, "_under", lambda axis_name: False)
+            return base.gated_push(*args, **kwargs)
+    return push
+
+
+def _plain_push_select(pred, fn, zeros, bufs, push, axis=None):
+    """``gated_push`` under a batch that cannot branch, written out: the
+    parent's form, a gated contribution and an unconditional push."""
+    return push(bufs, _plain_gated(pred, fn, zeros, axis))
+
+
+def _everywhere(monkeypatch, gate, push=None):
+    """Every engine's ``gated`` (paxos has none left) and ``gated_push``."""
     for mod in (pbft, raft, paxos):
-        monkeypatch.setattr(mod, "gated", fn)
+        if hasattr(mod, "gated"):
+            monkeypatch.setattr(mod, "gated", gate)
+        if push is not None:
+            monkeypatch.setattr(mod, "gated_push", push)
 
 
 # ------------------------------------------------------------ (a) (b) rows
@@ -84,29 +106,46 @@ def test_one_lane_with_a_view_change_among_lanes_without():
 # ------------------------------------------------------- (c) (d) lowering
 
 
-def test_batched_pbft_program_keeps_a_conditional_per_gated_site(monkeypatch):
-    calls = []
+@pytest.mark.parametrize("name", ["pbft-edge", "pbft-stat"])
+def test_batched_pbft_program_keeps_a_conditional_per_gated_site(monkeypatch, name):
+    """A ``gated`` site stays a conditional and a ``gated_push`` site a loop
+    of at most one trip, in the lone program and under the lane batch."""
+    gates, pushes = [], []
 
     def counting(pred, fn, zeros, axis=None):
-        calls.append(1)
+        gates.append(1)
         return base.gated(pred, fn, zeros, axis)
 
-    _everywhere(monkeypatch, counting)
-    cfg = SimConfig(protocol="pbft", n=8, sim_ms=350)  # traced nowhere else
+    def counting_push(pred, fn, zeros, bufs, push, axis=None):
+        pushes.append(1)
+        return base.gated_push(pred, fn, zeros, bufs, push, axis)
+
+    cfg = SWEPT[name].with_(sim_ms=350)  # traced nowhere else
+    # the loops that are not gates (the scan, the samplers' own), counted in
+    # the parent's form of the same program
+    _everywhere(monkeypatch, base.gated, _plain_push_select)
+    other_loops = runner.make_sim_fn.__wrapped__(cfg).lower(
+        jax.random.key(0)).as_text().count("stablehlo.while")
+    _everywhere(monkeypatch, counting, counting_push)
     # the lone program first, through the SAME cached solo factory: jit's
     # trace cache must not hand the lone jaxpr to the lane batch
     lone = runner.make_sim_fn(cfg).lower(jax.random.key(0)).as_text()
-    n_lone = len(calls)
-    text = sweep._batched_fn.__wrapped__(cfg, None).lower(_keys([1, 2])).as_text(
-        debug_info=True)
-    n_sites = len(calls) - n_lone
-    assert n_sites == n_lone >= 4
-    assert text.count("stablehlo.case") >= n_sites
-    assert lone.count("stablehlo.case") == n_sites
-    assert f"{base.GATE_SCOPE}/" in text
+    n_gates, n_pushes = len(gates), len(pushes)
+    lowered = sweep._batched_fn.__wrapped__(cfg, None).lower(_keys([1, 2]))
+    text, scopes = lowered.as_text(), lowered.as_text(debug_info=True)
+    assert (len(gates) - n_gates, len(pushes) - n_pushes) == (n_gates, n_pushes)
+    # all four channels push through the helper (the stat arms' fused
+    # chain-into-ring is the push itself, with no separate contribution)
+    assert (n_gates, n_pushes) == (0, 4)
+    for program in (text, lone):
+        assert program.count("stablehlo.case") == n_gates
+        assert program.count("stablehlo.while") == n_pushes + other_loops
+    assert f"{base.GATE_SCOPE}/" in scopes
+    assert f"{base.PUSH_SCOPE}/" in scopes
     dyn = sweep.dyn_batched_fn.__wrapped__(canonical_fault_cfg(cfg)).lower(
-        _keys([1, 2]), jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
-    assert dyn.as_text().count("stablehlo.case") >= n_sites
+        _keys([1, 2]), jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32)).as_text()
+    assert dyn.count("stablehlo.case") >= n_gates
+    assert dyn.count("stablehlo.while") >= n_pushes + other_loops
 
 
 def _lone_and_mesh_texts(calls):
@@ -136,28 +175,43 @@ def _lone_and_mesh_texts(calls):
     out = {}
     for name, build in builds.items():
         before = len(calls)
-        out[name] = (build().as_text(), len(calls) - before)
+        lowered = build()
+        out[name] = (lowered.as_text(), len(calls) - before,
+                     lowered.as_text(debug_info=True))
     return out
 
 
+# which of the programs above batch under ``select_vmap`` (every cond a select)
+SELECT_BATCHED = ("sweep-batched, mesh", "partition-dyn-sweep, nodes mesh")
+
+
 def test_programs_without_a_lane_axis_are_the_plain_cond_programs(monkeypatch):
+    """No lane axis, no lane rule: ``gated`` is the plain cond, and
+    ``gated_push`` the plain loop of at most one trip around fn and push,
+    or, under ``select_vmap``, the parent's gated contribution and
+    unconditional push."""
     calls = []
 
-    def counted(gate):
-        def fn(pred, fn_, zeros, axis=None):
+    def counted(rule):
+        def fn(*args, **kwargs):
             calls.append(1)
-            return gate(pred, fn_, zeros, axis)
+            return rule(*args, **kwargs)
         return fn
 
-    _everywhere(monkeypatch, counted(base.gated))
+    _everywhere(monkeypatch, counted(base.gated), counted(base.gated_push))
     with_rule = _lone_and_mesh_texts(calls)
-    _everywhere(monkeypatch, counted(_plain_gated))
-    plain = _lone_and_mesh_texts(calls)
-    for name, (text, n_gates) in with_rule.items():
+    _everywhere(monkeypatch, counted(_plain_gated),
+                counted(_plain_push_lone(monkeypatch)))
+    plain_lone = _lone_and_mesh_texts(calls)
+    _everywhere(monkeypatch, counted(_plain_gated), counted(_plain_push_select))
+    plain_select = _lone_and_mesh_texts(calls)
+    for name, (text, n_gates, scopes) in with_rule.items():
+        plain = plain_select if name in SELECT_BATCHED else plain_lone
         # both were traced anew (no trace cache answered for the other)
         assert n_gates == plain[name][1] >= 4, name
         assert text == plain[name][0], name
-        assert base.GATE_SCOPE not in text
+        assert base.GATE_SCOPE not in scopes
+        assert (base.PUSH_SCOPE in scopes) == (name not in SELECT_BATCHED), name
 
 
 def test_gated_under_an_unnamed_vmap_is_still_a_select():
@@ -219,3 +273,210 @@ def test_benchmark_table_holds_the_gate_metric_and_its_reader(monkeypatch):
     mod.loader.exec_module(reader)
     assert reader.read({"trace": None, "traffic": {"driver": "sweep"}}) is None
     assert base.GATE_SCOPE.startswith("ops.gate.")
+
+
+# ------------------------------------------------- (f) the push inside the gate
+
+RINGS = {"add": jnp.add, "max": jnp.maximum}
+
+
+def _ring_case(op):
+    """A toy channel: ``x`` [N] sends when it is positive; the contribution
+    is two delay buckets; one ring, or a tuple of two, of depth 5."""
+    combine = RINGS[op]
+
+    def push_one(ring, t, contrib):
+        for b in range(contrib.shape[0]):
+            ring = ring.at[(t + b) % ring.shape[0]].set(
+                combine(ring[(t + b) % ring.shape[0]], contrib[b]))
+        return ring
+
+    def contribution(x):
+        return jnp.stack([x, 2 * x])
+
+    def via_helper(x, rings, t, axis=None):
+        return base.gated_push(
+            (x > 0).any(), lambda: contribution(x), jnp.zeros((2,) + x.shape, x.dtype),
+            rings, lambda rs, c: jax.tree.map(lambda r: push_one(r, t, c), rs),
+            axis)
+
+    def via_gated(x, rings, t, axis=None):
+        c = base.gated((x > 0).any(), lambda: contribution(x),
+                       jnp.zeros((2,) + x.shape, x.dtype), axis)
+        return jax.tree.map(lambda r: push_one(r, t, c), rings)
+
+    return via_helper, via_gated
+
+
+def _assert_trees_equal(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(g, w)
+
+
+X = jnp.asarray([[0, 0, 0], [1, 0, 2], [0, 0, 0], [0, 3, 0]], jnp.int32)
+
+
+def _rings(tuple_of_rings, lanes=None):
+    shape = (5, 3) if lanes is None else (lanes, 5, 3)
+    ring = jnp.arange(np.prod(shape), dtype=jnp.int32).reshape(shape) % 7
+    return (ring, ring + 1) if tuple_of_rings else ring
+
+
+@pytest.mark.parametrize("op", list(RINGS))
+@pytest.mark.parametrize("tuple_of_rings", [False, True])
+@pytest.mark.parametrize("under", ["lone", "lanes", "select", "scan", "axis"])
+def test_gated_push_equals_pushing_the_gated_contribution(op, tuple_of_rings, under):
+    """Leaf for leaf, ``gated_push`` is ``push(bufs, gated(...))``: lone, under
+    a lane batch in which one lane sends and the others do not, under a
+    batch that cannot branch, inside a scan, and with a sharded axis."""
+    via_helper, via_gated = _ring_case(op)
+    t = jnp.int32(4)
+    if under == "lone":
+        for x in X:
+            rings = _rings(tuple_of_rings)
+            _assert_trees_equal(jax.jit(via_helper)(x, rings, t),
+                                via_gated(x, rings, t))
+    elif under in ("lanes", "select"):
+        vmap = base.lane_vmap if under == "lanes" else base.select_vmap
+        rings = _rings(tuple_of_rings, lanes=4)
+        got = jax.jit(vmap(lambda x, r: via_helper(x, r, t)))(X, rings)
+        want = jax.vmap(lambda x, r: via_gated(x, r, t))(X, rings)
+        _assert_trees_equal(got, want)
+        # no lane sends: the rings come back as they went in
+        quiet = jax.jit(vmap(lambda x, r: via_helper(x, r, t)))(0 * X, rings)
+        _assert_trees_equal(quiet, rings)
+    elif under == "scan":
+        def run(step):
+            def body(rings, xt):
+                x, tt = xt
+                return step(x, rings, tt), ()
+            return jax.lax.scan(body, _rings(tuple_of_rings),
+                                (X, jnp.arange(4, dtype=jnp.int32)))[0]
+        _assert_trees_equal(jax.jit(lambda: run(via_helper))(), run(via_gated))
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        mesh = jax.make_mesh((2,), ("nodes",))
+        x = jnp.asarray([0, 0, 5, 0], jnp.int32)  # only the second shard sends
+        ring = jnp.arange(20, dtype=jnp.int32).reshape(5, 4) % 7
+        rings = (ring, ring + 1) if tuple_of_rings else ring
+        spec = jax.tree.map(lambda _: P(None, "nodes"), rings)
+
+        def sharded(step):
+            # replication checking waived, as parallel/partition._shard_map does
+            return jax.jit(jax.shard_map(
+                lambda x, r: step(x, r, t, axis="nodes"), mesh=mesh,
+                in_specs=(P("nodes"), spec), out_specs=spec, check_vma=False))
+        _assert_trees_equal(sharded(via_helper)(x, rings),
+                            sharded(via_gated)(x, rings))
+
+
+def test_gated_push_lowers_to_one_loop_and_never_selects_the_ring():
+    """Lone and under the lane batch the helper is one ``while`` and no
+    ``case``, and no select has a ring-shaped operand; under ``select_vmap``
+    it is the parent's select on the contribution alone."""
+    via_helper, _ = _ring_case("add")
+    t = jnp.int32(1)
+    rings = _rings(False, lanes=4)
+    lone = jax.jit(via_helper).lower(X[0], rings[0], t).as_text()
+    lanes = jax.jit(base.lane_vmap(lambda x, r: via_helper(x, r, t))).lower(
+        X, rings).as_text()
+    select = jax.jit(base.select_vmap(lambda x, r: via_helper(x, r, t))).lower(
+        X, rings).as_text()
+    for text in (lone, lanes):
+        assert text.count("stablehlo.while") == 1
+        assert "stablehlo.case" not in text
+    assert "stablehlo.while" not in select and "stablehlo.case" not in select
+    for text, ring_type in ((lone, "tensor<5x3xi32>"), (lanes, "tensor<4x5x3xi32>"),
+                            (select, "tensor<4x5x3xi32>")):
+        selects = [ln for ln in text.splitlines() if "stablehlo.select" in ln]
+        assert not [ln for ln in selects if ring_type in ln], selects
+
+
+# ------------------------------------- (g) the traced programs, structurally
+
+STRUCTURAL = {
+    "pbft-edge": SimConfig(protocol="pbft", n=8, sim_ms=100),
+    "raft": SimConfig(protocol="raft", n=8, sim_ms=100),
+    "paxos": SimConfig(protocol="paxos", n=8, sim_ms=100),
+}
+
+
+def _ring_shapes(cfg, n_loc=None):
+    """The ring buffers' shapes (with ``n_loc`` rows where the node dim is
+    sharded); a batched program holds them under one more leading dim."""
+    proto = base.get_protocol(cfg.protocol)
+    _, bufs = jax.eval_shape(lambda: proto.init(cfg, jax.random.key(0)))
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(bufs)}
+    if n_loc is not None:
+        shapes = {(s[0], n_loc) + s[2:] for s in shapes}
+    return shapes
+
+
+def _walk(jaxpr, in_gate=False, in_scan=False):
+    """(eqn, inside a gate's loop, inside the tick scan) for every equation,
+    sub-jaxprs included.  The tick scan is the ``scan``; a ``while`` inside
+    it is a gate (the engines have no other)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_gate, in_scan
+        gate = in_gate or (in_scan and eqn.primitive.name == "while")
+        scan = in_scan or eqn.primitive.name == "scan"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, gate, scan)
+
+
+def _ring_shaped(aval, rings):
+    shape = tuple(getattr(aval, "shape", ()))
+    return shape in rings or shape[1:] in rings
+
+
+def _structure(closed, rings):
+    selects, updates_outside, updates_inside = [], 0, 0
+    for eqn, in_gate, in_scan in _walk(closed.jaxpr):
+        if not in_scan:
+            continue
+        name = eqn.primitive.name
+        if name == "select_n" and any(
+                _ring_shaped(v.aval, rings) for v in eqn.invars):
+            selects.append(str(eqn)[:200])
+        if name in ("dynamic_update_slice", "scatter", "scatter-add") and any(
+                _ring_shaped(v.aval, rings) for v in eqn.outvars):
+            if in_gate:
+                updates_inside += 1
+            else:
+                updates_outside += 1
+    return selects, updates_outside, updates_inside
+
+
+@pytest.mark.parametrize("name", list(STRUCTURAL))
+def test_no_select_touches_a_ring_and_only_pops_update_outside_a_gate(name):
+    cfg = STRUCTURAL[name]
+    rings = _ring_shapes(cfg)
+    n_rings = len(jax.tree.leaves(jax.eval_shape(
+        lambda: base.get_protocol(cfg.protocol).init(cfg, jax.random.key(0)))[1]))
+    canon = canonical_fault_cfg(cfg)
+    programs = {
+        "make_sim_fn": jax.make_jaxpr(runner.make_sim_fn.__wrapped__(cfg))(
+            jax.random.key(0)),
+        "dyn_batched_fn": jax.make_jaxpr(sweep.dyn_batched_fn.__wrapped__(canon))(
+            _keys([1, 2]), jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32)),
+    }
+    for tag, closed in programs.items():
+        selects, outside, inside = _structure(closed, rings)
+        assert selects == [], (tag, selects)
+        assert outside == n_rings, (tag, outside)  # one pop a ring, no push
+        assert inside >= n_rings, (tag, inside)
+
+
+def test_mesh_batched_program_never_selects_a_ring_either():
+    """``_batched_fn(cfg, mesh)`` cannot branch (``select_vmap``): the
+    contribution is selected as in the parent, the ring never."""
+    cfg = SimConfig(protocol="pbft", n=8, sim_ms=100)
+    mesh = make_mesh(n_node_shards=2, n_sweep=2)
+    shard.make_sharded_sim_fn.cache_clear()
+    closed = jax.make_jaxpr(sweep._batched_fn.__wrapped__(cfg, mesh))(_keys([1, 2]))
+    rings = _ring_shapes(cfg, n_loc=4) | _ring_shapes(cfg)
+    selects, outside, inside = _structure(closed, rings)
+    assert selects == []
+    assert inside == 0 and outside > 4  # pops and unconditional pushes
+    shard.make_sharded_sim_fn.cache_clear()

@@ -38,6 +38,13 @@ ALLOWLIST = frozenset({
     # small static window axis, measured as part of the tick engine (the
     # round fast path that owns the perf target has no vote table at all)
     "pbft.py::_scatter_window_events",
+    # one cumsum over a shard's n_loc * p (row, lane) pairs, inside a taken
+    # flood arm of a SHARDED relay only: 58 us at the tests' 192 pairs and
+    # 0.2 ms at 7,500 on XLA:CPU (this box, PR 32), in an arm whose dense
+    # form scatters 120,000 updates; on four v5e chips the flood's work
+    # reads 74 us a tick where the dense arms read 1,244 (PERF.md section 6,
+    # PR 32)
+    "delivery.py::_flood_exchange",
 })
 
 

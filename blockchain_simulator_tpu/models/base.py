@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from blockchain_simulator_tpu.ops import mesh as mesh_ops
+
 
 def get_protocol(name: str):
     """Runtime protocol selection (fixes the reference's compile-time switch)."""
@@ -113,7 +115,7 @@ def gated(pred, fn, zeros, axis=None):
     crosses a ``conditional``.  What is pushed into a ring goes through
     :func:`gated_push`, as every engine call site does."""
     if axis is not None:
-        pred = jax.lax.pmax(pred.astype(jnp.int32), axis) > 0
+        pred = mesh_ops.pmax(pred.astype(jnp.int32), axis) > 0
     if not _under(LANES_AXIS):
         return jax.lax.cond(pred, fn, lambda: zeros)
     with jax.named_scope(GATE_SCOPE):

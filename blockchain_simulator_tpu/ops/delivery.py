@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from blockchain_simulator_tpu.ops import mesh as mesh_ops
 from blockchain_simulator_tpu.ops import scopes
 from blockchain_simulator_tpu.ops.ring import node_minor
 from blockchain_simulator_tpu.ops.delay import (
@@ -52,7 +53,7 @@ def _gather(x, axis):
     """Local [n_loc, ...] -> global [N, ...] along the node axis."""
     if axis is None:
         return x
-    return lax.all_gather(x, axis, tiled=True)
+    return mesh_ops.gather(x, axis)
 
 
 def _global_ids(n_loc: int, axis):
@@ -431,16 +432,71 @@ def push_roundtrip_reply_counts_stat(buf, t, push_lo: int, key, send, n_peers,
 # --------------------------------------------------------------------------- #
 
 
+# A sharded flood exchanges its senders, not the row space.  The most live
+# (row, lane) pairs one shard may hold for each size of the exchange's scatter;
+# a tick with more on any shard takes the dense arm.  On most ticks of a flood
+# a few dozen of a shard's rows forward (single-decree Paxos at 10,000 nodes
+# over four shards: 27 at the median, 603 at most), and a scatter costs by the
+# update, taken or not.
+FLOOD_TIERS = (64, 256, 1024)
+
+
+def _flood_exchange(fwd_vals, d, vals, nbrs_loc, n_glob, lo, hi, axis, tiers,
+                    bits, dense):
+    """The sharded arm of :func:`gossip_fwd` without the global row space:
+    every shard packs its live (row, lane) pairs' updates, the packets are
+    all-gathered, and each shard scatters into its OWN rows the updates that
+    land there.  Equal to ``dense()`` entry for entry (a max over the same
+    updates).  A packet holds ``tiers[-1]`` pairs; ``dense`` is taken when a
+    shard has more.  An update travels as ``receiver | bucket << bits``."""
+    n_loc, p = fwd_vals.shape
+    deg, nb = nbrs_loc.shape[1], hi - lo
+    n_shards = n_glob // n_loc
+    kmax = tiers[-1]
+    live = (fwd_vals > 0).reshape(-1)  # pairs, row-major
+    upto = jnp.cumsum(live.astype(jnp.int32))
+    count = upto[-1]
+    # before the k-th live pair (from 0) lie the pairs whose running count is
+    # k or less
+    k = jnp.arange(kmax, dtype=jnp.int32)
+    pair = (upto[None, :] <= k[:, None]).sum(axis=1)
+    held = k < count
+    pair = jnp.where(held, pair, 0)
+    row, lane = pair // p, pair % p
+    code = ((d[row, :, lane] - lo) << bits) | nbrs_loc[row]  # [kmax, deg]
+    val = vals[row, :, lane] * held[:, None]
+    packet = jnp.concatenate([code, val, lane[:, None]], axis=1)
+    packet = jnp.concatenate(
+        [packet, jnp.full((1, 2 * deg + 1), count, jnp.int32)])
+    got = mesh_ops.gather(packet, axis).reshape(n_shards, kmax + 1, 2 * deg + 1)
+    most = got[:, kmax, 0].max()
+    start = lax.axis_index(axis) * n_loc
+
+    def scatter(size):
+        u = got[:, :size].reshape(n_shards * size, 2 * deg + 1)
+        code, val, lane = u[:, :deg], u[:, deg:2 * deg], u[:, 2 * deg]
+        loc = (code & ((1 << bits) - 1)) - start
+        mine = (loc >= 0) & (loc < n_loc) & (val > 0)
+        idx = jnp.where(mine, (code >> bits) * n_loc + loc, nb * n_loc)
+        flat = jnp.zeros((nb * n_loc, p), jnp.int32)
+        flat = flat.at[idx, lane[:, None]].max(val, mode="drop")
+        return flat.reshape(nb, n_loc, p)
+
+    arms = [lambda size=size: scatter(size) for size in tiers] + [dense]
+    return lax.switch((most > jnp.asarray(tiers)).sum(), arms)
+
+
 @_scoped
 def gossip_fwd(key, fwd_vals, nbrs_loc, n_glob, lo, hi, drop_prob=0.0, axis=None,
-               fold=0x0D22, impl="threefry"):
+               fold=0x0D22, impl="threefry", tiers=FLOOD_TIERS):
     """TTL-flood forwarding: ``fwd_vals [N_loc, P]`` (>0 TTL-encoded values
     held by local rows; P = any per-value lane — Paxos proposers, PBFT
     windows) → ``[B, N_loc, P]`` scatter-max contributions at each sender's
     out-neighbors (``nbrs_loc [N_loc, deg]`` global ids), one fresh delay draw
-    per (sender, edge, lane).  Sharded: scatter into the global row space,
-    pmax across shards (each shard contributes its senders' forwards), slice
-    the local rows back out."""
+    per (sender, edge, lane).  Sharded: the shards exchange their senders'
+    updates (:func:`_flood_exchange`) where every shard's fit ``tiers``, else
+    scatter into the global row space, pmax across shards (each shard
+    contributes its senders' forwards), slice the local rows back out."""
     n_loc, p = fwd_vals.shape
     deg = nbrs_loc.shape[1]
     k = _shard_key(key, axis)
@@ -451,17 +507,26 @@ def gossip_fwd(key, fwd_vals, nbrs_loc, n_glob, lo, hi, drop_prob=0.0, axis=None
             jax.random.fold_in(k, fold), 1.0 - drop_prob, (n_loc, deg, p)
         )
         vals = vals * keep
-    # one scatter-max over a flattened (bucket, receiver) index — XLA handles
-    # a single big scatter far better than hi-lo separate ones
-    flat_idx = (d - lo) * n_glob + nbrs_loc[:, :, None]  # [n_loc, deg, p]
-    flat = jnp.zeros(((hi - lo) * n_glob, p), jnp.int32)
-    flat = flat.at[flat_idx, jnp.arange(p)[None, None, :]].max(vals)
-    out = flat.reshape(hi - lo, n_glob, p)
-    if axis is not None:
-        out = lax.pmax(out, axis)
-        start = lax.axis_index(axis) * n_loc
-        out = lax.dynamic_slice_in_dim(out, start, n_loc, axis=1)
-    return out
+
+    def dense():
+        # one scatter-max over a flattened (bucket, receiver) index — XLA
+        # handles a single big scatter far better than hi-lo separate ones
+        flat_idx = (d - lo) * n_glob + nbrs_loc[:, :, None]  # [n_loc, deg, p]
+        flat = jnp.zeros(((hi - lo) * n_glob, p), jnp.int32)
+        flat = flat.at[flat_idx, jnp.arange(p)[None, None, :]].max(vals)
+        out = flat.reshape(hi - lo, n_glob, p)
+        if axis is not None:
+            out = mesh_ops.pmax(out, axis)
+            start = lax.axis_index(axis) * n_loc
+            out = lax.dynamic_slice_in_dim(out, start, n_loc, axis=1)
+        return out
+
+    bits = max(n_glob - 1, 1).bit_length()
+    tiers = tuple(t for t in tiers if t < n_loc * p)
+    if axis is None or not tiers or (hi - lo) << bits >= 2 ** 31:
+        return dense()
+    return _flood_exchange(fwd_vals, d, vals, nbrs_loc, n_glob, lo, hi, axis,
+                           tiers, bits, dense)
 
 
 # every scope above, by name (ops/scopes.py)

@@ -1,7 +1,8 @@
 """Stable names for the ops' work on the device.
 
-Every public delivery, sampler and ring op runs under a ``jax.named_scope``
-named ``ops.<module>.<function>``.  A scope is HLO metadata (the ``op_name``
+Every public delivery, sampler and ring op, and every collective of a
+node-sharded tick (``ops/mesh.py``), runs under a ``jax.named_scope`` named
+``ops.<module>.<function>``.  A scope is HLO metadata (the ``op_name``
 path of every operation traced inside it): nothing computed changes and it
 costs nothing at run time, but a profiler trace can then say which op a
 fusion belongs to, under a name that survives renumbering by the compiler.

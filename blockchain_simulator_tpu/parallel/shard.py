@@ -25,11 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from blockchain_simulator_tpu.models.base import get_protocol
+from blockchain_simulator_tpu.models.base import get_protocol, sim_metrics
 from blockchain_simulator_tpu.parallel import partition
 from blockchain_simulator_tpu.parallel.mesh import NODES_AXIS
 from blockchain_simulator_tpu.utils import aotcache
 from blockchain_simulator_tpu.utils import prng
+from blockchain_simulator_tpu.utils import telemetry
 from blockchain_simulator_tpu.utils.config import SimConfig
 
 # ----------------------------------------------------- rule declarations ---
@@ -253,10 +254,95 @@ def make_sharded_sim_fn(cfg: SimConfig, mesh: Mesh):
     return sim
 
 
+def _span_attrs(cfg: SimConfig, mesh: Mesh) -> dict:
+    n_shards = mesh.shape[NODES_AXIS]
+    rows = cfg.mixed_shards if cfg.protocol == "mixed" else cfg.n
+    return {"shards": n_shards, "rows_per_shard": rows // n_shards}
+
+
+def readback(cfg: SimConfig, mesh: Mesh, final):
+    """The ``shard.readback`` span of one sharded run: ONE ``jax.device_get``
+    brings the leaves the protocol's ``metrics`` reads (its module's
+    ``METRIC_FIELDS``; every field where a module declares none) from the
+    mesh to the host, each shard's copy started before the first is awaited,
+    as ``parallel/sweep._readback`` does for a batch.  Returns ``final``'s
+    own state type with host arrays in the fetched fields and None in the
+    others, which is all ``sim_metrics`` asks for.  The span's ``leaves``
+    and ``bytes`` attrs say what was fetched; it waits for the run if the
+    caller has not (``run_sharded`` has, under ``shard.execute``)."""
+    import dataclasses
+
+    names = [f.name for f in dataclasses.fields(final)]
+    fields = getattr(get_protocol(cfg.protocol), "METRIC_FIELDS", names)
+    picked = {f: getattr(final, f) for f in fields}
+    leaves = jax.tree.leaves(picked)
+    with telemetry.span(
+        "shard.readback", **_span_attrs(cfg, mesh), leaves=len(leaves),
+        bytes=sum(x.nbytes for x in leaves),
+    ):
+        host = jax.device_get(picked)
+    return type(final)(**{f: host.get(f) for f in names})
+
+
+def collective_counts(cfg: SimConfig, mesh: Mesh) -> dict:
+    """What the compiled sharded program says of its own communication,
+    read ONCE from the optimized post-SPMD module (``lint/comms/hlo.py``'s
+    parser; with jax's compile cache on, the compile is a load of the
+    executable the run itself uses).  A counter of the program, not a
+    measurement:
+
+    - ``collectives_per_tick`` / ``bytes_per_tick``: the collective
+      instructions inside the tick loop and the bytes of their outputs on
+      one device, as on a tick that takes every gate (a collective inside an
+      untaken ``conditional`` arm does not run);
+    - ``by_scope``: the same, keyed by the innermost ``ops.mesh.*`` scope on
+      the instruction's ``op_name`` (``"(none)"`` where it has none);
+    - ``flood_allreduces`` / ``flood_allreduce_bytes``: the all-reduces under
+      ``ops.delivery.gossip_fwd`` and the operand bytes of ONE of them: what
+      a flood arm all-reduces on a tick with more senders than its exchange
+      holds (the dense arm);
+    - ``flood_allgathers`` / ``flood_allgather_bytes``: the all-gathers under
+      it and the gathered bytes of ONE of them: the senders' packets every
+      taken flood arm exchanges."""
+    import re
+
+    from blockchain_simulator_tpu.lint.comms import hlo
+    from blockchain_simulator_tpu.ops import mesh as mesh_ops
+
+    sim = make_sharded_sim_fn(cfg, mesh)
+    module = hlo.parse_module(sim.lower(jax.random.key(0)).compile().as_text())
+    attrs = {ins.name: ins.attrs for instrs in module.computations.values()
+             for ins in instrs}
+    by_scope: dict = {}
+    flood = {"all-reduce": [], "all-gather": []}
+    in_loop = [c for c in hlo.collectives(module) if c.in_loop]
+    for c in in_loop:
+        op_name = "".join(re.findall(r'op_name="([^"]*)"', attrs[c.name]))
+        scope = next((s for s in mesh_ops.SCOPES if f"{s}/" in op_name + "/"),
+                     "(none)")
+        got = by_scope.setdefault(scope, {"count": 0, "bytes": 0})
+        got["count"] += 1
+        got["bytes"] += c.bytes
+        if c.opcode in flood and "ops.delivery.gossip_fwd" in op_name:
+            flood[c.opcode].append(c.bytes)
+    return {
+        "collectives_per_tick": len(in_loop),
+        "bytes_per_tick": sum(c.bytes for c in in_loop),
+        "by_scope": by_scope,
+        "flood_allreduces": len(flood["all-reduce"]),
+        "flood_allreduce_bytes": max(flood["all-reduce"], default=0),
+        "flood_allgathers": len(flood["all-gather"]),
+        "flood_allgather_bytes": max(flood["all-gather"], default=0),
+    }
+
+
 def run_sharded(cfg: SimConfig, mesh: Mesh, seed: int | None = None):
-    """Run one node-sharded simulation, return the protocol metrics dict."""
-    proto = get_protocol(cfg.protocol)
+    """Run one node-sharded simulation, return the protocol metrics dict.
+    Two host states by name on the profiler's clock (utils/telemetry.py):
+    ``shard.execute`` (dispatch and the wait for the mesh) and
+    ``shard.readback`` (:func:`readback`)."""
     sim = make_sharded_sim_fn(cfg, mesh)
     key = jax.random.key(cfg.seed if seed is None else seed)
-    final = jax.block_until_ready(sim(key))
-    return proto.metrics(cfg, final)
+    with telemetry.span("shard.execute", **_span_attrs(cfg, mesh)):
+        final = jax.block_until_ready(sim(key))
+    return sim_metrics(cfg, readback(cfg, mesh, final))

@@ -31,3 +31,43 @@ def pytest_configure(config):
         "slow: heavy end-to-end tests (bench subprocess pairs) excluded "
         "from the tier-1 870 s window via -m 'not slow'",
     )
+
+
+def _mapped_regions() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no /proc: nothing to count, nothing to drop
+        return 0
+
+
+def _map_limit() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+def pytest_runtest_teardown(item, nextitem):
+    """XLA:CPU maps memory for every executable it compiles (500 to 3,000
+    regions a test in the engine files), jax keeps every executable, and a
+    process may hold ``vm.max_map_count`` regions (65,530).  A worker that
+    draws the compile-heavy files in a row passes it: LLVM fails with
+    ``Cannot allocate memory`` and the worker aborts inside
+    ``backend_compile_and_load`` (PR 32: ``tests/test_raft_hb.py`` in three
+    whole runs of three; the parent commit aborts alike on that worker's
+    order of tests).  So when a process moves on to another module with a
+    quarter of the limit mapped, drop jax's executables (8,163 regions -> 691
+    after ``tests/test_differential.py``; six tests of ``test_raft_hb.py`` add
+    23,000), and
+    past three quarters drop them between any two tests: a recompile is
+    better than an abort.  Nothing a module holds is lost: a jitted function
+    compiles again when called."""
+    mapped, limit = _mapped_regions(), _map_limit()
+    between_modules = nextitem is None or nextitem.module is not item.module
+    if mapped > 3 * limit // 4 or (between_modules and mapped > limit // 4):
+        import gc
+
+        jax.clear_caches()
+        gc.collect()

@@ -549,13 +549,14 @@ def test_seed_sweep_emits_operands_execute_readback_with_rows(traced):
 
 
 def _all_scopes():
-    from blockchain_simulator_tpu.models import (base, mixed, pbft,
+    from blockchain_simulator_tpu.models import (base, mixed, paxos, pbft,
                                                  pbft_round, raft, raft_hb)
-    from blockchain_simulator_tpu.ops import delay, delivery, ring
+    from blockchain_simulator_tpu.ops import delay, delivery, mesh, ring
 
     return (pbft_round.SCOPES + pbft.SCOPES + delivery.SCOPES
             + delay.SCOPES + ring.SCOPES + base.SCOPES
-            + mixed.SCOPES + raft.SCOPES + raft_hb.SCOPES)
+            + mixed.SCOPES + raft.SCOPES + raft_hb.SCOPES
+            + paxos.SCOPES + mesh.SCOPES)
 
 
 @pytest.fixture(scope="module")
@@ -563,9 +564,13 @@ def lowered_programs():
     """The op_name metadata of the engines' lowered programs (nothing is
     compiled or run): the pbft tick engine on per-edge, stat and gossip
     delivery, the pbft round engine, and the raft and paxos tick engines
-    for the delivery ops only they call; one op no engine calls is lowered
-    alone; the lane-batched pbft tick program, where ``gated`` does work
-    of its own; and last a small mixed program on its fast path (both arms
+    for the delivery ops only they call, and the paxos tick engine on a
+    gossip relay (its flood decode does nothing elsewhere); one op no engine
+    calls is lowered alone; the lane-batched pbft tick program, where
+    ``gated`` does work of its own; the paxos engine sharded over a 2-device
+    mesh, on the relay (``ops.mesh.pmax`` / ``.psum``) and on the full mesh
+    (``ops.mesh.gather``); and last a small mixed program on its fast path
+    (both arms
     of its cond are lowered), which holds the raft and pbft tick engines
     under ``mixed.*``."""
     import jax
@@ -587,6 +592,8 @@ def lowered_programs():
         SimConfig(protocol="raft", n=8, sim_ms=200, delivery="stat",
                   schedule="tick"),
         SimConfig(protocol="paxos", n=8, sim_ms=200),
+        SimConfig(protocol="paxos", n=16, sim_ms=200, topology="gossip",
+                  degree=4, paxos_retry_timeout_ms=600),
     ]
     texts = [jax.jit(runner.make_sim_fn(c)).lower(jax.random.key(0))
              .as_text(debug_info=True) for c in cfgs]
@@ -600,6 +607,14 @@ def lowered_programs():
     texts.append(sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
         jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
     ).as_text(debug_info=True))
+    if len(jax.devices()) >= 2:
+        from blockchain_simulator_tpu.parallel import shard
+        from blockchain_simulator_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(n_node_shards=2, devices=jax.devices()[:2])
+        texts += [shard.make_sharded_sim_fn.__wrapped__(c, mesh).lower(
+            jax.random.key(0)).as_text(debug_info=True)
+            for c in (cfgs[-1], cfgs[-2])]
     texts.append(jax.jit(runner.make_sim_fn(SimConfig(
         protocol="mixed", n=24, mixed_shards=4, sim_ms=400, delivery="stat",
         model_serialization=False))).lower(jax.random.key(0))
@@ -613,6 +628,8 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
     operation of a lowered program (HLO metadata: nothing computed
     changes), as a whole path component."""
     assert any(f"{scope}/" in text for text in lowered_programs), scope
+    if scope.startswith("paxos.tick."):
+        assert f"{scope}/" in lowered_programs[7]  # the relay, one device
     if scope.startswith("pbft.tick."):
         assert f"{scope}/" in lowered_programs[0]
     if scope.startswith("pbft.round."):

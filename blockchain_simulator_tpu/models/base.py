@@ -100,7 +100,10 @@ def _under(axis_name: str) -> bool:
 def gated(pred, fn, zeros, axis=None):
     """Skip a delivery computation when no sender is active this tick.
     Sharded, the predicate must be globally agreed (the branch contains
-    collectives), so it is pmax-reduced over the mesh axis first.
+    collectives), so it is pmax-reduced over the mesh axis first; a
+    ``conditional`` is where a sharded arm's collectives live
+    (:func:`gated_push` takes its contribution from here and loops over the
+    push alone).
 
     Under a lane batch (:func:`lane_vmap`) a cond on a per-lane predicate
     would lower to a select, both arms run for every lane on every tick.
@@ -137,16 +140,26 @@ def gated_push(pred, fn, zeros, bufs, push, axis=None):
     saves the read-modify-write of every delay bucket's slice.
 
     The branch is :func:`gated`'s: on ``pred``, reduced over the lanes under
-    :func:`lane_vmap`.  The per-lane select
+    :func:`lane_vmap` and over the mesh under ``axis``, over both where both
+    are bound.  The per-lane select
     stays on the contribution; the ring crosses the branch untouched and is
-    never an operand of a select.  Under :func:`select_vmap`, where no
-    branch survives, and in a program sharded over ``axis``, whose arms
-    hold collectives (XLA:CPU runs a nested loop's collectives concurrently
-    with the tick's own and aborts, KNOWN_ISSUES #0b'), the push keeps the
-    parent's form outside the loop.  A call site whose
+    never an operand of a select.  A call site whose
     push computes its own contribution (the stat arms' fused
     chain-into-ring) passes ``tuple`` and ``()``: there a lane without a
     sender must push zeros, as it does by drawing from zero counts.
+
+    In a program sharded over ``axis`` the arm holds collectives, and those
+    stay out of the loop: XLA:CPU runs a nested loop's collectives
+    concurrently with the tick's own and aborts (KNOWN_ISSUES #0b').  So the
+    helper is two stages on the one reduced predicate: the contribution
+    comes out of :func:`gated`'s ``conditional``, as it always did (it
+    yields ``zeros`` on a tick on which no shard sends), and the push,
+    local slice arithmetic on a shard's own rows, runs in the loop with that
+    contribution as the value the arm closes over.  What keeps the parent's
+    form, a gated contribution and an unconditional push: a program under
+    :func:`select_vmap`, where no branch survives, and a fused push under
+    ``axis`` (``zeros == ()``), where push and collectives are one function
+    that the helper cannot split.
 
     The branch is a ``while`` of at most one trip, not a ``cond``: XLA:TPU
     updates a ``while``'s carry in place, but around a ``conditional`` with
@@ -155,12 +168,20 @@ def gated_push(pred, fn, zeros, bufs, push, axis=None):
     ``while`` body has a hazard of its own, which the carry below answers:
     whatever in it does not depend on the carry is hoisted out of the loop,
     and so runs on every tick."""
-    if axis is not None or _under(SELECT_AXIS):
+    separate = bool(jax.tree.leaves(zeros))
+    if _under(SELECT_AXIS) or (axis is not None and not separate):
         # the parent's programs: a gated contribution and an unconditional
         # push, or the fused push behind the gate with its ring as ``zeros``
-        if jax.tree.leaves(zeros):
+        if separate:
             return push(bufs, gated(pred, fn, zeros, axis))
         return gated(pred, lambda: push(bufs, fn()), bufs, axis)
+    if axis is not None:
+        # two stages on one globally agreed predicate: the contribution, with
+        # the arm's collectives, out of the conditional it was always in; the
+        # push, which has none, in the loop below
+        pred = mesh_ops.pmax(pred.astype(jnp.int32), axis) > 0
+        contrib = gated(pred, fn, zeros)
+        return gated_push(pred, lambda: contrib, zeros, bufs, push)
     lanes = _under(LANES_AXIS)
     any_lane = pred
     if lanes:

@@ -74,7 +74,9 @@ def _push(buf, t, lo: int, contrib, op: str):
     Every bucket read-modify-writes one slice of the ring whatever
     ``contrib`` holds, so a call site whose contribution comes out of a gate
     pushes through models/base.gated_push, inside the gate's branch, and a
-    tick with no sender touches no slice.  A ring is never the ``zeros`` of
+    tick with no sender touches no slice (sharded over a mesh axis too: the
+    chain holds no collective, so it goes into the gate's loop while the
+    contribution stays in its ``conditional``).  A ring is never the ``zeros`` of
     models/base.gated: that is an operand of a select and of a
     ``conditional``, and either costs passes over the whole ring.
     """

@@ -282,7 +282,9 @@ RINGS = {"add": jnp.add, "max": jnp.maximum}
 
 def _ring_case(op):
     """A toy channel: ``x`` [N] sends when it is positive; the contribution
-    is two delay buckets; one ring, or a tuple of two, of depth 5."""
+    is two delay buckets; one ring, or a tuple of two, of depth 5.  Through
+    the helper, written out, and as the call site whose push computes its
+    own contribution."""
     combine = RINGS[op]
 
     def push_one(ring, t, contrib):
@@ -305,7 +307,14 @@ def _ring_case(op):
                        jnp.zeros((2,) + x.shape, x.dtype), axis)
         return jax.tree.map(lambda r: push_one(r, t, c), rings)
 
-    return via_helper, via_gated
+    def via_fused(x, rings, t, axis=None):
+        return base.gated_push(
+            (x > 0).any(), tuple, (), rings,
+            lambda rs, _: jax.tree.map(
+                lambda r: push_one(r, t, contribution(x)), rs),
+            axis)
+
+    return via_helper, via_gated, via_fused
 
 
 def _assert_trees_equal(got, want):
@@ -329,7 +338,7 @@ def test_gated_push_equals_pushing_the_gated_contribution(op, tuple_of_rings, un
     """Leaf for leaf, ``gated_push`` is ``push(bufs, gated(...))``: lone, under
     a lane batch in which one lane sends and the others do not, under a
     batch that cannot branch, inside a scan, and with a sharded axis."""
-    via_helper, via_gated = _ring_case(op)
+    via_helper, via_gated, _ = _ring_case(op)
     t = jnp.int32(4)
     if under == "lone":
         for x in X:
@@ -354,28 +363,90 @@ def test_gated_push_equals_pushing_the_gated_contribution(op, tuple_of_rings, un
                                 (X, jnp.arange(4, dtype=jnp.int32)))[0]
         _assert_trees_equal(jax.jit(lambda: run(via_helper))(), run(via_gated))
     else:
-        from jax.sharding import PartitionSpec as P
-
-        mesh = jax.make_mesh((2,), ("nodes",))
         x = jnp.asarray([0, 0, 5, 0], jnp.int32)  # only the second shard sends
-        ring = jnp.arange(20, dtype=jnp.int32).reshape(5, 4) % 7
-        rings = (ring, ring + 1) if tuple_of_rings else ring
-        spec = jax.tree.map(lambda _: P(None, "nodes"), rings)
+        rings = _sharded_rings(tuple_of_rings)
+        _assert_trees_equal(_sharded(via_helper, rings, t)(x, rings),
+                            _sharded(via_gated, rings, t)(x, rings))
+        # no shard sends: the rings come back as they went in
+        _assert_trees_equal(_sharded(via_helper, rings, t)(0 * x, rings), rings)
 
-        def sharded(step):
-            # replication checking waived, as parallel/partition._shard_map does
-            return jax.jit(jax.shard_map(
-                lambda x, r: step(x, r, t, axis="nodes"), mesh=mesh,
-                in_specs=(P("nodes"), spec), out_specs=spec, check_vma=False))
-        _assert_trees_equal(sharded(via_helper)(x, rings),
-                            sharded(via_gated)(x, rings))
+
+def _sharded_rings(tuple_of_rings):
+    ring = jnp.arange(20, dtype=jnp.int32).reshape(5, 4) % 7
+    return (ring, ring + 1) if tuple_of_rings else ring
+
+
+def _sharded(step, rings, t):
+    """``step`` over two node shards of two rows each."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.make_mesh((2,), ("nodes",))
+    spec = jax.tree.map(lambda _: P(None, "nodes"), rings)
+    # replication checking waived, as parallel/partition._shard_map does
+    return jax.jit(jax.shard_map(
+        lambda x, r: step(x, r, t, axis="nodes"), mesh=mesh,
+        in_specs=(P("nodes"), spec), out_specs=spec, check_vma=False))
+
+
+@pytest.mark.parametrize("op", list(RINGS))
+@pytest.mark.parametrize("tuple_of_rings", [False, True])
+def test_sharded_gated_push_loops_over_the_push_alone(op, tuple_of_rings):
+    """Under a mesh axis a separate contribution comes out of ONE conditional
+    and is pushed in ONE loop, on one reduction of the predicate; a fused
+    push (``zeros == ()``) keeps the parent's form, the whole arm in the
+    conditional with the ring as its ``zeros``, and gives the same rings."""
+    via_helper, via_gated, via_fused = _ring_case(op)
+    t = jnp.int32(4)
+    rings = _sharded_rings(tuple_of_rings)
+    x = jnp.asarray([0, 0, 5, 0], jnp.int32)
+    for sends in (x, 0 * x):
+        _assert_trees_equal(_sharded(via_fused, rings, t)(sends, rings),
+                            _sharded(via_gated, rings, t)(sends, rings))
+    split = _sharded(via_helper, rings, t).lower(x, rings).as_text()
+    fused = _sharded(via_fused, rings, t).lower(x, rings).as_text()
+    parent = _sharded(via_gated, rings, t).lower(x, rings).as_text()
+    counts = {name: (text.count("stablehlo.while"), text.count("stablehlo.case"),
+                     text.count("stablehlo.all_reduce"))
+              for name, text in (("split", split), ("fused", fused),
+                                 ("parent", parent))}
+    assert counts == {"split": (1, 1, 1), "fused": (0, 1, 1),
+                      "parent": (0, 1, 1)}
+    for text in (split, fused):
+        selects = [ln for ln in text.splitlines() if "stablehlo.select" in ln]
+        assert not [ln for ln in selects if "tensor<5x2xi32>" in ln], selects
+
+
+def test_lane_batch_under_a_mesh_axis_reduces_the_predicate_over_both():
+    """No program in the repo binds a lane batch and a mesh axis at once;
+    where one does, the loop runs when any lane of any shard sends, each
+    lane keeps its own contribution, and a batch without a sender comes
+    back as it went in."""
+    from jax.sharding import PartitionSpec as P
+
+    via_helper, via_gated, _ = _ring_case("add")
+    t = jnp.int32(4)
+    mesh = jax.make_mesh((2,), ("nodes",))
+    xs = jnp.asarray([[0, 0, 0, 0], [0, 0, 5, 0], [0, 0, 0, 0]], jnp.int32)
+    rings = jnp.arange(60, dtype=jnp.int32).reshape(3, 5, 4) % 7
+    spec = P(None, None, "nodes")
+
+    def sharded(step, vmap):
+        return jax.jit(jax.shard_map(
+            vmap(lambda x, r: step(x, r, t, axis="nodes")), mesh=mesh,
+            in_specs=(P(None, "nodes"), spec), out_specs=spec, check_vma=False))
+
+    lanes = sharded(via_helper, base.lane_vmap)
+    _assert_trees_equal(lanes(xs, rings), sharded(via_gated, jax.vmap)(xs, rings))
+    _assert_trees_equal(lanes(0 * xs, rings), rings)
+    text = lanes.lower(xs, rings).as_text()
+    assert text.count("stablehlo.while") == 1 and text.count("stablehlo.case") == 1
 
 
 def test_gated_push_lowers_to_one_loop_and_never_selects_the_ring():
     """Lone and under the lane batch the helper is one ``while`` and no
     ``case``, and no select has a ring-shaped operand; under ``select_vmap``
     it is the parent's select on the contribution alone."""
-    via_helper, _ = _ring_case("add")
+    via_helper, _, _ = _ring_case("add")
     t = jnp.int32(1)
     rings = _rings(False, lanes=4)
     lone = jax.jit(via_helper).lower(X[0], rings[0], t).as_text()
@@ -480,3 +551,61 @@ def test_mesh_batched_program_never_selects_a_ring_either():
     assert selects == []
     assert inside == 0 and outside > 4  # pops and unconditional pushes
     shard.make_sharded_sim_fn.cache_clear()
+
+
+# --------------------------------- (h) the sharded programs, structurally
+
+# a flood over a relay overlay (the benchmark's mesh cell in small), and the
+# program that aborted on XLA:CPU with its arms inside a loop (KNOWN_ISSUES #0b')
+SHARDED = {
+    "paxos-gossip": SimConfig(protocol="paxos", n=16, sim_ms=100,
+                              topology="gossip", degree=4, gossip_hops=4,
+                              paxos_retry_timeout_ms=450),
+    "raft-queued": SimConfig(protocol="raft", n=16, sim_ms=100,
+                             queued_links=True),
+}
+COLLECTIVES = ("psum", "pmax", "pmin", "all_gather", "all_to_all", "ppermute",
+               "psum_scatter", "reduce_scatter", "pbroadcast")
+
+
+@pytest.mark.parametrize("name", list(SHARDED))
+def test_sharded_program_loops_over_pushes_and_keeps_collectives_outside(
+        monkeypatch, name):
+    """Sharded over a mesh axis, every ``gated_push`` site with a separate
+    contribution is one ``while`` in the tick: the ring updates are inside
+    it, no collective is (XLA:CPU would race it with the tick's own), the
+    arm's collectives stay in a ``cond``, and no ring is selected or copied."""
+    cfg = SHARDED[name]
+    sites = []
+
+    def counting_push(pred, fn, zeros, bufs, push, axis=None):
+        sites.append((bool(jax.tree.leaves(zeros)), axis,
+                      len(jax.tree.leaves(bufs))))
+        return base.gated_push(pred, fn, zeros, bufs, push, axis)
+
+    _everywhere(monkeypatch, base.gated, counting_push)
+    mesh = make_mesh(n_node_shards=4)
+    closed = jax.make_jaxpr(shard.make_sharded_sim_fn.__wrapped__(cfg, mesh))(
+        jax.random.key(0))
+    assert sites and all(separate and axis == "nodes"
+                         for separate, axis, _ in sites), sites
+    rings = _ring_shapes(cfg, n_loc=cfg.n // 4)
+    inside, outside, ring_copies = [], [], []
+    for eqn, in_gate, in_scan in _walk(closed.jaxpr):
+        if not in_scan:
+            continue
+        prim = eqn.primitive.name
+        (inside if in_gate else outside).append(prim)
+        if prim.startswith("copy") and any(
+                _ring_shaped(v.aval, rings) for v in eqn.outvars):
+            ring_copies.append(str(eqn)[:200])
+    assert outside.count("while") == len(sites)
+    # each contribution out of its conditional, with the arm's collectives
+    assert outside.count("cond") >= len(sites)
+    assert [p for p in outside if p.startswith(COLLECTIVES)]
+    assert not [p for p in inside if p.startswith(COLLECTIVES)]
+    selects, updates_outside, updates_inside = _structure(closed, rings)
+    assert selects == [] and ring_copies == []
+    assert updates_inside >= sum(n for _, _, n in sites)
+    if name == "paxos-gossip":  # one pop a ring, every push behind a gate
+        assert updates_outside == sum(n for _, _, n in sites) == 6

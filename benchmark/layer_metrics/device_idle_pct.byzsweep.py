@@ -1,0 +1,8 @@
+"""Device: the share of the traced window in which no operation ran on the
+device, in the cells the ``byzsweep`` driver drives (device trace)."""
+
+import readers
+
+
+def read(run: dict):
+    return readers.idle_pct(run, "byzsweep")

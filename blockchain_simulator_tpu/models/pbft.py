@@ -82,6 +82,9 @@ SCOPES = (
     "pbft.tick.view_change",
     "pbft.tick.pre_prepare",
     "pbft.tick.prepare",
+    # the forged COMMIT wave, inside ``prepare``; only a ``byz_forge``
+    # program has it
+    "pbft.tick.forge",
     "pbft.tick.commit",
     "pbft.tick.timers",
 )
@@ -482,12 +485,13 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # re-send lands in the accumulating counter, so f forgers cross any
             # threshold eventually.  A "2f1" receiver counts at most one vote per
             # sender *ever*, equivalent to the flood collapsing to a single send.
-            if cfg.quorum_rule == "2f1":
-                fire, copies = jnp.equal(t, bt), 1
-            else:
-                fire, copies = is_block_tick, cfg.faults.byz_copies
-            forgers = (state.alive & ~state.honest).astype(jnp.int32) * jnp.int32(fire)
-            commit_mat = commit_mat.at[:, w - 1].add(forgers * copies)
+            with jax.named_scope("pbft.tick.forge"):
+                if cfg.quorum_rule == "2f1":
+                    fire, copies = jnp.equal(t, bt), 1
+                else:
+                    fire, copies = is_block_tick, cfg.faults.byz_copies
+                forgers = (state.alive & ~state.honest).astype(jnp.int32) * jnp.int32(fire)
+                commit_mat = commit_mat.at[:, w - 1].add(forgers * copies)
         k_cm = chan_key(tkey, Channel.DELAY_BCAST)
         zeros_w = jnp.zeros((hi - lo, n_loc, w), jnp.int32)
         if stat:
@@ -804,9 +808,10 @@ def metrics(cfg, state: PbftState) -> dict:
     # it can only come from forged votes reaching quorum (quirk #2: the
     # reference's no-dedup counting lets f Byzantine nodes muster f*copies
     # votes; the 2f1 rule makes this impossible for f <= (n-1)//3)
-    forged_commits = int(((commits > 0) & ~proposed).sum())
+    forged = (commits > 0) & ~proposed
+    forged_commits = int(forged.sum())
     unattributed = int(np.asarray(state.unattributed).sum())
-    return {
+    out = {
         "protocol": "pbft",
         "n": cfg.n,
         "rounds_sent": rounds,
@@ -824,6 +829,15 @@ def metrics(cfg, state: PbftState) -> dict:
         # remain observable are forged/unattributed commits, reported above
         "agreement_ok": bool(forged_commits == 0 and unattributed == 0),
     }
+    if cfg.faults.byz_forge:
+        # how soon the attack won: the tick at which the last node so far
+        # finalized a never-proposed slot (-1: none did), and how many nodes
+        # did.  Only a forging deployment's rows carry the two.
+        out["forged_commit_ms"] = (
+            float(commit_tick[forged].max()) if forged_commits else -1.0)
+        out["forged_commit_nodes"] = int(commits[forged].max()) \
+            if forged_commits else 0
+    return out
 
 
 # the state fields :func:`metrics` reads, and the only ones: a batched

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +53,7 @@ import numpy as np
 from blockchain_simulator_tpu.chaos import inject
 from blockchain_simulator_tpu.models.base import (
     canonical_fault_cfg,
+    get_protocol,
     lane_vmap,
     select_vmap,
     sim_metrics,
@@ -66,6 +68,7 @@ from blockchain_simulator_tpu.runner import (
     make_sim_fn,
     make_topo_dyn_sim_fn,
     topo_tables_inslot,
+    use_round_schedule,
 )
 from blockchain_simulator_tpu.utils import aotcache, obs, telemetry
 from blockchain_simulator_tpu.utils.config import SimConfig
@@ -483,6 +486,63 @@ def _dyn_operands(cfg: SimConfig, fc) -> tuple[int, int]:
     return fc.resolved_n_crashed(cfg.n), fc.n_byzantine
 
 
+# the sweep layer's host spans, by name (utils/telemetry.py)
+SPANS = ("sweep.operands", "sweep.execute", "sweep.readback", "sweep.chunk",
+         "sweep.tile")
+
+# What one lane of a vmapped tick program takes on the device, as a multiple
+# of the state its scan carries.  XLA's ``memory_analysis`` of the programs
+# compiled for a described v5e: 6.03 GB of temporaries over 3.8 GB of state
+# at 32 lanes of pbft-fullmesh-1k (x1.58), 10.25 GB over 6.04 GB at 4 lanes of
+# pbft-byzsweep-100k (x1.70: the scan's carry and what a taken arm draws
+# beside it); on the chip that program reserved 7.91 GB (x1.31,
+# ``peak_bytes_reserved``), all 8 lanes in one dispatch 15.81 GB of the
+# 16.43 GB the device lets a process reserve (and ran a tenth slower than two
+# tiles), and the finals of the dispatch before stay on the device until
+# they are read (x0.08).  2 leaves room over all of them: a dispatch that
+# dies of memory loses the whole sweep, one dispatch more costs
+# milliseconds (PERF.md section 6, PR 35).
+_TEMP_FACTOR = 2.0
+
+
+@functools.lru_cache(maxsize=64)
+def _lane_state_bytes(canon: SimConfig) -> int:
+    """Bytes of state one lane of ``make_dyn_sim_fn(canon)`` carries through
+    its scan: ``eval_shape`` of the ``init`` that program calls (nothing is
+    allocated)."""
+    if canon.protocol == "pbft" and use_round_schedule(canon):
+        from blockchain_simulator_tpu.models import pbft_round as mod
+    else:
+        mod = get_protocol(canon.protocol)
+    shapes = jax.eval_shape(lambda: mod.init(canon, jax.random.key(0)))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+
+
+@functools.lru_cache(maxsize=1)
+def _device_bytes() -> int | None:
+    """The memory the default device reports it may use; None where a
+    backend reports none (XLA:CPU), and a list is then never tiled."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def _device_tile(canon: SimConfig, n_points: int) -> dict | None:
+    """How a point list that outgrows the device is cut: ``None`` where the
+    whole list fits as one lane batch (or the device reports no memory),
+    else the equal tile: as few dispatches as the device allows, the lanes
+    of each the list's length over that count, rounded up."""
+    device = _device_bytes()
+    if device is None or n_points <= 1:
+        return None
+    state = _lane_state_bytes(canon)
+    most = max(int(device // (_TEMP_FACTOR * state)), 1)
+    if n_points <= most:
+        return None
+    tiles = -(-n_points // most)
+    return {"lanes": -(-n_points // tiles), "state_bytes": state,
+            "device_bytes": int(device)}
+
+
 def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
                          n_out: int | None = None, mesh=None,
                          multi_seed: bool = False, probe=None):
@@ -556,12 +616,27 @@ def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
 
 
 def _run_chunk(canon, tile, record, n_out, mesh, supervise, journal, key,
-               index, multi_seed=False, probe=None):
+               index, multi_seed=False, probe=None, device_tile=None):
     """Compute ONE chunk, optionally under the supervisor's deadline →
     retry → degrade state machine (parallel/journal.py).  The
     ``sweep.chunk`` chaos point fires once per ATTEMPT with the arm in
     its ctx, so a drill can wedge exactly the primary arm and watch the
-    degrade arm answer."""
+    degrade arm answer.  Where the chunk is a tile of a list cut to the
+    device (``device_tile``, :func:`_device_tile`), each dispatch of it
+    stands under a ``sweep.tile`` span that says what was dispatched and
+    what it was sized from."""
+
+    def dispatch(mesh, multi_seed):
+        span = contextlib.nullcontext()
+        if device_tile is not None:
+            rows = len(tile) if n_out is None else n_out
+            span = telemetry.span(
+                "sweep.tile", tile=index, lanes=len(tile),
+                pad=len(tile) - rows, state_bytes=device_tile["state_bytes"],
+                device_bytes=device_tile["device_bytes"])
+        with span:
+            return _dispatch_dyn_points(canon, tile, record, n_out, mesh,
+                                        multi_seed, probe)
 
     def primary():
         inject.chaos_point("sweep.chunk", key=key, index=index,
@@ -572,8 +647,7 @@ def _run_chunk(canon, tile, record, n_out, mesh, supervise, journal, key,
         # post-mortem story "which chunk, which arm, how long" as data
         with telemetry.span("sweep.chunk", key=key, index=index,
                             n=len(tile), arm="primary"):
-            return _dispatch_dyn_points(canon, tile, record, n_out, mesh,
-                                        multi_seed, probe)
+            return dispatch(mesh, multi_seed)
 
     if supervise is None:
         return primary()
@@ -612,8 +686,7 @@ def _run_chunk(canon, tile, record, n_out, mesh, supervise, journal, key,
                                n=len(tile), arm="degrade", mesh=False)
             with telemetry.span("sweep.chunk", key=key, index=index,
                                 n=len(tile), arm="degrade"):
-                return _dispatch_dyn_points(canon, tile, record, n_out,
-                                            mesh=None, probe=probe)
+                return dispatch(None, False)
 
     rows, _events = journal_mod.run_supervised(
         primary, degrade, supervise, journal=journal, key=key,
@@ -651,6 +724,20 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     last point (padding lanes ride at the tail, so real-point indices are
     unchanged and pad metrics are never computed).  A mesh of size 1 takes
     the single-device path verbatim.
+
+    **A list that outgrows the device** runs as tiles.  A vmapped lane
+    batch on one device holds every lane's state at once (three 460 MB
+    rings a lane for the PBFT tick engine at n = 100,000), so the most
+    lanes a dispatch may have is the memory the device reports over
+    ``_TEMP_FACTOR`` times one lane's state bytes (:func:`_device_tile`:
+    derived, not configured).  A longer list is cut into as few equal
+    tiles as that allows and runs through the chunk loop below, every tile
+    through the ONE executable (the tail padded by repeating its last
+    point), each under a ``sweep.tile`` span; rows come back in order,
+    entry for entry those of one dispatch (exact sampler; the module
+    caveat for the normal one).  A list that fits dispatches as it always
+    did.  ``meta["tile"]`` says what was chosen (``lanes``,
+    ``state_bytes``, ``device_bytes``), None where nothing was cut.
 
     **Durable execution** (``journal=``, a parallel/journal.SweepJournal):
     the point list splits into ``chunk_size``-point chunks (default: one
@@ -708,7 +795,14 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     the single-device path even under a mesh (bit-equal, exact
     sampler)."""
     points = list(points)
-    meta = {"rows": [], "chunks": [], "lanes": 0, "dispatches": 0, "pad": 0}
+    meta = {"rows": [], "chunks": [], "lanes": 0, "dispatches": 0, "pad": 0,
+            "tile": None}
+    # a vmapped lane batch on one device holds every lane's state at once:
+    # a list that outgrows the device runs as tiles (the mesh arms and the
+    # ``lax.map`` program run a device's lanes one after another)
+    device_tile = None
+    if not multi_seed and (mesh is None or partition.mesh_size(mesh) == 1):
+        device_tile = _device_tile(canon, len(points))
 
     def _lanes(n: int) -> int:
         if n > 1 and mesh is not None and partition.mesh_size(mesh) > 1:
@@ -719,7 +813,7 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     def _done(rows):
         return (rows, meta) if with_index else rows
 
-    if journal is None and supervise is None:
+    if journal is None and supervise is None and device_tile is None:
         rows = _dispatch_dyn_points(canon, points, record, n_out, mesh,
                                     multi_seed, probe)
         if points:
@@ -742,12 +836,18 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
         chunk_size = partition.align_chunk(
             chunk_size, max(partition.sweep_axis_size(mesh), 1)
         )
+    if device_tile is not None:
+        chunk_size = min(chunk_size, device_tile["lanes"])
+        meta["tile"] = {**device_tile, "lanes": chunk_size}
     done = journal.completed() if journal is not None else {}
     out = []
     for index, start in enumerate(range(0, len(points), chunk_size)):
         tile = points[start:start + chunk_size]
-        want = len(tile) if n_out is None else max(0, min(len(tile), n_out))
+        want = len(tile) if n_out is None \
+            else max(0, min(len(tile), n_out - start))
         t_out = None if n_out is None else want
+        if device_tile is not None and want == 0:
+            break  # the tiles from here on hold bucket padding alone
         key = journal_mod.chunk_key(canon, index, tile, mesh, n_out=t_out)
         if probe is not None:
             # armed and disarmed flushes must never share a journal key:
@@ -768,8 +868,15 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
         # every dispatch ATTEMPT runs record=False: only the winning
         # arm's rows (journaled below) reach runs.jsonl — an abandoned
         # slow attempt finishing late must not double-record its points
-        rows = _run_chunk(canon, tile, False, t_out, mesh, supervise,
-                          journal, key, index, multi_seed, probe)
+        lanes, l_out = tile, t_out
+        if device_tile is not None and len(tile) < chunk_size:
+            # the tail tile runs the executable of the others: the lanes it
+            # lacks repeat its last point (as the mesh arm pads) and are
+            # not read back into rows
+            lanes = tile + [tile[-1]] * (chunk_size - len(tile))
+            l_out = want
+        rows = _run_chunk(canon, lanes, False, l_out, mesh, supervise,
+                          journal, key, index, multi_seed, probe, device_tile)
         # durable BEFORE the next chunk dispatches — the recompute-at-
         # most-one contract the kill -9 drill pins
         if journal is not None:
@@ -780,8 +887,8 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
             for (cfg_i, seed_i), m in zip(pts_out, rows):
                 obs.record_run({"seed": int(seed_i), **m}, cfg_i)
         meta["dispatches"] += 1
-        meta["lanes"] += _lanes(len(tile))
-        meta["pad"] += _lanes(len(tile)) - len(tile)
+        meta["lanes"] += _lanes(len(lanes))
+        meta["pad"] += _lanes(len(lanes)) - len(tile)
         meta["chunks"].append({"key": key, "index": index,
                                "cached": False, "n": len(rows)})
         meta["rows"] += [

@@ -572,7 +572,8 @@ def lowered_programs():
     (``ops.mesh.gather``); and last a small mixed program on its fast path
     (both arms
     of its cond are lowered), which holds the raft and pbft tick engines
-    under ``mixed.*``."""
+    under ``mixed.*``; before it, the pbft tick engine under the forging
+    attack."""
     import jax
     import jax.numpy as jnp
 
@@ -615,6 +616,14 @@ def lowered_programs():
         texts += [shard.make_sharded_sim_fn.__wrapped__(c, mesh).lower(
             jax.random.key(0)).as_text(debug_info=True)
             for c in (cfgs[-1], cfgs[-2])]
+    from blockchain_simulator_tpu.utils.config import FaultConfig
+
+    # the forging attack (only a ``byz_forge`` program holds its scope),
+    # second to last
+    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
+        protocol="pbft", n=8, sim_ms=200, delivery="stat", schedule="tick",
+        faults=FaultConfig(n_byzantine=1, byz_forge=True)))).lower(
+            jax.random.key(0)).as_text(debug_info=True))
     texts.append(jax.jit(runner.make_sim_fn(SimConfig(
         protocol="mixed", n=24, mixed_shards=4, sim_ms=400, delivery="stat",
         model_serialization=False))).lower(jax.random.key(0))
@@ -628,6 +637,10 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
     operation of a lowered program (HLO metadata: nothing computed
     changes), as a whole path component."""
     assert any(f"{scope}/" in text for text in lowered_programs), scope
+    if scope == "pbft.tick.forge":
+        assert f"pbft.tick.prepare/{scope}/" in lowered_programs[-2]
+        assert not any(f"{scope}/" in t for t in lowered_programs[:-2])
+        return
     if scope.startswith("paxos.tick."):
         assert f"{scope}/" in lowered_programs[7]  # the relay, one device
     if scope.startswith("pbft.tick."):

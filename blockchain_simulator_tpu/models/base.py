@@ -97,6 +97,11 @@ def _under(axis_name: str) -> bool:
     return True
 
 
+def _any_lane(pred):
+    with jax.named_scope(GATE_SCOPE):
+        return jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
+
+
 def gated(pred, fn, zeros, axis=None):
     """Skip a delivery computation when no sender is active this tick.
     Sharded, the predicate must be globally agreed (the branch contains
@@ -121,8 +126,7 @@ def gated(pred, fn, zeros, axis=None):
         pred = mesh_ops.pmax(pred.astype(jnp.int32), axis) > 0
     if not _under(LANES_AXIS):
         return jax.lax.cond(pred, fn, lambda: zeros)
-    with jax.named_scope(GATE_SCOPE):
-        any_lane = jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
+    any_lane = _any_lane(pred)
 
     def taken():
         out = fn()
@@ -183,11 +187,7 @@ def gated_push(pred, fn, zeros, bufs, push, axis=None):
         contrib = gated(pred, fn, zeros)
         return gated_push(pred, lambda: contrib, zeros, bufs, push)
     lanes = _under(LANES_AXIS)
-    any_lane = pred
-    if lanes:
-        with jax.named_scope(GATE_SCOPE):
-            any_lane = jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
-    trips = any_lane.astype(jnp.int32)
+    any_lane = _any_lane(pred) if lanes else pred
 
     def taken(bufs):
         with jax.named_scope(PUSH_SCOPE):
@@ -198,24 +198,55 @@ def gated_push(pred, fn, zeros, bufs, push, axis=None):
                         lambda o, z: jnp.where(pred, o, z), out, zeros)
             return push(bufs, out)
 
+    return _one_trip(any_lane, taken, bufs)
+
+
+def _one_trip(go, taken, carry):
+    """``taken(carry)`` if ``go`` (an unbatched bool) else ``carry``, as a
+    ``while`` of at most one trip whose carry XLA:TPU updates in place."""
+    trips = go.astype(jnp.int32)
     # everything the arm closes over rides the loop's carry, behind a barrier
     # with the trip counter: loop-invariant code motion would otherwise lift
     # the whole arm but its last adds into the tick (measured: the samplers of
     # mixed256x1k.solo ran on every tick, 75.1 -> 14.0 rounds/s)
-    arm = jax.make_jaxpr(taken)(bufs)
+    arm = jax.make_jaxpr(taken)(carry)
     consts = [jnp.asarray(c) for c in arm.consts]
-    treedef = jax.tree.structure(bufs)
+    treedef = jax.tree.structure(carry)
 
-    def body(carry):
-        i, consts, leaves = carry
+    def body(c):
+        i, consts, leaves = c
         i, consts = jax.lax.optimization_barrier((i, consts))
         leaves = jax.core.eval_jaxpr(arm.jaxpr, consts, *leaves)
         return i + 1, consts, leaves
 
     leaves = jax.lax.while_loop(
         lambda c: c[0] < trips, body,
-        (jnp.int32(0), consts, jax.tree.leaves(bufs)))[2]
+        (jnp.int32(0), consts, jax.tree.leaves(carry)))[2]
     return jax.tree.unflatten(treedef, leaves)
+
+
+def can_branch(axis=None) -> bool:
+    """Whether a program traced here can branch around a body that holds
+    rings and collectives-free state: not under a mesh ``axis`` (the body's
+    collectives may not sit in a nested loop, :func:`gated_push`) and not
+    under :func:`select_vmap` (no branch survives)."""
+    return axis is None and not _under(SELECT_AXIS)
+
+
+def gated_body(pred, body, carry, scope):
+    """``body(carry)`` on the ticks on which ``pred`` holds, else ``carry``
+    untouched, for a ``body`` that is the identity on a lane whose ``pred``
+    is false: under :func:`lane_vmap` the branch is taken on "any lane
+    active" and no per-lane select is needed.  The same ``while`` of at most
+    one trip as :func:`gated_push`'s, for its reasons, around a whole tick
+    body; ``scope`` names the taken trip (a ``jax.named_scope``).  Only
+    where :func:`can_branch` holds."""
+    def taken(carry):
+        with jax.named_scope(scope):
+            return body(carry)
+
+    return _one_trip(_any_lane(pred) if _under(LANES_AXIS) else pred, taken,
+                     carry)
 
 
 def fault_masks(cfg, n: int):

@@ -56,12 +56,22 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from blockchain_simulator_tpu.models.base import fault_masks, gated_push
+from blockchain_simulator_tpu.models.base import (
+    can_branch,
+    fault_masks,
+    gated_body,
+    gated_push,
+)
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import gatherdeliv as gd
 from blockchain_simulator_tpu.ops import topology
-from blockchain_simulator_tpu.ops.ring import ring_pop, ring_push_add, ring_push_max
+from blockchain_simulator_tpu.ops.ring import (
+    ring_pop,
+    ring_push_add,
+    ring_push_max,
+    slice_node_minor,
+)
 from blockchain_simulator_tpu.utils.prng import Channel, chan_key
 
 # propose-tick sentinel (min-reduced); np, not jnp: same int either way
@@ -77,6 +87,11 @@ GLOBAL_FIELDS = ("slot_commits", "slot_commit_tick", "slot_propose_tick")
 # the phases of :func:`step` as ``jax.named_scope`` names: HLO metadata only
 # (nothing computed changes), so a profiler trace attributes device time to
 # a phase that keeps its name across refactors.  ops/ scopes nest inside.
+# the taken trip of :func:`step`'s quiet-tick gate, around every phase after
+# the pops: the device events of one operation under it are the ticks that
+# ran.  Outside the ``pbft.`` / ``ops.`` families on purpose, so that a phase
+# stays the outermost program scope of what runs inside
+TAKEN_SCOPE = "gate.pbft.tick_taken"
 SCOPES = (
     "pbft.tick.pop",
     "pbft.tick.view_change",
@@ -87,6 +102,7 @@ SCOPES = (
     "pbft.tick.forge",
     "pbft.tick.commit",
     "pbft.tick.timers",
+    TAKEN_SCOPE,
 )
 
 
@@ -131,12 +147,21 @@ class PbftState:
     slot_propose_tick: jax.Array  # [S] first proposal tick, _NEVER sentinel
 
 
+# the rows of ``PbftBufs.due``
+_RINGS = _PP, _PREP_RT, _COMMIT, _VC = range(4)
+
+
 @struct.dataclass
 class PbftBufs:
     pp: jax.Array       # [D, N, W] PRE_PREPARE slot-id+1 values, max-combined
     prep_rt: jax.Array  # [D, N, W] PREPARE_RES (round-trip) reply counts
     commit: jax.Array   # [D, N, W] COMMIT arrival counts
     vc: jax.Array       # [D, N] VIEW_CHANGE, encoded v*N + leader + 1, max
+    # [4, D] bool, a row per ring in the order above: slot k is *due* iff a
+    # push arm ran for it since it was last popped.  A due slot may hold
+    # zeros; a slot that is not due holds nothing (:func:`step`'s gate rests
+    # on that).  Kept only by the programs that gate (``can_branch``)
+    due: jax.Array
 
 
 def eff_window(cfg) -> int:
@@ -144,6 +169,14 @@ def eff_window(cfg) -> int:
     if w <= 0 or w >= cfg.pbft_max_slots:
         return cfg.pbft_max_slots
     return w
+
+
+def _queued(cfg) -> bool:
+    """Queued-link transport (cfg.queued_links): blocks ride per-destination
+    serial-pipe FIFOs instead of the ring (see PbftState field comments);
+    with ser == 0 the pipe is never busy and queued == constant-latency
+    bit-exactly, so the plain ring path runs (engine.cpp behaves the same)."""
+    return cfg.queued_links and cfg.serialization_ticks(cfg.pbft_block_bytes) > 0
 
 
 def queue_len(cfg) -> int:
@@ -154,8 +187,7 @@ def queue_len(cfg) -> int:
     (the former steady-state backlog estimate undersized the FIFO under
     adversarial view-change timing, which both re-proposes stale slots and
     resets link_busy — ADVICE r5)."""
-    ser = cfg.serialization_ticks(cfg.pbft_block_bytes)
-    if not cfg.queued_links or ser == 0:
+    if not _queued(cfg):
         return 1  # dummy registers; the ring path carries the blocks
     return min(cfg.pbft_max_rounds, cfg.pbft_max_slots)
 
@@ -217,7 +249,8 @@ def init(cfg, key=None):
         slot_commit_tick=jnp.full((s,), -1, jnp.int32),
         slot_propose_tick=jnp.full((s,), _NEVER, jnp.int32),
     )
-    bufs = PbftBufs(pp=zi(d, n, w), prep_rt=zi(d, n, w), commit=zi(d, n, w), vc=zi(d, n))
+    bufs = PbftBufs(pp=zi(d, n, w), prep_rt=zi(d, n, w), commit=zi(d, n, w),
+                    vc=zi(d, n), due=zb(len(_RINGS), d))
     return state, bufs
 
 
@@ -253,8 +286,64 @@ def _scatter_window_events(acc_add, acc_max, acc_min, events, eff_sid, t, s):
     return out
 
 
+def _is_block_tick(cfg, t):
+    return (t % cfg.pbft_block_interval_ms == 0) & (t > 0)
+
+
 def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
          exchange=None):
+    """One tick: pop the four rings, then run the phases (:func:`_phases`).
+
+    The phases are the identity on a tick on which no popped slice holds
+    anything, no queued block lands and no block is due to be sent: every
+    crossing, send and trigger is false and every vote table gets ``+ 0``.
+    So where the program can branch (``can_branch``: one device, no
+    ``select_vmap``) they run inside one ``while`` of at most one trip,
+    taken on ``is_block_tick | any ring's slot t is due`` (``PbftBufs.due``),
+    under a lane batch on "any lane active" with no per-lane select, the
+    body being the identity for a lane with nothing due.  A quiet tick then
+    passes over no ``[N, W]`` table.  The pops stay out here, on every tick.
+    Under a mesh axis the phases hold collectives, and under ``select_vmap``
+    no branch survives: both run the phases on every tick (KNOWN_ISSUES
+    #0b')."""
+    with jax.named_scope("pbft.tick.pop"):
+        pp_t, pp = ring_pop(bufs.pp, t)
+        prep_t, prep_rt = ring_pop(bufs.prep_rt, t)
+        com_t, commit = ring_pop(bufs.commit, t)
+        vc_t, vc = ring_pop(bufs.vc, t)
+    popped = (pp_t, prep_t, com_t, vc_t)
+    bufs = bufs.replace(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc)
+    gate = can_branch(cfg.mesh_axis)
+
+    def phases(carry):
+        return _phases(cfg, *carry, popped, t, tkey, topo_tables, exchange,
+                       mark_due=gate)
+
+    if not gate:
+        return phases((state, bufs))
+    with jax.named_scope("pbft.tick.pop"):
+        d = bufs.due.shape[1]
+        now = jnp.arange(d) == t % d
+        active = _is_block_tick(cfg, t) | (bufs.due & now).any()
+        if _queued(cfg):  # those arrivals bypass the rings
+            active = active | (state.ppq_tick == t).any()
+        bufs = bufs.replace(due=bufs.due & ~now)
+    # the loop is laid out in isolation: the popped slices and the tables
+    # they meet keep the rings' node-minor order across its boundary
+    popped = jax.tree.map(slice_node_minor, popped)
+
+    def pinned(carry):
+        st, bf = phases(carry)
+        return jax.tree.map(slice_node_minor, st), bf
+
+    return gated_body(active, pinned, (state, bufs), TAKEN_SCOPE)
+
+
+def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
+            topo_tables, exchange, mark_due: bool):
+    """Everything of a tick after the pops, from the alive masks of the
+    ``popped`` slices to the end of the timers.  ``mark_due`` keeps
+    ``bufs.due`` at every push (the gated programs)."""
     n, s = cfg.n, cfg.pbft_max_slots
     w = eff_window(cfg)
     exact = w == s
@@ -274,19 +363,25 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
     windows = jnp.arange(w)
 
     ser = cfg.serialization_ticks(cfg.pbft_block_bytes)
-    # queued-link transport (cfg.queued_links): blocks ride per-destination
-    # serial-pipe FIFOs instead of the ring (see PbftState field comments);
-    # with ser == 0 the pipe is never busy and queued == constant-latency
-    # bit-exactly, so the plain ring path runs (engine.cpp behaves the same)
-    queued = cfg.queued_links and ser > 0
+    queued = _queued(cfg)
     prop = cfg.link_delay_ms
 
+    pp_t, prep_t, com_t, vc_t = popped
+    pp, prep_rt, commit, vc = bufs.pp, bufs.prep_rt, bufs.commit, bufs.vc
+    due = list(bufs.due) if mark_due else None
+    d = bufs.due.shape[1]
+
+    def push(ring, pred, fn, zeros, buf, into, lo_, n_buckets):
+        """``gated_push`` into ring ``ring``, whose slots ``t + lo_ + b``,
+        ``b < n_buckets``, the push arm writes: marked due on the lane's own
+        predicate."""
+        if mark_due:
+            ahead = jnp.mod(jnp.arange(d) - (t + lo_), d) < n_buckets
+            due[ring] = due[ring] | (pred & ahead)
+        return gated_push(pred, fn, zeros, buf, into, axis)
+
     with jax.named_scope("pbft.tick.pop"):
-        # ---- pop this tick's arrivals; crashed nodes process nothing ------------
-        pp_t, pp = ring_pop(bufs.pp, t)
-        prep_t, prep_rt = ring_pop(bufs.prep_rt, t)
-        com_t, commit = ring_pop(bufs.commit, t)
-        vc_t, vc = ring_pop(bufs.vc, t)
+        # ---- this tick's arrivals; crashed nodes process nothing ----------------
         am = state.alive.astype(jnp.int32)
         pp_t, prep_t, com_t = pp_t * am[:, None], prep_t * am[:, None], com_t * am[:, None]
         vc_t = vc_t * am
@@ -430,7 +525,8 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 if axis is not None:
                     n_voters = jax.lax.psum(n_voters, axis)
                 n_peers = n_voters - voters.astype(jnp.int32)
-            prep_rt = gated_push(
+            prep_rt = push(
+                _PREP_RT,
                 prep_active.any(),
                 tuple,
                 (),
@@ -442,10 +538,11 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                     # replies are per broadcast, i.e. per active (node, window)
                     expand=lambda c: c[:, None] * got_pp_i,
                 ),
-                axis,
+                rt_lo, len(rt_probs),
             )
         else:
-            prep_rt = gated_push(
+            prep_rt = push(
+                _PREP_RT,
                 prep_active.any(),
                 lambda: (
                     gd.roundtrip_reply_counts_kreg(
@@ -462,7 +559,7 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 lambda buf, rt_counts: ring_push_add(
                     buf, t, rt_lo, rt_counts[:, :, None] * got_pp_i[None, :, :]
                 ),
-                axis,
+                rt_lo, len(rt_probs),
             )
 
     with jax.named_scope("pbft.tick.prepare"):
@@ -475,7 +572,7 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
         prepare_vote = jnp.where(crossed_p, 0, pv)  # reset on threshold (quirk #4)
 
         bt = cfg.pbft_block_interval_ms
-        is_block_tick = (t % bt == 0) & (t > 0)
+        is_block_tick = _is_block_tick(cfg, t)
         commit_send = crossed_p & (state.alive & state.honest)[:, None]
         commit_mat = commit_send.astype(jnp.int32)
         if cfg.faults.byz_forge and cfg.faults.n_byzantine > 0:
@@ -498,7 +595,8 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # fused chain-into-ring (see the prep_rt channel above); the
             # kregular twin gathers the per-(receiver, slot) sender counts
             # over the in-table instead of totals-minus-own
-            commit = gated_push(
+            commit = push(
+                _COMMIT,
                 (commit_mat > 0).any(),
                 tuple,
                 (),
@@ -512,10 +610,11 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                         axis=axis, mode=smode,
                     )
                 ),
-                axis,
+                lo, hi - lo,
             )
         else:
-            commit = gated_push(
+            commit = push(
+                _COMMIT,
                 (commit_mat > 0).any(),
                 lambda: (
                     gd.bcast_slots_kreg(k_cm, commit_mat, nbr_in_loc, ids, lo,
@@ -528,7 +627,7 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 zeros_w,
                 commit,
                 lambda buf, c: ring_push_add(buf, t, lo, c),
-                axis,
+                lo, hi - lo,
             )
 
     with jax.named_scope("pbft.tick.commit"):
@@ -631,17 +730,19 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             # the random digraph, so mark the origin's copy as already seen
             seen_pp = jnp.maximum(seen_pp, origin_enc)
             pp_out = jnp.maximum(origin_enc, pp_fwd)
-            pp = gated_push(
+            pp = push(
+                _PP,
                 (pp_out > 0).any(),
                 lambda: dv.gossip_fwd(k_pp, pp_out, nbrs_loc, n, lo, hi, drop,
                                       axis=axis, impl=eimpl),
                 zeros_w,
                 pp,
                 push_pp,
-                axis,
+                lo + ser, hi - lo,
             )
         elif kreg:
-            pp = gated_push(
+            pp = push(
+                _PP,
                 send_block.any(),
                 lambda: (
                     gd.bcast_window_value_max_stat_kreg(
@@ -655,27 +756,29 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 zeros_w,
                 pp,
                 push_pp,
-                axis,
+                lo + ser, hi - lo,
             )
         elif stat:
-            pp = gated_push(
+            pp = push(
+                _PP,
                 send_block.any(),
                 lambda: dv.bcast_window_value_max_stat(k_pp, pp_val, ow_probs, drop,
                                                        axis=axis),
                 zeros_w,
                 pp,
                 push_pp,
-                axis,
+                lo + ser, hi - lo,
             )
         else:
-            pp = gated_push(
+            pp = push(
+                _PP,
                 send_block.any(),
                 lambda: dv.bcast_window_value_max_dense(k_pp, pp_val, lo, hi, drop,
                                                         axis=axis, impl=eimpl),
                 zeros_w,
                 pp,
                 push_pp,
-                axis,
+                lo + ser, hi - lo,
             )
         rounds_sent = state.rounds_sent + send_block
         (slot_propose_tick,) = _scatter_window_events(
@@ -707,17 +810,19 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
             vc_origin = (enc * h_enc + cfg.gossip_hops) * (enc > 0)
             seen_vc = jnp.maximum(seen_vc, vc_origin)  # self-loop guard
             vc_out = jnp.maximum(vc_origin, vc_fwd)
-            vc = gated_push(
+            vc = push(
+                _VC,
                 (vc_out > 0).any(),
                 lambda: dv.gossip_fwd(k_vc, vc_out[:, None], nbrs_loc, n, lo, hi,
                                       drop, axis=axis, impl=eimpl)[:, :, 0],
                 zeros_flat,
                 vc,
                 push_vc,
-                axis,
+                lo, hi - lo,
             )
         elif kreg:
-            vc = gated_push(
+            vc = push(
+                _VC,
                 trigger.any(),
                 lambda: (
                     gd.bcast_value_max_stat_kreg(k_vc, enc, nbr_in_loc, ow_probs,
@@ -730,26 +835,28 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
                 zeros_flat,
                 vc,
                 push_vc,
-                axis,
+                lo, hi - lo,
             )
         elif stat:
-            vc = gated_push(
+            vc = push(
+                _VC,
                 trigger.any(),
                 lambda: dv.bcast_value_max_stat(k_vc, enc, ow_probs, drop, axis=axis),
                 zeros_flat,
                 vc,
                 push_vc,
-                axis,
+                lo, hi - lo,
             )
         else:
-            vc = gated_push(
+            vc = push(
+                _VC,
                 trigger.any(),
                 lambda: dv.bcast_value_max_dense(k_vc, trigger, enc, lo, hi, drop,
                                                  axis=axis, impl=eimpl),
                 zeros_flat,
                 vc,
                 push_vc,
-                axis,
+                lo, hi - lo,
             )
 
     state = state.replace(
@@ -774,7 +881,8 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
         slot_commit_tick=slot_commit_tick,
         slot_propose_tick=slot_propose_tick,
     )
-    bufs = PbftBufs(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc)
+    bufs = PbftBufs(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc,
+                    due=jnp.stack(due) if mark_due else bufs.due)
     return state, bufs
 
 

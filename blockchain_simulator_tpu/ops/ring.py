@@ -102,5 +102,16 @@ def ring_push_max(buf, t, lo: int, contrib):
     return _push(buf, t, lo, contrib, "max")
 
 
+def slice_node_minor(x):
+    """:func:`node_minor` for one ``[N, W]`` slice of such a ring, or a table
+    of a slice's shape that meets slices elementwise: a ``while`` whose carry
+    holds them (the tick gate of models/pbft.step) is laid out in isolation
+    too, and where it chose another order for its carry every popped slice
+    was copied into it on every tick and back out inside the body."""
+    if x.ndim == 2 and x.shape[1] < _LANES <= x.shape[0]:
+        return with_layout_constraint(x, Layout(major_to_minor=(1, 0)))
+    return x
+
+
 # every scope above, by name (ops/scopes.py)
 SCOPES = tuple(_names)

@@ -45,8 +45,13 @@ def state_rules(global_fields=()):
     return partition.node_dim_rules(global_fields)
 
 
-# Ring/delivery buffers [D, N, ...]: the node axis is dim 1.
-BUF_RULES = ((r".*", P(None, NODES_AXIS)),)
+# Ring/delivery buffers [D, N, ...]: the node axis is dim 1.  PBFT's ``due``
+# bits ([rings, D], models/pbft.PbftBufs) have no node axis: every shard
+# holds the same copy, which a sharded program never touches.
+BUF_RULES = (
+    (r"(^|/)due$", partition.REPLICATED),
+    (r".*", P(None, NODES_AXIS)),
+)
 
 # Mixed shard-sim (models/mixed.py): raft leaves [S, ...] row-shard over
 # the shard axis; the S-representative PBFT layer is replicated (every

@@ -91,6 +91,15 @@ def load_checkpoint(path):
     s0, b0 = jax.eval_shape(
         lambda: proto.init(cfg, jax.random.key(0))
     )
+    for prefix, tree, what in (("s", s0, "state"), ("b", b0, "buffer")):
+        stored = sum(k[:1] == prefix and k[1:].isdigit() for k in z.files)
+        if stored != len(jax.tree.leaves(tree)):
+            # e.g. an archive from before PbftBufs held its due bits: its
+            # rings cannot be resumed by a program that skips quiet ticks
+            raise ValueError(
+                f"{path}: {stored} {what} leaves stored, this program's "
+                f"{cfg.protocol} init has {len(jax.tree.leaves(tree))}: the "
+                "checkpoint was written by another version of the engine")
     state = jax.tree.unflatten(
         jax.tree.structure(s0),
         [jax.numpy.asarray(z[f"s{i}"]) for i in range(len(jax.tree.leaves(s0)))],

@@ -473,12 +473,18 @@ STRUCTURAL = {
 }
 
 
+def _ring_leaves(cfg):
+    """The ring leaves of a protocol's buffers (PBFT's bool ``due`` bits are
+    bookkeeping about its rings, not one of them)."""
+    proto = base.get_protocol(cfg.protocol)
+    _, bufs = jax.eval_shape(lambda: proto.init(cfg, jax.random.key(0)))
+    return [x for x in jax.tree.leaves(bufs) if x.dtype != jnp.bool_]
+
+
 def _ring_shapes(cfg, n_loc=None):
     """The ring buffers' shapes (with ``n_loc`` rows where the node dim is
     sharded); a batched program holds them under one more leading dim."""
-    proto = base.get_protocol(cfg.protocol)
-    _, bufs = jax.eval_shape(lambda: proto.init(cfg, jax.random.key(0)))
-    shapes = {tuple(x.shape) for x in jax.tree.leaves(bufs)}
+    shapes = {tuple(x.shape) for x in _ring_leaves(cfg)}
     if n_loc is not None:
         shapes = {(s[0], n_loc) + s[2:] for s in shapes}
     return shapes
@@ -523,8 +529,7 @@ def _structure(closed, rings):
 def test_no_select_touches_a_ring_and_only_pops_update_outside_a_gate(name):
     cfg = STRUCTURAL[name]
     rings = _ring_shapes(cfg)
-    n_rings = len(jax.tree.leaves(jax.eval_shape(
-        lambda: base.get_protocol(cfg.protocol).init(cfg, jax.random.key(0)))[1]))
+    n_rings = len(_ring_leaves(cfg))
     canon = canonical_fault_cfg(cfg)
     programs = {
         "make_sim_fn": jax.make_jaxpr(runner.make_sim_fn.__wrapped__(cfg))(

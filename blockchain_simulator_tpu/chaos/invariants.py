@@ -46,14 +46,16 @@ def normalize_spans(spans) -> list[str]:
     ``sweep.*`` spans are excluded: a deadline-abandoned chunk attempt
     closes its span whenever the abandoned thread finishes, which can
     land inside one run's capture window and outside the other's — the
-    journal's ``event`` lines are that trail's deterministic record."""
+    journal's ``event`` lines are that trail's deterministic record.
+    ``build.*`` spans (utils/aotcache.py's build log) are excluded too:
+    whether a run builds depends on what the process built before it."""
     by_id: dict[tuple, dict] = {}
     recs = []
     for rec in spans:
         if rec.get("kind") != "span":
             continue
         name = str(rec.get("name"))
-        if name.startswith("sweep."):
+        if name.startswith(("sweep.", "build.")):
             continue
         by_id[(rec.get("trace"), rec.get("id"))] = rec
         recs.append(rec)

@@ -985,6 +985,9 @@ class ScenarioServer:
         return rec
 
     # -------------------------------------------------------------- prewarm
+    # both run inside registry.warming() (a fresh block a call): a compile
+    # after the first of them has returned is a late build in /stats
+    @aotcache.registry.warming()
     def prewarm(self, obj: dict) -> dict:
         """Compile (or load from the persistent AOT cache) every executable
         a request template's batch group can dispatch to — the solo program
@@ -1031,6 +1034,7 @@ class ScenarioServer:
                 )
         return wall
 
+    @aotcache.registry.warming()
     def prewarm_from(self, log_path: str, max_groups: int = 8) -> dict:
         """Prewarm from OBSERVED traffic instead of the fixed bucket
         ladder: read a prior access log (runs.jsonl — each served line

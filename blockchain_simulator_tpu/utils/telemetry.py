@@ -192,13 +192,15 @@ def capture():
 
 def emit(name: str, t0: float, t1: float | None = None,
          trace: str | None = None, parent: str | None = None,
-         span_id: str | None = None, status: str = "ok", **attrs) -> str:
+         span_id: str | None = None, status: str = "ok", sink=None,
+         **attrs) -> str:
     """Record one closed span from explicit ``time.monotonic()`` stamps —
     the request-lifecycle synthesizer (serve/server.py builds a request's
     whole segment tree at answer time from stamps, because the segments
     straddle threads).  Returns the span id so callers can parent
     children to it.  Emission goes to the flight-recorder ring, any
-    installed capture sinks, and the span log when armed."""
+    installed capture sinks, ``sink`` (this one record's own: the build
+    log of utils/aotcache.py) and the span log when armed."""
     t1 = time.monotonic() if t1 is None else t1
     sid = span_id or new_span_id()
     rec = {
@@ -217,9 +219,11 @@ def emit(name: str, t0: float, t1: float | None = None,
     flight.record(rec)
     with _sinks_lock:
         sinks = list(_sinks)
-    for sink in sinks:
+    if sink is not None:
+        sinks.append(sink)
+    for each in sinks:
         try:
-            sink(rec)
+            each(rec)
         except Exception:
             pass  # a broken sink must never break the emitting code path
     path = os.environ.get(SPANS_ENV)
@@ -246,13 +250,13 @@ def _twin(name: str, attrs: dict):
 
 @contextlib.contextmanager
 def span(name: str, ctx: TraceContext | None = None, record: bool = True,
-         **attrs):
+         sink=None, **attrs):
     """Open/close one span around a block: child of ``ctx`` (or the
     thread's current context; a fresh trace when neither exists), set as
     the thread's current context inside the block — so nested spans and
     outbound HTTP headers (serve/router.py ``_http``) pick it up.  An
     escaping exception marks ``status="error"`` and re-raises.  Yields
-    the span's own :class:`TraceContext`.
+    the span's own :class:`TraceContext`; ``sink`` is :func:`emit`'s.
 
     Every span has a twin in the profiler's trace (:func:`_twin`) that
     carries ``<trace id>:<span id>`` as its ``span`` stat (the header form:
@@ -285,7 +289,7 @@ def span(name: str, ctx: TraceContext | None = None, record: bool = True,
         _tls.ctx = prev
         emit(name, t0, t1, trace=tid,
              parent=parent.span_id if parent is not None else None,
-             span_id=sid, status=status, **attrs)
+             span_id=sid, status=status, sink=sink, **attrs)
 
 
 def trace_clock_offset_ns(records, twins: dict) -> float | None:
@@ -307,6 +311,14 @@ def on_trace_clock(rec: dict, offset_ns: float) -> tuple[float, float]:
     device trace's timeline."""
     start = rec["ts"] * 1e9 + offset_ns
     return start, start + rec["dur_ms"] * 1e6
+
+
+def on_monotonic_clock(rec: dict) -> tuple[float, float]:
+    """``(t0, t1)`` of a span record on THIS process's ``time.monotonic()``
+    clock, the clock :func:`emit` took them on: what compares a record with
+    a stamp the caller took itself (the benchmark's window start)."""
+    t0 = rec["ts"] - _EPOCH
+    return t0, t0 + rec["dur_ms"] / 1000.0
 
 
 # --------------------------------------------------------------- metrics ---
@@ -680,10 +692,3 @@ def install_crash_dump() -> None:
             prev_t(args)
 
     _threading.excepthook = thread_hook
-
-
-def reset() -> None:
-    """Fresh metrics + flight ring (test/drill isolation).  Does not
-    touch installed sinks or thread-local contexts."""
-    metrics.reset()
-    flight.reset()

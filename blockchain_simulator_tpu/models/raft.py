@@ -38,6 +38,19 @@ same receiver in the same tick resolve to one candidate — a documented
 large-N simplification).  Heartbeat acks never depend on follower state, so
 they are short-circuited round trips.  Echo-back (quirk #1) is not modeled.
 
+The per-block commit ticks (``RaftState.block_tick``, ``[N, B]``) are the one
+table of the state, and it changes on the few ticks on which a leader
+commits: at most one tick a block, none before proposals start (1 s after
+an election).  A one-hot select over it on every tick was the largest device
+operation of the mixed deployment's election prefix (256 shards:
+``[256, 1024, 50]``, 45% of a tick on which no entry changed; PERF.md
+section 6, PR 39), so the stamp runs inside ``base.gated_body``'s ``while``
+of at most one trip, taken when some node's commit lands (under a lane
+batch: some lane's), scope ``gate.raft.commit_taken``.  The stamp is the
+identity where nothing lands, so no per-lane select is needed and results
+are bit-equal.  Programs that cannot branch (a mesh ``axis``,
+``select_vmap``) keep the unconditional select (KNOWN_ISSUES #0b').
+
 Fidelity modes:
 - ``reference``: a plain heartbeat cancels the election timer *permanently*
   (the re-arm is commented out, raft-node.cc:177-178 — quirk #5), and a block
@@ -63,7 +76,12 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from blockchain_simulator_tpu.models.base import fault_masks, gated_push
+from blockchain_simulator_tpu.models.base import (
+    can_branch,
+    fault_masks,
+    gated_body,
+    gated_push,
+)
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import gatherdeliv as gd
@@ -79,6 +97,13 @@ from blockchain_simulator_tpu.utils.prng import Channel, chan_key
 # traces.
 DISARM = np.int32(1 << 30)
 
+# the taken trip of the commit gate inside ``raft.tick.ack_rx`` (see
+# ``RaftState.block_tick``): the device events of an operation under it are
+# the ticks on which the table was written.  Outside the ``raft.`` / ``ops.``
+# families, as models/pbft.TAKEN_SCOPE is, so the phase stays the outermost
+# program scope of what runs inside
+COMMIT_SCOPE = "gate.raft.commit_taken"
+
 # the phases of :func:`step` as ``jax.named_scope`` names, after its own
 # section comments (HLO metadata only — see models/pbft.SCOPES); ops/ scopes
 # nest inside, and under models/mixed.py these sit below ``mixed.tick.*``
@@ -90,6 +115,7 @@ SCOPES = (
     "raft.tick.ack_rx",
     "raft.tick.timer_vote",
     "raft.tick.timer_heartbeat",
+    COMMIT_SCOPE,
 )
 
 
@@ -111,7 +137,14 @@ class RaftState:
     hb_open: jax.Array        # [N] bool — current round not yet committed
     leader_tick: jax.Array    # [N] tick this node became leader (-1 = never)
     elections: jax.Array      # [N] sendVote firings (metrics)
-    block_tick: jax.Array     # [N, B] commit tick per block at the leader (-1)
+    # [N, B] commit tick per block at the leader (-1 = not committed).  An
+    # entry is written once, on the tick on which that node's commit lands:
+    # :func:`step` stamps the table through ``base.gated_body`` on "some
+    # node's (some lane's) commit lands this tick" and passes over it on no
+    # other tick; under a mesh axis or ``select_vmap`` the one-hot select
+    # runs on every tick (``base.can_branch``).  models/raft_hb.materialize
+    # writes the leader's row once a run.
+    block_tick: jax.Array
     alive: jax.Array          # [N] bool fault mask
     honest: jax.Array         # [N] bool fault mask
     # gossip (topology="gossip") dedup registers: highest TTL-encoded copy
@@ -588,12 +621,25 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             hb_cnt = jnp.where(done, 0, hc)
             hb_open = hb_open_in
         blk = jnp.clip(state.block_num, 0, cfg.raft_max_blocks - 1)
-        block_tick = jnp.where(
-            (jax.nn.one_hot(blk, cfg.raft_max_blocks, dtype=bool)
-             & commit[:, None] & (state.block_num < cfg.raft_max_blocks)[:, None]),
-            jnp.int32(t),
-            state.block_tick,
-        )
+
+        def stamp(block_tick):
+            return jnp.where(
+                (jax.nn.one_hot(blk, cfg.raft_max_blocks, dtype=bool)
+                 & commit[:, None]
+                 & (state.block_num < cfg.raft_max_blocks)[:, None]),
+                jnp.int32(t),
+                block_tick,
+            )
+
+        if can_branch(axis):
+            # the stamp is the identity unless some node's commit lands, on
+            # a lane (a shard of models/mixed.step, a seed of a sweep) as on
+            # a lone run: a quiet tick passes over no [N, B] table
+            lands = commit & (state.block_num < cfg.raft_max_blocks)
+            block_tick = gated_body(lands.any(), stamp, state.block_tick,
+                                    COMMIT_SCOPE)
+        else:
+            block_tick = stamp(state.block_tick)
         block_num = state.block_num + commit
         # blockNum >= 50 cancels the heartbeat (raft-node.cc:248-251).  Gossip
         # divergence: completion must NOT silence the failure detector — with

@@ -78,26 +78,48 @@ def test_mixed_sharded_shard_count_validated():
 STAT = CFG.with_(delivery="stat", model_serialization=False)
 
 
-def test_mixed_fast_path_matches_tick_engine():
+# the benchmark cell's rehearsal size (benchmark/configs/mixed-raft256x1k-
+# pbft.json: 8 shards of 256 nodes), cut to a window that holds the election
+# prefix and the first Raft commits
+REHEARSAL = STAT.with_(n=2048, sim_ms=1500)
+
+
+@pytest.mark.parametrize("cfg,final", [(STAT, 40), (REHEARSAL, 26)],
+                         ids=["8x6", "8x256"])
+def test_mixed_fast_path_matches_tick_engine(cfg, final):
     # stat delivery makes the raft shards heartbeat-schedulable: schedule
     # 'auto' resolves to the fast path (mixed.scan_fast), whose metrics must
     # equal the per-tick engine's exactly — the PBFT layer steps with
     # identical keys/alive masks and raft counts follow the raft_hb bit
     # contract
+    import jax
+
+    from blockchain_simulator_tpu.models import mixed
     from blockchain_simulator_tpu.runner import use_round_schedule
 
-    assert use_round_schedule(STAT)
+    assert use_round_schedule(cfg)
     assert not use_round_schedule(CFG)  # edge delivery stays per-tick
-    m_fast = run_simulation(STAT)
-    m_tick = run_simulation(STAT.with_(schedule="tick"))
+    m_fast = run_simulation(cfg)
+    m_tick = run_simulation(cfg.with_(schedule="tick"))
     # raft commit TICKS carry the +/-1 bucket-quantile jitter of the two
     # engines' independent draws (raft_hb's milestone contract); every
     # other key is equal
     tail = "raft_commit_tail_ms_max"
     assert abs(m_fast.pop(tail) - m_tick.pop(tail)) <= 1
     assert m_fast == m_tick
-    assert m_fast["global_blocks_final"] == 40
+    assert m_fast["global_blocks_final"] == final
     assert m_fast["shards_with_leader"] == 8
+    assert m_fast["raft_blocks_min"] >= 7
+    # the quiet prefix: no shard commits before the handoff (proposals start
+    # 1 s after an election), so raft.step's commit gate never takes its
+    # trip there and the table reaches the handoff as init made it
+    key = jax.random.key(cfg.seed)
+    state, bufs = mixed.init(cfg, jax.random.fold_in(key, 0x1217))
+    (st, _), ok_all, h_s = jax.jit(
+        lambda s, b: mixed.prefix_handoff(cfg, s, b, key))(state, bufs)
+    assert bool(ok_all)
+    assert (np.asarray(h_s.bn0) == 0).all()
+    assert (np.asarray(st.raft.block_tick) == -1).all()
 
 
 def test_mixed_fast_path_crash_majority_falls_back():

@@ -99,7 +99,7 @@ def _under(axis_name: str) -> bool:
 
 def _any_lane(pred):
     with jax.named_scope(GATE_SCOPE):
-        return jax.lax.pmax(pred.astype(jnp.int32), LANES_AXIS) > 0
+        return jax.lax.pmax(pred.astype(jnp.int32), lane_axes()) > 0
 
 
 def gated(pred, fn, zeros, axis=None):
@@ -110,7 +110,7 @@ def gated(pred, fn, zeros, axis=None):
     (:func:`gated_push` takes its contribution from here and loops over the
     push alone).
 
-    Under a lane batch (:func:`lane_vmap`) a cond on a per-lane predicate
+    Under a lane batch (:func:`lane_axes`) a cond on a per-lane predicate
     would lower to a select, both arms run for every lane on every tick.
     There the branch is taken on "any lane active", which is unbatched, so
     the cond stays a cond; inside the taken arm each lane keeps ``fn()``
@@ -124,7 +124,7 @@ def gated(pred, fn, zeros, axis=None):
     :func:`gated_push`, as every engine call site does."""
     if axis is not None:
         pred = mesh_ops.pmax(pred.astype(jnp.int32), axis) > 0
-    if not _under(LANES_AXIS):
+    if not lane_axes():
         return jax.lax.cond(pred, fn, lambda: zeros)
     any_lane = _any_lane(pred)
 
@@ -186,7 +186,7 @@ def gated_push(pred, fn, zeros, bufs, push, axis=None):
         pred = mesh_ops.pmax(pred.astype(jnp.int32), axis) > 0
         contrib = gated(pred, fn, zeros)
         return gated_push(pred, lambda: contrib, zeros, bufs, push)
-    lanes = _under(LANES_AXIS)
+    lanes = lane_axes()
     any_lane = _any_lane(pred) if lanes else pred
 
     def taken(bufs):
@@ -245,7 +245,7 @@ def gated_body(pred, body, carry, scope):
         with jax.named_scope(scope):
             return body(carry)
 
-    return _one_trip(_any_lane(pred) if _under(LANES_AXIS) else pred, taken,
+    return _one_trip(_any_lane(pred) if lane_axes() else pred, taken,
                      carry)
 
 
@@ -327,3 +327,62 @@ def apply_fault_masks(cfg, state, alive, honest):
             election_deadline=jnp.where(alive, state.election_deadline, DISARM)
         )
     return state
+
+
+# ---- below: added at the file's end, so that no line above moves (the
+# compile cache keys on the source lines of traced code, ROADMAP D11) ----
+
+# the batch axis of one tile of a committee stack (topo/committee.py), bound
+# by :func:`tile_vmap` alone.  A sweep or a served bucket over a committee
+# configuration binds BOTH: ``lane_vmap`` around a body that is itself a lane
+# batch.  A second ``vmap`` under the first's name would shadow it (a
+# reduction then sees the inner batch alone, the predicate stays batched over
+# the outer one and every cond is a select again), so the tile has a name of
+# its own and :func:`gated`'s "any lane active" is reduced over every lane
+# axis that is bound: B x T lanes, one branch.
+TILE_AXIS = "tile_lanes"
+LANE_AXES = (LANES_AXIS, TILE_AXIS)
+
+
+def tile_vmap(fn):
+    """``jax.vmap(fn)`` over the committees of one tile of a committee stack:
+    a lane batch as :func:`lane_vmap`'s, under :data:`TILE_AXIS`."""
+    return jax.vmap(fn, axis_name=TILE_AXIS)
+
+
+def lane_axes() -> tuple:
+    """The lane axes bound where this is traced (:data:`LANE_AXES`:
+    :func:`lane_vmap`'s, :func:`tile_vmap`'s, or one around the other): empty
+    in a lone program.  What :func:`gated`, :func:`gated_push` and
+    :func:`gated_body` reduce "any lane active" over."""
+    return tuple(a for a in LANE_AXES if _under(a))
+
+
+def metric_leaves(cfg, finals) -> dict:
+    """The leaves of a (stacked or batched) final state that the protocol's
+    ``metrics`` reads, by field: its module's ``METRIC_FIELDS`` (every field
+    where a module declares none, as models/mixed), those that are there."""
+    import dataclasses
+
+    names = [f.name for f in dataclasses.fields(finals)]
+    fields = getattr(get_protocol(cfg.protocol), "METRIC_FIELDS", names)
+    return {f: getattr(finals, f) for f in fields
+            if getattr(finals, f) is not None}
+
+
+def host_rows(finals, host: dict, rows: int) -> list:
+    """The first ``rows`` entries of a final state's leading axis as states
+    of the finals' own type, from the fetched leaves ``host`` (a
+    :func:`metric_leaves` dict after ONE ``jax.device_get``): ``host[f][i]``
+    (a numpy view) in the fetched fields and None in the others, which is
+    all ``metrics`` asks for."""
+    import dataclasses
+
+    return [
+        type(finals)(**{
+            f.name: jax.tree.map(lambda x: x[i], host[f.name])
+            if f.name in host else None
+            for f in dataclasses.fields(finals)
+        })
+        for i in range(rows)
+    ]

@@ -1,7 +1,7 @@
 """Probed twins of the dynamic-fault program factories.
 
 ``make_probed_dyn_sim_fn(cfg, pcfg)`` mirrors ``runner.make_dyn_sim_fn``
-arm for arm — committee ``lax.map`` stack, round-schedule raft heartbeat
+arm for arm — committee stack (tiles of lanes), round-schedule raft heartbeat
 fast path (taps thread through the ``lax.cond`` phase split), round-
 blocked PBFT, general tick engine — returning ``sim(key, n_crashed,
 n_byzantine) -> (final_state, probes)`` with the probe pytree described

@@ -231,8 +231,9 @@ def sharded_topo_sim_fn(cfg: SimConfig, mesh, layout: str = "exchange"):
       uneven replicates instead of sharding.
     - **committee, nodes > 1**: shard_map over the STACKED committee axis
       (``committees % shards == 0`` required): each device runs
-      ``topo/committee.stacked_body`` — the same ``lax.map`` of the
-      unvmapped inner engine — on its slice of the ``[C]`` key stack and
+      ``topo/committee.stacked_body`` — the same tiles of lanes as the
+      single-device stack, cut to that device by the same rule
+      (:func:`_device_tile`) — on its slice of the ``[C]`` key stack and
       ``[C, m]`` fault masks.  Committee bodies never communicate before
       the host-side outer aggregate, and the per-committee keys are
       computed from the GLOBAL committee index before the shard_map, so
@@ -509,7 +510,13 @@ _TEMP_FACTOR = 2.0
 def _lane_state_bytes(canon: SimConfig) -> int:
     """Bytes of state one lane of ``make_dyn_sim_fn(canon)`` carries through
     its scan: ``eval_shape`` of the ``init`` that program calls (nothing is
-    allocated)."""
+    allocated).  A committee stack counts as all its committees at once,
+    which is the most a lane can hold (topo/committee.py runs them in tiles
+    cut by the same rule, :func:`_device_tile` with ``outer`` lanes)."""
+    if canon.topology == "committee":
+        from blockchain_simulator_tpu.topo import committee
+
+        return canon.committees * _lane_state_bytes(committee.inner_cfg(canon))
     if canon.protocol == "pbft" and use_round_schedule(canon):
         from blockchain_simulator_tpu.models import pbft_round as mod
     else:
@@ -526,15 +533,19 @@ def _device_bytes() -> int | None:
     return stats.get("bytes_limit")
 
 
-def _device_tile(canon: SimConfig, n_points: int) -> dict | None:
+def _device_tile(canon: SimConfig, n_points: int, outer: int = 1) -> dict | None:
     """How a point list that outgrows the device is cut: ``None`` where the
     whole list fits as one lane batch (or the device reports no memory),
     else the equal tile: as few dispatches as the device allows, the lanes
-    of each the list's length over that count, rounded up."""
+    of each the list's length over that count, rounded up.  The one rule of
+    the sweeps' point lists and of a committee stack's committees
+    (topo/committee.tile_plan: ``canon`` the inner configuration,
+    ``n_points`` the committees, ``outer`` the lanes of a batch around the
+    stack, each of which holds a tile of its own)."""
     device = _device_bytes()
     if device is None or n_points <= 1:
         return None
-    state = _lane_state_bytes(canon)
+    state = _lane_state_bytes(canon) * outer
     most = max(int(device // (_TEMP_FACTOR * state)), 1)
     if n_points <= most:
         return None
@@ -1079,21 +1090,12 @@ def _readback(cfg: SimConfig, finals, rows: int):
     per leaf shape, compiled at the first partial bucket, inside a serving
     window.  The span's ``leaves`` and ``bytes`` attrs say what was
     fetched."""
-    from blockchain_simulator_tpu.models.base import get_protocol
+    from blockchain_simulator_tpu.models import base as base_model
 
-    names = [f.name for f in dataclasses.fields(finals)]
-    fields = getattr(get_protocol(cfg.protocol), "METRIC_FIELDS", names)
-    picked = {f: getattr(finals, f) for f in fields}
+    picked = base_model.metric_leaves(cfg, finals)
     leaves = jax.tree.leaves(picked)
     with telemetry.span(
         "sweep.readback", rows=rows, lanes=leaves[0].shape[0],
         leaves=len(leaves), bytes=sum(x.nbytes for x in leaves),
     ):
-        host = jax.device_get(picked)
-        yield [
-            type(finals)(**{
-                f: jax.tree.map(lambda x: x[i], host[f]) if f in host else None
-                for f in names
-            })
-            for i in range(rows)
-        ]
+        yield base_model.host_rows(finals, jax.device_get(picked), rows)

@@ -15,7 +15,7 @@ is:
   the dense program at degree k = N-1);
 - :mod:`~blockchain_simulator_tpu.topo.committee` — two-level committee
   consensus behind ``topology="committee"``: inner-quorum consensus per
-  committee (a scatter-free ``lax.map`` over the stacked committee axis)
+  committee (the stacked committee axis run as tiles of lanes)
   plus an outer aggregate step over committee representatives; with one
   committee it IS the flat protocol.
 

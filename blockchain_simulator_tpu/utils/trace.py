@@ -324,8 +324,8 @@ def _committee_traced_fn(cfg: SimConfig):
     hierarchy: the static-arm run_stacked body (runner.make_sim_fn
     committee arm — config's own fault counts on the dyn operand slots)
     with the standard probe sampled per tick INSIDE each committee's
-    ``lax.map`` body (topo/committee.stacked_body probe hook), so the
-    series leaves stack to ``[C, ticks]``."""
+    body (topo/committee.stacked_body probe hook; a lane of a tile), so
+    the series leaves stack to ``[C, ticks]``."""
     from blockchain_simulator_tpu.models import base as base_model
     from blockchain_simulator_tpu.topo import committee
 

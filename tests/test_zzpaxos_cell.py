@@ -204,10 +204,11 @@ def test_run_sharded_emits_execute_then_readback(small):
     cfg, mesh = small
     with telemetry.capture() as spans:
         m = shard.run_sharded(cfg, mesh, seed=3)
-    names = [s["name"] for s in spans if s["name"].startswith("shard.")]
-    assert names == ["shard.execute", "shard.readback"]
-    assert spans[0]["attrs"] == {"shards": mesh.shape["nodes"],
-                                 "rows_per_shard": cfg.n // mesh.shape["nodes"]}
+    # the worker's first run of this program also writes its build.* records
+    mine = [s for s in spans if s["name"].startswith("shard.")]
+    assert [s["name"] for s in mine] == ["shard.execute", "shard.readback"]
+    assert mine[0]["attrs"] == {"shards": mesh.shape["nodes"],
+                                "rows_per_shard": cfg.n // mesh.shape["nodes"]}
     assert set(paxos.MILESTONES) <= set(m) and m["protocol"] == "paxos"
 
 

@@ -98,6 +98,30 @@ def test_sharded_committee_bit_equal(mesh2):
     )
 
 
+def test_sharded_committee_readback_claims_no_lone_plan(mesh2):
+    """A stack spread over a mesh ran each device's slice by a plan of its
+    own: its one readback carries no ``tiles`` / ``tile_lanes`` and moves no
+    counter, although the lone stack of the same configuration was traced
+    in this process and wrote its plan down."""
+    from blockchain_simulator_tpu.topo import committee
+    from blockchain_simulator_tpu.utils import telemetry
+
+    cfg = SimConfig(protocol="pbft", n=16, sim_ms=400, topology="committee",
+                    committees=4, faults=FaultConfig(n_crashed=4), **BASE)
+    runner.run_simulation(cfg)
+    assert committee.ran_as(cfg) is not None
+    before = telemetry.metrics.snapshot()["counters"]
+    with telemetry.capture() as spans:
+        sweep.run_sharded_topo(cfg, mesh2)
+    after = telemetry.metrics.snapshot()["counters"]
+    rb = [s for s in spans if s["name"] == "topo.committee.readback"]
+    assert len(rb) == 1 and "tile_lanes" not in rb[0]["attrs"]
+    assert all(after.get(k, 0) == before.get(k, 0)
+               for k in committee.COUNTERS)
+    # the slice a device ran is written down under its own length
+    assert (canonical_fault_cfg(cfg), 2, 1, True) in committee._traced
+
+
 def test_mesh_size_1_identity(mesh1):
     # the degenerate arm IS the single-device program: same results, and
     # the factory returns a jitted make_dyn_sim_fn (no partition machinery)

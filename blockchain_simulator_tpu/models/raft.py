@@ -380,16 +380,6 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             ack_to = jnp.where(got_prop, (prop_t - 1) % (n + 1), n)  # n = drop
             k_ack = chan_key(tkey, Channel.DELAY_REPLY2)
 
-            def _ack_counts(wire):
-                c = jnp.zeros((n,), jnp.int32).at[ack_to].add(
-                    wire.astype(jnp.int32), mode="drop"
-                )
-                if axis is not None:
-                    c = jax.lax.psum(c, axis)
-                    start = jax.lax.axis_index(axis) * n_loc
-                    c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
-                return c
-
             def _push_acks(rings, _):
                 # fused chain-into-ring (ops/delivery.push_bucket_counts):
                 # bit-equal to the former stacked sample → ring_push_add pair
@@ -397,8 +387,10 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 # intermediate; there is no separate contribution: the gate
                 # skips the whole push, and a lane without a sender adds
                 # all-zero counts, which leave its rings as they were
-                mok = _ack_counts(got_prop & state.honest & state.alive)
-                mbad = _ack_counts(got_prop & ~state.honest & state.alive)
+                mok = dv.reply_count_by_target(
+                    got_prop & state.honest & state.alive, ack_to, n, axis)
+                mbad = dv.reply_count_by_target(
+                    got_prop & ~state.honest & state.alive, ack_to, n, axis)
                 if drop > 0.0:
                     kd = jax.random.fold_in(k_ack, 0x0D18)
                     mok = jnp.round(delay_ops.binom(
@@ -454,10 +446,13 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             # Byzantine receivers flip their replies (grant<->deny on the wire)
             ok_wire = (grant & state.honest) | (deny & ~state.honest)
             no_wire = (deny & state.honest) | (grant & ~state.honest)
-            # per-candidate reply counts, multinomially spread: a global
-            # scatter-add on the full mesh; the overlay routes them
-            # requester-side instead — candidate c gathers its out-neighbors'
-            # wires and keeps those addressed to it (ops/gatherdeliv.
+            # per-candidate reply counts, multinomially spread.  On the full
+            # mesh ops/delivery.reply_count_by_target: every id compared with
+            # every replier's target and summed up to its bound on n (no
+            # scatter: one fusion, which a lane batch widens), a global
+            # scatter-add above it; the overlay routes them requester-side
+            # instead — candidate c gathers its out-neighbors' wires and
+            # keeps those addressed to it (ops/gatherdeliv.
             # reply_counts_by_target_kreg: equal counts at k = N-1, and the
             # kregular program stays scatter-free, KNOWN_ISSUES #0i)
             def reply_counts(wire):
@@ -465,14 +460,7 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                     return gd.reply_counts_by_target_kreg(
                         wire, grant_to, nbr_out_loc, ids, axis, exchange
                     )
-                c = jnp.zeros((n,), jnp.int32).at[grant_to].add(
-                    wire.astype(jnp.int32), mode="drop"
-                )
-                if axis is not None:
-                    c = jax.lax.psum(c, axis)
-                    start = jax.lax.axis_index(axis) * n_loc
-                    c = jax.lax.dynamic_slice_in_dim(c, start, n_loc)
-                return c
+                return dv.reply_count_by_target(wire, grant_to, n, axis)
 
             any_req = has_req.any()
             k_vr = chan_key(tkey, Channel.DELAY_REPLY)

@@ -372,6 +372,65 @@ def roundtrip_reply_counts_stat(
 
 
 # --------------------------------------------------------------------------- #
+# per-target reply totals (stat Raft's vote replies and gossip acks)          #
+# --------------------------------------------------------------------------- #
+
+
+# The largest ``n`` at which :func:`reply_count_by_target` counts by compare
+# and sum; above it the scatter-add stays.  Set from chip readings of the two
+# forms alone (tools/reply_count_readings.py on a TPU v5e, PR 41; PERF.md
+# section 3), device us of one count, dense / scatter: at n = 4,096 lone
+# 11.1 / 28.1 and under 256 lanes 3,011 / 7,055; at n = 16,384 lone 193.9 /
+# 109.8 and under 256 lanes 150,680 / 40,531.  (At 1,024 under 256 lanes,
+# the mixed deployment's shards: 172.5 / 2,296.)
+REPLY_COUNT_DENSE_MAX_N = 4096
+
+
+def _reply_count_dense(wire, target, n: int):
+    """Every id against every row's target, hits summed: ``n_loc * n``
+    compare-adds in one fusion (no ``[n_loc, n]`` array is ever stored),
+    which vectorizes, and which a lane batch widens."""
+    aimed = jnp.where(wire, target, -1)
+    return (aimed[:, None] == jnp.arange(n, dtype=jnp.int32)).sum(
+        0, dtype=jnp.int32)
+
+
+def _reply_count_scatter(wire, target, n: int):
+    """A scatter-add, the engine's own until PR 41 and letter for letter:
+    XLA:TPU runs it one update after another (6.7-8.7 ns each, a lane batch
+    flattened into the one scatter), but it grows with ``n_loc`` and not
+    with ``n_loc * n``.  ``mode="drop"`` drops a target of ``n`` or more;
+    a negative one ``.at[]`` wraps first, so its wire must be clear (masking
+    it here read +0.3% on a whole standalone run at n = 100,000, PERF.md
+    section 6, PR 41)."""
+    return jnp.zeros((n,), jnp.int32).at[target].add(
+        wire.astype(jnp.int32), mode="drop"
+    )
+
+
+@_scoped
+def reply_count_by_target(wire, target, n: int, axis=None):
+    """Per-target reply totals on the full mesh: entry ``c`` of the result is
+    the number of local rows ``j`` with ``wire[j]`` set and ``target[j] ==
+    c`` (``wire [N_loc]`` bool, ``target [N_loc]`` int32 global ids; a
+    target outside ``[0, n)`` counts nowhere: a row with no target carries
+    ``n``, or a negative id with its wire clear, as both callers do), summed
+    over the shards under ``axis``.  Returns ``[N_loc]`` int32, this
+    shard's rows.
+
+    One count, two forms, chosen from the static ``n`` alone: compare and
+    sum up to :data:`REPLY_COUNT_DENSE_MAX_N`, the scatter-add above it.
+    Integer counts either way: the forms are bit-equal."""
+    dense = n <= REPLY_COUNT_DENSE_MAX_N
+    c = (_reply_count_dense if dense else _reply_count_scatter)(wire, target, n)
+    if axis is not None:
+        n_loc = wire.shape[0]
+        c = lax.psum(c, axis)
+        c = lax.dynamic_slice_in_dim(c, lax.axis_index(axis) * n_loc, n_loc)
+    return c
+
+
+# --------------------------------------------------------------------------- #
 # fused sample-and-push (stat chains combined straight into the rings)        #
 # --------------------------------------------------------------------------- #
 

@@ -104,6 +104,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queued-links", action="store_true",
                    help="ns-3-exact serial-link transport: packets queue per "
                         "directed 3 Mbps link (--engine cpp only)")
+    p.add_argument("--raft-terms", action="store_true",
+                   help="Raft with terms (Figure 2 of the Raft paper on "
+                        "upstream's message set): one vote a term, step-down "
+                        "on a higher term; raft, clean fidelity, edge "
+                        "delivery, topology full or committee")
     p.add_argument("--quorum-rule", choices=["n2", "2f1"], default=d.quorum_rule,
                    help="n2 = reference majority thresholds (no vote dedup); "
                         "2f1 = Byzantine-safe 2f+1 quorum with per-sender dedup")
@@ -182,6 +187,7 @@ def config_from_args(args) -> SimConfig:
         paxos_client_ms=args.paxos_client[1] if args.paxos_client else 0,
         echo_back=args.echo_back,
         queued_links=args.queued_links,
+        raft_terms=args.raft_terms,
         pbft_block_interval_ms=args.pbft_interval_ms,
         pbft_max_rounds=args.pbft_rounds,
         pbft_max_slots=args.pbft_max_slots,
@@ -232,9 +238,10 @@ def main(argv=None) -> int:
               "backends design the echo away; see SimConfig docs)",
               file=sys.stderr)
         return 2
-    if args.engine != "cpp" and args.queued_links:
+    if args.engine != "cpp" and (args.queued_links or args.raft_terms):
         # pbft (serial-pipe registers) and paxos (ser = 0) run on the
         # tensorized backends; anything else gets the runner's message
+        # (as does an arm of raft that has no terms)
         from blockchain_simulator_tpu.runner import _reject_cpp_only
 
         try:
@@ -273,7 +280,7 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             try:
                 m = run_cpp(cfg, seed=s)
-            except ValueError as e:
+            except (ValueError, NotImplementedError) as e:  # e.g. raft_terms
                 print(f"error: {e}", file=sys.stderr)
                 return 2
             if args.timing:

@@ -186,6 +186,12 @@ def cpp_config(cfg, seed: int | None = None) -> _CppCfg:
 def run_cpp(cfg, seed: int | None = None) -> dict:
     """Run one simulation on the C++ engine; returns the metrics dict
     (same keys as the matching JAX backend's ``metrics()``)."""
+    if cfg.raft_terms:
+        raise NotImplementedError(
+            "raft_terms is not implemented by the C++ engine (engine.cpp is "
+            "upstream's Raft, which has no terms); the per-message reference "
+            "with terms is benchmark/reference/raft_terms_engine.py"
+        )
     c = cpp_config(cfg, seed)
     buf = ctypes.create_string_buffer(4096)
     rc = _lib().run_sim(ctypes.byref(c), buf, len(buf))

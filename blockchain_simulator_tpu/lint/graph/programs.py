@@ -249,6 +249,16 @@ def audit_configs() -> dict[str, "object"]:
         "pbft_comm": SimConfig(protocol="pbft", n=8, sim_ms=200,
                                topology="committee", committees=2,
                                stat_sampler="exact"),
+        # Raft with terms (SimConfig.raft_terms): the per-edge full-mesh
+        # clean arm, flat and as a committee stack of independent groups
+        "raft_terms": SimConfig(protocol="raft", n=8, sim_ms=200,
+                                raft_terms=True, model_serialization=False,
+                                stat_sampler="exact"),
+        "raft_terms_comm": SimConfig(protocol="raft", n=10, sim_ms=200,
+                                     raft_terms=True,
+                                     model_serialization=False,
+                                     topology="committee", committees=2,
+                                     stat_sampler="exact"),
         # fast paths, explicitly scheduled (eligibility asserted in tests)
         "pbft_round": SimConfig(protocol="pbft", n=8, sim_ms=200,
                                 delivery="stat", schedule="round",
@@ -297,7 +307,8 @@ def build_catalog() -> list[ProgramSpec]:
                 # scatter-free beyond the dense engines' baselined [W]-fold
                 # accumulators (tests/test_zztopo.py counts them)
                 "pbft_kreg", "pbft_kreg_stat", "raft_kreg",
-                "raft_kreg_stat", "paxos_kreg", "pbft_comm"):
+                "raft_kreg_stat", "paxos_kreg", "pbft_comm", "raft_terms",
+                "raft_terms_comm"):
         specs.append(sim_spec(arm))
 
     # --- runner.make_segment_fn ("segment") -----------------------------

@@ -59,6 +59,53 @@ Fidelity modes:
 - ``clean``: heartbeats re-arm the election timer (real failure detection) and
   a block commits as soon as acks reach the majority, latched once per round.
 
+Terms (``cfg.raft_terms``; clean fidelity, edge delivery, full mesh — flat
+or inside a committee stack): Raft's Figure 2 (Ongaro & Ousterhout, USENIX
+ATC 2014, sections 5.1-5.2) on upstream's message set.  Every node has a
+``term`` (0 at start) and every message carries its sender's:
+
+- *timer*: a follower or candidate whose election timer fires takes the next
+  term, votes for itself (its one vote of that term), zeroes its counts,
+  broadcasts VOTE_REQ(term, id) and re-arms the timer.  A leader has none.
+- *any message of a higher term*: the node takes that term and is a
+  follower of it (a leader stops its heartbeat and its proposal schedule,
+  abandons its open ack window and arms an election timer; a candidate
+  stops counting); its vote of the new term is free; then the message is
+  handled.  Several messages of one tick are handled highest term first.
+- *VOTE_REQ(T, c)*: ``T < term`` is denied; ``T == term`` is granted iff no
+  vote has been given in ``term``, and a grant re-arms the election timer.
+  A reply carries the replier's term.
+- *VOTE_RES*: a grant counts only at a node that is a candidate of the term
+  it was asked in; ``vote_success + 1 > N/2`` makes it that term's leader as
+  upstream (timer off, first heartbeat now, proposals 1 s later).  A
+  candidate denied by a majority waits for its timer: upstream's ``lose``
+  rule released the vote latch inside the term and is dropped (a departure).
+- *HEARTBEAT(T)*, plain or carrying a proposal: ``T < term`` is ignored by
+  the receiver; else the receiver is a follower of term T (a candidate of T
+  steps down), re-arms its timer and stores the value.
+- the oracle ``term_conflicts``: raised at a node that becomes leader of a
+  term in which another node is leader.  Election safety says it stays 0.
+
+How the rings carry it: ``vreq[d, i, j]`` holds the term of candidate j's
+request (it held 1), ``hb_plain`` the heartbeat's term max-combined (it held
+a count), ``hb_prop`` ``term * (n + 1) + leader + 1`` (it held ``leader +
+1``), ``vres_no`` the highest term among the denials landing (it held their
+count, which nothing reads once ``lose`` is gone).  ``vres_ok``, ``hb_ok``
+and ``hb_bad`` stay term-less counts: a reply lands within ``rt_hi - 1``
+ticks of its request, a node asks again or proposes again no sooner than an
+election timeout later, so every count that lands belongs to the candidacy
+(``is_cand``) or the ack window that is open, or to none (:func:`init`
+checks ``raft_election_lo_ms`` against that horizon, as ``pbft.init`` checks
+its window).  Not modeled: upstream has no log, so Figure 2's log-matching
+check and section 5.4.1's up-to-date restriction have nothing to compare; no
+PreVote; HEARTBEAT_RES is no message of its own (the short-circuited round
+trip below), so a leader learns of a higher term from the VOTE_REQ that
+made it, broadcast to the leader too, not from a rejected heartbeat; the
+stop rule stays upstream's, as a state (a leader with 50 blocks sends no
+heartbeat, so its followers time out and elect in a higher term: legal
+Raft).  ``benchmark/reference/raft_terms_engine.py`` is the per-message
+twin, a term on every message.
+
 Gossip topology (``topology="gossip"``, clean + stat only): the three
 broadcast channels — VOTE_REQ, plain HEARTBEAT, proposal HEARTBEAT — flood
 over a random k-out digraph with a hop TTL (time-monotone value encodings,
@@ -106,9 +153,13 @@ COMMIT_SCOPE = "gate.raft.commit_taken"
 
 # the phases of :func:`step` as ``jax.named_scope`` names, after its own
 # section comments (HLO metadata only — see models/pbft.SCOPES); ops/ scopes
-# nest inside, and under models/mixed.py these sit below ``mixed.tick.*``
+# nest inside, and under models/mixed.py these sit below ``mixed.tick.*``.
+# ``raft.tick.term`` is what terms add (``cfg.raft_terms``: the step to a
+# higher term and down from a role, the oracle); a program without terms
+# has no operation under it
 SCOPES = (
     "raft.tick.pop",
+    "raft.tick.term",
     "raft.tick.heartbeat_rx",
     "raft.tick.vote_rx",
     "raft.tick.vote_reply_rx",
@@ -122,7 +173,8 @@ SCOPES = (
 @struct.dataclass
 class RaftState:
     is_leader: jax.Array      # [N] bool
-    has_voted: jax.Array      # [N] bool — single vote latch (no terms, quirk #6)
+    has_voted: jax.Array      # [N] bool — single vote latch (no terms, quirk
+    # #6); with ``cfg.raft_terms`` the vote of ``term``, free again in a new one
     election_deadline: jax.Array  # [N] tick of next sendVote; DISARM = canceled
     vote_success: jax.Array   # [N] election SUCCESS replies received
     vote_failed: jax.Array    # [N] election FAILED replies received
@@ -177,6 +229,22 @@ class RaftState:
     # small enough that queued deliveries stay ON the rings, whose depth
     # config.ring_depth widens accordingly; engine.cpp:198-215 is the twin).
     link_busy: jax.Array      # [N]
+    # Raft with terms (``cfg.raft_terms``; module docstring "Terms").  None
+    # without: a program that has no terms carries no leaf for them
+    term: jax.Array | None = None         # [N] current term (0 at start)
+    is_cand: jax.Array | None = None      # [N] bool — candidate of ``term``
+    lead_term0: jax.Array | None = None   # [N] term of the first win (0 = none)
+    won_tick: jax.Array | None = None     # [N] tick of the latest win (-1)
+    # [N] tick of the last heartbeat sent in the term this node FIRST led
+    # (-1 = none): a group's failover is measured from its first leader's
+    last_hb: jax.Array | None = None
+    step_downs: jax.Array | None = None   # [N] leader/candidate -> follower
+    term_conflicts: jax.Array | None = None  # [N] the election-safety oracle
+
+
+# the leaves above, in order
+TERM_FIELDS = ("term", "is_cand", "lead_term0", "won_tick", "last_hb",
+               "step_downs", "term_conflicts")
 
 
 @struct.dataclass
@@ -239,6 +307,14 @@ def init(cfg, key=None):
         my_base=zi(n),
         link_busy=zi(n),
     )
+    if cfg.raft_terms:
+        check_terms(cfg)
+        state = state.replace(
+            term=zi(n), is_cand=zb(n), lead_term0=zi(n),
+            won_tick=jnp.full((n,), -1, jnp.int32),
+            last_hb=jnp.full((n,), -1, jnp.int32),
+            step_downs=zi(n), term_conflicts=zi(n),
+        )
     if cfg.delivery == "stat":
         vreq = zi(d, n)
     elif cfg.topology == "kregular":
@@ -261,10 +337,70 @@ def init(cfg, key=None):
 
 
 
+def check_terms(cfg):
+    """``cfg.raft_terms``: what the term-less count channels stand on (module
+    docstring "Terms"), and every arm without terms refused by its name
+    rather than silently running the one-vote-a-run election.  The one place
+    the arms are listed: :func:`init` and :func:`step` ask it, and
+    runner.py's validation before anything is built (there ``topology`` may
+    still be ``"committee"``, whose groups are full meshes).  Protocol and
+    fidelity are ``SimConfig``'s own refusals; the C++ engine's is
+    engine.run_cpp's."""
+    if cfg.delivery != "edge":
+        raise NotImplementedError(
+            "raft_terms is not implemented for delivery='stat': its "
+            "vote-request channel max-combines candidate ids and keeps "
+            "no sender to carry a term (models/raft.py stat arm; "
+            "models/raft_hb is stat-only and has none either); use "
+            "delivery='edge'"
+        )
+    if cfg.topology not in ("full", "committee"):
+        raise NotImplementedError(
+            f"raft_terms is not implemented for topology="
+            f"{cfg.topology!r}: the gossip arm votes for the newest "
+            "election on time-monotone bases (models/raft.py my_base), "
+            "which is not Raft's integer term, and the kregular overlay "
+            "has no term channel; use topology='full' or 'committee'"
+        )
+    if cfg.queued_links:
+        raise NotImplementedError(
+            "raft_terms is not implemented with queued_links: the "
+            "serial-pipe registers follow one block sender and reset on "
+            "a change of leader they find without terms (models/raft.py "
+            "link_busy)"
+        )
+    if cfg.mesh_axis is not None:
+        raise NotImplementedError(
+            "raft_terms is not implemented under a mesh axis (the node-"
+            "sharded programs of parallel/shard.py): the step to a higher "
+            "term and the oracle reduce over a group's nodes on one device"
+        )
+    if cfg.topology == "committee":
+        return  # the constants below are a group's: asked again on its config
+    _, rt_hi = cfg.roundtrip_range()
+    horizon = rt_hi - 1 + cfg.serialization_ticks(cfg.raft_block_bytes)
+    if min(cfg.raft_election_lo_ms, cfg.raft_proposal_delay_ms) <= horizon:
+        raise ValueError(
+            f"raft_terms: a reply lands up to {horizon} ticks after its "
+            f"request, and raft_election_lo_ms={cfg.raft_election_lo_ms} / "
+            f"raft_proposal_delay_ms={cfg.raft_proposal_delay_ms} must exceed "
+            "that, or a count of an older term could reach a node that "
+            "counts again (the reply channels carry no term)"
+        )
+    if cfg.sim_ms // cfg.raft_election_lo_ms * cfg.n * (cfg.n + 1) >= 2**31:
+        raise ValueError(
+            "raft_terms: hb_prop encodes term * (n + 1) + leader + 1, and "
+            "sim_ms / raft_election_lo_ms * n terms of it overflow int32"
+        )
+
+
 def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
          exchange=None):
     n = cfg.n
     axis = cfg.mesh_axis
+    terms = cfg.raft_terms
+    if terms:
+        check_terms(cfg)
     lo, hi = cfg.one_way_range()
     rt_lo, rt_hi = cfg.roundtrip_range()
     drop = cfg.faults.drop_prob
@@ -349,9 +485,64 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             plain_t, seen_hb, hb_fwd = _decode(plain_t, seen_hb)
             prop_t, seen_prop, prop_fwd = _decode(prop_t, seen_prop)
 
+    if terms:
+        with jax.named_scope("raft.tick.term"):
+            # ---- the highest term this tick's arrivals carry: a node behind
+            # it takes it and is its follower BEFORE any of them is handled
+            # (arrivals at a crashed node were masked above)
+            t_in = jnp.maximum(
+                jnp.maximum(plain_t, prop_t // (n + 1)),
+                jnp.maximum(vreq_t.max(axis=1), no_t))
+            step_up = t_in > state.term
+            deposed = step_up & state.is_leader
+
+            def rearm_on(channel):
+                """A fresh election deadline a node, on a stream of its own."""
+                return t + jax.random.randint(
+                    chan_key(tkey, channel), (n_loc,),
+                    cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
+                    dtype=jnp.int32)
+
+            state = state.replace(
+                term=jnp.maximum(state.term, t_in),
+                step_downs=state.step_downs
+                + (step_up & (state.is_leader | state.is_cand)),
+                is_leader=state.is_leader & ~step_up,
+                is_cand=state.is_cand & ~step_up,
+                # the vote of the new term is free; counts start over
+                has_voted=state.has_voted & ~step_up,
+                vote_success=jnp.where(step_up, 0, state.vote_success),
+                vote_failed=jnp.where(step_up, 0, state.vote_failed),
+                # a deposed leader: heartbeat and proposal schedule off, the
+                # open ack window abandoned (in-flight acks keep landing and
+                # must not commit for a later leadership), a follower's
+                # election timer armed
+                next_hb=jnp.where(deposed, DISARM, state.next_hb),
+                proposal_tick=jnp.where(deposed, DISARM, state.proposal_tick),
+                add_change_value=state.add_change_value & ~deposed,
+                hb_succ=jnp.where(deposed, 0, state.hb_succ),
+                hb_cnt=jnp.where(deposed, 0, state.hb_cnt),
+                hb_open=state.hb_open & ~deposed,
+                election_deadline=jnp.where(
+                    deposed, rearm_on(Channel.ELECTION + 200),
+                    state.election_deadline),
+            )
+            term, is_cand = state.term, state.is_cand
+            step_downs = state.step_downs
+
     with jax.named_scope("raft.tick.heartbeat_rx"):
         # ---- heartbeat arrivals (follower side, raft-node.cc:170-193) -----------
-        got_hb = (plain_t > 0) | (prop_t > 0)
+        if terms:
+            # a heartbeat of an older term is ignored; one of this term makes
+            # a candidate of it a follower (a leader never hears one)
+            prop_ok = (prop_t > 0) & (prop_t // (n + 1) == term)
+            got_hb = (((plain_t > 0) & (plain_t == term)) | prop_ok) \
+                & ~state.is_leader
+            step_downs = step_downs + (got_hb & is_cand)
+            is_cand = is_cand & ~got_hb
+            prop_t = jnp.where(prop_ok, prop_t % (n + 1), 0)  # leader + 1
+        else:
+            got_hb = (plain_t > 0) | (prop_t > 0)
         if gossip:
             # proposal value = the leader id riding the flood encoding
             m_value = jnp.where(prop_t > 0, (prop_t - 1) % (n + 1), state.m_value)
@@ -495,16 +686,28 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             # order is undefined, so we fix a deterministic choice).
             has_req = vreq_t > 0
             any_req = has_req.any(axis=1)
-            first = jnp.argmax(has_req, axis=1)  # lowest j with a request
+            # with terms a request holds its candidate's term, and only one
+            # of the receiver's own term (no arrival is ahead of it any
+            # more) can be granted; an older one is denied
+            grantable = has_req & (vreq_t == term[:, None]) if terms else has_req
+            any_grantable = grantable.any(axis=1) if terms else any_req
+            first = jnp.argmax(grantable, axis=1)  # lowest j with a request
             grant_mask = (
                 jax.nn.one_hot(first, vreq_t.shape[1], dtype=jnp.int32)
-                * (any_req & can_grant).astype(jnp.int32)[:, None]
+                * (any_grantable & can_grant).astype(jnp.int32)[:, None]
             )
             deny_mask = has_req.astype(jnp.int32) - grant_mask
-            has_voted = state.has_voted | (any_req & can_grant)
+            has_voted = state.has_voted | (any_grantable & can_grant)
             hn = state.honest.astype(jnp.int32)[:, None]
             ok_wire = grant_mask * hn + deny_mask * (1 - hn)
             no_wire = deny_mask * hn + grant_mask * (1 - hn)
+            if terms:
+                # a grant re-arms the election timer (Figure 2, Followers);
+                # a denial carries the denier's term
+                election_deadline = jnp.where(
+                    any_grantable & can_grant,
+                    rearm_on(Channel.ELECTION + 300), election_deadline)
+                no_wire = no_wire * term[:, None]
             k_vr = chan_key(tkey, Channel.DELAY_REPLY)
             if kreg:
                 # slot-indexed wires route back requester-side through the
@@ -518,27 +721,43 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 def _unicast(kk, wire):
                     return dv.unicast_reply_counts_dense(
                         kk, wire, lo, hi, drop, axis=axis, impl=eimpl)
+            if terms:
+                def _unicast_no(kk, wire):
+                    return dv.unicast_reply_value_max_dense(
+                        kk, wire, lo, hi, drop, impl=eimpl)
+            else:
+                _unicast_no = _unicast
             vres_ok, vres_no = gated_push(
                 any_req.any(),
                 lambda: jnp.stack([
                     _unicast(jax.random.fold_in(k_vr, 7), ok_wire),
-                    _unicast(jax.random.fold_in(k_vr, 8), no_wire),
+                    _unicast_no(jax.random.fold_in(k_vr, 8), no_wire),
                 ]),
                 jnp.zeros((2, hi - lo, n_loc), jnp.int32),
                 (vres_ok, vres_no),
                 lambda rings, both: (
                     ring_push_add(rings[0], t, lo, both[0]),
-                    ring_push_add(rings[1], t, lo, both[1]),
+                    (ring_push_max if terms else ring_push_add)(
+                        rings[1], t, lo, both[1]),
                 ),
                 axis,
             )
 
     with jax.named_scope("raft.tick.vote_reply_rx"):
         # ---- vote responses (candidate side, raft-node.cc:196-232) --------------
-        vs = state.vote_success + ok_t * (~state.is_leader)
-        vf = state.vote_failed + no_t * (~state.is_leader)
-        win = ~state.is_leader & (ok_t > 0) & (vs + 1 >= cfg.majority_need) & state.alive
-        lose = ~win & (no_t > 0) & (vf >= cfg.raft_lose_need) & ~state.is_leader
+        if terms:
+            # a grant counts at the candidate of the term it was asked in
+            # (module docstring "Terms": none of another term can land here);
+            # a majority of denials changes nothing until the timer fires
+            vs = state.vote_success + ok_t * is_cand
+            vf = state.vote_failed
+            win = is_cand & (ok_t > 0) & (vs + 1 >= cfg.majority_need) & state.alive
+            lose = jnp.zeros((n_loc,), bool)
+        else:
+            vs = state.vote_success + ok_t * (~state.is_leader)
+            vf = state.vote_failed + no_t * (~state.is_leader)
+            win = ~state.is_leader & (ok_t > 0) & (vs + 1 >= cfg.majority_need) & state.alive
+            lose = ~win & (no_t > 0) & (vf >= cfg.raft_lose_need) & ~state.is_leader
         vote_success = jnp.where(win | lose, 0, vs)
         vote_failed = jnp.where(win | lose, 0, vf)
         # winner: cancel own timer, first heartbeat NOW, proposals in +1 s
@@ -592,6 +811,20 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         hb_succ_in = jnp.where(resign, 0, state.hb_succ)
         hb_cnt_in = jnp.where(resign, 0, state.hb_cnt)
         hb_open_in = state.hb_open & ~resign
+
+    if terms:
+        with jax.named_scope("raft.tick.term"):
+            # ---- the winner's records, and the oracle: a node that becomes
+            # leader of a term in which another node is leader
+            is_cand = is_cand & ~win
+            lead_term0 = jnp.where(win & (state.lead_term0 == 0), term,
+                                   state.lead_term0)
+            won_tick = jnp.where(win, jnp.int32(t), state.won_tick)
+            same_term_leaders = (
+                (term[:, None] == term[None, :]) & is_leader[None, :]
+            ).sum(axis=1)
+            term_conflicts = state.term_conflicts + (
+                win & (same_term_leaders > 1))
 
     with jax.named_scope("raft.tick.ack_rx"):
         # ---- proposal acks (leader side, raft-node.cc:234-251) ------------------
@@ -647,7 +880,12 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             & state.alive
         )
         has_voted = has_voted | fire  # self-vote latch
-        if gossip:
+        if terms:
+            # the next term, whose candidate this node is; its one vote of
+            # that term is the self-vote above
+            term = term + fire
+            is_cand = is_cand | fire
+        if gossip or terms:
             # fresh election: restart the reply count (stale replies from the
             # previous election drained long ago — reply horizon << timeout)
             vote_success = jnp.where(fire, 0, vote_success)
@@ -715,8 +953,10 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             vreq = gated_push(
                 fire.any(),
                 lambda: dv.bcast_matrix_dense(
-                    k_vq, fire, fire.astype(jnp.int32), lo, hi, drop, axis=axis,
-                    impl=eimpl),
+                    k_vq, fire,
+                    # VOTE_REQ(term, id): the request's value is its term
+                    term * fire if terms else fire.astype(jnp.int32),
+                    lo, hi, drop, axis=axis, impl=eimpl),
                 jnp.zeros((hi - lo, n_loc, n), jnp.int32),
                 vreq,
                 push_vreq,
@@ -743,6 +983,9 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         # documented gossip divergence.
         plain_send = hb_fire if gossip else (hb_fire & ~add_change_value)
         next_hb = jnp.where(hb_fire, next_hb + cfg.raft_heartbeat_ms, next_hb)
+        if terms:
+            last_hb = jnp.where(hb_fire & (term == lead_term0), jnp.int32(t),
+                                state.last_hb)
         # SendTX: round++; at round==50 stop adding proposals (raft-node.cc:361-365)
         round_ = state.round + prop_send
         add_change_value = add_change_value & ~(
@@ -756,8 +999,9 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         k_hb = chan_key(tkey, Channel.DELAY_BCAST2)
 
         def push_plain(buf, contrib):
-            # the gossip flood carries a value, the direct arms a count
-            push = ring_push_max if gossip else ring_push_add
+            # the gossip flood carries a value, and so does a heartbeat
+            # with terms (its term); the other direct arms a count
+            push = ring_push_max if gossip or terms else ring_push_add
             return push(buf, t, lo, contrib)
 
         def push_prop(buf, contrib):
@@ -910,8 +1154,12 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         else:
             hb_plain = gated_push(
                 plain_send.any(),
-                lambda: dv.bcast_counts_dense(k_hb, plain_send, lo, hi, drop,
-                                              axis=axis, impl=eimpl),
+                # HEARTBEAT(term) with terms, an arrival count without
+                lambda: dv.bcast_value_max_dense(
+                    k_hb, plain_send, term * plain_send, lo, hi, drop,
+                    impl=eimpl) if terms else
+                dv.bcast_counts_dense(k_hb, plain_send, lo, hi, drop,
+                                      axis=axis, impl=eimpl),
                 zeros_flat,
                 hb_plain,
                 push_plain,
@@ -921,7 +1169,9 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 prop_send.any(),
                 lambda: dv.bcast_value_max_dense(
                     jax.random.fold_in(k_hb, 1), prop_send,
-                    (ids + 1) * prop_send.astype(jnp.int32), lo, hi, drop,
+                    # with terms: term * (n + 1) + leader + 1
+                    (term * (n + 1) + ids + 1 if terms else ids + 1)
+                    * prop_send.astype(jnp.int32), lo, hi, drop,
                     axis=axis, impl=eimpl),
                 zeros_flat,
                 hb_prop,
@@ -1050,6 +1300,12 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
         my_base=my_base,
         link_busy=link_busy,
     )
+    if terms:
+        state = state.replace(
+            term=term, is_cand=is_cand, lead_term0=lead_term0,
+            won_tick=won_tick, last_hb=last_hb, step_downs=step_downs,
+            term_conflicts=term_conflicts,
+        )
     bufs = RaftBufs(
         vreq=vreq, vres_ok=vres_ok, vres_no=vres_no, hb_plain=hb_plain,
         hb_prop=hb_prop, hb_ok=hb_ok, hb_bad=hb_bad,
@@ -1065,6 +1321,9 @@ def metrics(cfg, state: RaftState) -> dict:
     """The reference's measurement surface (SURVEY.md §5): leader-elected time
     (raft-node.cc:212), per-block processed time (:246), final Blocks/Rounds
     summary (:122-123), election starts (:399)."""
+    if state.term is not None:  # with terms: a stack of one group
+        return metrics_stacked(cfg, {
+            f: np.asarray(getattr(state, f))[None] for f in METRIC_FIELDS}, 1)[0]
     alive = np.asarray(state.alive)
     is_leader = np.asarray(state.is_leader)
     leader_tick = np.asarray(state.leader_tick)
@@ -1072,8 +1331,11 @@ def metrics(cfg, state: RaftState) -> dict:
     block_tick = np.asarray(state.block_tick)
     m_value = np.asarray(state.m_value)
     leaders = np.flatnonzero(is_leader & alive)
-    # under Byzantine double-voting a split brain is possible (no terms);
-    # report the earliest-elected leader as "the" leader
+    # without terms (the default) a split brain is possible: under Byzantine
+    # double-voting, and in any small group once the first leader stops at
+    # 50 blocks and the second election deposes nobody (KNOWN_ISSUES #0r;
+    # ``cfg.raft_terms`` is the repair); report the earliest-elected leader
+    # as "the" leader
     lead = int(leaders[np.argmin(leader_tick[leaders])]) if leaders.size else -1
     blocks = int(block_num[lead]) if lead >= 0 else 0
     bt = block_tick[lead][: blocks] if lead >= 0 else np.array([])
@@ -1100,8 +1362,100 @@ def metrics(cfg, state: RaftState) -> dict:
 
 
 # the state fields :func:`metrics` reads, and the only ones (see
-# pbft.METRIC_FIELDS; parallel/sweep._readback fetches these leaves alone)
+# pbft.METRIC_FIELDS; parallel/sweep._readback fetches these leaves alone;
+# the leaves of terms are fetched where a state has them)
 METRIC_FIELDS = (
     "alive", "block_num", "block_tick", "elections", "is_leader",
     "leader_tick", "m_value", "round",
+    "term", "lead_term0", "won_tick", "last_hb", "step_downs",
+    "term_conflicts",
 )
+
+
+def metrics_stacked(cfg, host: dict, groups: int) -> list:
+    """:func:`metrics` of ``groups`` stacked groups that ran with terms
+    (``cfg.raft_terms``), at once: the list of their dicts from their
+    fetched leaves ``host[field]`` of shape ``[groups, N, ...]`` (numpy;
+    topo/committee.metrics' one readback), computed as array reductions
+    over the node axis.  The one implementation: a lone group's
+    :func:`metrics` is a stack of one.  20,000 groups of 5 take a tenth of a
+    second this way where a dict a call took 2.8 s of every run's host time
+    (PERF.md section 6, PR 42).
+
+    "The" leader is the leader of the highest term; ``blocks``, ``rounds``,
+    ``last_block_ms`` and ``mean_block_interval_ms`` are over every block
+    committed in the group, whichever node led (a node counts what it
+    committed while it led): after the first leader's stop at 50 blocks the
+    group fails over, and the leader at the end of a run is as a rule not
+    the one that committed them.  Beyond the keys of a run without terms:
+    ``term_final`` (the group's highest term), ``leader_term`` (0 where no
+    node leads), ``n_leaders_term_final``, ``term_conflicts`` and
+    ``step_downs`` (summed), ``first_leader_ms`` / ``first_leader_term`` /
+    ``first_leader_blocks`` (the node that won first), and ``failover_ms``:
+    from the first leader's last heartbeat of the term it first led to the
+    next election anyone wins (-1.0 where none did).  ``agreement_ok``:
+    there is no log to compare, so every value an alive node stored names a
+    node that won an election and proposed, and no term had two leaders."""
+    never = np.int64(1) << 40
+    alive, term = host["alive"], host["term"]
+    leader_tick, won_tick = host["leader_tick"], host["won_tick"]
+    block_num, rounds = host["block_num"], host["round"]
+    m_value = host["m_value"]
+    at = lambda a, i: np.take_along_axis(a, i[:, None], axis=1)[:, 0]  # noqa: E731
+    conflicts = host["term_conflicts"].sum(axis=1)
+    leaders = host["is_leader"] & alive
+    n_leaders = leaders.sum(axis=1)
+    lead = np.where(n_leaders > 0,
+                    np.argmax(np.where(leaders, term, -1), axis=1), -1)
+    lead_at = np.maximum(lead, 0)
+    term_final = term.max(axis=1)
+    led = leader_tick >= 0
+    has_first = led.any(axis=1)
+    first = np.argmin(np.where(led, leader_tick, never), axis=1)
+    others = led & (np.arange(term.shape[1])[None, :] != first[:, None])
+    first_tick, again = at(leader_tick, first), at(won_tick, first)
+    later = np.minimum(
+        np.where(others, leader_tick, never).min(axis=1),
+        np.where(again > first_tick, again, never))
+    last_hb = at(host["last_hb"], first)
+    failover = np.where(has_first & (later < never) & (last_hb >= 0),
+                        later - last_hb, -1).astype(np.float64)
+    committed = (np.arange(host["block_tick"].shape[2])[None, None, :]
+                 < block_num[:, :, None])
+    blocks = block_num.sum(axis=1)
+    bt_last = np.where(committed, host["block_tick"], -1).max(axis=(1, 2))
+    bt_first = np.where(committed, host["block_tick"], never).min(axis=(1, 2))
+    stored = alive & (m_value >= 0)
+    named = np.maximum(m_value, 0)
+    unfounded = stored & ~(
+        (np.take_along_axis(leader_tick, named, axis=1) >= 0)
+        & (np.take_along_axis(rounds, named, axis=1) > 0))
+    columns = {
+        "n_leaders": n_leaders,
+        "leader": lead,
+        "leader_elected_ms": np.where(
+            lead >= 0, at(won_tick, lead_at), -1).astype(np.float64),
+        "blocks": blocks,
+        "rounds": rounds.sum(axis=1),
+        "elections": host["elections"].sum(axis=1),
+        "last_block_ms": np.where(blocks > 0, bt_last, -1).astype(np.float64),
+        "mean_block_interval_ms": np.where(
+            blocks > 1, (bt_last - bt_first) / np.maximum(blocks - 1, 1), -1.0),
+        "agreement_ok": (conflicts == 0) & ~unfounded.any(axis=1),
+        "term_final": term_final,
+        "leader_term": np.where(lead >= 0, at(term, lead_at), 0),
+        "n_leaders_term_final": (
+            leaders & (term == term_final[:, None])).sum(axis=1),
+        "term_conflicts": conflicts,
+        "step_downs": host["step_downs"].sum(axis=1),
+        "first_leader_ms": np.where(
+            has_first, first_tick, -1).astype(np.float64),
+        "first_leader_term": np.where(
+            has_first, at(host["lead_term0"], first), 0),
+        "first_leader_blocks": np.where(has_first, at(block_num, first), 0),
+        "failover_ms": failover,
+    }
+    keys = ("protocol", "n") + tuple(columns)
+    return [dict(zip(keys, ("raft", cfg.n) + row))
+            for row in zip(*(v[:groups].tolist() for v in columns.values()))]
+

@@ -12,7 +12,10 @@ plain dict pytree:
 - ``probes["monitors"]`` — on-device invariant monitors evaluated on the
   FINAL state (int32 scalars; ``[C]`` per committee): ``viol_agreement``
   (safety: conflicting/forged/unattributed commits among correct nodes),
-  ``viol_quorum`` (quorum-certificate consistency), and ``liveness_lag``
+  ``viol_quorum`` (quorum-certificate consistency; for Raft with terms,
+  ``SimConfig.raft_terms``, the two read the leaves of terms instead:
+  the program's oracle ``RaftState.term_conflicts``, and alive honest
+  leaders that share a ``term``), and ``liveness_lag``
   (samples since the protocol's progress counter last advanced; the
   sample axis is ticks on the tick engines, rounds/heartbeats on the
   fast paths — ``summarize`` records the unit).

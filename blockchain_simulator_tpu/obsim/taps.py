@@ -145,6 +145,17 @@ def monitors(cfg, state) -> dict:
              & (state.slot_commit_tick < state.slot_propose_tick)).sum()
         )
         return {"viol_agreement": viol_agree, "viol_quorum": viol_quorum}
+    if p == "raft" and state.term is not None:
+        # with terms (cfg.raft_terms) the leader at the end is as a rule
+        # not the node whose id the followers stored, and an old leader may
+        # stand beside a newer term's for the ticks a message takes; what
+        # must hold is election safety: the program's own oracle, and no
+        # two alive honest leaders of one term
+        lead = state.is_leader & state.alive & state.honest
+        same = ((state.term[:, None] == state.term[None, :])
+                & lead[:, None] & lead[None, :]).sum() - lead.sum()
+        return {"viol_agreement": _i32(state.term_conflicts.sum()),
+                "viol_quorum": _i32(same // 2)}
     if p == "raft":
         cand = state.is_leader & state.alive
         lt = jnp.where(cand, state.leader_tick, _I32_NEVER)

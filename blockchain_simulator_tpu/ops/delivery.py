@@ -588,5 +588,28 @@ def gossip_fwd(key, fwd_vals, nbrs_loc, n_glob, lo, hi, drop_prob=0.0, axis=None
                            tiers, bits, dense)
 
 
+@_scoped
+def unicast_reply_value_max_dense(key, reply, lo, hi, drop_prob=0.0,
+                                  impl="threefry"):
+    """:func:`unicast_reply_counts_dense` for replies that carry a VALUE the
+    requester max-combines (Raft with terms: a denied vote's reply carries
+    the replier's term, and the candidate needs the highest it hears).
+    ``reply[r, c]`` = the value (> 0; 0 = no reply) node r sends node c
+    this tick, one delay drawn per edge.  Returns [B, N] indexed by
+    requester, 0 where nothing lands.  Unsharded only: no program with terms
+    runs under a mesh axis (models/raft.check_terms)."""
+    n = reply.shape[0]
+    d = sample_edge_delays(key, (n, n), lo, hi, impl)
+    r = reply.astype(jnp.int32) * (1 - jnp.eye(n, dtype=jnp.int32))
+    if drop_prob > 0.0:
+        keep = jax.random.bernoulli(
+            jax.random.fold_in(key, 0x0D0F), 1.0 - drop_prob, (n, n)
+        )
+        r = r * keep.astype(jnp.int32)
+    return (
+        r[None] * (d[None] == _bucket_iota(lo, hi, d.ndim)).astype(jnp.int32)
+    ).max(1)  # [B, N]
+
+
 # every scope above, by name (ops/scopes.py)
 SCOPES = tuple(_names)

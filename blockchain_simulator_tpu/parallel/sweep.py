@@ -508,9 +508,17 @@ _TEMP_FACTOR = 2.0
 
 @functools.lru_cache(maxsize=64)
 def _lane_state_bytes(canon: SimConfig) -> int:
-    """Bytes of state one lane of ``make_dyn_sim_fn(canon)`` carries through
-    its scan: ``eval_shape`` of the ``init`` that program calls (nothing is
-    allocated).  A committee stack counts as all its committees at once,
+    """LOGICAL bytes of state one lane of ``make_dyn_sim_fn(canon)`` carries
+    through its scan: ``eval_shape`` of the ``init`` that program calls
+    (nothing is allocated), elements times item size, with no account of how
+    the device tiles them.  At the lanes the rule was set from (``[D, N, W]``
+    rings of 500 to 100,000 nodes) the two agree; a lane of narrow leaves (a
+    Raft group of 5: 4,010 bytes, every minor dimension 5 or 50) would pad
+    29-fold under an (8, 128) tile with the lane axis leading, but XLA:TPU
+    lays such a batch out lane-minor by itself and 20,000 of them reserve 153
+    MB for 83 MB logical (PERF.md section 6, PR 42), well inside the
+    ``_TEMP_FACTOR`` of :func:`_device_tile`.  A committee stack counts as
+    all its committees at once,
     which is the most a lane can hold (topo/committee.py runs them in tiles
     cut by the same rule, :func:`_device_tile` with ``outer`` lanes)."""
     if canon.topology == "committee":

@@ -131,6 +131,12 @@ def _reject_cpp_only(cfg: SimConfig) -> None:
             "engines fast; the C++ engine covers the quirk and "
             "tests/test_fidelity.py pins the traffic delta"
         )
+    if cfg.raft_terms:
+        # what has no terms refuses them by its name (models/raft.check_terms
+        # lists the arms), before anything is built
+        from blockchain_simulator_tpu.models import raft
+
+        raft.check_terms(cfg)
     if cfg.queued_links:
         # pbft: per-destination serial-pipe registers (models/pbft.py).
         # paxos: every message is 3-4 bytes (ser = 0), the pipe is never
@@ -425,13 +431,25 @@ def run_simulation(cfg: SimConfig, seed: int | None = None, with_timing: bool = 
         from blockchain_simulator_tpu.utils import obs
 
         final, compile_s, wall = obs.timed_run(sim, key)
-        m = base_model.sim_metrics(cfg, final)
+        m = _metrics(cfg, final)
         m["wallclock_s"] = wall
         m["compile_plus_first_run_s"] = round(compile_s, 3)
         m["ticks"] = cfg.ticks
         return m
     final = jax.block_until_ready(sim(key))
-    return base_model.sim_metrics(cfg, final)
+    return _metrics(cfg, final)
+
+
+def _metrics(cfg: SimConfig, final) -> dict:
+    """``sim_metrics`` of one run; a flat Raft run with terms counts its one
+    group (utils/telemetry.count_raft_groups; a stack of groups is counted
+    by topo/committee.metrics, where its per-group dicts are)."""
+    m = base_model.sim_metrics(cfg, final)
+    if cfg.raft_terms and cfg.topology != "committee":
+        from blockchain_simulator_tpu.utils import telemetry
+
+        telemetry.count_raft_groups([m])
+    return m
 
 
 def final_state(cfg: SimConfig, seed: int | None = None):

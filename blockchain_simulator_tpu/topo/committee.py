@@ -62,7 +62,8 @@ Names in a profiler trace (``SCOPES``, ``SPANS``, ``COUNTERS``): scope
 (per-committee metrics and the outer aggregate); counters
 ``committee.tiles`` and ``committee.tile_lanes`` (tiles run, and lanes run
 in them, padding included, by the lone stacks whose metrics were read: their
-quotient is T).  Both, and the readback span's ``tiles`` / ``tile_lanes``,
+quotient is T; a stack of Raft groups with terms also counts them,
+``telemetry.RAFT_COUNTERS``).  Both, and the readback span's ``tiles`` / ``tile_lanes``,
 are the plan :func:`stacked_body` took where that stack was traced
 (:func:`ran_as`), never the rule asked again.
 """
@@ -252,17 +253,16 @@ def milestone_ms(protocol: str, inner_metrics: dict) -> float:
         else -1.0
 
 
-def _host_rows(cfg, finals) -> list:
-    """The stacked finals as C host states, from ONE fetch: the leaves the
+def _host_leaves(cfg, finals) -> dict:
+    """The stacked finals' metric leaves on the host, from ONE fetch: the leaves the
     inner protocol's ``metrics`` reads (its ``METRIC_FIELDS``; every field
     where a module declares none) cross the host link in one
-    ``jax.device_get``, each copy started before the first is awaited, and
-    committee ``i`` is the finals' own state type with numpy views in those
-    fields and None elsewhere (parallel/sweep._readback's way; a sweep's row
-    arrives here as host arrays already, fetched under ``sweep.readback``,
-    and is only sliced).
-    A slice per leaf and committee on the device, with ``metrics`` blocking
-    on every read, is (leaves + fields) x C round trips for the same bytes."""
+    ``jax.device_get``, each copy started before the first is awaited, as
+    a dict by field of ``[C, ...]`` numpy arrays (parallel/sweep._readback's
+    way; a sweep's row arrives here as host arrays already, fetched under
+    ``sweep.readback``).  A slice per leaf and committee on the device, with
+    ``metrics`` blocking on every read, is (leaves + fields) x C round trips
+    for the same bytes."""
     picked = base_model.metric_leaves(cfg, finals)
     leaves = jax.tree.leaves(picked)
     host = picked
@@ -286,12 +286,12 @@ def _host_rows(cfg, finals) -> list:
             telemetry.metrics.counter(COUNTERS[0]).inc(plan["tiles"])
             telemetry.metrics.counter(COUNTERS[1]).inc(
                 plan["tiles"] * plan["lanes"])
-    return base_model.host_rows(finals, host, cfg.committees)
+    return host
 
 
 def metrics(cfg, finals) -> dict:
     """Host-side metrics of a stacked committee final state, from one
-    readback (:func:`_host_rows`).
+    readback (:func:`_host_leaves`).
 
     C = 1: the flat protocol's full metrics dict (bit-equal to the flat
     run — the tests' contract) plus the ``outer_*`` keys.  C > 1: the
@@ -306,9 +306,16 @@ def metrics(cfg, finals) -> dict:
     proto = base_model.get_protocol(cfg.protocol)
     c = cfg.committees
     icfg = inner_cfg(cfg)
-    rows = _host_rows(cfg, finals)
+    host = _host_leaves(cfg, finals)
+    # committee i as a host state: the finals' own state type with numpy
+    # views in the fetched fields and None elsewhere.  Raft groups with terms
+    # are read at once from the stacked leaves instead (20,000 groups:
+    # models/raft.metrics_stacked)
+    rows = None if icfg.raft_terms else base_model.host_rows(finals, host, c)
     with telemetry.span("topo.committee.outer", committees=c):
-        inner = [proto.metrics(icfg, row) for row in rows]
+        inner = (proto.metrics_stacked(icfg, host, c) if rows is None
+                 else [proto.metrics(icfg, row) for row in rows])
+        telemetry.count_raft_groups(inner)
         miles = [milestone_ms(cfg.protocol, m) for m in inner]
         decided = sorted(t for t in miles if t >= 0)
         quorum = c // 2 + 1

@@ -221,6 +221,15 @@ class SimConfig:
     raft_max_rounds: int = 50  # stop proposals at round==50 (raft-node.cc:361)
     raft_tx_size: int = 200  # 200 B per tx (raft-node.cc:23)
     raft_tx_speed: int = 2000  # 2000 tx/s (raft-node.cc:24)
+    # Raft WITH terms (Ongaro & Ousterhout, Figure 2, on upstream's message
+    # set): a term a node carried by every message, one vote a TERM (not one
+    # a run, quirk #6), a candidate or leader that sees a higher term steps
+    # down, a grant re-arms the election timer (models/raft.py "Terms").
+    # A protocol choice a deployment states, as ``quorum_rule`` is.  Needs
+    # fidelity="clean"; implemented for delivery="edge" on the full mesh
+    # (flat, and inside topology="committee"); every other arm refuses it
+    # by name (models/raft.check_terms).
+    raft_terms: bool = False
 
     # --- Paxos (paxos-node.cc) -----------------------------------------------
     paxos_delay_lo: int = 0  # random send delay U[0,50) ms
@@ -283,6 +292,21 @@ class SimConfig:
                 "relies on the clean latches (each node votes once per slot); "
                 "the reference's reset-on-threshold counters re-count"
             )
+        if self.raft_terms:
+            if self.protocol != "raft":
+                raise ValueError(
+                    "raft_terms is standalone Raft's (protocol='raft'); "
+                    f"protocol {self.protocol!r} does not implement terms"
+                    + (" (the mixed shard sim runs the stat arm of "
+                       "models/raft.py, which has none)"
+                       if self.protocol == "mixed" else "")
+                )
+            if self.fidelity != "clean":
+                raise ValueError(
+                    "raft_terms requires fidelity='clean': reference "
+                    "fidelity is upstream's Raft, which has no terms "
+                    "(quirk #6) and never re-arms an election timer"
+                )
         if self.faults.byz_forge:
             if self.protocol != "pbft":
                 raise ValueError(

@@ -692,3 +692,27 @@ def install_crash_dump() -> None:
             prev_t(args)
 
     _threading.excepthook = thread_hook
+
+
+# ---- Raft with terms (SimConfig.raft_terms): what the groups of a run went
+# through, as counters, made where ``committee.*`` are: host side, where the
+# metrics dicts are computed (topo/committee.metrics for a stack of groups,
+# runner.run_simulation for a flat run; models/ stays free of this module)
+RAFT_COUNTERS = ("raft.groups", "raft.term_bumps", "raft.step_downs",
+                 "raft.term_conflicts")
+
+
+def count_raft_groups(groups) -> None:
+    """Add the Raft metrics dicts ``groups`` (one a group) that ran with
+    terms to :data:`RAFT_COUNTERS`: groups read, and over them the sums of
+    ``term_final``, ``step_downs`` and ``term_conflicts``.  A dict without
+    terms counts nothing."""
+    with_terms = [g for g in groups if "term_final" in g]
+    if not with_terms:
+        return
+    for name, by in zip(RAFT_COUNTERS, (
+            len(with_terms),
+            sum(g["term_final"] for g in with_terms),
+            sum(g["step_downs"] for g in with_terms),
+            sum(g["term_conflicts"] for g in with_terms))):
+        metrics.counter(name).inc(by)

@@ -618,6 +618,12 @@ def lowered_programs():
             for c in (cfgs[-1], cfgs[-2])]
     from blockchain_simulator_tpu.utils.config import FaultConfig
 
+    # Raft with terms (only a ``raft_terms`` program holds ``raft.tick.term``
+    # and the denial's value-max unicast)
+    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
+        protocol="raft", n=8, sim_ms=200, raft_terms=True,
+        model_serialization=False))).lower(
+            jax.random.key(0)).as_text(debug_info=True))
     # the forging attack (only a ``byz_forge`` program holds its scope),
     # second to last
     texts.append(jax.jit(runner.make_sim_fn(SimConfig(
@@ -640,6 +646,12 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
     if scope == "pbft.tick.forge":
         assert f"pbft.tick.prepare/{scope}/" in lowered_programs[-2]
         assert not any(f"{scope}/" in t for t in lowered_programs[:-2])
+        return
+    if scope == "raft.tick.term":
+        # what terms add: in the program with terms (third to last) alone
+        assert f"{scope}/" in lowered_programs[-3]
+        assert not any(f"{scope}/" in t for t in
+                       lowered_programs[:-3] + lowered_programs[-2:])
         return
     if scope.startswith("paxos.tick."):
         assert f"{scope}/" in lowered_programs[7]  # the relay, one device

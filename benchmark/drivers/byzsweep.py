@@ -5,7 +5,8 @@ The timed path is ``parallel/sweep.run_byzantine_sweep(cfg, f_values, seeds=
 seed per call, as lanes of the one vmapped dynamic-operand tick program, in
 as many dispatches as the sweep layer's own tile makes of them (it sizes a
 dispatch from the program's state bytes and the memory the device reports),
-per-row readback into metrics dicts included.  A point counts when its dict
+the readback included (one fetch a dispatch, then a metrics dict a row on
+the host).  A point counts when its dict
 is back and sound (``byz_checks.sound``).  After the window every row is
 held to the configuration's guarantees against the plain reference
 ``reference/pbft_byz_engine.py``, level by level, and a seeded sample of

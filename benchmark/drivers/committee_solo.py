@@ -6,9 +6,9 @@ The timed path is the same seam of ``runner.run_simulation``
 (``make_sim_fn(cfg)(key)``, then ``models.base.sim_metrics``, which for a
 committee configuration is ``topo.committee.metrics``).  What differs is
 what a run yields and what it is held to: a unit of work is a round, a block
-final on all honest nodes of every one of the committees, the minimum over
-them of ``blocks_final_all_nodes`` (``committee_checks.rounds``), and the
-checks are ``committee_checks``' —
+final on all honest nodes of a committee, the mean over the committees of
+``blocks_final_all_nodes`` (``committee_checks.rounds``), and the checks are
+``committee_checks``' —
 the configuration's guarantees on every run, the combining rule exactly, and
 the counts and times of the plain reference
 ``reference/committee_engine.py`` at the committees' own size.
@@ -39,6 +39,7 @@ import time
 
 import committee_checks
 import program
+import readers
 
 _spec = importlib.util.spec_from_file_location(
     "bench_drivers_solo",
@@ -116,18 +117,19 @@ class Driver(solo.Driver):
         now = self._counters()
         out["counters"] = {k: now[k] - self.counters0.get(k, 0.0) for k in now}
         rows = [s["row"] for s in out["samples"]]
-        units = [s["units"] for s in out["samples"]]
+        mins = [min(m["per_committee"]["blocks_final_all_nodes"])
+                for m in rows]
         out["notes"].update(
-            # how a run's rounds spread: the minimum over all committees
-            # moves with the view changes the run's seed drew
-            units_histogram=" ".join(
-                f"{u}:{units.count(u)}" for u in sorted(set(units))),
+            # a run's unit is the mean over its committees; its minimum over
+            # them, the hierarchy's floor, moves with the view changes the
+            # run's seed drew and is reported beside it
+            units_histogram=readers.histogram(
+                [round(s["units"], 3) for s in out["samples"]]),
+            run_min_histogram=readers.histogram(mins),
             committees_with_view_change=sum(
                 1 for m in rows for c in committee_checks.committees_of(m)
                 if c["view_changes"]),
-            blocks_final_min_any_committee=min(
-                (c["blocks_final_all_nodes"] for m in rows
-                 for c in committee_checks.committees_of(m)), default=0))
+            blocks_final_min_any_committee=min(mins, default=0))
         return out
 
     def verify(self, window: dict) -> list[dict]:

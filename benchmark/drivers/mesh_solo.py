@@ -160,6 +160,9 @@ class Driver(solo.Driver):
             "retries": sorted(collections.Counter(
                 m["retries"] for m in rows).items()),
         }
+        if self.ctx["traffic"].get("seed_classes", {}).get("by"):
+            notes["seed_class_misses"] = solo.seed_class_misses(
+                self.ctx["traffic"], samples)
         return {"samples": samples, "attempted": len(samples),
                 "failed": sum(1 for s in samples if not s["units"]),
                 "unit": "rounds", "steps_per_dispatch": self.cfg.ticks,

@@ -38,6 +38,7 @@ import os
 import time
 
 import raftgroups_checks
+import readers
 
 
 def _sibling(name: str):
@@ -103,12 +104,7 @@ class Driver(committee_solo.Driver):
         now = self._counters()
         out["counters"] = {k: now[k] - self.counters0.get(k, 0.0) for k in now}
         rows = [s["row"] for s in out["samples"]]
-        pooled = raftgroups_checks.pooled
-
-        def histogram(values):
-            return " ".join(f"{v}:{values.count(v)}"
-                            for v in sorted(set(values)))
-
+        pooled, histogram = raftgroups_checks.pooled, readers.histogram
         out["notes"].update(
             units_histogram=histogram(
                 [round(s["units"], 3) for s in out["samples"]]),

@@ -13,15 +13,59 @@ when the last queued run is read back.
 Why a queue: the machine's host pauses now and then for up to a few seconds
 (PERF.md section 6).  The device works through what is queued meanwhile, so
 a pause shorter than the queue costs the rate nothing.
+
+A run's seed is drawn fresh from ``--seed``, unless the traffic file gives
+``seed_classes`` (``{"classes": {<name>: [run seeds]}, "by": <the key of a
+run's metrics whose value names its class>}``; ``by`` may be left out, as
+where there is one class): run seeds sorted beforehand by how much WORK they
+make (a Paxos run whose proposers retry six times takes 7.6% longer than one
+with three).  The runs then take the classes in turn at the file's own
+ratio, so that every prefix of a window, whatever its length, holds each
+class's share to within one run (``class_order``); inside a class the seeds
+come in an order that ``--seed`` shuffles, round and round.  A window's rate
+then does not move with the share of long runs its seeds happened to draw,
+at any speed of the program and any ``--seconds``.  ``seed_class_misses``
+counts the runs that came out of another class than the file has them in,
+for a window's notes (``mesh_solo``, the one driver whose cell has classes).
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import time
 
 import checks
 import program
+
+
+def class_order(classes: dict, rng):
+    """The run seeds of ``classes`` (``{name: [seeds]}``) without end: each
+    class round and round in an order ``rng`` shuffles, the classes taken in
+    turn so that after any k seeds each class has given its share of k
+    (its size over the sizes' sum) to within one: the next seed is always of
+    the class furthest behind its share (the first by name of those that tie).
+    After as many seeds as there are, every one has come once."""
+    names = sorted(classes)
+    pools = {k: list(classes[k]) for k in names}
+    for k in names:
+        rng.shuffle(pools[k])
+    total = sum(len(v) for v in pools.values())
+    given = dict.fromkeys(names, 0)
+    for k in itertools.count(1):
+        behind = max(names,
+                     key=lambda c: len(pools[c]) * k - given[c] * total)
+        yield pools[behind][given[behind] % len(pools[behind])]
+        given[behind] += 1
+
+
+def seed_class_misses(traffic: dict, samples: list) -> int:
+    """Runs of a window whose metrics put them in another class than the
+    traffic file's ``seed_classes`` has their seed in."""
+    spec = traffic["seed_classes"]
+    of = {s: name for name, seeds in spec["classes"].items() for s in seeds}
+    return sum(1 for s in samples
+               if str(s["row"].get(spec["by"])) != of.get(s["seed"]))
 
 
 class Driver:
@@ -35,8 +79,13 @@ class Driver:
         self.rng = ctx["rng"]
         self.rounds = self.cfg.pbft_max_rounds
         self.in_flight = int(ctx["traffic"].get("in_flight", 1))
+        classes = ctx["traffic"].get("seed_classes")
+        self.seeds = class_order(classes["classes"], self.rng) \
+            if classes else None
 
     def _seed(self) -> int:
+        if self.seeds is not None:
+            return next(self.seeds)
         return self.rng.randrange(2**31 - 1)
 
     def _dispatch(self, seed: int) -> tuple:

@@ -1,8 +1,9 @@
 """Driver ``sweep``: one caller, one Monte Carlo dispatch after another.
 
 The timed path is ``parallel/sweep.run_seed_sweep(cfg, seeds)``: ``lanes``
-fresh seeds per call as one vmapped program, per-row readback into metrics
-dicts included.  A row counts when its dict is back and checked.  After the
+fresh seeds per call as one vmapped program, the readback included (one
+fetch of the leaves the metrics read, for every lane; then a metrics dict a
+row on the host).  A row counts when its dict is back and checked.  After the
 window a seeded sample of the window's own rows is re-run solo
 (``runner.run_simulation``) and must be dict-equal.
 """

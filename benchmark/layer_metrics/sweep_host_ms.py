@@ -1,7 +1,7 @@
 """Sweep layer: per traced dispatch, the wall of the ``run_seed_sweep`` call
-minus the device-busy time inside it — key build, per-row slicing and the
-metrics readback (device trace + the harness's span on the same clock);
-median over the traced dispatches."""
+minus the device-busy time inside it — key build, the one fetch of the rows
+and their metrics dicts (device trace + the harness's span on the same
+clock); median over the traced dispatches."""
 
 import statistics
 

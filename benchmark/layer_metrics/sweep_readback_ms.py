@@ -1,6 +1,7 @@
-"""Sweep layer: median of the program's ``sweep.readback`` span (per-row
-slicing, ``sim_metrics``, ``obs.record_run``) over the traced dispatches
-(program span, on the profiler's clock)."""
+"""Sweep layer: median of the program's ``sweep.readback`` span (one
+``device_get`` of the metrics' leaves for every lane, then per row
+``sim_metrics`` on host views and ``obs.record_run``) over the traced
+dispatches (program span, on the profiler's clock)."""
 
 import program_trace
 

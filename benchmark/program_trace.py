@@ -24,7 +24,11 @@ into the same trace:
   An operation's time is its *self* time (its children's taken out), so the
   tables sum to the device's busy time; *inner* is the last program scope on
   the path (the op that owns the operation), *outer* the first (the engine
-  phase).
+  phase).  Inside the whole runs of the main program the inner table is also
+  kept by HLO instruction (``runs_by_inner_instruction``: events and self
+  seconds), for a reader that counts how often one operation ran
+  (``byz_trace.ring_pop_hbm_pct``: an instruction runs at most once a tick,
+  one inside a gate on the taken ticks only).
 - **spans** — ``utils/telemetry.span`` twins: host events named ``sweep.*``
   and ``serve.*`` on the profiler's clock, with their stats (``span`` =
   ``<trace id>:<span id>``, ``rows``, ``lanes``, ``size``, ``bucket``,
@@ -37,10 +41,12 @@ A trace of a program without scopes or spans (the parent of the PR that
 added them) reduces to empty tables; the readers in ``layer_metrics/`` then
 return nothing.
 
-    python benchmark/program_trace.py <trace dir or .xplane.pb[.gz]>
+    python benchmark/program_trace.py <trace dir or file> [inner scope ...]
 
 prints the whole table for one trace: what ``PERF.md`` section 5 is written
-from.  ``tests/test_program_trace.py`` checks the reduction on
+from (by instruction for the inner scopes named after it; the file is an
+``.xplane.pb`` or ``.xplane.pb.gz``).  ``tests/test_program_trace.py``
+checks the reduction on
 ``fixtures/served_small.xplane.pb.gz``.
 """
 
@@ -127,9 +133,10 @@ def scope_path(op_name: str) -> tuple:
 
 def load(path: str) -> dict:
     """``{"devices": {plane: {"ops": [(scopes, start_ns, end_ns)],
-    "modules": [(name, start_ns, end_ns)]}}, "host": [(name, start_ns,
-    end_ns, thread, stats)]}`` — ``host`` holds the program's spans and the
-    harness's traced window."""
+    "instructions": [name], "modules": [(name, start_ns, end_ns)]}}, "host":
+    [(name, start_ns, end_ns, thread, stats)]}`` — ``instructions`` names the
+    HLO instruction of each entry of ``ops``, index for index; ``host`` holds
+    the program's spans and the harness's traced window."""
     opener = gzip.open if path.endswith(".gz") else open
     with opener(path, "rb") as f:
         space = _xspace_class()()
@@ -156,7 +163,8 @@ def load(path: str) -> dict:
                      for s in e.value.stats}
             meta[e.key] = (_text(e.value.name), stats)
         if is_dev:
-            dev = devices.setdefault(pname, {"ops": [], "modules": []})
+            dev = devices.setdefault(
+                pname, {"ops": [], "instructions": [], "modules": []})
             scopes_of = {k: scope_path(str(st.get("tf_op", "")))
                          for k, (_, st) in meta.items()}
             for line in plane.lines:
@@ -166,6 +174,9 @@ def load(path: str) -> dict:
                         (scopes_of.get(e.metadata_id, ()),
                          t0 + e.offset_ps / 1e3,
                          t0 + (e.offset_ps + e.duration_ps) / 1e3)
+                        for e in line.events)
+                    dev["instructions"].extend(
+                        xplane.op_name(meta.get(e.metadata_id, ("?",))[0])
                         for e in line.events)
                 elif lname == "XLA Modules":
                     dev["modules"].extend(
@@ -246,14 +257,21 @@ def summarize(trace_dir_or_file: str, n_devices: int = 1) -> dict:
     # per-step figures: the first device's whole runs of its main program
     first = raw["devices"][planes[0]]
     main, runs = _main_runs(first["modules"], w0, w1)
-    ordered = sorted(first["ops"], key=lambda e: e[1])
+    names = first.get("instructions") or ["?"] * len(first["ops"])
+    ordered = sorted((((scopes, name), a, b) for (scopes, a, b), name
+                      in zip(first["ops"], names)), key=lambda e: e[1])
     starts = [e[1] for e in ordered]
     run_table: dict = {}
+    run_instr: dict = {}  # inner scope -> instruction -> [events, self s]
     for a, b in runs:
         inside = ordered[bisect.bisect_left(starts, a):
                          bisect.bisect_right(starts, b)]
-        for k, ns in xplane.self_times(inside, a, b).items():
-            run_table[k] = run_table.get(k, 0.0) + ns
+        for (scopes, name), _, _ in inside:
+            by = run_instr.setdefault(scopes[-1] if scopes else UNSCOPED, {})
+            by.setdefault(name, [0, 0.0])[0] += 1
+        for (scopes, name), ns in xplane.self_times(inside, a, b).items():
+            run_table[scopes] = run_table.get(scopes, 0.0) + ns
+            run_instr[scopes[-1] if scopes else UNSCOPED][name][1] += ns / 1e9
     n_runs, run_ns = len(runs), sum(b - a for a, b in runs)
     busy = busies[0]
     busy_ns = sum(b.covered(w0, w1) for b in busies) / len(busies)
@@ -299,6 +317,7 @@ def summarize(trace_dir_or_file: str, n_devices: int = 1) -> dict:
         "scoped_s": scoped, "by_inner_s": inner, "by_outer_s": outer,
         "main_module": main, "main_runs": n_runs, "main_runs_s": run_ns / 1e9,
         "runs_by_inner_s": run_inner, "runs_by_outer_s": run_outer,
+        "runs_by_inner_instruction": run_instr,
         "spans": spans, "batcher": batcher,
     }
 
@@ -375,4 +394,9 @@ if __name__ == "__main__":
     for k in ("by_inner_s", "by_outer_s", "runs_by_inner_s",
               "runs_by_outer_s"):
         s[k] = order(s[k])
+    # by instruction only under the scopes named after the trace, largest first
+    s["runs_by_inner_instruction"] = {
+        k: dict(sorted(v.items(), key=lambda kv: -kv[1][1]))
+        for k, v in s["runs_by_inner_instruction"].items()
+        if k in sys.argv[2:]}
     print(json.dumps(s, indent=1))

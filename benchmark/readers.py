@@ -18,6 +18,12 @@ def completed_per_s(run: dict, unit: str):
         w["samples"][-1]["t1"] - run["t_window"])
 
 
+def histogram(values) -> str:
+    """``value:count`` pairs in the order of the values, for a result's
+    notes."""
+    return " ".join(f"{v}:{values.count(v)}" for v in sorted(set(values)))
+
+
 def nearest_rank(values, q: float):
     """The q-th percentile by nearest rank (no interpolation: a tail is one
     of the requests)."""

@@ -8,6 +8,10 @@ One process: it reaches the chip, builds and warms the cell's programs
 what the window produced, and prints one JSON line last on stdout.  With
 ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, the device's busy time and a breakdown.
+Every number compared stands beside its limit in the line's last key,
+``checks`` (a list of ``[name, value, rule, limit]``), and as the last lines
+of stderr: the two places of which the driver's record of a run that is not
+correct keeps the end.
 
 Everything that belongs to one cell is data found by name from
 ``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<traffic>.json``,
@@ -17,8 +21,8 @@ Everything that belongs to one cell is data found by name from
 Off a ``tpu`` it refuses: exit 2 and no line.  When the caller set
 ``JAX_PLATFORMS`` (a rehearsal) the same path runs at the tiny sizes the
 configuration and traffic files give under ``rehearsal*``, NOTHING is printed
-on stdout, the last stderr line is the result line without any metric value,
-and the exit code is 3.
+on stdout, the result line goes to stderr without any metric value (before
+the compared numbers), and the exit code is 3.
 """
 
 from __future__ import annotations
@@ -278,10 +282,7 @@ def run_cell(args) -> int:
     out = sys.stdout if on_chip else sys.stderr
     ctx = make_ctx(spec, args.workload, args.seed, trace_on, on_chip)
     run, comparisons = drive(ctx, args.seconds, CompileCounter(), len(devs))
-    correct = True
-    for c in comparisons:
-        print("check " + json.dumps(c), flush=True, file=out)
-        correct = correct and c["ok"]
+    correct = all(c["ok"] for c in comparisons)
     window = run["window"]
     for k, v in window.get("notes", {}).items():
         print(f"note {k}={v}", file=out)
@@ -304,10 +305,15 @@ def run_cell(args) -> int:
         # a rehearsal: the path ran, nothing was measured, stdout stays empty
         line["metrics"] = {k: {"unit": v["unit"]} for k, v in metrics.items()}
         line["rehearsal"] = True
-        print(json.dumps(line), file=sys.stderr, flush=True)
-        return EXIT_REHEARSAL
-    print(json.dumps(line), flush=True)
-    return 0
+    # every number compared beside its limit, last in the line and last on
+    # stderr: what a record of a run that is not correct keeps
+    line["checks"] = [[c["name"], c["value"], c["rule"], c["limit"]]
+                      for c in comparisons]
+    print(json.dumps(line), flush=True, file=out)
+    for c in comparisons:
+        print(f"check {c['name']} {c['value']} {c['rule']} {c['limit']}"
+              + ("" if c["ok"] else "  FAILED"), file=sys.stderr)
+    return 0 if on_chip else EXIT_REHEARSAL
 
 
 def main(argv=None) -> int:

@@ -211,3 +211,7 @@ def main(root: str) -> int:
         print("selftest: FAULT " + b, file=sys.stderr)
     print(json.dumps({"selftest_ok": not bad, "faults": len(bad)}))
     return 0 if not bad else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))

@@ -143,6 +143,9 @@ def test_readers_on_a_traced_call(monkeypatch):
                                 "ops.delay.bucket_count_chain": 0.24,
                                 "ops.gate.any_lane": 0.012},
             "runs_by_outer_s": {},
+            # the three pops on each of 2 x 600 tile-ticks, 500 us an event
+            "runs_by_inner_instruction": {"ops.ring.ring_pop": {
+                f"fusion.{k}": [1200, 0.6] for k in (6, 7, 8)}},
             "spans": {"sweep.tile": [{"stats": {"lanes": 4}},
                                      {"stats": {"lanes": 4}}]}},
     }
@@ -155,9 +158,9 @@ def test_readers_on_a_traced_call(monkeypatch):
     assert read("sweep_host_ms.byzsweep") == pytest.approx(100.0)
     assert read("device_idle_pct.byzsweep") == pytest.approx(4.0)
     assert read("device_scoped_pct.byzsweep") == pytest.approx(75.0)
-    # four lanes x three rings x (a slice read + one written) of 25.6 MB
-    assert byz_trace.ring_pop_bytes_per_tick(run["fields"], 4) == 614_400_000
-    # 614.4 MB in 1,500 us is 409.6 GB/s: half of a v5e's 819 GB/s
+    # four lanes x (a slice read + one written) of 25.6 MB, a ring's pop
+    assert byz_trace.ring_pop_bytes(run["fields"], 4) == 204_800_000
+    # 3,600 pops of 204.8 MB in 1.8 s are 409.6 GB/s: half of a v5e's 819 GB/s
     import jax
 
     monkeypatch.setattr(jax, "devices", lambda: [type(
@@ -168,3 +171,123 @@ def test_readers_on_a_traced_call(monkeypatch):
         "D", (), {"device_kind": "cpu"})()])
     assert read("ring_pop_hbm_pct.byzsweep") is None
     assert program_trace.of_run(run) is run["_program_trace"]
+
+
+def crafted(pop_ticks: tuple, pop_us: float = 600.0):
+    """A traced call as ``program_trace.load`` hands it over: two runs of the
+    main program (two tiles of four lanes), 20 tile-ticks of 3 ms each; the
+    three ring pops (``pop_us`` each, one instruction a ring), the
+    view-change ring's (a twelfth of that) and a bitcast (3 us) on the ticks
+    of every ten that ``pop_ticks`` names, a taken arm of 1 ms on every tick,
+    under the scan's ``while``."""
+    import xplane
+
+    pop = ("pbft.tick.pop", "ops.ring.ring_pop")
+    ops, names, modules, host = [], [], [], [
+        (xplane.WINDOW, 0.0, 200e6, ("/host:CPU", 1), {})]
+
+    def op(name, scopes, a, b):
+        ops.append((scopes, a, b))
+        names.append(name)
+
+    for r in range(2):
+        r0 = 1e6 + r * 70e6
+        modules.append(("jit_batched", r0, r0 + 60e6))
+        host.append(("sweep.tile", r0 - 1e5, r0 + 61e6, ("/host:CPU", 1),
+                     {"lanes": 4}))
+        op("while.1", (), r0, r0 + 60e6)
+        for k in range(20):
+            t = r0 + k * 3e6
+            if k % 10 in pop_ticks:
+                for i in range(3):
+                    op(f"fusion.{6 + i}", pop, t + i * pop_us * 1e3,
+                       t + (i + 1) * pop_us * 1e3)
+                op("fusion.vc", pop, t + 1800e3,
+                   t + 1800e3 + pop_us * 1e3 / 12)
+                op("bitcast.5", pop, t + 1890e3, t + 1893e3)
+            op("fusion.arm", ("pbft.tick.commit",), t + 1900e3, t + 2900e3)
+    return {"devices": {"/device:TPU:0": {
+        "ops": ops, "instructions": names, "modules": modules}},
+        "host": host}
+
+
+def traced_run(monkeypatch, tmp_path, made: dict) -> dict:
+    import jax
+    import program_trace
+
+    monkeypatch.setattr(program_trace, "load", lambda path: made)
+    monkeypatch.setattr(jax, "devices", lambda: [type(
+        "D", (), {"device_kind": "TPU v5 lite"})()])
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(b"")
+    return {"traffic": {"driver": "byzsweep"},
+            "fields": {"n": 100_000, "pbft_max_slots": 64},
+            "window": {"steps_per_dispatch": 20, "tiles_per_call": 2},
+            "trace": {"path": str(path)}}
+
+
+@pytest.mark.parametrize("pop_ticks,events", (
+    (tuple(range(10)), 120), ((0, 3, 7), 36)))
+def test_ring_pop_share_counts_the_pops_that_ran(monkeypatch, tmp_path,
+                                                 pop_ticks, events):
+    """The share follows the pops in the trace, not the ticks of the run:
+    with the pops on every tick and on 30% of the ticks (inside a gate) it
+    reads the same, under 100: 3 x 204.8 MB in 1,853 us."""
+    run = traced_run(monkeypatch, tmp_path, crafted(pop_ticks))
+    pops = byz_trace.ring_pops(run)
+    assert sorted(pops) == ["bitcast.5", "fusion.6", "fusion.7", "fusion.8",
+                            "fusion.vc"]
+    whole = byz_trace.whole_ring_pops(pops)
+    assert sorted(whole) == ["fusion.6", "fusion.7", "fusion.8"]
+    assert sum(n for n, _ in whole.values()) == events
+    assert pops["fusion.6"][1] == pytest.approx(events / 3 * 600e-6)
+    share = bench.load_module(
+        "layer_metrics", "ring_pop_hbm_pct.byzsweep").read(run)
+    assert share == pytest.approx(100 * 3 * 204.8e6 / 1853e-6 / 819e9)
+    assert 0 < share < 100
+    # another driver's run reads nothing
+    assert byz_trace.ring_pops({**run, "traffic": {"driver": "sweep"}}) is None
+
+
+def test_ring_pop_share_is_not_held_under_the_roofline(monkeypatch, tmp_path):
+    """Pops that run faster than the HBM could move ``ring_pop_bytes`` (a
+    ring packed into a narrower type, say: 100 us where 204.8 MB need 250)
+    are still picked, by their time beside the others', and the share reads
+    over 100: the reading the driver refuses, not one the reader hides."""
+    run = traced_run(monkeypatch, tmp_path, crafted((0, 3, 7), pop_us=100.0))
+    whole = byz_trace.whole_ring_pops(byz_trace.ring_pops(run))
+    assert sorted(whole) == ["fusion.6", "fusion.7", "fusion.8"]
+    share = bench.load_module(
+        "layer_metrics", "ring_pop_hbm_pct.byzsweep").read(run)
+    under_us = 3 * 100 + 100 / 12 + 3
+    assert share == pytest.approx(100 * 3 * 204.8e6 / under_us * 1e6 / 819e9)
+    assert share > 200
+
+
+def test_whole_ring_pops_by_time_beside_the_largest():
+    pops = {"a": [10, 6.2e-3], "b": [10, 6.0e-3], "c": [4, 2.5e-3],
+            "vc": [10, 0.58e-3], "over": [10, 1.6e-3], "under": [10, 1.5e-3],
+            "idle": [0, 0.0]}  # the largest: c, 625 us an event
+    assert sorted(byz_trace.whole_ring_pops(pops)) == ["a", "b", "c", "over"]
+    assert byz_trace.whole_ring_pops({}) == {}
+
+
+def test_instruction_table_is_the_inner_table_kept_apart():
+    """On the recorded TPU trace (the served driver: three whole runs of 45
+    ticks, the four pops on every tick): under every inner scope the
+    instructions' self times sum to ``runs_by_inner_s``, and under
+    ``ops.ring.ring_pop`` there are four instructions, each with one event a
+    tick."""
+    import program_trace
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "fixtures", "served_small.xplane.pb.gz")
+    whole = program_trace.summarize(path)
+    table = whole["runs_by_inner_instruction"]
+    assert sorted(table) == sorted(whole["runs_by_inner_s"])
+    for scope, by in table.items():
+        assert sum(s for _, s in by.values()) == pytest.approx(
+            whole["runs_by_inner_s"][scope], rel=1e-9, abs=1e-15)
+    pops = table[byz_trace.POP_SCOPE]
+    assert len(pops) == 4
+    assert {n for n, _ in pops.values()} == {whole["main_runs"] * 45}

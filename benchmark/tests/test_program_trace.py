@@ -117,22 +117,30 @@ def test_readers_report_in_their_cell_and_nowhere_else():
     assert run["_program_trace"] is not None
 
 
-def test_readers_return_nothing_on_a_program_without_scopes_or_spans():
+def _bare(trace, driver):
+    """A run's record as ``run.drive`` makes it, with nothing in it."""
+    return {"trace": trace, "traffic": {"driver": driver}, "fields": {},
+            "setup": {}, "setup_s": 30.0, "t_window": 1000.0,
+            "window": {"steps_per_dispatch": 20, "samples": [],
+                       "unit": "rounds"}}
+
+
+@pytest.mark.parametrize("driver", sorted(
+    f[:-3] for f in os.listdir(os.path.join(bench.HERE, "drivers"))
+    if f.endswith(".py")))
+def test_readers_return_nothing_on_a_program_without_scopes_or_spans(driver):
+    """Every reader ``BENCHMARK.json`` lists, under every driver: nothing on
+    a run without a trace, and nothing on the trace of a program that has no
+    scopes and no spans (the harness's own summary of it empty, so that only
+    what the program wrote could be read)."""
     spec = bench.load_json(bench.ROOT, "BENCHMARK.json")
-    new = [m["name"] for m in spec["per_layer"]
-           if m["name"] not in {"warm_build_s", "round_step_us",
-                                "tick_step_us.sweep", "sweep_host_ms",
-                                "serve_dispatch_ms", "serve_queue_ms",
-                                "serve_batch_occupancy"}
-           and not m["name"].startswith("device_idle_pct.")]
-    assert len(new) == 14
-    for driver in ("solo", "sweep", "served"):
-        run = _run(SOLO, driver, 20)
-        for name in new:
-            assert bench.load_module("layer_metrics", name).read(run) is None
+    empty = {"path": SOLO, "spans": {}, "busy_s": 0.0, "window_s": 0.0}
+    for trace in (None, empty):
+        run = _bare(trace, driver)
+        for m in spec["per_layer"]:
+            assert bench.load_module(
+                "layer_metrics", m["name"]).read(run) is None, m["name"]
     # an untraced run, and a trace that cannot be read
-    run = _run(SOLO, "solo", 20)
-    run["trace"] = None
-    assert program_trace.of_run(run) is None
+    assert program_trace.of_run(_bare(None, "solo")) is None
     assert program_trace.of_run(_run("/nonexistent.xplane.pb", "solo", 1)) \
         is None

@@ -183,52 +183,42 @@ def _pmax(x, axis):
     return x if axis is None else jax.lax.pmax(x, axis)
 
 
-def _crossing_loop(buckets, need, clean: bool, start=None):
+def _crossing_loop(rows, need, clean: bool, start=None):
     """Threshold crossings of a vote counter fed bucket-by-bucket.
 
-    ``buckets``: [B, N] arrival counts in tick order.  Replicates the tick
+    ``rows``: B arrival-count rows ``[N]`` in tick order.  Replicates the tick
     engine's per-tick rule (pbft.step / pbft-node.cc:231,248): counter +=
     arrivals; crossed iff arrivals > 0 and counter >= need; on crossing the
     counter resets to 0 (reference fidelity; the whole batch is consumed) —
-    ``clean`` latches so only the first crossing fires.
+    ``clean`` latches instead, so only the first crossing fires (the counter
+    never resets before it, so the running sum IS the counter up to there).
+    ``start`` is the counter carried in.
 
-    Returns (crossed [B, N] bool, n_crossings [N], first_bucket [N] — index
-    of first crossing, B if none).
+    One elementwise pass over N with B unrolled, rows in and rows out: a
+    ``[B, N]`` array assembled from the rows costs a round a write and a read
+    of its own on the chip (PERF.md section 6, PR 45).
+
+    Returns (crossed: B bool rows ``[N]``, n_crossings ``[N]``, first_bucket
+    ``[N]`` — index of the first crossing, B if none).
     """
-    b, n = buckets.shape
-    if clean:
-        # latched first crossing only: the counter never resets before it
-        # fires, so the running cumulative sum IS the counter up to the
-        # crossing, and the crossing is the FIRST bucket with arrivals at or
-        # past the threshold (argmax of a bool picks the first True).  The
-        # running sums are built by an unrolled add chain — NOT jnp.cumsum,
-        # whose XLA:CPU lowering measured ~2.5 ms/round slower — and the
-        # latch collapses to ~4 [B, N] ops instead of ~6 [N] ops per bucket.
-        run = jnp.zeros((n,), jnp.int32) if start is None else start
-        csums = []
-        for k in range(b):
-            run = run + buckets[k]
-            csums.append(run)
-        csum = jnp.stack(csums)  # [B, N]
-        qual = (buckets > 0) & (csum >= need)
-        any_q = qual.any(axis=0)
-        first = jnp.argmax(qual, axis=0)  # first qualifying bucket
-        crossed_mat = (jnp.arange(b)[:, None] == first[None, :]) & any_q[None, :]
-        n_cross = any_q.astype(jnp.int32)
-        return crossed_mat, n_cross, jnp.where(any_q, first, b)
-    cnt = jnp.zeros((n,), jnp.int32) if start is None else start
-    crossed_list = []
-    for k in range(b):
-        arr = buckets[k]
+    b = len(rows)
+    cnt = jnp.zeros_like(rows[0]) if start is None else start
+    fired = jnp.zeros(rows[0].shape, bool)
+    n_cross = jnp.zeros(rows[0].shape, jnp.int32)
+    first = jnp.full(rows[0].shape, b, jnp.int32)
+    crossed = []
+    for k, arr in enumerate(rows):
         cnt = cnt + arr
-        crossed = (arr > 0) & (cnt >= need)
-        cnt = jnp.where(crossed, 0, cnt)
-        crossed_list.append(crossed)
-    crossed_mat = jnp.stack(crossed_list)  # [B, N]
-    n_cross = crossed_mat.astype(jnp.int32).sum(axis=0)
-    first = jnp.argmax(crossed_mat, axis=0)
-    first = jnp.where(crossed_mat.any(axis=0), first, b)
-    return crossed_mat, n_cross, first
+        hit = (arr > 0) & (cnt >= need)
+        if clean:
+            hit = hit & ~fired
+        else:
+            cnt = jnp.where(hit, 0, cnt)
+        first = jnp.where(hit & ~fired, k, first)
+        fired = fired | hit
+        n_cross = n_cross + hit
+        crossed.append(hit)
+    return crossed, n_cross, first
 
 
 def step_round(cfg, state: PbftRoundState, r, key):
@@ -308,74 +298,80 @@ def step_round(cfg, state: PbftRoundState, r, key):
         voters = state.alive & state.honest
         n_voters = _psum(voters.astype(jnp.int32).sum(), axis)
         k_rt = chan_key(tkey, Channel.DELAY_ROUNDTRIP)
-        # the tick engine's own stat round-trip helper: per-receiver reply
-        # counts with (1-p)^2 two-leg thinning under drops
-        rt_counts = dv.roundtrip_reply_counts_stat(
-            k_rt, recv, n_voters - voters.astype(jnp.int32), rt_probs, drop,
-            axis=axis, mode=smode,
-        )  # [B2, N] reply counts, bucket k -> tick t0 + ser + d_j + rt_lo + k
-        rt_land = (t0 + ser + d_j[None, :] + rt_lo + jnp.arange(b2)[:, None]) < t_end
-        rt_counts = rt_counts * rt_land.astype(jnp.int32)
+        # the tick engine's own stat round trip (per-receiver reply counts
+        # with (1-p)^2 two-leg thinning under drops), its chain taken a bucket
+        # at a time: B2 rows [N], bucket k -> tick t0 + ser + d_j + rt_lo + k
+        k_rt, m_rt = dv._roundtrip_stat_m(
+            k_rt, recv, n_voters - voters.astype(jnp.int32), drop, axis, smode
+        )
+        rt_counts = [
+            c.astype(jnp.int32) * (t0 + ser + d_j + rt_lo + k < t_end)
+            for k, c in enumerate(
+                delay_ops.bucket_count_rows(k_rt, m_rt, rt_probs, smode)
+            )
+        ]
         crossed_p, _, _ = _crossing_loop(rt_counts, cfg.pbft_prepare_need, clean)
-        commit_send = crossed_p & (state.alive & state.honest)[None, :]  # [B2, N]
+        commit_send = [c & voters for c in crossed_p]  # B2 rows [N]
 
     with jax.named_scope("pbft.round.commit"):
         # ---- C. COMMIT waves -> finality ---------------------------------------
         # sender j's k-th crossing happens at offset o = ser + d_j + rt_lo + k;
-        # group send counts by absolute offset o = (d_j - lo) + k: a length-b1
-        # polynomial convolution along the tiny offset axis, materialized as b1
-        # shifted pad-and-add terms instead of the former w_send x b2 nest of
-        # masked [N] adds — dispatch count, not bytes, dominates the round step
-        # on the CPU fallback path (VERDICT r5 weak-#4).  NOT a scatter-add:
-        # XLA:CPU serializes scatter updates (measured 2.6x slower end-to-end).
+        # group send counts by absolute offset o = (d_j - lo) + k: row o of
+        # send_at is the sum of the rows whose two indices add to o (a length-
+        # b1 convolution along the tiny offset axis, integer sums)
         w_send = b1 + b2 - 1  # distinct send offsets
         off_base = ser + lo + rt_lo
-        oh_d = d_j[None, :] == (lo + jnp.arange(b1))[:, None]  # [b1, N]
-        cs = commit_send.astype(jnp.int32)
-        send_at = sum(
-            jnp.pad(cs * oh_d[e][None, :], ((e, b1 - 1 - e), (0, 0)))
-            for e in range(b1)
-        )  # [w_send, N]
-        totals = _psum(send_at.sum(axis=1), axis)  # [w_send] global commit senders
+        oh_d = [d_j == lo + e for e in range(b1)]  # b1 rows [N]
+        send_at = [
+            sum(
+                (commit_send[o - e] & oh_d[e]).astype(jnp.int32)
+                for e in range(b1) if 0 <= o - e < b2
+            )
+            for o in range(w_send)
+        ]
+        # [w_send] global commit senders, one collective under a mesh axis
+        totals = _psum(jnp.stack([row.sum() for row in send_at]), axis)
         # receiver m hears, per send offset o, totals[o] - own sends at o,
-        # spread multinomially over the one-way buckets.  One batched [W_send, N]
-        # chain instead of W_send independent [N] chains: identical multinomial
-        # statistics (sample_bucket_counts is elementwise over its leading
-        # shape), ~W_send fewer PRNG/elementwise dispatches per round — the
-        # dominant cost of a round step on the CPU fallback path.
+        # spread multinomially over the one-way buckets: ONE chain over the
+        # w_send rows (one draw of z words for all of them, as the stacked
+        # [w_send, N] call made it), each row's sampler math left to fuse
+        # into the sums that read it
         k_cm = chan_key(tkey, Channel.DELAY_BCAST)
         w_arr = w_send + b1 - 1
-        m_all = jnp.where(state.alive[None, :], totals[:, None] - send_at, 0)
+        m_all = [
+            jnp.where(state.alive, totals[o] - send_at[o], 0)
+            for o in range(w_send)
+        ]
         if drop > 0.0:
-            m_all = jnp.round(delay_ops.binom(
+            # the thinning draws over the whole [w_send, N] shape under one key
+            m_all = list(jnp.round(delay_ops.binom(
                 _shard_key(jax.random.fold_in(k_cm, 0x0D12), axis),
-                m_all, 1.0 - drop, smode,
-            )).astype(jnp.int32)
-        cnt_all = delay_ops.sample_bucket_counts(
-            _shard_key(k_cm, axis), m_all, ow_probs, smode
-        )  # [b1, w_send, N]
-        # fold send offset + travel bucket into the arrival axis (i = o + e):
-        # the same anti-diagonal pad-and-add convolution as send_at above,
-        # replacing the b1 x w_send nest of [N] adds
-        arrivals = sum(
-            jnp.pad(cnt_all[e], ((e, b1 - 1 - e), (0, 0)))
-            for e in range(b1)
-        )  # [w_arr, N]
-        arr_land = (t0 + off_base + lo + jnp.arange(w_arr)) < t_end  # [w_arr]
-        arrivals = arrivals * arr_land.astype(jnp.int32)[:, None]
+                jnp.stack(m_all), 1.0 - drop, smode,
+            )).astype(jnp.int32))
+        cnt_all = [
+            [c.astype(jnp.int32) for c in bucket]
+            for bucket in delay_ops.bucket_count_rows(
+                _shard_key(k_cm, axis), m_all, ow_probs, smode
+            )
+        ]  # b1 buckets of w_send rows [N]
+        # fold send offset + travel bucket into the arrival axis (i = o + e);
+        # bucket i of `arrivals` lands at tick t0 + (off_base + o) + (lo + e)
+        # = t0 + off_base + lo + i
+        arrivals = [
+            sum(cnt_all[e][i - e] for e in range(b1) if 0 <= i - e < w_send)
+            * (t0 + off_base + lo + i < t_end)
+            for i in range(w_arr)
+        ]
         crossed_c, n_cross_c, _ = _crossing_loop(
             arrivals, cfg.pbft_commit_need, clean
         )
-        first_commit = crossed_c.any(axis=0) & active
+        first_commit = functools.reduce(jnp.logical_or, crossed_c) & active
         block_num = state.block_num + jnp.where(active, n_cross_c, 0)
-        # last finalization tick of this slot (pbft.step scatters per-tick max;
-        # arrival bucket tau -> tick t0 + off_base + lo + tau... offsets: bucket
-        # index i of `arrivals` is send offset o + e, arrival tick = t0 + o_abs
-        # + e_abs = t0 + (off_base + o) + (lo + e) -> t0 + off_base + lo + i
-        bucket_idx = jnp.arange(w_arr, dtype=jnp.int32)[:, None]
-        last_local = jnp.max(
-            jnp.where(crossed_c, t0 + off_base + lo + bucket_idx, -1)
-        )
+        # last finalization tick of this slot (pbft.step scatters per-tick max)
+        last_local = jnp.max(functools.reduce(jnp.maximum, [
+            jnp.where(c, t0 + off_base + lo + i, -1)
+            for i, c in enumerate(crossed_c)
+        ]))
         last_tick = _pmax(last_local, axis)
         n_first = _psum(first_commit.astype(jnp.int32).sum(), axis)
         slot_commits = state.slot_commits.at[slot_idx].add(

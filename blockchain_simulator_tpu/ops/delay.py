@@ -137,6 +137,31 @@ def _fast_normal(key: jax.Array, shape) -> jax.Array:
     return (z.astype(jnp.float32) - 8.0) * 0.5
 
 
+def _fast_normal_rows(key: jax.Array, shape, at):
+    """``_fast_normal(key, shape)`` read by rows: returns ``z`` with
+    ``z(b, *o)`` bit-equal to ``_fast_normal(key, shape)[(b, *o)]`` for every
+    index ``o`` in ``at``.  The words are drawn in the one call of the whole
+    shape (XLA's RngBitGenerator is not batch-invariant: a row drawn alone
+    holds other bits) and sliced BEFORE the popcount, so the two halves are
+    never concatenated.  The barrier keeps each row of words a value of its
+    own: XLA:TPU otherwise fuses every slice with its popcount into one
+    fusion that re-lays the draw's tiling into ``[N]`` rows 128 lanes at a
+    time, the largest operation of the 100k round (111.7 us a round without
+    the barrier, 92.9 with it: PERF.md section 6, PR 45)."""
+    half = (shape[0] + 1) // 2
+    words = jax.random.bits(_rbg_key(key), (half,) + tuple(shape[1:]), jnp.uint32)
+    rows = jax.lax.optimization_barrier(
+        {(h, *o): words[(h, *o)] for h in range(half) for o in at}
+    )
+
+    def z(b, *o):
+        w = rows[(b % half, *o)]
+        field = w & jnp.uint32(0xFFFF) if b < half else w >> 16
+        return (jax.lax.population_count(field).astype(jnp.float32) - 8.0) * 0.5
+
+    return z
+
+
 @_scoped
 def binom(key: jax.Array, n: jax.Array, p: float, mode: str = "exact") -> jax.Array:
     """Binomial(n, p) draw (float32 out, same shape as ``n``).
@@ -232,6 +257,53 @@ def bucket_count_chain(key: jax.Array, n: jax.Array, probs: np.ndarray,
             c = binom(keys[b], remaining, frac, mode)
         yield c
         remaining = remaining - c
+        p_left -= pb
+
+
+@_scoped
+def bucket_count_rows(key: jax.Array, n, probs: np.ndarray, mode: str = "exact"):
+    """:func:`bucket_count_chain` for a consumer that keeps rows
+    (models/pbft_round.py): ``n`` is one array, or a list of equal-shaped
+    arrays standing for ``jnp.stack(n)`` (the commit wave, a row per send
+    offset), and each bucket comes as ``n`` came: an array, or the list of its
+    rows.  Bit-equal to the chain over the stacked ``n``: the same key, the
+    same draws in the same shapes, the same float arithmetic in the same
+    order.  Under ``"normal"`` nothing stacked is built on the way: the z
+    words are one draw of the stacked shape, read row by row
+    (:func:`_fast_normal_rows`).  The ``"exact"`` binomial draws over the
+    whole stacked shape under one key, so there the rows are stacked for each
+    draw and handed back as rows."""
+    rows = isinstance(n, (list, tuple))
+    remaining = [jnp.asarray(x, jnp.float32) for x in (n if rows else (n,))]
+    # where each part sits in the stacked shape, after the bucket index
+    at = [(o,) for o in range(len(remaining))] if rows else [()]
+    shape = ((len(remaining),) if rows else ()) + remaining[0].shape
+    nb = len(probs)
+    z = (
+        _fast_normal_rows(key, (nb - 1,) + shape, at)
+        if mode == "normal" and nb > 1 else None
+    )
+    keys = (
+        jax.vmap(lambda b: jax.random.fold_in(key, b))(jnp.arange(nb - 1))
+        if mode != "normal" and nb > 1 else None
+    )
+    p_left = 1.0
+    for b, pb in enumerate(probs):
+        frac = float(min(max(pb / max(p_left, 1e-9), 0.0), 1.0))
+        if b == nb - 1 or frac >= 1.0:
+            c = remaining
+        elif mode == "normal":
+            c = []
+            for rem, o in zip(remaining, at):
+                mu = rem * frac
+                sigma = jnp.sqrt(jnp.maximum(mu * (1.0 - frac), 0.0))
+                c.append(jnp.clip(jnp.round(mu + sigma * z(b, *o)), 0.0, rem))
+        else:
+            whole = jnp.stack(remaining) if rows else remaining[0]
+            c = binom(keys[b], whole, frac, mode)
+            c = list(c) if rows else [c]
+        yield c if rows else c[0]
+        remaining = [rem - x for rem, x in zip(remaining, c)]
         p_left -= pb
 
 

@@ -234,6 +234,46 @@ def test_bucket_counts_moments(mode):
         assert abs(c[b].mean() - n_each * p) < 4 * se, (mode, b, c[b].mean())
 
 
+@pytest.mark.parametrize("shape", [(4, 33), (5, 33), (1, 7, 33), (2, 7, 33)])
+def test_fast_normal_rows_are_the_stacked_draw_by_index(shape):
+    # the same words, sliced before the popcount: bit-equal, for an even and
+    # an odd count of rows, by one index and by two
+    key = jax.random.key(45)
+    whole = np.asarray(delay_ops._fast_normal(key, shape))
+    at = [(o,) for o in range(shape[1])] if len(shape) == 3 else [()]
+    z = delay_ops._fast_normal_rows(key, shape, at)
+    for b in range(shape[0]):
+        for o in at:
+            np.testing.assert_array_equal(np.asarray(z(b, *o)), whole[(b, *o)])
+
+
+@pytest.mark.parametrize("mode", ["normal", "exact"])
+@pytest.mark.parametrize("nb", [1, 3, 5])
+def test_bucket_count_rows_equal_the_stacked_chain(mode, nb):
+    # a list of rows stands for their stack: bucket b comes as the list of
+    # its rows, bit-equal to the chain's over the stack (models/
+    # pbft_round.py's commit wave, a row per send offset); one array comes
+    # back as arrays (its prepare wave)
+    key = jax.random.key(7)
+    rows = [jnp.asarray(np.random.default_rng(o).integers(0, 5000, 41), jnp.int32)
+            for o in range(7)]
+    probs = np.convolve(np.full(3, 1 / 3), np.full(3, 1 / 3))[:nb]
+    probs = probs / probs.sum()
+    want = list(delay_ops.bucket_count_chain(key, jnp.stack(rows), probs, mode))
+    got = list(delay_ops.bucket_count_rows(key, rows, probs, mode))
+    assert len(got) == len(want) == nb
+    for g, w in zip(got, want):
+        assert isinstance(g, list) and len(g) == 7
+        np.testing.assert_array_equal(np.stack(g), np.asarray(w))
+    np.testing.assert_array_equal(
+        sum(np.stack(g) for g in got), np.stack(rows).astype(np.float32))
+    want = list(delay_ops.bucket_count_chain(key, rows[0], probs, mode))
+    got = list(delay_ops.bucket_count_rows(key, rows[0], probs, mode))
+    assert len(got) == nb
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_bucket_count_chain_yields_what_sample_stacks():
     probs = delay_ops.roundtrip_probs(0, 3)
     key = jax.random.key(9)

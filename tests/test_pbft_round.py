@@ -7,8 +7,15 @@ both, so counts match exactly under no faults), same view-change sequence
 time-to-finality within the delay distribution's tick-quantization slack.
 """
 
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
+import pbft_round_stacked as stacked  # tests/: the round as it stood before PR 45
+from blockchain_simulator_tpu.models import pbft_round
 from blockchain_simulator_tpu.runner import make_sim_fn, run_simulation, use_round_schedule
 from blockchain_simulator_tpu.utils.config import FaultConfig, SimConfig
 
@@ -228,3 +235,184 @@ def test_exact_sampler_round_mode():
     b = run_simulation(SimConfig(**BASE, schedule="round", stat_sampler="normal"))
     for k in MILESTONES:
         assert a[k] == b[k], k
+
+
+# --------------------------------------------------------------------------- #
+# the row form (PR 45): every per-bucket quantity of a round is B rows [N],   #
+# never a [B, N] array assembled from them                                    #
+# --------------------------------------------------------------------------- #
+
+_SER = dict(sim_ms=4200, model_serialization=True, pbft_block_interval_ms=200,
+            pbft_tx_speed=300)
+ROW_CASES = {
+    "no_faults": dict(pbft_view_change_num=0),
+    "crash_faults": dict(faults=FaultConfig(n_crashed=8)),
+    "drops_no_view_change": dict(pbft_view_change_num=0,
+                                 faults=FaultConfig(drop_prob=0.05)),
+    "serialization_offset": _SER,
+    "truncated_final_wave": dict(sim_ms=2465, pbft_max_rounds=60),
+    # 200 rounds at upstream's 1/100: both seeds below change view
+    "view_changes_1_100": dict(sim_ms=10100, pbft_max_rounds=200,
+                               pbft_max_slots=208),
+}
+
+
+def _final(monkeypatch, cfg, step, wrap=lambda sim: sim, key=None):
+    """The final state of the round program of ``cfg`` built around ``step``
+    (the engine's ``step_round`` or the stacked reference's), outside the
+    registry, which would hand one form's program to the other."""
+    monkeypatch.setattr(pbft_round, "step_round", step)
+
+    def sim(key):
+        state, _ = pbft_round.init(cfg, key)
+        return pbft_round.scan_rounds(cfg, state, key)
+
+    key = jax.random.key(cfg.seed) if key is None else key
+    return jax.block_until_ready(jax.jit(wrap(sim))(key))
+
+
+def _assert_leaves_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb) == 12
+    for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a), lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("seed", [3, 4])
+@pytest.mark.parametrize("case", list(ROW_CASES))
+@pytest.mark.parametrize("sampler", ["normal", "exact"])
+@pytest.mark.parametrize("fidelity", ["clean", "reference"])
+def test_rows_bit_equal_to_stacked(monkeypatch, fidelity, sampler, case, seed):
+    cfg = SimConfig(**{**BASE, **ROW_CASES[case]}, schedule="round",
+                    fidelity=fidelity, stat_sampler=sampler, seed=seed)
+    assert pbft_round.eligible(cfg)
+    step = pbft_round.step_round
+    want = _final(monkeypatch, cfg, stacked.step_round)
+    got = _final(monkeypatch, cfg, step)
+    _assert_leaves_equal(got, want)
+    # the case does what its name says: blocks commit (none is a dead run),
+    # and the view-change case changes view
+    assert int(np.asarray(got.slot_commits).sum()) > 0
+    if case == "view_changes_1_100":
+        assert int(np.asarray(got.view_changes).sum()) > 0
+
+
+def test_rows_bit_equal_under_mesh_axis(monkeypatch):
+    # two node shards: _psum / _pmax over rows, one collective for `totals`
+    from blockchain_simulator_tpu.parallel import shard
+    from blockchain_simulator_tpu.parallel.mesh import make_mesh
+
+    cfg = SimConfig(**BASE, schedule="round", stat_sampler="normal",
+                    faults=FaultConfig(n_crashed=8), seed=5)
+    mesh = make_mesh(n_node_shards=2, devices=jax.devices()[:2])
+    step, out = pbft_round.step_round, {}
+    for name, fn in (("stacked", stacked.step_round), ("rows", step)):
+        monkeypatch.setattr(pbft_round, "step_round", fn)
+        sim = shard._make_sharded_round_fn.__wrapped__(cfg, mesh)
+        out[name] = jax.block_until_ready(sim(jax.random.key(cfg.seed)))
+    _assert_leaves_equal(out["rows"], out["stacked"])
+    assert int(np.asarray(out["rows"].slot_commits).sum()) > 0
+
+
+def test_rows_bit_equal_under_lane_batch(monkeypatch):
+    # the lane-batched round sweep (parallel/sweep._batched_fn): four seeds
+    # as lanes of one program
+    from blockchain_simulator_tpu.models.base import lane_vmap
+
+    cfg = SimConfig(**BASE, schedule="round", stat_sampler="normal",
+                    fidelity="reference")
+    keys = jax.vmap(jax.random.key)(jnp.arange(4, dtype=jnp.uint32))
+    step = pbft_round.step_round
+    want = _final(monkeypatch, cfg, stacked.step_round, lane_vmap, keys)
+    got = _final(monkeypatch, cfg, step, lane_vmap, keys)
+    _assert_leaves_equal(got, want)
+    assert np.asarray(got.slot_commits).shape[0] == 4
+
+
+def test_round_program_assembles_no_bucket_array():
+    # the lowered text (jax's own StableHLO, the same on every platform) of
+    # the round program at n = 4,096: no concatenate and no pad whose result
+    # has n as its minor dimension (six and six before PR 45); the PRNG
+    # keys' own tensor<2xui32> concatenates stay
+    n = 4096
+    cfg = SimConfig(protocol="pbft", n=n, sim_ms=1100, delivery="stat",
+                    model_serialization=False, schedule="round")
+    assert cfg.eff_stat_sampler == "normal" and cfg.fidelity == "clean"
+
+    def sim(key):
+        state, _ = pbft_round.init(cfg, key)
+        return pbft_round.scan_rounds(cfg, state, key)
+
+    text = jax.jit(sim).lower(jax.random.key(0)).as_text()
+    wide = re.compile(
+        r"stablehlo\.(concatenate|pad)\b.*->\s*tensor<(?:\d+x)*%dx\w+>" % n)
+    found = [m.group(1) for m in map(wide.search, text.splitlines()) if m]
+    assert found == [], found
+    assert "stablehlo.concatenate" in text  # the keys' own
+
+
+def _rows(*cols):
+    """B rows [N] from N columns of B arrivals each."""
+    return [jnp.asarray(r, jnp.int32) for r in zip(*cols)]
+
+
+@pytest.mark.parametrize("clean", [True, False])
+def test_crossing_loop_by_hand(clean):
+    # need = 5 over four buckets; one node a column
+    rows = _rows(
+        (5, 0, 0, 0),   # crosses in the first bucket
+        (1, 1, 1, 2),   # crosses in the last
+        (1, 1, 1, 1),   # never
+        (0, 0, 7, 0),   # one batch past the threshold, mid-wave
+        (4, 0, 0, 1),   # quiet buckets hold the count: crosses on arrival
+    )
+    crossed, n_cross, first = pbft_round._crossing_loop(rows, 5, clean)
+    assert len(crossed) == 4 and all(c.dtype == bool for c in crossed)
+    np.testing.assert_array_equal(
+        np.stack(crossed).T,
+        [[1, 0, 0, 0], [0, 0, 0, 1], [0, 0, 0, 0], [0, 0, 1, 0], [0, 0, 0, 1]])
+    np.testing.assert_array_equal(n_cross, [1, 1, 0, 1, 1])
+    np.testing.assert_array_equal(first, [0, 3, 4, 2, 3])
+
+
+def test_crossing_loop_two_crossings():
+    # 3, 3, 3, 3 against need = 5: the reference resets on a crossing and
+    # crosses again; clean latches the first
+    rows = _rows((3, 3, 3, 3), (6, 0, 6, 0), (2, 2, 0, 0))
+    crossed, n_cross, first = pbft_round._crossing_loop(rows, 5, False)
+    np.testing.assert_array_equal(
+        np.stack(crossed).T, [[0, 1, 0, 1], [1, 0, 1, 0], [0, 0, 0, 0]])
+    np.testing.assert_array_equal(n_cross, [2, 2, 0])
+    np.testing.assert_array_equal(first, [1, 0, 4])
+    crossed, n_cross, first = pbft_round._crossing_loop(rows, 5, True)
+    np.testing.assert_array_equal(
+        np.stack(crossed).T, [[0, 1, 0, 0], [1, 0, 0, 0], [0, 0, 0, 0]])
+    np.testing.assert_array_equal(n_cross, [1, 1, 0])
+    np.testing.assert_array_equal(first, [1, 0, 4])
+
+
+@pytest.mark.parametrize("clean", [True, False])
+def test_crossing_loop_start_carried_in(clean):
+    # a counter carried in: 4 of the 5 are already there
+    rows = _rows((1, 0), (0, 1), (0, 0))
+    start = jnp.asarray([4, 4, 4], jnp.int32)
+    crossed, n_cross, first = pbft_round._crossing_loop(rows, 5, clean, start)
+    np.testing.assert_array_equal(
+        np.stack(crossed).T, [[1, 0], [0, 1], [0, 0]])
+    np.testing.assert_array_equal(n_cross, [1, 1, 0])
+    np.testing.assert_array_equal(first, [0, 1, 2])
+
+
+@pytest.mark.parametrize("clean", [True, False])
+def test_crossing_loop_equals_stacked(clean):
+    rng = np.random.default_rng(45)
+    mat = rng.integers(0, 4, size=(9, 257)).astype(np.int32)
+    mat[rng.random(mat.shape) < 0.4] = 0
+    start = jnp.asarray(rng.integers(0, 3, size=257), jnp.int32)
+    for st in (None, start):
+        want = stacked._crossing_loop(jnp.asarray(mat), 6, clean, st)
+        got = pbft_round._crossing_loop(list(jnp.asarray(mat)), 6, clean, st)
+        np.testing.assert_array_equal(np.stack(got[0]), want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[2], want[2])

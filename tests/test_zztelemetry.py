@@ -565,8 +565,8 @@ def lowered_programs():
     compiled or run): the pbft tick engine on per-edge, stat and gossip
     delivery, the pbft round engine, and the raft and paxos tick engines
     for the delivery ops only they call, and the paxos tick engine on a
-    gossip relay (its flood decode does nothing elsewhere); one op no engine
-    calls is lowered alone; the lane-batched pbft tick program, where
+    gossip relay (its flood decode does nothing elsewhere); two ops no engine
+    calls are lowered alone; the lane-batched pbft tick program, where
     ``gated`` does work of its own; the paxos engine sharded over a 2-device
     mesh, on the relay (``ops.mesh.pmax`` / ``.psum``) and on the full mesh
     (``ops.mesh.gather``); and last a small mixed program on its fast path
@@ -602,6 +602,13 @@ def lowered_programs():
     texts.append(jax.jit(
         lambda k, m: delivery.bcast_slots_stat(k, m, probs)
     ).lower(jax.random.key(0), jnp.ones((8, 4), jnp.int32))
+        .as_text(debug_info=True))
+    # the round engine took the stacked stat round trip until PR 45; it
+    # takes the chain by rows now, and the op is the fused push's reference
+    rt_probs = delay.roundtrip_probs(3, 6)
+    texts.append(jax.jit(
+        lambda k, m: delivery.roundtrip_reply_counts_stat(k, m, 7, rt_probs)
+    ).lower(jax.random.key(0), jnp.ones((8,), bool))
         .as_text(debug_info=True))
     from blockchain_simulator_tpu.parallel import sweep
 

@@ -294,33 +294,41 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
          exchange=None):
     """One tick: pop the four rings, then run the phases (:func:`_phases`).
 
-    The phases are the identity on a tick on which no popped slice holds
-    anything, no queued block lands and no block is due to be sent: every
-    crossing, send and trigger is false and every vote table gets ``+ 0``.
-    So where the program can branch (``can_branch``: one device, no
-    ``select_vmap``) they run inside one ``while`` of at most one trip,
-    taken on ``is_block_tick | any ring's slot t is due`` (``PbftBufs.due``),
-    under a lane batch on "any lane active" with no per-lane select, the
-    body being the identity for a lane with nothing due.  A quiet tick then
-    passes over no ``[N, W]`` table.  The pops stay out here, on every tick.
-    Under a mesh axis the phases hold collectives, and under ``select_vmap``
-    no branch survives: both run the phases on every tick (KNOWN_ISSUES
-    #0b')."""
-    with jax.named_scope("pbft.tick.pop"):
-        pp_t, pp = ring_pop(bufs.pp, t)
-        prep_t, prep_rt = ring_pop(bufs.prep_rt, t)
-        com_t, commit = ring_pop(bufs.commit, t)
-        vc_t, vc = ring_pop(bufs.vc, t)
-    popped = (pp_t, prep_t, com_t, vc_t)
-    bufs = bufs.replace(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc)
+    Pops and phases are the identity on a tick on which no ring's slot
+    ``t % D`` is due, no queued block lands and no block is due to be sent:
+    a slot that is not due holds nothing (``PbftBufs.due``), so its pop reads
+    zeros and writes zeros back, every crossing, send and trigger is false
+    and every vote table gets ``+ 0``.  So where the program can branch
+    (``can_branch``: one device, no ``select_vmap``) both run inside one
+    ``while`` of at most one trip, taken on ``is_block_tick | any ring's slot
+    t is due | a queued block lands``, under a lane batch on "any lane
+    active" with no per-lane select, the body being the identity for a lane
+    with nothing due.  A quiet tick then touches no ring and no ``[N, W]``
+    table: the predicate and the clearing of slot ``t``'s due bits are all it
+    runs.  Under a mesh axis the phases hold collectives, and under
+    ``select_vmap`` no branch survives: both pop and run the phases on every
+    tick (KNOWN_ISSUES #0b')."""
     gate = can_branch(cfg.mesh_axis)
+    # the gate's loop is laid out in isolation: the popped slices and the
+    # tables they meet keep the rings' node-minor order inside it (the
+    # programs that cannot branch see the whole tick and need no pin)
+    pin = slice_node_minor if gate else (lambda x: x)
 
-    def phases(carry):
-        return _phases(cfg, *carry, popped, t, tkey, topo_tables, exchange,
-                       mark_due=gate)
+    def tick(carry):
+        st, bf = carry
+        with jax.named_scope("pbft.tick.pop"):
+            pp_t, pp = ring_pop(bf.pp, t)
+            prep_t, prep_rt = ring_pop(bf.prep_rt, t)
+            com_t, commit = ring_pop(bf.commit, t)
+            vc_t, vc = ring_pop(bf.vc, t)
+        popped = jax.tree.map(pin, (pp_t, prep_t, com_t, vc_t))
+        bf = bf.replace(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc)
+        st, bf = _phases(cfg, st, bf, popped, t, tkey, topo_tables, exchange,
+                         mark_due=gate)
+        return jax.tree.map(pin, st), bf
 
     if not gate:
-        return phases((state, bufs))
+        return tick((state, bufs))
     with jax.named_scope("pbft.tick.pop"):
         d = bufs.due.shape[1]
         now = jnp.arange(d) == t % d
@@ -328,15 +336,7 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
         if _queued(cfg):  # those arrivals bypass the rings
             active = active | (state.ppq_tick == t).any()
         bufs = bufs.replace(due=bufs.due & ~now)
-    # the loop is laid out in isolation: the popped slices and the tables
-    # they meet keep the rings' node-minor order across its boundary
-    popped = jax.tree.map(slice_node_minor, popped)
-
-    def pinned(carry):
-        st, bf = phases(carry)
-        return jax.tree.map(slice_node_minor, st), bf
-
-    return gated_body(active, pinned, (state, bufs), TAKEN_SCOPE)
+    return gated_body(active, tick, (state, bufs), TAKEN_SCOPE)
 
 
 def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,

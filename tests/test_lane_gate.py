@@ -540,7 +540,9 @@ def test_no_select_touches_a_ring_and_only_pops_update_outside_a_gate(name):
     for tag, closed in programs.items():
         selects, outside, inside = _structure(closed, rings)
         assert selects == [], (tag, selects)
-        assert outside == n_rings, (tag, outside)  # one pop a ring, no push
+        # one pop a ring and no push; PBFT pops inside its tick gate
+        # (models/pbft.step), so nothing of its rings is left outside
+        assert outside == (0 if cfg.protocol == "pbft" else n_rings), (tag, outside)
         assert inside >= n_rings, (tag, inside)
 
 

@@ -1,8 +1,9 @@
-"""``models/pbft.step``'s quiet-tick gate: the phases run only on a tick on
-which something is due (a block tick, a due ring slot, a queued block), in
-one ``while`` of at most one trip, and every final state and metric is
-bit-equal to the ungated form of the same tick, which is what the programs
-that cannot branch (a mesh axis, ``select_vmap``) run on every tick."""
+"""``models/pbft.step``'s quiet-tick gate: the four ring pops and the phases
+run only on a tick on which something is due (a block tick, a due ring slot,
+a queued block), in one ``while`` of at most one trip, and every final state
+and metric is bit-equal to the ungated form of the same tick, which is what
+the programs that cannot branch (a mesh axis, ``select_vmap``) run on every
+tick."""
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from blockchain_simulator_tpu.parallel import shard
 from blockchain_simulator_tpu.parallel.mesh import make_mesh
 from blockchain_simulator_tpu.utils import prng
 from blockchain_simulator_tpu.utils.config import FaultConfig
+from test_lane_gate import _ring_shapes, _structure  # tests/: ring updates by place
 
 EDGE = SimConfig(protocol="pbft", n=8, sim_ms=330, stat_sampler="exact")
 STAT = EDGE.with_(delivery="stat", schedule="tick")
@@ -187,26 +189,33 @@ def test_due_bits_are_exact(monkeypatch, name):
 def test_active_ticks_equal_the_schedule(monkeypatch):
     """With one-bucket delays the schedule is arithmetic: per block the
     block tick, the PRE_PREPARE's arrival, the replies' and the COMMITs',
-    four taken trips; the phases run on those ticks and no others."""
+    four taken trips; the four pops and the phases run on those ticks and no
+    others."""
     cfg = STAT.with_(pbft_delay_lo=3, pbft_delay_hi=3, pbft_view_change_num=0,
                      model_serialization=False, sim_ms=330)
     lo, hi = cfg.one_way_range()
     assert hi - lo == 1 and cfg.roundtrip_range() == (2 * lo, 2 * lo + 1)
-    ticks = []
-    phases = pbft._phases
+    ticks, pops = [], []
+    phases, ring_pop = pbft._phases, pbft.ring_pop
 
     def counted(cfg_, state, bufs, popped, t, *args, **kwargs):
         jax.debug.callback(lambda t: ticks.append(int(t)), t)
         return phases(cfg_, state, bufs, popped, t, *args, **kwargs)
 
+    def counted_pop(buf, t):
+        jax.debug.callback(lambda t: pops.append(int(t)), t)
+        return ring_pop(buf, t)
+
     with monkeypatch.context() as m:
         m.setattr(pbft, "_phases", counted)
+        m.setattr(pbft, "ring_pop", counted_pop)
         (state, _), _ = jax.block_until_ready(_run(cfg, [1], [0]))
     jax.effects_barrier()
     bt = cfg.pbft_block_interval_ms
     want = sorted(b + off for b in range(bt, cfg.ticks, bt)
                   for off in (0, lo, 3 * lo, 4 * lo) if b + off < cfg.ticks)
     assert sorted(ticks) == want
+    assert sorted(pops) == sorted(want * 4)
     assert len(want) == 4 * ((cfg.ticks - 1) // bt)
     assert sim_metrics(cfg, state)["blocks_final_all_nodes"] == (cfg.ticks - 1) // bt
 
@@ -214,27 +223,27 @@ def test_active_ticks_equal_the_schedule(monkeypatch):
 # --------------------------------------------------------------- lowering
 
 
-def _whiles(lowered):
-    return lowered.as_text().count("stablehlo.while")
+def _whiles(traced):
+    return traced.lower().as_text().count("stablehlo.while")
 
 
 def _lone(cfg):
     canon = canonical_fault_cfg(cfg)
-    return jax.jit(lambda k: _scan(canon, k, jnp.int32(0))[0][0]).lower(
+    return jax.jit(lambda k: _scan(canon, k, jnp.int32(0))[0][0]).trace(
         jax.random.key(0))
 
 
 def _lanes(cfg):
     canon = canonical_fault_cfg(cfg)
     return jax.jit(base.lane_vmap(
-        lambda k: _scan(canon, k, jnp.int32(0))[0][0])).lower(
+        lambda k: _scan(canon, k, jnp.int32(0))[0][0])).trace(
             jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32)))
 
 
 def _select(cfg):
     canon = canonical_fault_cfg(cfg)
     return jax.jit(base.select_vmap(
-        lambda k: _scan(canon, k, jnp.int32(0))[0][0])).lower(
+        lambda k: _scan(canon, k, jnp.int32(0))[0][0])).trace(
             jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32)))
 
 
@@ -242,7 +251,7 @@ def _sharded(cfg):
     if len(jax.devices()) < 2:
         pytest.skip("needs two devices")
     mesh = make_mesh(n_node_shards=2, devices=jax.devices()[:2])
-    return shard.make_sharded_sim_fn.__wrapped__(cfg, mesh).lower(
+    return shard.make_sharded_sim_fn.__wrapped__(cfg, mesh).trace(
         jax.random.key(0))
 
 
@@ -256,19 +265,36 @@ def test_one_new_while_where_the_program_can_branch_and_none_elsewhere(
         _ungated(monkeypatch, lambda: _whiles(program(cfg))) + new
 
 
+@pytest.mark.parametrize("program,gated", [
+    (_lone, True), (_lanes, True), (_select, False)])
+def test_no_ring_is_updated_at_the_scan_bodys_level_where_the_program_can_branch(
+        program, gated):
+    """Pops and pushes alike stand inside the gate's ``while`` (the pushes
+    in loops of their own within it); the program that cannot branch pops
+    and pushes at the body's own level."""
+    cfg = EDGE.with_(sim_ms=210)
+    selects, outside, inside = _structure(program(cfg).jaxpr, _ring_shapes(cfg))
+    assert selects == []
+    if gated:
+        assert outside == 0 and inside > 4
+    else:
+        assert outside > 4 and inside == 0
+
+
 def test_taken_scope_wraps_the_phases_and_leaves_them_outermost():
-    """Every phase but the pops' own ring work sits under the taken trip's
-    scope, which is outside the ``pbft.`` / ``ops.`` families: a reader that
-    takes the first such scope of a path still reads the phase."""
-    text = _lone(EDGE.with_(sim_ms=210)).as_text(debug_info=True)
+    """Every phase, the pops' own ring work too, sits under the taken trip's
+    scope and nowhere else; that scope is outside the ``pbft.`` / ``ops.``
+    families: a reader that takes the first such scope of a path still reads
+    the phase."""
+    text = _lone(EDGE.with_(sim_ms=210)).lower().as_text(debug_info=True)
     taken = pbft.TAKEN_SCOPE
     assert not taken.startswith(("pbft.", "ops."))
     for phase in pbft.SCOPES:
         if phase in (taken, "pbft.tick.forge"):
             continue
         assert f"{taken}/{phase}/" in text, phase
-    assert "pbft.tick.pop/ops.ring.ring_pop/" in text
-    assert f"{taken}/pbft.tick.pop/ops.ring.ring_pop" not in text
+    pops = "pbft.tick.pop/ops.ring.ring_pop/"
+    assert text.count(f"{taken}/{pops}") == text.count(pops) > 0
 
 
 def test_a_checkpoint_without_the_due_leaf_is_refused(tmp_path):

@@ -12,13 +12,16 @@ spawn inherit them through ``os.environ``.
 """
 
 import os
+import pickle
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
+import filelock  # noqa: E402
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 assert jax.default_backend() == "cpu"
 
@@ -29,8 +32,41 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: heavy end-to-end tests (bench subprocess pairs) excluded "
-        "from the tier-1 870 s window via -m 'not slow'",
+        "from tier-1 (the 1,470 s command of /root/TESTS_LAST_RUN.json) via "
+        "-m 'not slow'",
     )
+
+
+@pytest.fixture(scope="session")
+def shared(tmp_path_factory):
+    """``shared(name, build)``: what ``build()`` returns, built ONCE A RUN OF
+    THE SUITE.  ``--dist load`` deals a module's tests to every worker, so a
+    module fixture or a ``functools.cache`` is built once a worker; here the
+    first worker to ask builds under a lock file in the run's common temp
+    directory and pickles the value, and the others load it (pytest-xdist's
+    documented pattern for a fixture that must run once), with this
+    process's own dict in front.  For plain values that several tests assert
+    on and that cost a compile or a long run: metrics dicts, rows, lowered
+    texts; every call hands out a copy of its own.  Never a device array or a
+    jitted function.  A worker that asks while another builds waits for it:
+    where all of them would ask at once, build in parts and start each
+    worker on another part (tests/test_zztelemetry.py).  A ``build`` that
+    raises leaves nothing behind and the next to ask builds again."""
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent  # the workers' base temps are its children
+    mine = {}
+
+    def get(name: str, build):
+        if name not in mine:
+            path = base / f"shared-{name}.pkl"
+            with filelock.FileLock(f"{path}.lock"):
+                if not path.exists():
+                    path.write_bytes(pickle.dumps(build()))
+                mine[name] = path.read_bytes()
+        return pickle.loads(mine[name])
+
+    return get
 
 
 def _mapped_regions() -> int:

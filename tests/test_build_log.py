@@ -96,16 +96,23 @@ def test_inner_trace_has_its_parent_and_stays_out_of_the_sums():
 def test_a_call_with_tracers_whose_trace_jax_had_leaves_nothing():
     """jax reports a trace on every call of a jitted function with tracers,
     even when its own cache answers it: a served request's
-    ``jax.random.key`` of a seed array.  No record per dispatch."""
-    keys = jax.vmap(jax.random.key)
+    ``jax.random.key`` of a seed array.  No record per dispatch.  Counted
+    on a function of this test's own name: the registry's sums are the
+    process's, and another thread's build would move them."""
+    @jax.jit
+    def blog_keyed(seed):
+        return jax.random.key(seed)
+
+    keys = jax.vmap(blog_keyed)
     seeds = jnp.arange(4)
     keys(seeds)  # the first one traces, lowers and compiles
     keys(seeds + 1)
-    n = aotcache.registry.stats_snapshot()["builds"]["n"]
+    n = len(records_of("blog_keyed"))
     told = []
 
-    def listener(event, *a, **kw):
-        told.append(event)
+    def listener(event, *a, fun_name=None, **kw):
+        if fun_name == "blog_keyed":
+            told.append(event)
 
     jax.monitoring.register_event_time_span_listener(listener)
     try:
@@ -114,7 +121,7 @@ def test_a_call_with_tracers_whose_trace_jax_had_leaves_nothing():
     finally:
         jax.monitoring.unregister_event_time_span_listener(listener)
     assert told.count("/jax/core/compile/jaxpr_trace_duration") >= 5
-    assert aotcache.registry.stats_snapshot()["builds"]["n"] == n
+    assert len(records_of("blog_keyed")) == n
 
 
 def test_a_root_hangs_under_the_threads_span_and_is_still_a_root():

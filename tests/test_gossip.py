@@ -32,16 +32,19 @@ def test_graph_diameter_covers_hop_budget():
         assert flood_reach_hops(GCFG.n, GCFG.degree, nbrs, src) <= GCFG.gossip_hops
 
 
-def test_gossip_paxos_converges():
-    m = run_simulation(GCFG)
+def test_gossip_paxos_converges(shared):
+    m = shared("gossip.paxos", lambda: run_simulation(GCFG))
     assert m["n_committed_proposers"] >= 1
     assert m["agreement_ok"]
     # the flood reached every acceptor: all 256 executed the decided command
     assert m["acceptor_executes"] == GCFG.n
 
 
-def test_gossip_determinism():
-    assert run_simulation(GCFG) == run_simulation(GCFG)
+def test_gossip_determinism(shared):
+    # one fresh run against the run of the suite's (tests/conftest.py
+    # ``shared``), which another process may have made
+    assert run_simulation(GCFG) == shared(
+        "gossip.paxos", lambda: run_simulation(GCFG))
 
 
 def test_gossip_with_crashed_relays():
@@ -99,6 +102,8 @@ PBFT_GCFG = SimConfig(
 
 
 def test_gossip_pbft_converges():
+    # the whole log: the one test that needs all 3,000 ticks of this
+    # configuration (52 s of execution on XLA:CPU)
     m = run_simulation(PBFT_GCFG)
     assert m["rounds_sent"] == 40
     assert m["blocks_final_all_nodes"] == 40
@@ -116,7 +121,14 @@ def test_gossip_pbft_no_serialization_is_fast():
 
 
 def test_gossip_pbft_determinism():
-    assert run_simulation(PBFT_GCFG) == run_simulation(PBFT_GCFG)
+    # 900 ms: the first blocks with their floods, votes and finality (a
+    # block takes 250-900 ms here).  Two runs that draw alike that far draw
+    # alike to the end (one scan, one key schedule), and a tick costs 17 ms
+    # on XLA:CPU
+    cfg = PBFT_GCFG.with_(sim_ms=900)
+    first = run_simulation(cfg)
+    assert first["blocks_final_all_nodes"] >= 3
+    assert run_simulation(cfg) == first
 
 
 def test_gossip_pbft_crashed_relays():
@@ -160,8 +172,8 @@ RAFT_GCFG = SimConfig(
 )
 
 
-def test_gossip_raft_elects_and_replicates():
-    m = run_simulation(RAFT_GCFG)
+def test_gossip_raft_elects_and_replicates(shared):
+    m = shared("gossip.raft", lambda: run_simulation(RAFT_GCFG))
     assert m["n_leaders"] == 1
     # multi-hop ack latency shifts commit times but replication completes:
     # 50 rounds proposed, commits within a couple of rounds of the full mesh
@@ -170,8 +182,8 @@ def test_gossip_raft_elects_and_replicates():
     assert m["agreement_ok"]
 
 
-def test_gossip_raft_milestones_match_full_mesh():
-    mg = run_simulation(RAFT_GCFG)
+def test_gossip_raft_milestones_match_full_mesh(shared):
+    mg = shared("gossip.raft", lambda: run_simulation(RAFT_GCFG))
     mf = run_simulation(RAFT_GCFG.with_(topology="full"))
     assert mg["n_leaders"] == mf["n_leaders"] == 1
     assert mg["rounds"] == mf["rounds"] == 50

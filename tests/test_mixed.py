@@ -84,9 +84,17 @@ STAT = CFG.with_(delivery="stat", model_serialization=False)
 REHEARSAL = STAT.with_(n=2048, sim_ms=1500)
 
 
+def fast_and_tick(shared, name, cfg):
+    """(fast path's metrics, tick engine's) of ``cfg``, one pair of compiles
+    a run of the suite (tests/conftest.py ``shared``; the fast path alone
+    compiles for over a minute on XLA:CPU and runs in a third of a second)."""
+    return shared(f"mixed.{name}", lambda: (
+        run_simulation(cfg), run_simulation(cfg.with_(schedule="tick"))))
+
+
 @pytest.mark.parametrize("cfg,final", [(STAT, 40), (REHEARSAL, 26)],
                          ids=["8x6", "8x256"])
-def test_mixed_fast_path_matches_tick_engine(cfg, final):
+def test_mixed_fast_path_matches_tick_engine(shared, request, cfg, final):
     # stat delivery makes the raft shards heartbeat-schedulable: schedule
     # 'auto' resolves to the fast path (mixed.scan_fast), whose metrics must
     # equal the per-tick engine's exactly — the PBFT layer steps with
@@ -99,8 +107,7 @@ def test_mixed_fast_path_matches_tick_engine(cfg, final):
 
     assert use_round_schedule(cfg)
     assert not use_round_schedule(CFG)  # edge delivery stays per-tick
-    m_fast = run_simulation(cfg)
-    m_tick = run_simulation(cfg.with_(schedule="tick"))
+    m_fast, m_tick = fast_and_tick(shared, request.node.callspec.id, cfg)
     # raft commit TICKS carry the +/-1 bucket-quantile jitter of the two
     # engines' independent draws (raft_hb's milestone contract); every
     # other key is equal
@@ -130,23 +137,33 @@ def test_mixed_fast_path_crash_majority_falls_back():
 
 
 def test_mixed_fast_path_explicit_round_gates():
+    import jax
     import pytest as _pytest
 
     from blockchain_simulator_tpu.runner import make_sim_fn
 
     with _pytest.raises(ValueError, match="mixed"):
         make_sim_fn(CFG.with_(schedule="round"))  # edge delivery: ineligible
-    assert run_simulation(STAT.with_(schedule="round")) == run_simulation(STAT)
+
+    # the explicit schedule IS the program 'auto' resolves to, lowered text
+    # for lowered text (so every metric of it is that run's: the test above
+    # holds them), and not the tick engine's; a lowering is seconds where
+    # the compile it used to take is over a minute
+    def text(schedule):
+        return jax.jit(make_sim_fn(STAT.with_(schedule=schedule))).lower(
+            jax.random.key(0)).as_text()
+
+    assert text("round") == text("auto") != text("tick")
 
 
-def test_mixed_fast_path_sharded_matches_unsharded():
+def test_mixed_fast_path_sharded_matches_unsharded(shared):
     from blockchain_simulator_tpu.parallel.mesh import make_mesh
     from blockchain_simulator_tpu.parallel.shard import run_sharded
 
     # per-shard steady-scan keys fold the GLOBAL shard id, so the sharded
     # fast path is bit-identical to the single-device fast path
     m8 = run_sharded(STAT, make_mesh(n_node_shards=8))
-    assert m8 == run_simulation(STAT)
+    assert m8 == fast_and_tick(shared, "8x6", STAT)[0]
 
 
 def test_mixed_sharded_matches_unsharded():

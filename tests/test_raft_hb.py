@@ -23,9 +23,9 @@ CONSENSUS = ("n_leaders", "leader", "leader_elected_ms", "blocks", "rounds",
              "agreement_ok")
 
 
-def both(kw):
-    tick = run_simulation(SimConfig(**kw, schedule="tick"))
-    hb = run_simulation(SimConfig(**kw, schedule="round"))
+def both(kw, seed=None):
+    tick = run_simulation(SimConfig(**kw, schedule="tick"), seed=seed)
+    hb = run_simulation(SimConfig(**kw, schedule="round"), seed=seed)
     return tick, hb
 
 
@@ -89,9 +89,10 @@ def test_byzantine_majority_falls_back_to_tick_engine():
 
 
 def test_milestones_match_across_seeds():
+    # the seed is the key operand of ONE pair of programs (the default
+    # run's), not a field of three more configurations to compile
     for seed in (3, 11, 42):
-        kw = dict(**BASE, seed=seed)
-        tick, hb = both(kw)
+        tick, hb = both(BASE, seed=seed)
         for k in CONSENSUS + ("elections",):
             assert hb[k] == tick[k], (seed, k)
 
@@ -110,16 +111,23 @@ def test_small_proposal_delay_falls_back_disarm_regression():
     assert tick["blocks"] == 49  # proposals really ran (not the 1-block bug)
 
 
-def test_round_schedule_vmaps_in_seed_sweeps():
+ROUND_4S = SimConfig(**{**BASE, "sim_ms": 4000}, schedule="round")
+
+
+def _solo_round_4s(shared):
+    """Seeds 0, 1, 2 of the unsharded fast path, one run of the suite
+    (tests/conftest.py ``shared``): the sweep and the sharded test below
+    both compare against them, each from the worker it lands on."""
+    return shared("raft_hb.round-4s", lambda: [
+        run_simulation(ROUND_4S, seed=s) for s in (0, 1, 2)])
+
+
+def test_round_schedule_vmaps_in_seed_sweeps(shared):
     # the traced handoff (lax.cond) must lower under vmap: a batched
     # round-schedule sweep returns exactly the per-seed single runs
     from blockchain_simulator_tpu.parallel.sweep import run_seed_sweep
 
-    cfg = SimConfig(**{**BASE, "sim_ms": 4000}, schedule="round")
-    seeds = [0, 1, 2]
-    batched = run_seed_sweep(cfg, seeds)
-    for s, m in zip(seeds, batched):
-        assert m == run_simulation(cfg, seed=s), s
+    assert run_seed_sweep(ROUND_4S, [0, 1, 2]) == _solo_round_4s(shared)
 
 
 def test_schedule_resolution_and_gates():
@@ -138,7 +146,7 @@ def test_schedule_resolution_and_gates():
                               faults=FaultConfig(drop_prob=0.01)))
 
 
-def test_sharded_round_schedule_matches_sharded_tick_and_unsharded():
+def test_sharded_round_schedule_matches_sharded_tick_and_unsharded(shared):
     """The heartbeat fast path under shard_map (the handoff reductions ride
     psum/pmax; the steady scan is replicated O(1) work).  Contract: the
     sharded round schedule must reproduce the sharded tick engine's
@@ -149,10 +157,11 @@ def test_sharded_round_schedule_matches_sharded_tick_and_unsharded():
     from blockchain_simulator_tpu.parallel.mesh import make_mesh
     from blockchain_simulator_tpu.parallel.shard import run_sharded
 
-    cfg = SimConfig(**{**BASE, "sim_ms": 4000}, schedule="round")
+    cfg = ROUND_4S
     mesh = make_mesh(n_node_shards=4)
     m_round = run_sharded(cfg, mesh)
     m_tick = run_sharded(cfg.with_(schedule="tick"), mesh)
     for k in CONSENSUS + ("elections",):
         assert m_round[k] == m_tick[k], k
-    assert m_round == run_simulation(cfg)  # bit-equal to the unsharded fast path
+    # bit-equal to the unsharded fast path
+    assert m_round == _solo_round_4s(shared)[cfg.seed]

@@ -64,25 +64,34 @@ def engine():
 
 
 @pytest.fixture(scope="module")
-def program_rows():
-    """All eight levels as lanes of the one dynamic-operand executable."""
-    cfg = cfg_of()
-    canon = canonical_fault_cfg(cfg)
-    keys = jax.vmap(jax.random.key)(jnp.full((LEVELS,), SEED % 2**32, jnp.uint32))
-    finals = sweep.dyn_batched_fn(canon)(
-        keys, jnp.zeros((LEVELS,), jnp.int32), jnp.asarray(F_VALUES, jnp.int32))
-    rows = []
-    for i, f in enumerate(F_VALUES):
-        cfg_i = cfg.with_(faults=dataclasses.replace(cfg.faults, n_byzantine=f))
-        rows.append(sim_metrics(cfg_i, jax.tree.map(lambda x: x[i], finals)))
-    return rows
+def program_rows(shared):
+    """All eight levels as lanes of the one dynamic-operand executable (one
+    dispatch a run of the suite: tests/conftest.py ``shared``)."""
+    def build():
+        cfg = cfg_of()
+        canon = canonical_fault_cfg(cfg)
+        keys = jax.vmap(jax.random.key)(
+            jnp.full((LEVELS,), SEED % 2**32, jnp.uint32))
+        finals = sweep.dyn_batched_fn(canon)(
+            keys, jnp.zeros((LEVELS,), jnp.int32),
+            jnp.asarray(F_VALUES, jnp.int32))
+        rows = []
+        for i, f in enumerate(F_VALUES):
+            cfg_i = cfg.with_(
+                faults=dataclasses.replace(cfg.faults, n_byzantine=f))
+            rows.append(
+                sim_metrics(cfg_i, jax.tree.map(lambda x: x[i], finals)))
+        return rows
+
+    return shared("zzbyz_sweep.program_rows", build)
 
 
 @pytest.fixture(scope="module")
-def reference_rows(engine):
-    return [engine.run({**FIELDS, "faults": {**FIELDS["faults"],
-                                             "n_byzantine": f}}, SEED + k)
-            for k, f in enumerate(F_VALUES)]
+def reference_rows(engine, shared):
+    return shared("zzbyz_sweep.reference_rows", lambda: [
+        engine.run({**FIELDS, "faults": {**FIELDS["faults"],
+                                         "n_byzantine": f}}, SEED + k)
+        for k, f in enumerate(F_VALUES)])
 
 
 @pytest.mark.parametrize("k", range(LEVELS))
@@ -155,6 +164,8 @@ def points_of(cfg: SimConfig, seed: int = 5) -> list:
 
 @pytest.fixture(scope="module")
 def one_dispatch():
+    # a worker's own: it also builds the program the tiled sweeps then run,
+    # whose ``sweep.tile`` spans must hold no ``build.*`` span
     cfg = sweep_cfg()
     with telemetry.capture() as spans:
         rows = sweep.run_byzantine_sweep(cfg, grid(cfg.n), seeds=(5,))

@@ -71,18 +71,24 @@ def bench():
 
 
 @pytest.fixture(scope="module", params=sorted(SHAPES))
-def held(request, bench):
+def held(request, bench, shared):
     """One shape's comparisons, by name: the program's runs over ``SEEDS``
-    at upstream's defaults against the reference's undisturbed run."""
-    c, m = SHAPES[request.param]
-    fields = {**bench["ctx"]["config"]["fields"], "n": c * m, "committees": c,
-              "sim_ms": 600}
-    cfg = bench["program"].sim_config(fields)
-    rows = [runner.run_simulation(cfg, seed=s) for s in SEEDS]
-    cc, config = bench["committee_checks"], bench["ctx"]["config"]
-    ref = cc.reference_milestones(config, fields, SEEDS[0])
-    out = cc.guarantees(rows, fields) + cc.against_reference(rows, ref, config)
-    return {"rows": rows, "ref": ref, "checks": {r["name"]: r for r in out}}
+    at upstream's defaults against the reference's undisturbed run; made
+    once a run of the suite (tests/conftest.py ``shared``)."""
+    def build():
+        c, m = SHAPES[request.param]
+        fields = {**bench["ctx"]["config"]["fields"], "n": c * m,
+                  "committees": c, "sim_ms": 600}
+        cfg = bench["program"].sim_config(fields)
+        rows = [runner.run_simulation(cfg, seed=s) for s in SEEDS]
+        cc, config = bench["committee_checks"], bench["ctx"]["config"]
+        ref = cc.reference_milestones(config, fields, SEEDS[0])
+        out = cc.guarantees(rows, fields) + cc.against_reference(
+            rows, ref, config)
+        return {"rows": rows, "ref": ref,
+                "checks": {r["name"]: r for r in out}}
+
+    return shared(f"zzcommittee.held.{request.param}", build)
 
 
 @pytest.mark.parametrize("name", (
@@ -378,21 +384,25 @@ def test_per_committee_lists_are_the_flat_metrics(one_stack):
 
 
 @pytest.fixture(scope="module")
-def op_names():
+def op_names(shared):
     """The ``op_name`` paths of the compiled stack (what a profiler trace's
     event metadata carries), on a device that holds its three committees as
-    one tile."""
+    one tile; compiled once a run of the suite (tests/conftest.py
+    ``shared``)."""
     import re
 
-    cfg = SimConfig(protocol="pbft", n=24, topology="committee", committees=3,
-                    sim_ms=100)
-    state = sweep._lane_state_bytes(committee.inner_cfg(cfg))
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(sweep, "_device_bytes",
-                   lambda: int(3 * sweep._TEMP_FACTOR * state) + 1)
-        text = jax.jit(runner.make_sim_fn(cfg)).lower(
-            jax.random.key(0)).compile().as_text()
-    return set(re.findall(r'op_name="([^"]*)"', text))
+    def build():
+        cfg = SimConfig(protocol="pbft", n=24, topology="committee",
+                        committees=3, sim_ms=100)
+        state = sweep._lane_state_bytes(committee.inner_cfg(cfg))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(sweep, "_device_bytes",
+                       lambda: int(3 * sweep._TEMP_FACTOR * state) + 1)
+            text = jax.jit(runner.make_sim_fn(cfg)).lower(
+                jax.random.key(0)).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', text))
+
+    return shared("zzcommittee.op_names", build)
 
 
 @pytest.mark.parametrize("scope", committee.SCOPES)

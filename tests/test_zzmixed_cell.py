@@ -59,12 +59,13 @@ def bench():
 
 
 @pytest.fixture(scope="module")
-def reference(bench):
+def reference(bench, shared):
     """The plain reference's milestones at the rehearsal size."""
     ctx = bench["ctx"]
     try:
-        return bench["mixed_checks"].reference_milestones(
-            ctx["config"], ctx["reference_fields"], SEEDS[0])
+        return shared("zzmixed_cell.reference", lambda: bench[
+            "mixed_checks"].reference_milestones(
+                ctx["config"], ctx["reference_fields"], SEEDS[0]))
     except (OSError, RuntimeError) as e:
         pytest.skip(f"the reference cannot be compiled here: {e}")
 
@@ -95,8 +96,10 @@ def compare(bench, reference: dict, rows: list[dict]) -> dict:
 
 
 @pytest.fixture(scope="module")
-def sound_rows(bench):
-    return rows_of(bench, bench["ctx"]["fields"])
+def sound_rows(bench, shared):
+    # one compile of the rehearsal a run of the suite (tests/conftest.py)
+    return shared("zzmixed_cell.sound_rows",
+                  lambda: rows_of(bench, bench["ctx"]["fields"]))
 
 
 def test_sound_rows_are_correct_against_the_reference(bench, reference,

@@ -82,12 +82,22 @@ CASES = {
 }
 
 
+def both_of_case(shared, reference, case: str) -> dict:
+    """``{seed: both(...)}`` for every seed of ``CASES[case]``, made once a
+    run of the suite (tests/conftest.py ``shared``): a case's seeds share
+    one compiled program (tens of seconds; a run is a fraction of one), so
+    the process that builds it runs them all."""
+    fields, seeds = CASES[case]
+    return shared(f"zzmixed_reference.{case}", lambda: {
+        seed: both(reference, fields, seed) for seed in seeds})
+
+
 @pytest.mark.parametrize("case,seed", [
     pytest.param(name, seed, id=f"{name}-seed{seed}")
     for name, (_, seeds) in CASES.items() for seed in seeds])
-def test_milestones_equal_the_references(reference, case, seed):
+def test_milestones_equal_the_references(shared, reference, case, seed):
     fields = CASES[case][0]
-    m, ref = both(reference, fields, seed)
+    m, ref = both_of_case(shared, reference, case)[seed]
     for key in COUNTS:
         assert m[key] == ref[key], (key, m, ref)
     assert m["shards_with_leader"] == fields["mixed_shards"]
@@ -120,11 +130,14 @@ def test_crashed_majority_never_joins_the_quorum(reference):
 
 
 @pytest.mark.parametrize("seed", (1, 3))
-def test_crashed_minority_changes_nothing(reference, seed):
+def test_crashed_minority_changes_nothing(shared, reference, seed):
     """3 of 16 nodes crashed in every shard: every count is that of the
     uncrashed deployment, on both sides."""
-    m, ref = both(reference, {**STAT, "faults": {"n_crashed": 3}}, seed)
-    whole, _ = both(reference, STAT, seed)
+    crashed = shared("zzmixed_reference.crashed-minority", lambda: {
+        s: both(reference, {**STAT, "faults": {"n_crashed": 3}}, s)
+        for s in (1, 3)})
+    m, ref = crashed[seed]
+    whole, _ = both_of_case(shared, reference, "8x16-stat-fast")[seed]
     for key in COUNTS:
         assert m[key] == ref[key] == whole[key], (key, m, ref, whole)
     for key, tol in TIMES.items():

@@ -67,8 +67,9 @@ def drive(bench, fields=None, seconds=2.0) -> dict:
 
 
 @pytest.fixture(scope="module")
-def sound(bench):
-    return drive(bench)
+def sound(bench, shared):
+    # one rehearsal a run of the suite (tests/conftest.py ``shared``)
+    return shared("zzpaxos_cell.sound", lambda: drive(bench))
 
 
 def test_rehearsal_is_correct(sound):

@@ -54,10 +54,11 @@ def engine(bench):
 
 
 @pytest.fixture(scope="module")
-def reference(bench):
+def reference(bench, shared):
     ctx = bench["ctx"]
-    return bench["paxos_checks"].reference_milestones(
-        ctx["config"], ctx["reference_fields"], SEEDS[0])
+    return shared("zzpaxos_reference.reference", lambda: bench[
+        "paxos_checks"].reference_milestones(
+            ctx["config"], ctx["reference_fields"], SEEDS[0]))
 
 
 def rows_of(bench, shards: int) -> list[dict]:
@@ -72,10 +73,12 @@ def rows_of(bench, shards: int) -> list[dict]:
 
 
 @pytest.fixture(scope="module", params=(1, 4), ids=("one-device", "mesh4"))
-def rows(request, bench):
+def rows(request, bench, shared):
     if len(jax.devices()) < request.param:
         pytest.skip(f"needs {request.param} devices")
-    return rows_of(bench, request.param)
+    # one compile a layout a run of the suite (tests/conftest.py ``shared``)
+    return shared(f"zzpaxos_reference.rows.{request.param}",
+                  lambda: rows_of(bench, request.param))
 
 
 def test_program_is_correct_against_the_reference(bench, reference, rows):

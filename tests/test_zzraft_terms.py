@@ -160,18 +160,22 @@ def test_elections_hold_against_the_reference(name, bench, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def whole(bench):
+def whole(bench, shared):
     """The deployment's whole run at 128 x 5: one row, the reference's
-    sample, and the cell's comparisons by name."""
-    rc = bench["raftgroups_checks"]
-    fields = _fields(bench, *WHOLE)
-    with pytest.MonkeyPatch.context() as mp:
-        rows = [_row(bench, fields, mp)]
-    config = _config(bench, "whole")
-    ref = rc.reference_groups(config, fields, SEED)
-    got = rc.guarantees(rows, ref) + rc.against_reference(rows, ref, config)
-    return {"rows": rows, "ref": ref, "fields": fields, "config": config,
-            "checks": _by_name(got)}
+    sample, and the cell's comparisons by name; made once a run of the
+    suite (tests/conftest.py ``shared``)."""
+    def build():
+        rc = bench["raftgroups_checks"]
+        fields = _fields(bench, *WHOLE)
+        with pytest.MonkeyPatch.context() as mp:
+            rows = [_row(bench, fields, mp)]
+        config = _config(bench, "whole")
+        ref = rc.reference_groups(config, fields, SEED)
+        got = rc.guarantees(rows, ref) + rc.against_reference(rows, ref, config)
+        return {"rows": rows, "ref": ref, "fields": fields, "config": config,
+                "checks": _by_name(got)}
+
+    return shared("zzraft_terms.whole", build)
 
 
 def test_whole_run_holds_against_the_reference(whole):

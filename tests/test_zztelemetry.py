@@ -560,7 +560,7 @@ def _all_scopes():
 
 
 @pytest.fixture(scope="module")
-def lowered_programs():
+def lowered_programs(shared):
     """The op_name metadata of the engines' lowered programs (nothing is
     compiled or run): the pbft tick engine on per-edge, stat and gossip
     delivery, the pbft round engine, and the raft and paxos tick engines
@@ -573,13 +573,20 @@ def lowered_programs():
     (both arms
     of its cond are lowered), which holds the raft and pbft tick engines
     under ``mixed.*``; before it, the pbft tick engine under the forging
-    attack."""
+    attack.
+
+    Each text is lowered once a run of the suite (tests/conftest.py
+    ``shared``).  ``--dist load`` hands this test's cases to every worker
+    at once, so each worker starts on another text and finds the others'
+    done when it comes to them."""
     import jax
     import jax.numpy as jnp
 
     from blockchain_simulator_tpu import runner
     from blockchain_simulator_tpu.ops import delay, delivery
-    from blockchain_simulator_tpu.utils.config import SimConfig
+    from blockchain_simulator_tpu.parallel import shard, sweep
+    from blockchain_simulator_tpu.parallel.mesh import make_mesh
+    from blockchain_simulator_tpu.utils.config import FaultConfig, SimConfig
 
     cfgs = [
         SimConfig(protocol="pbft", n=8, sim_ms=200),
@@ -596,51 +603,55 @@ def lowered_programs():
         SimConfig(protocol="paxos", n=16, sim_ms=200, topology="gossip",
                   degree=4, paxos_retry_timeout_ms=600),
     ]
-    texts = [jax.jit(runner.make_sim_fn(c)).lower(jax.random.key(0))
-             .as_text(debug_info=True) for c in cfgs]
+
+    def solo(cfg):
+        return lambda: jax.jit(runner.make_sim_fn(cfg)).lower(
+            jax.random.key(0)).as_text(debug_info=True)
+
+    def sharded(cfg):
+        mesh = make_mesh(n_node_shards=2, devices=jax.devices()[:2])
+        return lambda: shard.make_sharded_sim_fn.__wrapped__(cfg, mesh).lower(
+            jax.random.key(0)).as_text(debug_info=True)
+
     probs = delay.uniform_probs(3, 6)
-    texts.append(jax.jit(
-        lambda k, m: delivery.bcast_slots_stat(k, m, probs)
-    ).lower(jax.random.key(0), jnp.ones((8, 4), jnp.int32))
-        .as_text(debug_info=True))
     # the round engine took the stacked stat round trip until PR 45; it
     # takes the chain by rows now, and the op is the fused push's reference
     rt_probs = delay.roundtrip_probs(3, 6)
-    texts.append(jax.jit(
-        lambda k, m: delivery.roundtrip_reply_counts_stat(k, m, 7, rt_probs)
-    ).lower(jax.random.key(0), jnp.ones((8,), bool))
-        .as_text(debug_info=True))
-    from blockchain_simulator_tpu.parallel import sweep
-
-    texts.append(sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
-        jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
-    ).as_text(debug_info=True))
+    builds = [solo(c) for c in cfgs] + [
+        lambda: jax.jit(
+            lambda k, m: delivery.bcast_slots_stat(k, m, probs)
+        ).lower(jax.random.key(0), jnp.ones((8, 4), jnp.int32))
+        .as_text(debug_info=True),
+        lambda: jax.jit(
+            lambda k, m: delivery.roundtrip_reply_counts_stat(
+                k, m, 7, rt_probs)
+        ).lower(jax.random.key(0), jnp.ones((8,), bool))
+        .as_text(debug_info=True),
+        lambda: sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
+            jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
+        ).as_text(debug_info=True),
+    ]
     if len(jax.devices()) >= 2:
-        from blockchain_simulator_tpu.parallel import shard
-        from blockchain_simulator_tpu.parallel.mesh import make_mesh
-
-        mesh = make_mesh(n_node_shards=2, devices=jax.devices()[:2])
-        texts += [shard.make_sharded_sim_fn.__wrapped__(c, mesh).lower(
-            jax.random.key(0)).as_text(debug_info=True)
-            for c in (cfgs[-1], cfgs[-2])]
-    from blockchain_simulator_tpu.utils.config import FaultConfig
-
-    # Raft with terms (only a ``raft_terms`` program holds ``raft.tick.term``
-    # and the denial's value-max unicast)
-    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
-        protocol="raft", n=8, sim_ms=200, raft_terms=True,
-        model_serialization=False))).lower(
-            jax.random.key(0)).as_text(debug_info=True))
-    # the forging attack (only a ``byz_forge`` program holds its scope),
-    # second to last
-    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
-        protocol="pbft", n=8, sim_ms=200, delivery="stat", schedule="tick",
-        faults=FaultConfig(n_byzantine=1, byz_forge=True)))).lower(
-            jax.random.key(0)).as_text(debug_info=True))
-    texts.append(jax.jit(runner.make_sim_fn(SimConfig(
-        protocol="mixed", n=24, mixed_shards=4, sim_ms=400, delivery="stat",
-        model_serialization=False))).lower(jax.random.key(0))
-        .as_text(debug_info=True))
+        builds += [sharded(cfgs[-1]), sharded(cfgs[-2])]
+    builds += [
+        # Raft with terms (only a ``raft_terms`` program holds
+        # ``raft.tick.term`` and the denial's value-max unicast)
+        solo(SimConfig(protocol="raft", n=8, sim_ms=200, raft_terms=True,
+                       model_serialization=False)),
+        # the forging attack (only a ``byz_forge`` program holds its scope),
+        # second to last
+        solo(SimConfig(protocol="pbft", n=8, sim_ms=200, delivery="stat",
+                       schedule="tick",
+                       faults=FaultConfig(n_byzantine=1, byz_forge=True))),
+        solo(SimConfig(protocol="mixed", n=24, mixed_shards=4, sim_ms=400,
+                       delivery="stat", model_serialization=False)),
+    ]
+    worker = int((os.environ.get("PYTEST_XDIST_WORKER") or "gw0")[2:])
+    workers = int(os.environ.get("PYTEST_XDIST_WORKER_COUNT") or 1)
+    n = len(builds)
+    texts = [None] * n
+    for i in ((worker * n // workers + k) % n for k in range(n)):
+        texts[i] = shared(f"zztelemetry.lowered.{i}", builds[i])
     return texts
 
 

@@ -186,6 +186,10 @@ def cpp_config(cfg, seed: int | None = None) -> _CppCfg:
 def run_cpp(cfg, seed: int | None = None) -> dict:
     """Run one simulation on the C++ engine; returns the metrics dict
     (same keys as the matching JAX backend's ``metrics()``)."""
+    if cfg.faults.crashes:  # refused in the one place that lists the arms
+        from blockchain_simulator_tpu.models import raft
+
+        raft.check_schedule(cfg, engine="cpp")
     if cfg.raft_terms:
         raise NotImplementedError(
             "raft_terms is not implemented by the C++ engine (engine.cpp is "

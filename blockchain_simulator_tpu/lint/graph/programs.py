@@ -259,6 +259,18 @@ def audit_configs() -> dict[str, "object"]:
                                      model_serialization=False,
                                      topology="committee", committees=2,
                                      stat_sampler="exact"),
+        # a crash schedule over it (FaultConfig.crashes: the fault phase at
+        # the head of the tick, the per-crash records at its end)
+        "raft_crash": SimConfig(protocol="raft", n=8, sim_ms=200,
+                                raft_terms=True, model_serialization=False,
+                                stat_sampler="exact",
+                                faults=_crash_schedule()),
+        "raft_crash_comm": SimConfig(protocol="raft", n=10, sim_ms=200,
+                                     raft_terms=True,
+                                     model_serialization=False,
+                                     topology="committee", committees=2,
+                                     stat_sampler="exact",
+                                     faults=_crash_schedule()),
         # fast paths, explicitly scheduled (eligibility asserted in tests)
         "pbft_round": SimConfig(protocol="pbft", n=8, sim_ms=200,
                                 delivery="stat", schedule="round",
@@ -271,6 +283,12 @@ def audit_configs() -> dict[str, "object"]:
                                 sim_ms=400, delivery="stat",
                                 schedule="round", stat_sampler="exact"),
     }
+
+
+def _crash_schedule():
+    from blockchain_simulator_tpu.utils.config import FaultConfig
+
+    return FaultConfig(crashes=2, first_ms=40, period_ms=80, downtime_ms=30)
 
 
 def _audit_mesh():
@@ -308,7 +326,7 @@ def build_catalog() -> list[ProgramSpec]:
                 # accumulators (tests/test_zztopo.py counts them)
                 "pbft_kreg", "pbft_kreg_stat", "raft_kreg",
                 "raft_kreg_stat", "paxos_kreg", "pbft_comm", "raft_terms",
-                "raft_terms_comm"):
+                "raft_terms_comm", "raft_crash", "raft_crash_comm"):
         specs.append(sim_spec(arm))
 
     # --- runner.make_segment_fn ("segment") -----------------------------

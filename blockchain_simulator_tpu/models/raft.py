@@ -106,6 +106,49 @@ heartbeat, so its followers time out and elect in a higher term: legal
 Raft).  ``benchmark/reference/raft_terms_engine.py`` is the per-message
 twin, a term on every message.
 
+Crash schedule (``cfg.faults.crashes`` > 0; only with terms, so per-edge,
+full mesh, clean — flat or inside a committee stack; :func:`check_schedule`
+refuses every other arm by name): faults that happen during the run.  A
+group draws one ``phase`` from U{0..period_ms-1} off its init key
+(``Channel.FAULT``: part of the state, as the election deadlines are, and no
+other stream moves).  Crash k, k = 0..crashes-1, falls at the head of tick
+``first_ms + k * period_ms + phase`` (scope ``raft.tick.fault``, before
+anything is popped) and hits the node that is an alive leader then (the
+leader of the highest term, lowest id, should there be two); where none
+leads it is recorded as having found no leader and kills nobody.  Raft's
+crash, on upstream's message set:
+
+- from its crash to its restart a node sends nothing, handles nothing and
+  fires no timer; what it sent before the crash still arrives; what arrives
+  for it while it is down is lost (the rings' slots are popped and masked).
+- ``term`` and the vote of that term (``has_voted``) survive: Raft persists
+  them.  ``is_leader``, ``is_cand``, the vote counts, the heartbeat and
+  proposal schedules and the ack window do not.
+- ``downtime_ms`` later it is back, a follower with an election deadline
+  ``now + U[lo, hi)`` off the channel a deposed leader's re-arm uses.
+- the majority stays ``N // 2 + 1`` whoever is down.
+
+Records beside PR 42's ``last_hb``, a group's own ``[crashes]`` leaves:
+``crash_tick`` (-1 until it falls), ``crash_node`` (-1: found no leader),
+``replaced_tick`` (the tick on which a node of the group next wins an
+election, necessarily in a higher term; only the newest crash can be
+replaced, so one that is still open when the next falls stays unreplaced)
+and ``crash_elections`` (timers that fired from the crash up to that win:
+1 where the first election succeeds).  Two oracles that stay 0:
+``dead_acts`` (a node that is down fired a timer, won, took a term or a
+heartbeat, or voted) and ``double_votes`` (a node voted twice in one term,
+against its own record ``voted_term``, which no restart touches).
+Departures, each also ``benchmark/reference/raft_crash_engine.py``'s but the
+last: upstream has no crash at all; there is no log, so the paper's
+"candidates with shorter logs are not eligible" (section 9.3) has nothing
+to compare and every alive server is eligible; the paper forces a heartbeat
+before each kill, the schedule does not (a uniform ``phase`` against a
+group's own heartbeat phase is the paper's "uniformly within the heartbeat
+interval"); and, the program's alone, a proposal's acks are decided where
+it is sent (the short-circuited round trip), so a follower that is down at
+the send and back before the arrival acks in the reference and not here:
+with three followers alive no commit hinges on it.
+
 Gossip topology (``topology="gossip"``, clean + stat only): the three
 broadcast channels — VOTE_REQ, plain HEARTBEAT, proposal HEARTBEAT — flood
 over a random k-out digraph with a hop TTL (time-monotone value encodings,
@@ -150,14 +193,20 @@ DISARM = np.int32(1 << 30)
 # families, as models/pbft.TAKEN_SCOPE is, so the phase stays the outermost
 # program scope of what runs inside
 COMMIT_SCOPE = "gate.raft.commit_taken"
+# the taken trip of the fault gate inside ``raft.tick.fault``: the ticks on
+# which some group's crash or some node's restart was due
+FAULT_SCOPE = "gate.raft.fault_taken"
 
 # the phases of :func:`step` as ``jax.named_scope`` names, after its own
 # section comments (HLO metadata only — see models/pbft.SCOPES); ops/ scopes
 # nest inside, and under models/mixed.py these sit below ``mixed.tick.*``.
 # ``raft.tick.term`` is what terms add (``cfg.raft_terms``: the step to a
 # higher term and down from a role, the oracle); a program without terms
-# has no operation under it
+# has no operation under it; ``raft.tick.fault`` is what a crash schedule
+# adds (``cfg.faults.crashes``: the kill and the restart at the head of the
+# tick, the per-crash records and the two oracles at its end), likewise
 SCOPES = (
+    "raft.tick.fault",
     "raft.tick.pop",
     "raft.tick.term",
     "raft.tick.heartbeat_rx",
@@ -167,6 +216,7 @@ SCOPES = (
     "raft.tick.timer_vote",
     "raft.tick.timer_heartbeat",
     COMMIT_SCOPE,
+    FAULT_SCOPE,
 )
 
 
@@ -242,9 +292,26 @@ class RaftState:
     term_conflicts: jax.Array | None = None  # [N] the election-safety oracle
 
 
+    # A crash schedule (``cfg.faults.crashes`` = K; module docstring "Crash
+    # schedule").  None without: no schedule, no leaf
+    crash_phase: jax.Array | None = None    # () the group's draw, U{0..P-1}
+    crash_tick: jax.Array | None = None     # [K] tick crash k fell (-1)
+    crash_node: jax.Array | None = None     # [K] node it killed (-1 = none)
+    replaced_tick: jax.Array | None = None  # [K] tick of the next win (-1)
+    crash_elections: jax.Array | None = None  # [K] timers fired until then
+    restart_tick: jax.Array | None = None   # [N] when a dead node is back
+    restarts: jax.Array | None = None       # [N] times it came back
+    voted_term: jax.Array | None = None     # [N] last term it voted in
+    dead_acts: jax.Array | None = None      # [N] oracle: acted while down
+    double_votes: jax.Array | None = None   # [N] oracle: two votes a term
+
+
 # the leaves above, in order
 TERM_FIELDS = ("term", "is_cand", "lead_term0", "won_tick", "last_hb",
                "step_downs", "term_conflicts")
+CRASH_FIELDS = ("crash_phase", "crash_tick", "crash_node", "replaced_tick",
+                "crash_elections", "restart_tick", "restarts", "voted_term",
+                "dead_acts", "double_votes")
 
 
 @struct.dataclass
@@ -263,6 +330,7 @@ class RaftBufs:
 
 
 def init(cfg, key=None):
+    check_arms(cfg)
     n, d = cfg.n, cfg.ring_depth
     b = cfg.raft_max_blocks
     alive, honest = fault_masks(cfg, n)
@@ -308,12 +376,25 @@ def init(cfg, key=None):
         link_busy=zi(n),
     )
     if cfg.raft_terms:
-        check_terms(cfg)
         state = state.replace(
             term=zi(n), is_cand=zb(n), lead_term0=zi(n),
             won_tick=jnp.full((n,), -1, jnp.int32),
             last_hb=jnp.full((n,), -1, jnp.int32),
             step_downs=zi(n), term_conflicts=zi(n),
+        )
+    if cfg.faults.crashes:
+        kk = cfg.faults.crashes
+        state = state.replace(
+            crash_phase=jax.random.randint(
+                jax.random.fold_in(k, Channel.FAULT), (), 0,
+                cfg.faults.period_ms, dtype=jnp.int32),
+            crash_tick=jnp.full((kk,), -1, jnp.int32),
+            crash_node=jnp.full((kk,), -1, jnp.int32),
+            replaced_tick=jnp.full((kk,), -1, jnp.int32),
+            crash_elections=zi(kk),
+            restart_tick=jnp.full((n,), DISARM),
+            restarts=zi(n), voted_term=zi(n), dead_acts=zi(n),
+            double_votes=zi(n),
         )
     if cfg.delivery == "stat":
         vreq = zi(d, n)
@@ -398,9 +479,9 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
          exchange=None):
     n = cfg.n
     axis = cfg.mesh_axis
-    terms = cfg.raft_terms
-    if terms:
-        check_terms(cfg)
+    terms, sched = cfg.raft_terms, cfg.faults.crashes > 0
+    if terms or sched:
+        check_arms(cfg)
     lo, hi = cfg.one_way_range()
     rt_lo, rt_hi = cfg.roundtrip_range()
     drop = cfg.faults.drop_prob
@@ -418,6 +499,18 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
     # queued-link transport (see RaftState.link_busy): with ser == 0 the pipe
     # is never busy and queued == constant-latency, so the plain path runs
     queued = cfg.queued_links and ser > 0
+
+    def rearm_on(channel):
+        """A fresh election deadline a node, on a stream of its own."""
+        return t + jax.random.randint(
+            chan_key(tkey, channel), (n_loc,),
+            cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
+            dtype=jnp.int32)
+
+    if sched:
+        with jax.named_scope("raft.tick.fault"):
+            state = _crash_and_restart(cfg, state, t, rearm_on)
+        term_in, voted_in = state.term, state.has_voted
 
     with jax.named_scope("raft.tick.pop"):
         # ---- pop arrivals; crashed nodes process nothing ------------------------
@@ -495,14 +588,6 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
                 jnp.maximum(vreq_t.max(axis=1), no_t))
             step_up = t_in > state.term
             deposed = step_up & state.is_leader
-
-            def rearm_on(channel):
-                """A fresh election deadline a node, on a stream of its own."""
-                return t + jax.random.randint(
-                    chan_key(tkey, channel), (n_loc,),
-                    cfg.raft_election_lo_ms, cfg.raft_election_hi_ms,
-                    dtype=jnp.int32)
-
             state = state.replace(
                 term=jnp.maximum(state.term, t_in),
                 step_downs=state.step_downs
@@ -704,8 +789,9 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             if terms:
                 # a grant re-arms the election timer (Figure 2, Followers);
                 # a denial carries the denier's term
+                granted = any_grantable & can_grant
                 election_deadline = jnp.where(
-                    any_grantable & can_grant,
+                    granted,
                     rearm_on(Channel.ELECTION + 300), election_deadline)
                 no_wire = no_wire * term[:, None]
             k_vr = chan_key(tkey, Channel.DELAY_REPLY)
@@ -1306,6 +1392,12 @@ def step(cfg, state: RaftState, bufs: RaftBufs, t, tkey, *, topo_tables=None,
             won_tick=won_tick, last_hb=last_hb, step_downs=step_downs,
             term_conflicts=term_conflicts,
         )
+    if sched:
+        with jax.named_scope("raft.tick.fault"):
+            state = _crash_records(
+                state, t, win=win, fire=fire, granted=granted,
+                acted=win | fire | hb_fire | got_hb | granted
+                | (term != term_in) | (has_voted & ~voted_in))
     bufs = RaftBufs(
         vreq=vreq, vres_ok=vres_ok, vres_no=vres_no, hb_plain=hb_plain,
         hb_prop=hb_prop, hb_ok=hb_ok, hb_bad=hb_bad,
@@ -1323,7 +1415,8 @@ def metrics(cfg, state: RaftState) -> dict:
     summary (:122-123), election starts (:399)."""
     if state.term is not None:  # with terms: a stack of one group
         return metrics_stacked(cfg, {
-            f: np.asarray(getattr(state, f))[None] for f in METRIC_FIELDS}, 1)[0]
+            f: np.asarray(getattr(state, f))[None] for f in METRIC_FIELDS
+            if getattr(state, f) is not None}, 1)[0]
     alive = np.asarray(state.alive)
     is_leader = np.asarray(state.is_leader)
     leader_tick = np.asarray(state.leader_tick)
@@ -1369,6 +1462,8 @@ METRIC_FIELDS = (
     "leader_tick", "m_value", "round",
     "term", "lead_term0", "won_tick", "last_hb", "step_downs",
     "term_conflicts",
+    "crash_tick", "crash_node", "replaced_tick", "crash_elections",
+    "restarts", "dead_acts", "double_votes",
 )
 
 
@@ -1395,7 +1490,20 @@ def metrics_stacked(cfg, host: dict, groups: int) -> list:
     from the first leader's last heartbeat of the term it first led to the
     next election anyone wins (-1.0 where none did).  ``agreement_ok``:
     there is no log to compare, so every value an alive node stored names a
-    node that won an election and proposed, and no term had two leaders."""
+    node that won an election and proposed, and no term had two leaders.
+
+    With a crash schedule (``cfg.faults.crashes`` = K; the keys above keep
+    their meaning, ``failover_ms`` included) the failover is reported as a
+    distribution over the group's crashes: ``crashes`` (that fell inside
+    the run), ``crashes_found_no_leader``, ``crashes_unreplaced`` (hit a
+    leader, no win before the next crash or the end), ``failovers`` (the
+    rest: a crashed leader replaced by a leader of a higher term),
+    ``failovers_multi_election`` (of those, more than one timer fired
+    first), ``failover_mean_ms`` / ``_median_ms`` / ``_p90_ms`` (nearest
+    rank) / ``_max_ms`` over the group's failovers (-1.0 where it has
+    none), per crash k ``crash{k}_failover_ms`` (-1.0 where it was not
+    replaced) and ``crash{k}_elections``, ``restarts``, and the oracles
+    ``dead_acts`` and ``double_votes`` (summed; 0)."""
     never = np.int64(1) << 40
     alive, term = host["alive"], host["term"]
     leader_tick, won_tick = host["leader_tick"], host["won_tick"]
@@ -1455,7 +1563,204 @@ def metrics_stacked(cfg, host: dict, groups: int) -> list:
         "first_leader_blocks": np.where(has_first, at(block_num, first), 0),
         "failover_ms": failover,
     }
+    if "crash_tick" in host:
+        columns.update(_crash_columns(host))
     keys = ("protocol", "n") + tuple(columns)
     return [dict(zip(keys, ("raft", cfg.n) + row))
             for row in zip(*(v[:groups].tolist() for v in columns.values()))]
 
+
+
+# ---- the crash schedule (``cfg.faults.crashes``; module docstring "Crash
+# schedule"): below the metrics, so that no line of :func:`step`'s callees
+# above moves but those the schedule's three call sites add
+
+
+def check_arms(cfg):
+    """What a state or a program of ``cfg`` needs of its arm: a crash
+    schedule's refusals first (they name every arm, whatever else is off),
+    then those of terms."""
+    check_schedule(cfg)
+    if cfg.raft_terms:
+        check_terms(cfg)
+
+
+def check_schedule(cfg, engine: str = "jax"):
+    """A crash schedule (``cfg.faults.crashes``) runs where terms do, and
+    every other arm refuses it by its name rather than running on with a
+    leader that never dies.  The one place the arms are listed:
+    :func:`init` and :func:`step` ask it, runner.py's validation before
+    anything is built (any protocol; ``topology`` may still be
+    ``"committee"``), and engine.run_cpp (``engine="cpp"``)."""
+    f = cfg.faults
+    if not f.crashes:
+        return
+    arms = (
+        (engine == "cpp", "the C++ engine (--engine cpp)",
+         "engine.cpp's nodes crash from t = 0 or never; the per-message "
+         "reference with a schedule is "
+         "benchmark/reference/raft_crash_engine.py"),
+        (cfg.protocol == "pbft", "protocol='pbft'",
+         "a view change under a dead leader is upstream's 1-in-100 coin "
+         "here (ROADMAP R1's remainder)"),
+        (cfg.protocol == "paxos", "protocol='paxos'",
+         "its proposers have no leader to kill"),
+        (cfg.protocol == "mixed", "protocol='mixed'",
+         "its shards run the stat arm of models/raft.py and the heartbeat-"
+         "blocked models/raft_hb, which have no terms"),
+        (cfg.fidelity != "clean", f"fidelity={cfg.fidelity!r}",
+         "upstream's Raft never re-arms an election timer, so a dead "
+         "leader is never detected"),
+        (cfg.topology not in ("full", "committee"),
+         f"topology={cfg.topology!r}",
+         "the gossip and kregular arms have no terms"),
+        (cfg.delivery == "stat", "delivery='stat'",
+         "the stat arm of models/raft.py and the heartbeat-blocked "
+         "models/raft_hb keep no per-node sender to silence"),
+        (cfg.queued_links, "queued_links",
+         "the serial-pipe registers follow one block sender"),
+        (cfg.mesh_axis is not None, "a mesh axis",
+         "picking the leader to kill reduces over a group's nodes on one "
+         "device"),
+        (not cfg.raft_terms, "raft_terms=False",
+         "without terms a restarted leader is never deposed and a second "
+         "election is not bound to one winner (KNOWN_ISSUES #0r)"),
+    )
+    for refused, arm, why in arms:
+        if refused:
+            raise NotImplementedError(
+                f"a crash schedule (faults.crashes={f.crashes}) is not "
+                f"implemented for {arm}: {why}; it runs on protocol='raft' "
+                "with raft_terms=True, delivery='edge', topology='full' or "
+                "'committee' (models/raft.check_schedule)")
+    if cfg.topology == "committee":
+        return  # the horizon below is a group's: asked again on its config
+    _, rt_hi = cfg.roundtrip_range()
+    horizon = rt_hi - 1 + cfg.serialization_ticks(cfg.raft_block_bytes)
+    if f.downtime_ms <= horizon:
+        raise ValueError(
+            f"a crash schedule: a reply lands up to {horizon} ticks after "
+            f"its request, and downtime_ms={f.downtime_ms} must exceed that, "
+            "or a count sent to the node before its crash could reach it "
+            "after its restart (the reply channels carry no term)")
+
+
+def _crash_and_restart(cfg, state: RaftState, t, rearm_on) -> RaftState:
+    """The head of a tick under a crash schedule: the crash that is due
+    kills the group's alive leader, and a node whose downtime is over is
+    back as a follower.  Behind ``base.gated_body`` on "a crash or a restart
+    is due" (under a lane batch: in some group), the body being the identity
+    on a group where neither is; where nothing can branch it runs on every
+    tick.  ``rearm_on`` is :func:`step`'s draw of a fresh election deadline:
+    a restart takes it off the channel a deposed leader's re-arm uses."""
+    f = cfg.faults
+    n_loc = state.alive.shape[0]
+    since = jnp.int32(t) - f.first_ms - state.crash_phase
+    k_due = since // f.period_ms
+    due = (since >= 0) & (since % f.period_ms == 0) & (k_due < f.crashes)
+    names = ("alive", "is_leader", "is_cand", "vote_success", "vote_failed",
+             "next_hb", "proposal_tick", "add_change_value", "hb_succ",
+             "hb_cnt", "hb_open", "election_deadline", "restart_tick",
+             "restarts", "crash_tick", "crash_node")
+
+    def body(c):
+        leads = c["is_leader"] & c["alive"]
+        target = jnp.argmax(jnp.where(leads, state.term, -1))
+        hit = due & leads & (jnp.arange(n_loc) == target)
+        back = c["restart_tick"] == jnp.int32(t)
+        this = due & (jnp.arange(f.crashes) == k_due)
+        off = lambda x: jnp.where(hit, DISARM, x)   # noqa: E731
+        zero = lambda x: jnp.where(hit, 0, x)       # noqa: E731
+        return {
+            "alive": (c["alive"] & ~hit) | back,
+            # volatile state is lost; term and has_voted are persistent
+            "is_leader": c["is_leader"] & ~hit,
+            "is_cand": c["is_cand"] & ~hit,
+            "vote_success": zero(c["vote_success"]),
+            "vote_failed": zero(c["vote_failed"]),
+            "next_hb": off(c["next_hb"]),
+            "proposal_tick": off(c["proposal_tick"]),
+            "add_change_value": c["add_change_value"] & ~hit,
+            "hb_succ": zero(c["hb_succ"]),
+            "hb_cnt": zero(c["hb_cnt"]),
+            "hb_open": c["hb_open"] & ~hit,
+            "election_deadline": jnp.where(
+                back, rearm_on(Channel.ELECTION + 200),
+                off(c["election_deadline"])),
+            "restart_tick": jnp.where(
+                hit, jnp.int32(t) + f.downtime_ms,
+                jnp.where(back, DISARM, c["restart_tick"])),
+            "restarts": c["restarts"] + back,
+            "crash_tick": jnp.where(this, jnp.int32(t), c["crash_tick"]),
+            "crash_node": jnp.where(this & leads.any(), target,
+                                    c["crash_node"]),
+        }
+
+    carry = {k: getattr(state, k) for k in names}
+    if can_branch(cfg.mesh_axis):
+        pred = due | (state.restart_tick == jnp.int32(t)).any()
+        carry = gated_body(pred, body, carry, FAULT_SCOPE)
+    else:
+        carry = body(carry)
+    return state.replace(**carry)
+
+
+def _crash_records(state: RaftState, t, *, win, fire, granted,
+                   acted) -> RaftState:
+    """The end of a tick under a crash schedule: the newest crash, if it
+    hit a leader and is not replaced yet, is replaced by this tick's win or
+    else counts this tick's timers; and the two oracles."""
+    fell = state.crash_tick >= 0
+    newest = jnp.arange(fell.shape[0]) == fell.sum() - 1
+    is_open = newest & (state.crash_node >= 0) & (state.replaced_tick < 0)
+    won = win.any()
+    # votes of this tick, each against the node's own record: a grant in
+    # the term the request found it in, then the self-vote of a timer
+    term_grant = state.term - fire
+    twice = (granted & (state.voted_term == term_grant)).astype(jnp.int32) \
+        + (fire & (jnp.where(granted, term_grant, state.voted_term)
+                   == state.term))
+    return state.replace(
+        replaced_tick=jnp.where(is_open & won, jnp.int32(t),
+                                state.replaced_tick),
+        crash_elections=state.crash_elections
+        + (is_open & ~won) * fire.sum(),
+        voted_term=jnp.where(granted | fire, state.term, state.voted_term),
+        double_votes=state.double_votes + twice,
+        dead_acts=state.dead_acts + (acted & ~state.alive),
+    )
+
+
+def _crash_columns(host: dict) -> dict:
+    """:func:`metrics_stacked`'s columns of a crash schedule, from the
+    per-crash leaves ``[groups, K]``."""
+    tick, node = host["crash_tick"], host["crash_node"]
+    won, fired = host["replaced_tick"], host["crash_elections"]
+    fell, hit = tick >= 0, node >= 0
+    done = hit & (won >= 0)
+    count = done.sum(axis=1)
+    ms = np.where(done, won - tick, -1).astype(np.float64)
+    ranked = np.sort(np.where(done, ms, np.inf), axis=1)
+    rank = lambda i: np.where(count > 0, np.take_along_axis(  # noqa: E731
+        ranked, np.clip(i, 0, None)[:, None], axis=1)[:, 0], -1.0)
+    columns = {
+        "crashes": fell.sum(axis=1),
+        "crashes_found_no_leader": (fell & ~hit).sum(axis=1),
+        "crashes_unreplaced": (hit & ~done).sum(axis=1),
+        "failovers": count,
+        "failovers_multi_election": (done & (fired > 1)).sum(axis=1),
+        "failover_mean_ms": np.where(
+            count > 0, np.where(done, ms, 0.0).sum(axis=1)
+            / np.maximum(count, 1), -1.0),
+        "failover_median_ms": np.where(
+            count > 0, (rank((count - 1) // 2) + rank(count // 2)) / 2, -1.0),
+        "failover_p90_ms": rank(-(-9 * count // 10) - 1),
+        "failover_max_ms": rank(count - 1),
+        "restarts": host["restarts"].sum(axis=1),
+        "dead_acts": host["dead_acts"].sum(axis=1),
+        "double_votes": host["double_votes"].sum(axis=1),
+    }
+    for k in range(tick.shape[1]):
+        columns[f"crash{k}_failover_ms"] = ms[:, k]
+        columns[f"crash{k}_elections"] = fired[:, k]
+    return columns

@@ -14,8 +14,10 @@ plain dict pytree:
   (safety: conflicting/forged/unattributed commits among correct nodes),
   ``viol_quorum`` (quorum-certificate consistency; for Raft with terms,
   ``SimConfig.raft_terms``, the two read the leaves of terms instead:
-  the program's oracle ``RaftState.term_conflicts``, and alive honest
-  leaders that share a ``term``), and ``liveness_lag``
+  the program's oracle ``RaftState.term_conflicts`` (under a crash
+  schedule, ``FaultConfig.crashes``, joined by its oracles ``dead_acts``
+  and ``double_votes``), and alive honest leaders that share a ``term``),
+  and ``liveness_lag``
   (samples since the protocol's progress counter last advanced; the
   sample axis is ticks on the tick engines, rounds/heartbeats on the
   fast paths — ``summarize`` records the unit).

@@ -154,8 +154,15 @@ def monitors(cfg, state) -> dict:
         lead = state.is_leader & state.alive & state.honest
         same = ((state.term[:, None] == state.term[None, :])
                 & lead[:, None] & lead[None, :]).sum() - lead.sum()
-        return {"viol_agreement": _i32(state.term_conflicts.sum()),
-                "viol_quorum": _i32(same // 2)}
+        viol_agree = _i32(state.term_conflicts.sum())
+        if state.dead_acts is not None:
+            # under a crash schedule (cfg.faults.crashes) ``alive`` is the
+            # mask at the end of the run, and the schedule's own oracles
+            # join the program's: a node that acted while it was down, a
+            # node that voted twice in one term across a restart
+            viol_agree = viol_agree + _i32(
+                state.dead_acts.sum() + state.double_votes.sum())
+        return {"viol_agreement": viol_agree, "viol_quorum": _i32(same // 2)}
     if p == "raft":
         cand = state.is_leader & state.alive
         lt = jnp.where(cand, state.leader_tick, _I32_NEVER)

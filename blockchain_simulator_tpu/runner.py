@@ -131,12 +131,12 @@ def _reject_cpp_only(cfg: SimConfig) -> None:
             "engines fast; the C++ engine covers the quirk and "
             "tests/test_fidelity.py pins the traffic delta"
         )
-    if cfg.raft_terms:
-        # what has no terms refuses them by its name (models/raft.check_terms
-        # lists the arms), before anything is built
+    if cfg.raft_terms or cfg.faults.crashes:
+        # what has no terms, or cannot run a crash schedule, refuses it by
+        # its name (models/raft.check_arms), before anything is built
         from blockchain_simulator_tpu.models import raft
 
-        raft.check_terms(cfg)
+        raft.check_arms(cfg)
     if cfg.queued_links:
         # pbft: per-destination serial-pipe registers (models/pbft.py).
         # paxos: every message is 3-4 bytes (ser = 0), the pipe is never

@@ -31,11 +31,11 @@ as one tile of 200 lanes took 50 GB of host memory).
 Every committee's final state is bit-equal to the flat dyn program run with
 that committee's key and masks, whatever T (tests/test_zzcommittee.py).
 
-Fault layout: masks keep the repo's global last-ids rule
-(models/base.dyn_fault_masks over the FULL id space, reshaped [C, m]) —
-fault counts therefore concentrate in the tail committees, whose inner
-consensus stalls first; counts stay traced operands, so ONE executable
-serves every fault level per (protocol, committee structure).
+Fault layout: masks keep the global last-ids rule (models/base.dyn_fault_masks
+over the FULL id space, reshaped [C, m]), so fault counts concentrate in the
+tail committees; counts stay traced operands: ONE executable per (protocol,
+committee structure).  A crash schedule (FaultConfig.crashes) is a lane's own:
+its phase is drawn in the lane's ``init`` from the lane's key (models/raft.py).
 
 One-committee contract (the pin in tests/test_zztopo.py): at C = 1 the
 committee keys ARE the flat sim's key stream and the body IS the flat

@@ -46,6 +46,33 @@ class FaultConfig:
     # sender id, capping each forger at one counted vote.
     byz_forge: bool = False
     byz_copies: int = 3
+    # A crash schedule: faults that happen DURING the run (Raft with terms;
+    # models/raft.py "Crash schedule").  Structure static and hashable, the
+    # times a draw: crash k of a group (k = 0..crashes-1) falls at tick
+    # ``first_ms + k * period_ms + phase``, ``phase`` one draw a group from
+    # U{0..period_ms-1} off the group's init key, and hits whichever node of
+    # the group is an alive leader on that tick (none: the crash is recorded
+    # as having found no leader and kills nobody); the node is back
+    # ``downtime_ms`` later as a follower.  ``crashes = 0``: no schedule,
+    # and a program built without one carries no leaf and no operation for
+    # it.  Every arm that cannot run one refuses it by name
+    # (models/raft.check_schedule).
+    crashes: int = 0
+    first_ms: int = 0
+    period_ms: int = 0
+    downtime_ms: int = 0
+
+    def __post_init__(self):
+        if self.crashes < 0:
+            raise ValueError(f"faults.crashes={self.crashes} must be >= 0")
+        if self.crashes and not (
+                self.first_ms >= 0 and 0 < self.downtime_ms < self.period_ms):
+            raise ValueError(
+                f"a crash schedule needs first_ms >= 0 and 0 < downtime_ms < "
+                f"period_ms (a killed node is back before the next kill), got "
+                f"first_ms={self.first_ms} period_ms={self.period_ms} "
+                f"downtime_ms={self.downtime_ms}"
+            )
 
     def resolved_n_crashed(self, n: int) -> int:
         if self.n_crashed >= 0:

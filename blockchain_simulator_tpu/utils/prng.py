@@ -34,3 +34,9 @@ def tick_key(base: jax.Array, tick) -> jax.Array:
 def chan_key(tkey: jax.Array, channel: int) -> jax.Array:
     """Key for one use site within a tick."""
     return jax.random.fold_in(tkey, channel)
+
+
+# added at the file's end, so that no line above moves (the compile cache
+# keys on the source lines of traced code, ROADMAP D11): fault-schedule
+# draws (a Raft group's crash phase, models/raft.init)
+Channel.FAULT = 10

@@ -698,21 +698,31 @@ def install_crash_dump() -> None:
 # through, as counters, made where ``committee.*`` are: host side, where the
 # metrics dicts are computed (topo/committee.metrics for a stack of groups,
 # runner.run_simulation for a flat run; models/ stays free of this module)
-RAFT_COUNTERS = ("raft.groups", "raft.term_bumps", "raft.step_downs",
-                 "raft.term_conflicts")
+# each counter with the key of a group's metrics dict it sums
+# (``raft.groups``: the groups themselves)
+_RAFT_COUNTED = {
+    "raft.groups": None, "raft.term_bumps": "term_final",
+    "raft.step_downs": "step_downs", "raft.term_conflicts": "term_conflicts",
+    # under a crash schedule (FaultConfig.crashes)
+    "raft.crashes": "crashes", "raft.restarts": "restarts",
+    "raft.failovers": "failovers",
+    "raft.crashes_no_leader": "crashes_found_no_leader",
+}
+RAFT_COUNTERS = tuple(_RAFT_COUNTED)
 
 
 def count_raft_groups(groups) -> None:
     """Add the Raft metrics dicts ``groups`` (one a group) that ran with
     terms to :data:`RAFT_COUNTERS`: groups read, and over them the sums of
-    ``term_final``, ``step_downs`` and ``term_conflicts``.  A dict without
-    terms counts nothing."""
+    ``term_final``, ``step_downs`` and ``term_conflicts``, and of
+    ``crashes``, ``restarts``, ``failovers`` and ``crashes_found_no_leader``
+    where they ran under a crash schedule.  A dict without terms counts
+    nothing."""
     with_terms = [g for g in groups if "term_final" in g]
     if not with_terms:
         return
-    for name, by in zip(RAFT_COUNTERS, (
-            len(with_terms),
-            sum(g["term_final"] for g in with_terms),
-            sum(g["step_downs"] for g in with_terms),
-            sum(g["term_conflicts"] for g in with_terms))):
-        metrics.counter(name).inc(by)
+    for name, key in _RAFT_COUNTED.items():
+        if key is None:
+            metrics.counter(name).inc(len(with_terms))
+        elif key in with_terms[0]:
+            metrics.counter(name).inc(sum(g[key] for g in with_terms))

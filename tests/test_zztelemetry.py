@@ -634,6 +634,12 @@ def lowered_programs(shared):
     if len(jax.devices()) >= 2:
         builds += [sharded(cfgs[-1]), sharded(cfgs[-2])]
     builds += [
+        # a crash schedule over Raft with terms (only a program under one
+        # holds ``raft.tick.fault`` and its gate's taken trip), fourth to last
+        solo(SimConfig(protocol="raft", n=8, sim_ms=200, raft_terms=True,
+                       model_serialization=False,
+                       faults=FaultConfig(crashes=2, first_ms=40,
+                                          period_ms=80, downtime_ms=30))),
         # Raft with terms (only a ``raft_terms`` program holds
         # ``raft.tick.term`` and the denial's value-max unicast)
         solo(SimConfig(protocol="raft", n=8, sim_ms=200, raft_terms=True,
@@ -665,11 +671,18 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
         assert f"pbft.tick.prepare/{scope}/" in lowered_programs[-2]
         assert not any(f"{scope}/" in t for t in lowered_programs[:-2])
         return
-    if scope == "raft.tick.term":
-        # what terms add: in the program with terms (third to last) alone
-        assert f"{scope}/" in lowered_programs[-3]
+    if scope in ("raft.tick.fault", "gate.raft.fault_taken"):
+        # what a crash schedule adds: in the program under one alone
+        assert f"{scope}/" in lowered_programs[-4]
         assert not any(f"{scope}/" in t for t in
-                       lowered_programs[:-3] + lowered_programs[-2:])
+                       lowered_programs[:-4] + lowered_programs[-3:])
+        return
+    if scope == "raft.tick.term":
+        # what terms add: in the two programs with terms alone (third and
+        # fourth to last)
+        assert all(f"{scope}/" in t for t in lowered_programs[-4:-2])
+        assert not any(f"{scope}/" in t for t in
+                       lowered_programs[:-4] + lowered_programs[-2:])
         return
     if scope.startswith("paxos.tick."):
         assert f"{scope}/" in lowered_programs[7]  # the relay, one device

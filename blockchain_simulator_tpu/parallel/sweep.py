@@ -409,23 +409,34 @@ def run_sharded_topo(cfg: SimConfig, mesh, seed: int | None = None):
 
 @aotcache.cached_factory("multi-seed-tick")
 def multi_seed_fn(cfg: SimConfig, n_seeds: int):
-    """THE single-device multi-seed Monte Carlo executable:
+    """THE single-device lane-after-lane executable:
     ``batched(keys[B], n_crashed[B], n_byzantine[B]) -> finals`` running B
-    seeds of one fault structure as ONE dispatch of a ``lax.map`` over the
+    lanes of one fault structure as ONE dispatch of a ``lax.map`` over the
     UNVMAPPED dyn program (partition.seq_map — the per-device body of the
-    mesh sweep arm, without the mesh).
+    mesh sweep arm, without the mesh).  :func:`run_dyn_points` picks it over
+    :func:`dyn_batched_fn` for lanes that are a large share of the device's
+    memory (:func:`_device_place`); ``multi_seed=True`` forces it.
 
-    Why this beats the vmapped ``dyn_batched_fn`` on the tick path
-    (ISSUE 13 / ROADMAP item 4): every tick-engine channel push is a
-    dynamic-update-slice on a scan-carried ring, and vmap over the batch
-    axis lowers each one to XLA generic scatter, which XLA:CPU serializes
-    (KNOWN_ISSUES #0b/#0i — the mesh bench measured the scatter-free body
-    ~2.3x per lane at 10k nodes on the round path; the tick engine pushes
-    3-4 rings per tick, so its gap is wider, see ARTIFACT_tick_bench.json).
-    The ``lax.map`` body keeps every push a plain DUS, each lane is the
-    batch-1-shaped program (the only shape ever observed to survive the
-    TPU batch>=2 hazard, issue #2), and the whole batch costs one Python
-    dispatch + one executable.
+    When it beats the lane batch.  *On a TPU*: where ONE lane fills the
+    chip.  Each lane is then the lone program, whose ``[D, n, slots]`` rings
+    keep the one layout every ring op asks for (ops/ring.node_minor).  By
+    the two traces of ``pbft100k.byzsweep`` (PERF.md section 5; PR 49, a call
+    of 8 lanes of n = 100,000 as two four-lane tiles against the 8 in turn,
+    3.466 against 1.985 s of device time): the lane batch's 1.84 GB layout
+    copy of the COMMIT ring is gone (0.543 s a call; the pushes under
+    ``push_bucket_counts`` 0.932 -> 0.263 s), a ring pop is a plain
+    dynamic-update-slice of 82-86 us a lane where the tile's, with vmap's
+    select over lanes, took 113-156 us a lane, and the vote tables' passes
+    take 0.64 s for 1.04; it holds 1.9 GB of temporaries for a tile's 10.6.
+    722 against 414 us a lane-tick there, and the other way round at n =
+    1,024, where a lone lane leaves the chip idle (section 7 (h): the
+    readings beside ``_MAP_LANE_SHARE``).  *On XLA:CPU* (ISSUE 13, no user's
+    backend): at every size read, because vmap
+    lowers each of the tick engine's ring pushes, a dynamic-update-slice on
+    a scan-carried ring, to XLA generic scatter, which XLA:CPU serializes
+    (KNOWN_ISSUES #0b/#0i, ARTIFACT_tick_bench.json); the ``lax.map`` body
+    keeps every push a plain DUS.  Either way the whole batch costs one
+    Python dispatch and one executable.
 
     ``cfg`` must already be canonical (models/base.canonical_fault_cfg):
     one registry entry per (fault structure, B) — seeds and fault counts
@@ -497,13 +508,61 @@ SPANS = ("sweep.operands", "sweep.execute", "sweep.readback", "sweep.chunk",
 # at 32 lanes of pbft-fullmesh-1k (x1.58), 10.25 GB over 6.04 GB at 4 lanes of
 # pbft-byzsweep-100k (x1.70: the scan's carry and what a taken arm draws
 # beside it); on the chip that program reserved 7.91 GB (x1.31,
-# ``peak_bytes_reserved``), all 8 lanes in one dispatch 15.81 GB of the
-# 16.43 GB the device lets a process reserve (and ran a tenth slower than two
-# tiles), and the finals of the dispatch before stay on the device until
-# they are read (x0.08).  2 leaves room over all of them: a dispatch that
-# dies of memory loses the whole sweep, one dispatch more costs
-# milliseconds (PERF.md section 6, PR 35).
+# ``peak_bytes_reserved``), all 8 lanes in one dispatch OF THE LANE BATCH
+# 15.81 GB of the 16.43 GB the device lets a process reserve (and ran a tenth
+# slower than two tiles), and the finals of the dispatch before stay on the
+# device until they are read (x0.08).  2 leaves room over all of them: a
+# dispatch that dies of memory loses the whole sweep, one dispatch more costs
+# milliseconds (PERF.md section 6, PR 35).  The same 8 lanes one after
+# another under ``lax.map`` (:func:`multi_seed_fn`) are 1.86 GB of temporaries
+# over one lane's 1.51 GB (x1.23) and 0.96 GB of stacked results, 8.1 GB
+# reserved on the chip with the lane batch's executable beside it (PERF.md
+# section 7 (h), PR 47): the factor covers the one lane a map holds, too.
 _TEMP_FACTOR = 2.0
+
+# A lane that is more than this share of the device's memory runs ALONE: a
+# one-device list of such lanes goes lane after lane through ``lax.map``
+# (:func:`multi_seed_fn`) in place of the lane batch (:func:`_device_place`).
+# A lane that is a large share of the chip fills it by itself and pays for
+# company (a batch axis on every ``[D, n, slots]`` ring, a tile's temporaries,
+# the dispatches between tiles); a small lane needs company to fill the chip.
+# The readings it was set from, all on one v5e (``bytes_limit``
+# 16,909,336,064: the share is 264.2 MB there, "fewer than 32 lanes would fit
+# as a batch" under ``_TEMP_FACTOR`` = 2), warm, three dispatches each, the
+# same operands to both programs, rows or leaves equal (my chip runs: PR 47,
+# PERF.md section 7 (h); the starred rows read in PR 49, section 6: the three
+# that PR 47 had came out the same to three digits, the two at n = 1,536 and
+# 2,304 are new):
+#
+#    lane state   of the   shape (lanes)                  lane     lax.map
+#                 device                                  batch
+#       7.0 MB    1/2416   Paxos n=1,024 (32)            13.320 s   7.784 s  map x1.71 (a)
+#      30.4 MB    1/556    byzsweep at n=2,016 (8)        0.0780    0.1198   batch x1.53
+#     121.4 MB *  1/139    pbft-fullmesh-1k (32)          0.3713    0.6662   batch x1.79 (b)
+#     121.7 MB    1/139    byzsweep at n=8,064 (8)        0.2522    0.1953   map x1.29 (c)
+#     182.1 MB *  1/93     per-edge PBFT n=1,536 (32)     0.7928    1.0611   batch x1.34
+#     273.1 MB *  1/62     per-edge PBFT n=2,304 (16)     1.1938    0.9374   map x1.27
+#     278.7 MB *  1/61     tick Raft n=1,024 (32)        20.645    17.780    map x1.16
+#     486.9 MB *  1/35     byzsweep at n=32,256 (8)       1.1720    0.6428   map x1.82
+#    1509.4 MB    1/11     pbft-byzsweep-100k (8)         3.4886    1.9987   map x1.746
+#
+# (a) its engine's reason, not its size's; (b) per-edge delivery: the lane
+# of ``pbft1k.mc`` and of the server's buckets; (c) stat delivery: bytes
+# cannot tell it from (b).  Bytes sort seven of the nine: the per-edge
+# engine crosses over between 1/93 and 1/62, which is where the constant
+# lies; the stat engine crosses lower (between 1/556 and 1/139), so (c) and
+# (a) are MISPLACED: they stay on the lane batch, as before this rule, and
+# lose nothing they had.  The constant is provisional in that sense: a rule
+# that also read the delivery would place (c), and needs a cell of stat lanes
+# at mid n to be claimed in (PERF.md section 7 (h)).  It is a reading of the
+# hardware, not an option: nothing sets it but this line.
+_MAP_LANE_SHARE = 1 / 64
+
+
+def _logical_bytes(shapes) -> int:
+    """Elements times item size over a pytree of shapes, with no account of
+    how a device tiles them."""
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
 
 
 @functools.lru_cache(maxsize=64)
@@ -529,8 +588,8 @@ def _lane_state_bytes(canon: SimConfig) -> int:
         from blockchain_simulator_tpu.models import pbft_round as mod
     else:
         mod = get_protocol(canon.protocol)
-    shapes = jax.eval_shape(lambda: mod.init(canon, jax.random.key(0)))
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    return _logical_bytes(
+        jax.eval_shape(lambda: mod.init(canon, jax.random.key(0))))
 
 
 @functools.lru_cache(maxsize=1)
@@ -562,15 +621,67 @@ def _device_tile(canon: SimConfig, n_points: int, outer: int = 1) -> dict | None
             "device_bytes": int(device)}
 
 
+@functools.lru_cache(maxsize=64)
+def _lane_result_bytes(canon: SimConfig) -> int:
+    """LOGICAL bytes of what one lane of ``make_dyn_sim_fn(canon)`` returns
+    (``eval_shape`` of the program: nothing is allocated, nothing runs): a
+    ``lax.map`` over lanes stacks these, one a lane, while it holds the
+    state of one lane only.  The tick engines return the state without the
+    rings, 0.12 GB of a 1.51 GB lane at n = 100,000."""
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    cnt = jax.ShapeDtypeStruct((), jnp.int32)
+    return _logical_bytes(
+        jax.eval_shape(make_dyn_sim_fn(canon), key, cnt, cnt))
+
+
+def _device_place(canon: SimConfig, n_points: int) -> dict | None:
+    """Where a one-device point list runs, from what the code can observe (a
+    lane's state bytes, the memory the device reports, the list's length):
+    ``None`` for the whole list as ONE lane batch, which is every list where
+    the device reports no memory (XLA:CPU), every list of one point, and
+    every list of small lanes that fits; else the plan of the chunk loop,
+    ``{"program", "lanes", "points", "state_bytes", "device_bytes"}`` with
+    ``lanes`` the lanes the device holds at once and ``points`` the lanes of
+    one dispatch:
+
+    - ``"lax.map"``: a lane over ``_MAP_LANE_SHARE`` of the device runs
+      alone, lane after lane in one dispatch of :func:`multi_seed_fn`
+      (``lanes`` 1).  The dispatch holds one lane's state and temporaries
+      (``_TEMP_FACTOR`` times its state) and every lane's results
+      (:func:`_lane_result_bytes`), so a list whose stacked results outgrow
+      what is left of the device is cut into as few equal dispatches as fit
+      (the ceiling ran on a v5e: 115 lanes of n = 100,000 a dispatch,
+      ``peak_bytes_in_use`` 13.8 of 16.9 GB, two such dispatches in a row:
+      PERF.md section 6, PR 49).
+    - ``"lane-batch"``: smaller lanes that outgrow the device as one batch
+      run as the equal tiles of :func:`_device_tile` (``lanes`` ==
+      ``points``)."""
+    device = _device_bytes()
+    if device is None or n_points <= 1:
+        return None
+    state = _lane_state_bytes(canon)
+    if state <= _MAP_LANE_SHARE * device:
+        cut = _device_tile(canon, n_points)
+        return cut and {"program": "lane-batch", "points": cut["lanes"], **cut}
+    room = device - _TEMP_FACTOR * state
+    most = max(int(room // _lane_result_bytes(canon)), 1)
+    dispatches = -(-n_points // most)
+    return {"program": "lax.map", "lanes": 1,
+            "points": -(-n_points // dispatches), "state_bytes": state,
+            "device_bytes": int(device)}
+
+
 def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
                          n_out: int | None = None, mesh=None,
                          multi_seed: bool = False, probe=None):
     """ONE un-journaled batched dispatch of a same-structure point list —
     the body :func:`run_dyn_points` either calls directly (no journal) or
     wraps in chunked, supervised, durable execution.  ``multi_seed``
-    selects the scatter-free ``lax.map`` program (:func:`multi_seed_fn`)
-    over the vmapped one on the single-device path; a mesh dispatch
-    already maps sequentially per device, so the flag is a no-op there.
+    selects the ``lax.map`` program (:func:`multi_seed_fn`: lane after
+    lane) over the lane batch on the single-device path, by the caller's
+    word or by :func:`_device_place`'s (through :func:`_run_chunk`); a mesh
+    dispatch already maps sequentially per device, so the flag is a no-op
+    there.
     ``probe`` (an obsim/schema.ProbeConfig) swaps in the armed twin of
     the same arm (obsim/build.py ``consobs-*`` registry entries) and
     attaches a per-row ``"probe"`` summary; monitor violations trip the
@@ -635,24 +746,32 @@ def _dispatch_dyn_points(canon: SimConfig, points, record: bool = True,
 
 
 def _run_chunk(canon, tile, record, n_out, mesh, supervise, journal, key,
-               index, multi_seed=False, probe=None, device_tile=None):
+               index, multi_seed=False, probe=None, placed=None):
     """Compute ONE chunk, optionally under the supervisor's deadline →
     retry → degrade state machine (parallel/journal.py).  The
     ``sweep.chunk`` chaos point fires once per ATTEMPT with the arm in
     its ctx, so a drill can wedge exactly the primary arm and watch the
-    degrade arm answer.  Where the chunk is a tile of a list cut to the
-    device (``device_tile``, :func:`_device_tile`), each dispatch of it
-    stands under a ``sweep.tile`` span that says what was dispatched and
-    what it was sized from."""
+    degrade arm answer.  Where the chunk is a dispatch of a list placed on
+    the device (``placed``, :func:`_device_place`), each dispatch of
+    it runs the placed program and stands under a ``sweep.tile`` span that
+    says what was dispatched and what it was sized from: ``lanes`` the lanes
+    the device holds AT ONCE (the tile's under the lane batch, 1 under
+    ``lax.map``, where a device event is one lane's), ``points`` the lanes
+    the dispatch runs in all."""
 
     def dispatch(mesh, multi_seed):
         span = contextlib.nullcontext()
-        if device_tile is not None:
+        if placed is not None:
+            # the placement holds on the degrade arm too: a chunk of lanes
+            # sized to run one after another does not fit as a lane batch
+            mapped = placed["program"] == "lax.map"
+            multi_seed = multi_seed or mapped
             rows = len(tile) if n_out is None else n_out
             span = telemetry.span(
-                "sweep.tile", tile=index, lanes=len(tile),
-                pad=len(tile) - rows, state_bytes=device_tile["state_bytes"],
-                device_bytes=device_tile["device_bytes"])
+                "sweep.tile", tile=index, lanes=1 if mapped else len(tile),
+                points=len(tile), pad=len(tile) - rows,
+                state_bytes=placed["state_bytes"],
+                device_bytes=placed["device_bytes"])
         with span:
             return _dispatch_dyn_points(canon, tile, record, n_out, mesh,
                                         multi_seed, probe)
@@ -744,19 +863,32 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     unchanged and pad metrics are never computed).  A mesh of size 1 takes
     the single-device path verbatim.
 
-    **A list that outgrows the device** runs as tiles.  A vmapped lane
-    batch on one device holds every lane's state at once (three 460 MB
-    rings a lane for the PBFT tick engine at n = 100,000), so the most
-    lanes a dispatch may have is the memory the device reports over
-    ``_TEMP_FACTOR`` times one lane's state bytes (:func:`_device_tile`:
-    derived, not configured).  A longer list is cut into as few equal
-    tiles as that allows and runs through the chunk loop below, every tile
-    through the ONE executable (the tail padded by repeating its last
-    point), each under a ``sweep.tile`` span; rows come back in order,
-    entry for entry those of one dispatch (exact sampler; the module
-    caveat for the normal one).  A list that fits dispatches as it always
-    did.  ``meta["tile"]`` says what was chosen (``lanes``,
-    ``state_bytes``, ``device_bytes``), None where nothing was cut.
+    **On one device the code places the list** (:func:`_device_place`:
+    derived from a lane's state bytes and the memory the device reports,
+    not configured).  *Lanes that are a large share of the device* (over
+    ``_MAP_LANE_SHARE`` of its memory: three 460 MB rings a lane for the
+    PBFT tick engine at n = 100,000) run one after another through the
+    ``lax.map`` executable (:func:`multi_seed_fn`), the whole list in one
+    dispatch, cut into as few equal dispatches as fit only where the
+    stacked results outgrow the device: such a lane fills the chip alone
+    and a lane batch makes each of them dearer (722 against 414 us a
+    lane-tick at four lanes of 100,000: my chip runs, PR 49, PERF.md
+    section 6).
+    *Smaller lanes* run as ONE lane batch, which holds every lane's state
+    at once, so the most lanes a dispatch may have is the memory the device
+    reports over ``_TEMP_FACTOR`` times one lane's state bytes
+    (:func:`_device_tile`); a longer list is cut into as few equal tiles as
+    that allows.  Either cut runs through the chunk loop below, every
+    dispatch through the ONE executable (the tail padded by repeating its
+    last point), each under a ``sweep.tile`` span; rows come back in
+    order, entry for entry those of one dispatch (exact sampler; the
+    module caveat for the normal one).  A list of small lanes that fits,
+    a list of one point, and every list where the device reports no
+    memory (XLA:CPU) dispatch as one lane batch, as they always did.
+    ``meta["tile"]`` says what was chosen (``program``: ``"lax.map"`` or
+    ``"lane-batch"``; ``lanes`` the device holds at once, ``points`` a
+    dispatch, ``state_bytes``, ``device_bytes``), None where the list ran
+    as one lane batch.
 
     **Durable execution** (``journal=``, a parallel/journal.SweepJournal):
     the point list splits into ``chunk_size``-point chunks (default: one
@@ -780,13 +912,13 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     only be swallowed into an un-gated degrade-to-solo
     (serve/dispatch.run_batch's typed-error wrapper).
 
-    ``multi_seed=True`` dispatches single-device batches through the
-    scatter-free ``lax.map`` executable (:func:`multi_seed_fn`) instead of
-    the vmapped one — the tick-path throughput arm (ISSUE 13; measured in
-    ARTIFACT_tick_bench.json), rows bit-equal under the exact sampler.
-    The default stays the vmapped program so existing registry
-    trajectories and pins are untouched; ``runner.run_multi_seed`` and
-    the sweeps' ``multi_seed=`` kwarg are the opt-ins.
+    ``multi_seed=True`` forces single-device batches through the
+    ``lax.map`` executable (:func:`multi_seed_fn`) whatever the lanes'
+    size, the whole list in one dispatch (``runner.run_multi_seed`` and
+    the sweeps' ``multi_seed=`` kwarg pass it; rows bit-equal under the
+    exact sampler).  The default, ``False``, means the code chooses, as
+    above: where the device reports no memory that is the lane batch, so
+    registry trajectories and pins on XLA:CPU are untouched.
 
     ``probe=`` (an obsim/schema.ProbeConfig) arms the in-program
     consensus taps: every row gains a ``"probe"`` summary
@@ -816,12 +948,14 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     points = list(points)
     meta = {"rows": [], "chunks": [], "lanes": 0, "dispatches": 0, "pad": 0,
             "tile": None}
-    # a vmapped lane batch on one device holds every lane's state at once:
-    # a list that outgrows the device runs as tiles (the mesh arms and the
-    # ``lax.map`` program run a device's lanes one after another)
-    device_tile = None
+    # on one device the code places the list (:func:`_device_place`): lanes
+    # that are a large share of the device run one after another under
+    # ``lax.map``, smaller ones as a lane batch, tiled where it outgrows the
+    # device.  A mesh arm already runs a device's lanes one after another,
+    # and ``multi_seed=True`` is the caller's own choice of the map.
+    placed = None
     if not multi_seed and (mesh is None or partition.mesh_size(mesh) == 1):
-        device_tile = _device_tile(canon, len(points))
+        placed = _device_place(canon, len(points))
 
     def _lanes(n: int) -> int:
         if n > 1 and mesh is not None and partition.mesh_size(mesh) > 1:
@@ -832,7 +966,7 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
     def _done(rows):
         return (rows, meta) if with_index else rows
 
-    if journal is None and supervise is None and device_tile is None:
+    if journal is None and supervise is None and placed is None:
         rows = _dispatch_dyn_points(canon, points, record, n_out, mesh,
                                     multi_seed, probe)
         if points:
@@ -855,9 +989,11 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
         chunk_size = partition.align_chunk(
             chunk_size, max(partition.sweep_axis_size(mesh), 1)
         )
-    if device_tile is not None:
-        chunk_size = min(chunk_size, device_tile["lanes"])
-        meta["tile"] = {**device_tile, "lanes": chunk_size}
+    if placed is not None:
+        chunk_size = min(chunk_size, placed["points"])
+        placed = {**placed, "points": chunk_size,
+                  "lanes": min(chunk_size, placed["lanes"])}
+        meta["tile"] = placed
     done = journal.completed() if journal is not None else {}
     out = []
     for index, start in enumerate(range(0, len(points), chunk_size)):
@@ -865,7 +1001,7 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
         want = len(tile) if n_out is None \
             else max(0, min(len(tile), n_out - start))
         t_out = None if n_out is None else want
-        if device_tile is not None and want == 0:
+        if placed is not None and want == 0:
             break  # the tiles from here on hold bucket padding alone
         key = journal_mod.chunk_key(canon, index, tile, mesh, n_out=t_out)
         if probe is not None:
@@ -888,14 +1024,14 @@ def run_dyn_points(canon: SimConfig, points, record: bool = True,
         # arm's rows (journaled below) reach runs.jsonl — an abandoned
         # slow attempt finishing late must not double-record its points
         lanes, l_out = tile, t_out
-        if device_tile is not None and len(tile) < chunk_size:
+        if placed is not None and len(tile) < chunk_size:
             # the tail tile runs the executable of the others: the lanes it
             # lacks repeat its last point (as the mesh arm pads) and are
             # not read back into rows
             lanes = tile + [tile[-1]] * (chunk_size - len(tile))
             l_out = want
         rows = _run_chunk(canon, lanes, False, l_out, mesh, supervise,
-                          journal, key, index, multi_seed, probe, device_tile)
+                          journal, key, index, multi_seed, probe, placed)
         # durable BEFORE the next chunk dispatches — the recompute-at-
         # most-one contract the kill -9 drill pins
         if journal is not None:

@@ -461,21 +461,27 @@ def final_state(cfg: SimConfig, seed: int | None = None):
 
 def run_multi_seed(cfg: SimConfig, seeds, record: bool = True):
     """Multi-seed Monte Carlo: run ``len(seeds)`` seeds of one config as ONE
-    dispatch of the scatter-free ``lax.map`` executable
-    (parallel/sweep.multi_seed_fn — the tick-path throughput arm of
-    ISSUE 13 / ROADMAP item 4).  Returns one metrics dict per seed, in
+    dispatch of the ``lax.map`` executable (parallel/sweep.multi_seed_fn:
+    the lone program, seed after seed), whatever the lanes' size: the
+    caller's own choice of the program that ``run_dyn_points`` otherwise
+    picks from a lane's state bytes and the device's memory
+    (parallel/sweep._device_place).  Returns one metrics dict per seed, in
     order, each bit-equal (exact sampler; parallel/sweep.py caveat for the
     "normal" CLT float path) to ``run_simulation(cfg, seed=s)``.
 
     Compared to looping :func:`run_simulation`: one executable per
     (fault structure, seed count) — seed values ride the key operand, so a
     fresh seed set never recompiles — and one Python dispatch + sync for
-    the whole batch.  Compared to the vmapped ``run_seed_sweep``: the
-    unvmapped ``lax.map`` body keeps the tick engine's ring pushes plain
-    dynamic-update-slices instead of vmap's DUS→scatter lowering, which
-    XLA:CPU serializes (KNOWN_ISSUES #0i; measured on the tick path in
-    ARTIFACT_tick_bench.json).  Mixed (the one un-batchable protocol)
-    raises the typed :class:`UnbatchableConfigError`."""
+    the whole batch.  Compared to the lane batch (``run_seed_sweep``,
+    ``sweep.dyn_batched_fn``): neither wins everywhere.  On the chip the
+    map wins where one lane fills the device (x1.75 at n = 100,000) and
+    loses where it does not (x1.79 the other way at n = 1,024 x 32:
+    PERF.md section 7 (h)), which is the choice ``run_dyn_points`` makes
+    by itself; on XLA:CPU, which serializes the scatters that vmap makes
+    of the tick engine's ring pushes, the map wins on the tick path at
+    every size read (KNOWN_ISSUES #0i, ARTIFACT_tick_bench.json).  Mixed
+    (the one un-batchable protocol) raises the typed
+    :class:`UnbatchableConfigError`."""
     from blockchain_simulator_tpu.parallel import sweep
 
     canon = base_model.canonical_fault_cfg(cfg)

@@ -104,3 +104,68 @@ def test_run_dyn_points_multi_seed_mixed_fault_counts():
     default = sweep.run_dyn_points(canon, points, record=False)
     ms = sweep.run_dyn_points(canon, points, record=False, multi_seed=True)
     assert default == ms
+
+
+# ------------------------------ the code's own choice of the map (PR 49) ---
+
+
+def _large_lanes(monkeypatch, cfg):
+    """A device on which every lane of ``cfg`` is a large share of the memory
+    it reports (XLA:CPU reports none: the rule then never engages)."""
+    canon = canonical_fault_cfg(cfg)
+    state = sweep._lane_state_bytes(canon)
+    monkeypatch.setattr(sweep, "_device_bytes", lambda: 16 * state)
+    assert state > sweep._MAP_LANE_SHARE * 16 * state
+    return canon
+
+
+@pytest.mark.parametrize("forced", (False, True), ids=("placed", "forced"))
+def test_placed_map_is_the_forced_maps_executable(forced, monkeypatch):
+    """The default on a device of large lanes and ``multi_seed=True``
+    anywhere run ONE registry entry (``multi-seed-tick``), rows bit-equal to
+    solo runs; only the placed one stands under a ``sweep.tile`` span."""
+    from blockchain_simulator_tpu.utils import telemetry
+
+    cfg = _cfg(n=32, sim_ms=200, pbft_max_rounds=3, pbft_max_slots=8)
+    canon = _large_lanes(monkeypatch, cfg)
+    points = [(cfg, s) for s in (0, 1)]
+    sweep.run_dyn_points(canon, points, record=False, multi_seed=True)
+    s0 = aotcache.registry.stats()
+    with telemetry.capture() as spans:
+        rows, meta = sweep.run_dyn_points(canon, points, record=False,
+                                          multi_seed=forced, with_index=True)
+    assert aotcache.registry.stats()["misses"] == s0["misses"]
+    assert rows == [runner.run_simulation(cfg, seed=s) for s in (0, 1)]
+    tiles = [s["attrs"] for s in spans if s["name"] == "sweep.tile"]
+    if forced:
+        assert tiles == [] and meta["tile"] is None
+    else:
+        assert [(t["lanes"], t["points"]) for t in tiles] == [(1, 2)]
+        assert meta["tile"]["program"] == "lax.map"
+    assert (meta["dispatches"], meta["lanes"], meta["pad"]) == (1, 2, 0)
+
+
+def test_placed_map_takes_the_probed_twin(monkeypatch):
+    """An armed flush follows the same choice through the same branch: the
+    ``lax.map`` twin of the probed program, summaries and rows those of the
+    armed lane batch."""
+    from blockchain_simulator_tpu.obsim import build, schema
+
+    cfg = _cfg(n=16, sim_ms=160, pbft_max_rounds=2, pbft_max_slots=8)
+    canon = canonical_fault_cfg(cfg)
+    pcfg = schema.ProbeConfig(windows=4)
+    points = [(cfg.with_(faults=FaultConfig(n_byzantine=b)), 3)
+              for b in (0, 1, 2)]
+    batch = sweep.run_dyn_points(canon, points, record=False, probe=pcfg)
+    _large_lanes(monkeypatch, cfg)
+    asked = []
+    real = build.probed_batched_fn
+
+    def spy(canon, probe, multi_seed=False):
+        asked.append(multi_seed)
+        return real(canon, probe, multi_seed=multi_seed)
+
+    monkeypatch.setattr(build, "probed_batched_fn", spy)
+    mapped = sweep.run_dyn_points(canon, points, record=False, probe=pcfg)
+    assert asked == [True] and mapped == batch
+    assert all("probe" in m for m in mapped)

@@ -18,8 +18,11 @@ Channels come in two flavors (SURVEY.md §7 "variable-size inboxes"):
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from blockchain_simulator_tpu.ops import scopes
@@ -32,6 +35,42 @@ _scoped = scopes.scoped("ops.ring", _names)
 _LANES = 128
 
 
+# ring values pinned by the lane rule (:func:`node_minor`) so far, counted
+# where a program is traced: plain Python, never a traced value.  The program
+# builders move it to the ``ring.lane_pinned`` counter (utils/aotcache.py)
+lane_pinned = [0]
+
+
+@functools.cache
+def _lane_pin(lanes: int):
+    """The identity on a value with ``lanes`` leading lane axes, constrained
+    to the physical order "its own axes, then the lanes": slot major-most,
+    lanes minor-most.  A plain ``with_layout_constraint`` under ``vmap``
+    makes the batch axis major-most (``jax/_src/pjit.get_layout_for_vmap``),
+    which pads a ring's 5 nodes to 128; a ``custom_vmap`` sees the batch
+    axis arrive (at the front, one a ``vmap`` around it) and places it."""
+
+    @custom_vmap
+    def pin(x):
+        if not lanes:
+            return x
+        order = (*range(lanes, x.ndim), *range(lanes))
+        return with_layout_constraint(x, Layout(major_to_minor=order))
+
+    @pin.def_vmap
+    def _(axis_size, in_batched, x):
+        return _lane_pin(lanes + 1)(x), True
+
+    return pin
+
+
+def _lane_batched() -> bool:
+    # models/base.py imports this package
+    from blockchain_simulator_tpu.models.base import lane_axes
+
+    return bool(lane_axes())
+
+
 def node_minor(buf):
     """Pin a ``[D, N, W]`` ring whose rows are narrower than a lane tile to
     the node-minor layout.  XLA:TPU picks that layout itself where it sees
@@ -40,9 +79,21 @@ def node_minor(buf):
     W-minor default for one ring: twice the bytes (W = 64 padded to 128) and
     a transposing copy of the whole ring on every tick (PERF.md section 6,
     PR 31).  With every ring op asking for the one layout, none is left to
-    guess."""
+    guess.
+
+    A ring ``[D, N]`` or ``[D, N, W]`` of fewer nodes than a lane tile has
+    no axis of its own to fill one: under a lane batch (models/base.lane_axes)
+    the lanes do, and the ring is pinned slot-major, lane-minor
+    (:func:`_lane_pin`).  Left alone XLA:TPU put the slot axis second-minor
+    in five of a multi-Raft stack's seven rings, a slot one sublane row of
+    every tile, and every pop copied the whole ring to the slot-major order
+    on every tick (PERF.md section 6, PR 50).  A lone program binds no lane
+    axis and keeps its text."""
     if buf.ndim == 3 and buf.shape[2] < _LANES <= buf.shape[1]:
         return with_layout_constraint(buf, Layout(major_to_minor=(0, 2, 1)))
+    if buf.ndim in (2, 3) and buf.shape[1] < _LANES and _lane_batched():
+        lane_pinned[0] += 1
+        return _lane_pin(0)(buf)
     return buf
 
 

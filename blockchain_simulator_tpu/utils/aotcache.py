@@ -46,6 +46,7 @@ import collections
 import contextlib
 import functools
 import os
+import sys
 import threading
 import time
 
@@ -158,6 +159,7 @@ class BuildLog:
         self._last_late: dict | None = None
         self._warming = 0
         self._warmed = False
+        self._lane_pinned = 0  # of ops/ring.lane_pinned, moved so far
 
     # ----------------------------------------------------------- reading ---
     def records(self) -> list[dict]:
@@ -294,6 +296,8 @@ class BuildLog:
 
         shift = time.monotonic() - time.time()
         entry = self._close(name)
+        if name == "build.trace":
+            self._count_lane_pinned()
         if name != "build.compile" and end - start < FLOOR_S:
             return
         attrs = {"fun": fun_name, "thread": threading.current_thread().name}
@@ -311,6 +315,21 @@ class BuildLog:
         telemetry.emit(name, start + shift, end + shift, trace=entry.trace,
                        parent=entry.parent, span_id=entry.sid,
                        sink=functools.partial(self._add, entry.root), **attrs)
+
+    def _count_lane_pinned(self) -> None:
+        """Move what the ring ops' lane rule pinned while programs were
+        traced (ops/ring.lane_pinned, plain Python: traced code never calls
+        utils/telemetry.py) to the ``ring.lane_pinned`` counter."""
+        from blockchain_simulator_tpu.utils import telemetry
+
+        ring = sys.modules.get("blockchain_simulator_tpu.ops.ring")
+        if ring is None:
+            return
+        with self._lock:
+            n = ring.lane_pinned[0] - self._lane_pinned
+            self._lane_pinned += n
+        if n:
+            telemetry.metrics.counter(telemetry.RING_COUNTER).inc(n)
 
 
 class ExecutableRegistry:

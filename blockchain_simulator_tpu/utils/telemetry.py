@@ -726,3 +726,11 @@ def count_raft_groups(groups) -> None:
             metrics.counter(name).inc(len(with_terms))
         elif key in with_terms[0]:
             metrics.counter(name).inc(sum(g[key] for g in with_terms))
+
+
+# ---- the ring ops' lane rule (ops/ring.node_minor): ring values pinned
+# slot-major, lane-minor while programs were traced.  Traced code never
+# calls this module: ops/ring.py counts in plain Python where it is traced
+# and the program builders move the count here when a trace closes
+# (utils/aotcache.BuildLog.stage_closed)
+RING_COUNTER = "ring.lane_pinned"

@@ -64,6 +64,19 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--shards", type=int, default=0,
                    help="shard node state over this many devices (jax engine)")
     p.add_argument("--link-delay-ms", type=int, default=d.link_delay_ms)
+    p.add_argument("--link-classes", type=int, nargs="+", default=(),
+                   metavar="COUNT",
+                   help="link classes (a rack, a region): the node count of "
+                        "each class, ids contiguous, summing to --n; with "
+                        "--link-class-delay-ms (pbft, edge delivery, full "
+                        "mesh)")
+    p.add_argument("--link-class-delay-ms", nargs="+", default=(),
+                   metavar="ROW",
+                   help="one-way propagation per class pair in ms, one "
+                        "comma-separated ROW a sender class (K rows of K), "
+                        "in --link-delay-ms's place: "
+                        "--link-classes 6 5 3 --link-class-delay-ms "
+                        "3,40,90 40,4,130 90,130,5")
     p.add_argument("--serialization", choices=["on", "off"],
                    default="on" if d.model_serialization else "off",
                    help="model per-message block serialization time "
@@ -176,6 +189,9 @@ def config_from_args(args) -> SimConfig:
         schedule=args.schedule,
         quorum_rule=args.quorum_rule,
         link_delay_ms=args.link_delay_ms,
+        link_classes=args.link_classes,
+        link_class_delay_ms=[[int(d) for d in row.split(",")]
+                             for row in args.link_class_delay_ms],
         model_serialization=args.serialization == "on",
         topology=args.topology,
         degree=args.degree,
@@ -238,10 +254,12 @@ def main(argv=None) -> int:
               "backends design the echo away; see SimConfig docs)",
               file=sys.stderr)
         return 2
-    if args.engine != "cpp" and (args.queued_links or args.raft_terms):
+    if args.engine != "cpp" and (args.queued_links or args.raft_terms
+                                 or args.link_classes):
         # pbft (serial-pipe registers) and paxos (ser = 0) run on the
         # tensorized backends; anything else gets the runner's message
-        # (as does an arm of raft that has no terms)
+        # (as does an arm of raft that has no terms, and any arm without
+        # link classes)
         from blockchain_simulator_tpu.runner import _reject_cpp_only
 
         try:

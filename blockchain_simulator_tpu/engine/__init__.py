@@ -190,6 +190,10 @@ def run_cpp(cfg, seed: int | None = None) -> dict:
         from blockchain_simulator_tpu.models import raft
 
         raft.check_schedule(cfg, engine="cpp")
+    if cfg.link_classes:  # likewise (ops/linkclass.check_arms)
+        from blockchain_simulator_tpu.ops import linkclass
+
+        linkclass.check_arms(cfg, engine="cpp")
     if cfg.raft_terms:
         raise NotImplementedError(
             "raft_terms is not implemented by the C++ engine (engine.cpp is "

@@ -271,6 +271,14 @@ def audit_configs() -> dict[str, "object"]:
                                      topology="committee", committees=2,
                                      stat_sampler="exact",
                                      faults=_crash_schedule()),
+        # link classes (SimConfig.link_classes): per-edge PBFT whose channels
+        # read sender-side delay lines, three classes and an asymmetric
+        # matrix, so that the traced reads are real class-by-class reads
+        "pbft_geo": SimConfig(protocol="pbft", n=8, sim_ms=200,
+                              quorum_rule="2f1", stat_sampler="exact",
+                              link_classes=(4, 3, 1),
+                              link_class_delay_ms=((3, 12, 30), (10, 4, 25),
+                                                   (30, 20, 5))),
         # fast paths, explicitly scheduled (eligibility asserted in tests)
         "pbft_round": SimConfig(protocol="pbft", n=8, sim_ms=200,
                                 delivery="stat", schedule="round",
@@ -326,7 +334,8 @@ def build_catalog() -> list[ProgramSpec]:
                 # accumulators (tests/test_zztopo.py counts them)
                 "pbft_kreg", "pbft_kreg_stat", "raft_kreg",
                 "raft_kreg_stat", "paxos_kreg", "pbft_comm", "raft_terms",
-                "raft_terms_comm", "raft_crash", "raft_crash_comm"):
+                "raft_terms_comm", "raft_crash", "raft_crash_comm",
+                "pbft_geo"):
         specs.append(sim_spec(arm))
 
     # --- runner.make_segment_fn ("segment") -----------------------------

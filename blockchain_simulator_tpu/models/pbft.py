@@ -65,6 +65,7 @@ from blockchain_simulator_tpu.models.base import (
 from blockchain_simulator_tpu.ops import delay as delay_ops
 from blockchain_simulator_tpu.ops import delivery as dv
 from blockchain_simulator_tpu.ops import gatherdeliv as gd
+from blockchain_simulator_tpu.ops import linkclass as lc
 from blockchain_simulator_tpu.ops import topology
 from blockchain_simulator_tpu.ops.ring import (
     ring_pop,
@@ -162,6 +163,25 @@ class PbftBufs:
     # zeros; a slot that is not due holds nothing (:func:`step`'s gate rests
     # on that).  Kept only by the programs that gate (``can_branch``)
     due: jax.Array
+    # the sender-side delay lines of a program with link classes
+    # (:class:`LinkLines`); None without classes: no leaf
+    lines: "LinkLines | None" = None
+
+
+@struct.dataclass
+class LinkLines:
+    """What every node sent on each channel over the last ticks
+    (``ops/linkclass.DelayLine``), read back a class pair's offset later.
+    The broadcasts keep the value a node sends (a leader sends one slot a
+    tick, so its PRE_PREPARE is the slot id + 1 and not a row of windows);
+    ``prepare`` keeps the (node, window) pairs that broadcast PREPARE, a bit
+    a window (``ops/linkclass.pack_bits``), and is read over the round trip's
+    offsets: its replies are short-circuited (module docstring)."""
+
+    pp: lc.DelayLine       # [T, N] int32 slot id + 1 a leader announced
+    prepare: lc.DelayLine  # [T2, N, W / 32] uint32 PREPARE broadcast, packed
+    commit: lc.DelayLine   # [T, N, W] int32 COMMIT votes broadcast
+    vc: lc.DelayLine       # [T, N] int32 VIEW_CHANGE, encoded as the ring's
 
 
 def eff_window(cfg) -> int:
@@ -196,6 +216,7 @@ def init(cfg, key=None):
     n, s = cfg.n, cfg.pbft_max_slots
     w = eff_window(cfg)
     d = cfg.ring_depth
+    lc.check_arms(cfg)
     if cfg.topology == "gossip" and w < s:
         raise ValueError(
             "pbft gossip (topology='gossip') requires exact vote-table mode "
@@ -251,7 +272,22 @@ def init(cfg, key=None):
     )
     bufs = PbftBufs(pp=zi(d, n, w), prep_rt=zi(d, n, w), commit=zi(d, n, w),
                     vc=zi(d, n), due=zb(len(_RINGS), d))
+    if cfg.link_classes:
+        holds = {"pp": ((n,), jnp.int32),
+                 "prepare": ((n, -(-w // 32)), jnp.uint32),
+                 "commit": ((n, w), jnp.int32), "vc": ((n,), jnp.int32)}
+        bufs = bufs.replace(lines=LinkLines(**{
+            f: lc.line_init(plan, *holds[f])
+            for f, plan in _line_plans(cfg).items()}))
+        lc.note_traced(cfg, (state, bufs))
     return state, bufs
+
+
+def _line_plans(cfg) -> dict:
+    """The static plan of each line, by the line's field in
+    :class:`LinkLines`."""
+    ow, rt = lc.one_way_plan(cfg), lc.roundtrip_plan(cfg)
+    return {"pp": ow, "prepare": rt, "commit": ow, "vc": ow}
 
 
 def finalize(state: PbftState, axis) -> PbftState:
@@ -336,6 +372,12 @@ def step(cfg, state: PbftState, bufs: PbftBufs, t, tkey, *, topo_tables=None,
         if _queued(cfg):  # those arrivals bypass the rings
             active = active | (state.ppq_tick == t).any()
         bufs = bufs.replace(due=bufs.due & ~now)
+        if cfg.link_classes:  # what a delay line holds for this tick's readers
+            lines = {}
+            for f, plan in _line_plans(cfg).items():
+                lines[f] = lc.line_clear(getattr(bufs.lines, f), t)
+                active = active | lc.line_any(lines[f], t, plan)
+            bufs = bufs.replace(lines=LinkLines(**lines))
     return gated_body(active, tick, (state, bufs), TAKEN_SCOPE)
 
 
@@ -368,6 +410,11 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
 
     pp_t, prep_t, com_t, vc_t = popped
     pp, prep_rt, commit, vc = bufs.pp, bufs.prep_rt, bufs.commit, bufs.vc
+    # link classes: each channel's sends go into its delay line and what the
+    # receivers of each class read back out of it is delivered, with the
+    # jitter draw and the ring push of a program without classes
+    classed = bool(cfg.link_classes)
+    lines, plans = bufs.lines, _line_plans(cfg) if classed else None
     due = list(bufs.due) if mark_due else None
     d = bufs.due.shape[1]
 
@@ -379,6 +426,23 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
             ahead = jnp.mod(jnp.arange(d) - (t + lo_), d) < n_buckets
             due[ring] = due[ring] | (pred & ahead)
         return gated_push(pred, fn, zeros, buf, into, axis)
+
+    def push_classed(ring, name, sending, deliver, zeros, buf, into, lo_,
+                     n_buckets):
+        """``push`` under link classes: ``sending [N, ...]`` (what the nodes
+        send on channel ``name`` this tick) goes into the channel's delay
+        line, and ``deliver`` gets what the receivers of each class read back
+        out of it (``[K, N, ...]``, ops/linkclass.line_get).  The read stands
+        outside the push's gate, on every taken tick: a line that crossed the
+        gate (closed over, or riding its carry beside the ring) was laid out
+        anew in the gate's loop and copied whole on every tick (PERF.md
+        section 6, PR 51); what crosses is the read, K x N rows."""
+        nonlocal lines
+        line = lc.line_put(getattr(lines, name), t, sending)
+        lines = lines.replace(**{name: line})
+        sent_k = lc.line_get(line, t, plans[name])
+        return push(ring, lc.line_any(line, t, plans[name]),
+                    lambda: deliver(sent_k), zeros, buf, into, lo_, n_buckets)
 
     with jax.named_scope("pbft.tick.pop"):
         # ---- this tick's arrivals; crashed nodes process nothing ----------------
@@ -540,6 +604,23 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
                 ),
                 rt_lo, len(rt_probs),
             )
+        elif classed:
+            # the replies of peer class k to a broadcast of ``rt``'s offset
+            # ago start their jittered way back now
+            def prepare_replies(words_k):  # [K, N, W / 32] packed
+                sent_k = lc.unpack_bits(words_k, w)
+                rt_counts = dv.roundtrip_reply_counts_classed(
+                    k_rt, sent_k.any(axis=(0, 2)), plans["prepare"].bounds,
+                    lo, hi, drop, peer_mask=voters, impl=eimpl)
+                return jnp.einsum("bki,kiw->biw", rt_counts,
+                                  sent_k.astype(jnp.int32))
+
+            prep_rt = push_classed(
+                _PREP_RT, "prepare", lc.pack_bits(got_pp), prepare_replies,
+                jnp.zeros((len(rt_probs), n_loc, w), jnp.int32), prep_rt,
+                lambda buf, c: ring_push_add(buf, t, rt_lo, c),
+                rt_lo, len(rt_probs),
+            )
         else:
             prep_rt = push(
                 _PREP_RT,
@@ -610,6 +691,16 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
                         axis=axis, mode=smode,
                     )
                 ),
+                lo, hi - lo,
+            )
+        elif classed:
+            commit = push_classed(
+                _COMMIT, "commit", commit_mat,
+                lambda slot_k: dv.bcast_slots_classed(
+                    k_cm, slot_k, plans["commit"].bounds, lo, hi, drop,
+                    impl=eimpl),
+                zeros_w, commit,
+                lambda buf, c: ring_push_add(buf, t, lo, c),
                 lo, hi - lo,
             )
         else:
@@ -758,6 +849,17 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
                 push_pp,
                 lo + ser, hi - lo,
             )
+        elif classed:
+            def pre_prepares(val_k):  # [K, N] slot id + 1
+                in_w = windows[None, None, :] == ((val_k - 1) % w)[:, :, None]
+                return dv.bcast_window_value_max_classed(
+                    k_pp, jnp.where(in_w, val_k[:, :, None], 0),
+                    plans["pp"].bounds, lo, hi, drop, impl=eimpl)
+
+            pp = push_classed(
+                _PP, "pp", jnp.where(send_block, next_n + 1, 0), pre_prepares,
+                zeros_w, pp, push_pp, lo + ser, hi - lo,
+            )
         elif stat:
             pp = push(
                 _PP,
@@ -837,6 +939,13 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
                 push_vc,
                 lo, hi - lo,
             )
+        elif classed:
+            vc = push_classed(
+                _VC, "vc", enc,
+                lambda enc_k: dv.bcast_value_max_classed(
+                    k_vc, enc_k, plans["vc"].bounds, lo, hi, drop, impl=eimpl),
+                zeros_flat, vc, push_vc, lo, hi - lo,
+            )
         elif stat:
             vc = push(
                 _VC,
@@ -882,7 +991,7 @@ def _phases(cfg, state: PbftState, bufs: PbftBufs, popped, t, tkey,
         slot_propose_tick=slot_propose_tick,
     )
     bufs = PbftBufs(pp=pp, prep_rt=prep_rt, commit=commit, vc=vc,
-                    due=jnp.stack(due) if mark_due else bufs.due)
+                    due=jnp.stack(due) if mark_due else bufs.due, lines=lines)
     return state, bufs
 
 

@@ -611,5 +611,96 @@ def unicast_reply_value_max_dense(key, reply, lo, hi, drop_prob=0.0,
     ).max(1)  # [B, N]
 
 
+# --------------------------------------------------------------------------- #
+# classed delivery (link classes, ops/linkclass.py): the dense arms with a   #
+# sender tensor a receiver class                                              #
+# --------------------------------------------------------------------------- #
+#
+# Under link classes what a node "sends now" differs by the class of the
+# receiver (``ops/linkclass.line_get``: entry ``[k, i]`` is what reaches the
+# receivers of class ``k`` from node ``i`` this tick, the class pair's share
+# of the propagation already behind it).  Each arm below is its dense twin
+# above with that leading class axis: ONE jitter draw an edge on the same key
+# and of the same shape, the bucket axis the jitter's own, the receivers'
+# columns taken class by class (``bounds``: K contiguous row ranges), so the
+# work is the dense arm's and one class is the dense arm itself, number for
+# number.  Unsharded: no classed program runs under a mesh axis
+# (ops/linkclass.check_arms).
+
+
+@_scoped
+def bcast_value_max_classed(key, value_k, bounds, lo, hi, drop_prob=0.0,
+                            impl="threefry"):
+    """:func:`bcast_value_max_dense` of ``value_k [K, N]``.  Returns
+    [B, N]."""
+    value_k = value_k.astype(jnp.int32)
+    send = value_k.max(axis=0) > 0
+    hits = _edge_hits(key, send, lo, hi, drop_prob, impl=impl)
+    return jnp.concatenate(
+        [(hits[:, :, a:b] * value_k[k][None, :, None]).max(1)
+         for k, (a, b) in enumerate(bounds)], axis=1)
+
+
+@_scoped
+def bcast_slots_classed(key, slot_k, bounds, lo, hi, drop_prob=0.0,
+                        impl="threefry"):
+    """:func:`bcast_slots_dense` of ``slot_k [K, N, S]``.  Returns
+    [B, N, S]."""
+    slot_k = slot_k.astype(jnp.int32)
+    send = slot_k.max(axis=(0, 2)) > 0
+    hits = _edge_hits(key, send, lo, hi, drop_prob, impl=impl)
+    return jnp.concatenate(
+        [jnp.einsum("bij,is->bjs", hits[:, :, a:b], slot_k[k])
+         for k, (a, b) in enumerate(bounds)], axis=1)
+
+
+@_scoped
+def bcast_window_value_max_classed(key, value_k, bounds, lo, hi, drop_prob=0.0,
+                                   impl="threefry"):
+    """:func:`bcast_window_value_max_dense` of ``value_k [K, N, W]``.
+    Returns [B, N, W]."""
+    value_k = value_k.astype(jnp.int32)
+    send = value_k.max(axis=(0, 2)) > 0
+    hits = _edge_hits(key, send, lo, hi, drop_prob, impl=impl)
+    return jnp.concatenate(
+        [(hits[:, :, a:b, None] * value_k[k][None, :, None, :]).max(axis=1)
+         for k, (a, b) in enumerate(bounds)], axis=1)
+
+
+@_scoped
+def roundtrip_reply_counts_classed(key, send, bounds, lo, hi, drop_prob=0.0,
+                                   peer_mask=None, impl="threefry"):
+    """:func:`roundtrip_reply_counts_dense` with the replies counted by the
+    PEER's class: [B2, K, N], entry ``[b, k, i]`` the replies that reach
+    sender ``i`` from its peers of class ``k`` with ``2*lo + b`` ticks of
+    jitter and base propagation.  ``send [N]``: the senders with a
+    broadcast whose replies any peer class starts now; which of its
+    broadcasts a peer class answers is the caller's to weigh the counts
+    by."""
+    n = send.shape[0]
+    peers = jnp.ones((n,), bool) if peer_mask is None else peer_mask
+    d1 = sample_edge_delays(jax.random.fold_in(key, 1), (n, n), lo, hi, impl)
+    d2 = sample_edge_delays(jax.random.fold_in(key, 2), (n, n), lo, hi, impl)
+    total = d1 + d2
+    notself = jnp.arange(n)[:, None] != jnp.arange(n)[None, :]
+    mask = (
+        send.astype(jnp.int32)[:, None]
+        * notself.astype(jnp.int32)
+        * peers.astype(jnp.int32)[None, :]
+    )
+    if drop_prob > 0.0:
+        keep = jax.random.bernoulli(
+            jax.random.fold_in(key, 0x0D0E), (1.0 - drop_prob) ** 2, (n, n)
+        )
+        mask = mask * keep.astype(jnp.int32)
+    lo2 = 2 * lo
+    nb = 2 * (hi - lo) - 1
+    landed = (
+        (total[None] == _bucket_iota(lo2, lo2 + nb, total.ndim)).astype(jnp.int32)
+        * mask[None]
+    )  # [B2, N sender, N peer]
+    return jnp.stack([landed[:, :, a:b].sum(2) for a, b in bounds], axis=1)
+
+
 # every scope above, by name (ops/scopes.py)
 SCOPES = tuple(_names)

@@ -210,6 +210,11 @@ def make_sharded_sim_fn(cfg: SimConfig, mesh: Mesh):
     from blockchain_simulator_tpu.runner import _reject_cpp_only, use_round_schedule
 
     _reject_cpp_only(cfg)
+    if cfg.link_classes:
+        # refused by name here: ``init`` below runs on the unsharded config
+        from blockchain_simulator_tpu.ops import linkclass
+
+        linkclass.check_arms(cfg.with_(mesh_axis=NODES_AXIS))
     if use_round_schedule(cfg):
         if cfg.protocol == "raft":
             return _make_sharded_raft_hb_fn(cfg, mesh)

@@ -137,6 +137,12 @@ def _reject_cpp_only(cfg: SimConfig) -> None:
         from blockchain_simulator_tpu.models import raft
 
         raft.check_arms(cfg)
+    if cfg.link_classes:
+        # what cannot run link classes refuses them by its name, before
+        # anything is built
+        from blockchain_simulator_tpu.ops import linkclass
+
+        linkclass.check_arms(cfg)
     if cfg.queued_links:
         # pbft: per-destination serial-pipe registers (models/pbft.py).
         # paxos: every message is 3-4 bytes (ser = 0), the pipe is never

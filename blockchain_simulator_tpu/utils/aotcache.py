@@ -160,6 +160,7 @@ class BuildLog:
         self._warming = 0
         self._warmed = False
         self._lane_pinned = 0  # of ops/ring.lane_pinned, moved so far
+        self._linkclass = {}  # of ops/linkclass.traced, moved so far
 
     # ----------------------------------------------------------- reading ---
     def records(self) -> list[dict]:
@@ -319,7 +320,8 @@ class BuildLog:
     def _count_lane_pinned(self) -> None:
         """Move what the ring ops' lane rule pinned while programs were
         traced (ops/ring.lane_pinned, plain Python: traced code never calls
-        utils/telemetry.py) to the ``ring.lane_pinned`` counter."""
+        utils/telemetry.py) to the ``ring.lane_pinned`` counter, and what
+        the classed programs traced hold to the ``linkclass.*`` counters."""
         from blockchain_simulator_tpu.utils import telemetry
 
         ring = sys.modules.get("blockchain_simulator_tpu.ops.ring")
@@ -330,6 +332,18 @@ class BuildLog:
             self._lane_pinned += n
         if n:
             telemetry.metrics.counter(telemetry.RING_COUNTER).inc(n)
+        lc = sys.modules.get("blockchain_simulator_tpu.ops.linkclass")
+        if lc is None:
+            return
+        # likewise what the classed programs traced hold (ops/linkclass.traced
+        # -> the ``linkclass.*`` counters)
+        with self._lock:
+            moved = {k: v - self._linkclass.get(k, 0)
+                     for k, v in lc.traced.items()}
+            self._linkclass = dict(lc.traced)
+        for k, v in moved.items():
+            if v:
+                telemetry.metrics.counter(f"linkclass.{k}").inc(v)
 
 
 class ExecutableRegistry:

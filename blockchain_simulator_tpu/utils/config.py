@@ -126,6 +126,21 @@ class SimConfig:
     # messages are all 3-4 bytes, so queued == constant-latency there
     # (accepted as a bit-exact no-op).  The mixed shard sim refuses the flag.
     queued_links: bool = False
+    # Link classes (ROADMAP R4; ops/linkclass.py): where the replicas are.
+    # ``link_classes`` gives the node count of each class (a rack, a region,
+    # a continent), ids contiguous in that order and summing to ``n``;
+    # ``link_class_delay_ms`` is the K x K matrix of one-way propagation in
+    # ms, row = the sender's class, column = the receiver's, and takes
+    # ``link_delay_ms``'s place between and inside classes: a one-way delay
+    # is ``matrix[class(i)][class(j)]`` + the protocol's jitter draw (+ the
+    # block's serialization, as ever).  Lists freeze to tuples, so a config
+    # built from JSON hashes and equals one built from tuples.  Empty (the
+    # default): one scalar link latency, and a program built without classes
+    # carries no leaf and no operation for them.  Per-edge PBFT on the full
+    # mesh runs them, flat and under the lane batch; every other arm refuses
+    # them by name (ops/linkclass.check_arms).
+    link_classes: tuple = ()
+    link_class_delay_ms: tuple = ()
 
     # --- topology -----------------------------------------------------------
     # The runtime topology axis (topo/): how the N nodes are wired.
@@ -299,6 +314,7 @@ class SimConfig:
     def __post_init__(self):
         if self.topology == "dense":  # alias: one spelling in the registry key
             object.__setattr__(self, "topology", "full")
+        self._freeze_link_classes()
         if self.protocol not in ("pbft", "raft", "paxos", "mixed"):
             raise ValueError(f"unknown protocol {self.protocol!r}")
         if self.delivery not in ("edge", "stat"):
@@ -450,6 +466,38 @@ class SimConfig:
                     "(shard the SWEEP axis instead, parallel/partition.py)"
                 )
 
+    def _freeze_link_classes(self):
+        """Lists (JSON) to tuples, then the shape of the two class fields:
+        K counts >= 1 summing to ``n``, a K x K matrix of whole ms >= 0."""
+        counts = tuple(self.link_classes)
+        matrix = tuple(tuple(row) for row in self.link_class_delay_ms)
+        object.__setattr__(self, "link_classes", counts)
+        object.__setattr__(self, "link_class_delay_ms", matrix)
+        if not counts and not matrix:
+            return
+        k = len(counts)
+        if not k:
+            raise ValueError(
+                "link_class_delay_ms needs link_classes: the node count of "
+                "each class whose pairs the matrix gives"
+            )
+        if any(int(c) != c or c < 1 for c in counts) or sum(counts) != self.n:
+            raise ValueError(
+                f"link_classes={counts} must be whole node counts >= 1 that "
+                f"sum to n={self.n} (ids contiguous, class after class)"
+            )
+        if len(matrix) != k or any(len(row) != k for row in matrix):
+            raise ValueError(
+                f"link_class_delay_ms must be a square {k} x {k} matrix, one "
+                f"row a class of link_classes={counts}; got rows of "
+                f"{[len(row) for row in matrix]}"
+            )
+        if any(int(d) != d or d < 0 for row in matrix for d in row):
+            raise ValueError(
+                "link_class_delay_ms holds one-way propagation in whole ms "
+                f">= 0 (1 tick = 1 ms); got {matrix}"
+            )
+
     # --- derived quantities (plain python; all static under jit) ------------
     @property
     def eff_stat_sampler(self) -> str:
@@ -480,13 +528,24 @@ class SimConfig:
             lo, hi = self.raft_delay_lo, self.raft_delay_hi
         else:
             lo, hi = self.paxos_delay_lo, self.paxos_delay_hi
-        d = self.link_delay_ms
+        d = self.link_base_ms
         lo, hi = lo + d, hi + d
         if lo < 1:  # a message can never arrive in the tick it was sent
             lo, hi = 1, max(hi, 2)
         if hi <= lo:  # degenerate range (e.g. delay_lo == delay_hi): one bucket
             hi = lo + 1
         return lo, hi
+
+    @property
+    def link_base_ms(self) -> int:
+        """The propagation every message pays: ``link_delay_ms``, or under
+        link classes the matrix's smallest entry.  What a class pair adds to
+        it is the sender-side delay lines' to hold (ops/linkclass.py), so
+        the ranges above and below, the jitter's bucket axis and the rings'
+        depth stay those of one scalar latency."""
+        if not self.link_classes:
+            return self.link_delay_ms
+        return min(min(row) for row in self.link_class_delay_ms)
 
     def roundtrip_range(self) -> tuple[int, int]:
         """[lo, hi) request+reply delay (reply is processed instantly at the

@@ -734,3 +734,14 @@ def count_raft_groups(groups) -> None:
 # and the program builders move the count here when a trace closes
 # (utils/aotcache.BuildLog.stage_closed)
 RING_COUNTER = "ring.lane_pinned"
+
+
+# ---- link classes (ops/linkclass.py): what the classed programs traced so
+# far hold, one increment a traced program (``linkclass.programs``) and over
+# them the sums of their classes, the distinct offsets of their one-way delay
+# lines, their ring depths and the bytes of state one lane carries.  Counted
+# in plain Python where ``models/pbft.init`` is traced (ops/linkclass.traced)
+# and moved here when a trace closes, as the ring counter above is
+LINKCLASS_COUNTERS = ("linkclass.programs", "linkclass.classes",
+                      "linkclass.offsets", "linkclass.ring_depth",
+                      "linkclass.lane_state_bytes")

@@ -262,10 +262,12 @@ def test_benchmark_table_holds_the_gate_metric_and_its_reader(monkeypatch):
         spec = json.load(f)
     assert importlib.import_module("selftest").validate(spec, root, here) == []
     entry = [m for m in spec["per_layer"] if m["name"] == "ops_gate_us.sweep"]
+    # later cells driven by ``sweep`` are appended to its list (PR 51:
+    # ``pbftgeo209.mc``); nothing else of the entry moves
+    assert len(entry) == 1 and entry[0].pop("workloads")[0] == "pbft1k.mc"
     assert entry == [{
         "name": "ops_gate_us.sweep", "unit": "us", "better": "lower",
-        "source": "device_trace", "layer": "ops", "moves": "points_per_s",
-        "workloads": ["pbft1k.mc"]}]
+        "source": "device_trace", "layer": "ops", "moves": "points_per_s"}]
     mod = importlib.util.spec_from_file_location(
         "ops_gate_us_sweep",
         os.path.join(here, "layer_metrics", "ops_gate_us.sweep.py"))

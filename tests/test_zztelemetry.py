@@ -551,12 +551,13 @@ def test_seed_sweep_emits_operands_execute_readback_with_rows(traced):
 def _all_scopes():
     from blockchain_simulator_tpu.models import (base, mixed, paxos, pbft,
                                                  pbft_round, raft, raft_hb)
-    from blockchain_simulator_tpu.ops import delay, delivery, mesh, ring
+    from blockchain_simulator_tpu.ops import (delay, delivery, linkclass, mesh,
+                                              ring)
 
     return (pbft_round.SCOPES + pbft.SCOPES + delivery.SCOPES
             + delay.SCOPES + ring.SCOPES + base.SCOPES
             + mixed.SCOPES + raft.SCOPES + raft_hb.SCOPES
-            + paxos.SCOPES + mesh.SCOPES)
+            + paxos.SCOPES + mesh.SCOPES + linkclass.SCOPES)
 
 
 @pytest.fixture(scope="module")
@@ -630,6 +631,12 @@ def lowered_programs(shared):
         lambda: sweep._batched_fn.__wrapped__(cfgs[0], None).lower(
             jax.vmap(jax.random.key)(jnp.arange(2, dtype=jnp.uint32))
         ).as_text(debug_info=True),
+        # link classes (only a classed program holds the delay lines'
+        # ``ops.linkclass.*`` and the ``ops.delivery.*_classed`` arms), [11]
+        solo(SimConfig(protocol="pbft", n=8, sim_ms=200,
+                       link_classes=(4, 3, 1),
+                       link_class_delay_ms=((3, 12, 30), (10, 4, 25),
+                                            (30, 20, 5)))),
     ]
     if len(jax.devices()) >= 2:
         builds += [sharded(cfgs[-1]), sharded(cfgs[-2])]
@@ -667,6 +674,12 @@ def test_lowered_programs_carry_the_scope(scope, lowered_programs):
     operation of a lowered program (HLO metadata: nothing computed
     changes), as a whole path component."""
     assert any(f"{scope}/" in text for text in lowered_programs), scope
+    if scope.startswith("ops.linkclass.") or scope.endswith("_classed"):
+        # what link classes add: in the classed program alone
+        assert f"{scope}/" in lowered_programs[11]
+        assert not any(f"{scope}/" in t for t in
+                       lowered_programs[:11] + lowered_programs[12:])
+        return
     if scope == "pbft.tick.forge":
         assert f"pbft.tick.prepare/{scope}/" in lowered_programs[-2]
         assert not any(f"{scope}/" in t for t in lowered_programs[:-2])

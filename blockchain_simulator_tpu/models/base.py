@@ -386,3 +386,22 @@ def host_rows(finals, host: dict, rows: int) -> list:
         })
         for i in range(rows)
     ]
+
+
+def host_final(cfg, final, picked: dict):
+    """A LONE final state (no lane axis) as :func:`sim_metrics` takes it,
+    from ONE fetch: ``picked`` (its :func:`metric_leaves`) crosses the host
+    link in one ``jax.device_get``, each copy started before the first is
+    awaited.  Returns the final's own state type with host arrays in the
+    fetched fields and None in the others, which is all ``metrics`` asks for
+    (parallel/shard.readback's return, :func:`host_rows` without the rows).
+    A ``metrics`` call on the device state is one blocking read per leaf
+    for the same bytes.  A committee stack is returned as it is:
+    topo/committee.metrics makes that one fetch itself."""
+    import dataclasses
+
+    if cfg.topology == "committee":
+        return final
+    host = jax.device_get(picked)
+    return type(final)(**{f.name: host.get(f.name)
+                          for f in dataclasses.fields(final)})

@@ -16,6 +16,23 @@ costs one discarded vmap lane of compute.  The occupancy histogram on the
 stats endpoint makes the padding observable (KNOWN_ISSUES: the
 batching/latency trade-off entry).
 
+The lone flush (one queued request: the solo path, and every breaker,
+quarantine and degrade retry) has three host states between admission and
+answer, each a span (:func:`_solo_metrics`): ``serve.dispatch.operands``
+builds the operands on the host and runs no device program (the key wrapped
+around host key data, the fault counts numpy scalars that ride the call;
+attr ``device_programs``); ``serve.dispatch.execute`` is the ONE call of the
+solo executable and the wait for it (while the device runs, the host picks
+the leaves the readback will fetch); ``serve.dispatch.readback`` is ONE
+``jax.device_get`` of the leaves the protocol's ``metrics`` reads
+(``models/base.metric_leaves`` / ``host_final``; a committee stack's is
+``topo/committee.metrics``' own, under its span inside this one) and
+``metrics`` on numpy (attrs ``leaves``, ``bytes``, ``fetches``).  Built on
+the device and read leaf by leaf, the same three scalars and nine small
+leaves were some twenty host round trips: about 5 ms of operands and 5.3 ms
+of readback in a 36 ms flush at n=1024 on the chip (PERF.md section 6,
+PR 52).
+
 Robustness: a failed batched dispatch degrades to per-request solo
 dispatch (``serve-solo`` executable, also registry-cached) so one poisoned
 request fails alone — its peers still get answers — and every lane failure
@@ -35,10 +52,10 @@ from __future__ import annotations
 import time
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from blockchain_simulator_tpu.chaos import inject
-from blockchain_simulator_tpu.models.base import sim_metrics
+from blockchain_simulator_tpu.models import base as base_model
 from blockchain_simulator_tpu.runner import make_dyn_sim_fn
 from blockchain_simulator_tpu.serve import schema
 from blockchain_simulator_tpu.utils import aotcache, obs, telemetry
@@ -64,16 +81,22 @@ def bucket_size(n: int, max_batch: int) -> int:
     return min(b, max_batch)
 
 
-def _operands(reqs):
-    """(keys[B], n_crashed[B], n_byzantine[B]) for a padded request list."""
-    keys = jax.vmap(jax.random.key)(
-        jnp.asarray([r.seed for r in reqs], jnp.uint32)
-    )
-    nc = jnp.asarray(
-        [r.cfg.faults.resolved_n_crashed(r.cfg.n) for r in reqs], jnp.int32
-    )
-    nb = jnp.asarray([r.cfg.faults.n_byzantine for r in reqs], jnp.int32)
-    return keys, nc, nb
+def _key(seed: int):
+    """``jax.random.key(seed)`` for one request, with no device program:
+    the typed key wrapped around host key data (the two words ``[0, seed]``,
+    what threefry's seeding program makes of a 32-bit seed), one 8-byte
+    upload that rides the call.  Seeding on the device is a program launched
+    (and, under ``vmap``, traced) per request.  A seed outside uint32 raises,
+    as it did when the seed was uploaded as one.  Under another default PRNG
+    implementation the words would be another key than the static run's:
+    refused, which the caller answers as a typed dispatch failure."""
+    impl = jax.config.jax_default_prng_impl
+    if impl != "threefry2x32":
+        raise NotImplementedError(
+            f"jax_default_prng_impl={impl}: the served key is built on the "
+            "host from threefry2x32's seeding")
+    return jax.random.wrap_key_data(np.array([0, seed], np.uint32),
+                                    impl="threefry2x32")
 
 
 def _solo_metrics(req):
@@ -82,10 +105,11 @@ def _solo_metrics(req):
     id, so a drill can poison exactly one request (chaos/inject.py).
     Dispatch stamps (span synthesis at answer time, serve/server._answer)
     bracket the whole attempt; inside it three spans name the host's
-    states — ``serve.dispatch.operands`` / ``.execute`` / ``.readback``,
-    children of the request's ``serve.dispatch`` span, whose id is
-    pre-minted here because the server only emits that span at answer
-    time.  All host-side, per the telemetry rule."""
+    states — ``serve.dispatch.operands`` / ``.execute`` / ``.readback``
+    (module docstring: what each holds, and its attrs), children of the
+    request's ``serve.dispatch`` span, whose id is pre-minted here because
+    the server only emits that span at answer time.  All host-side, per
+    the telemetry rule."""
     # stamp BEFORE the chaos point (and fire the point INSIDE the
     # try/finally): a poisoned request that raises at the injection
     # still records a near-zero dispatch-attempt span instead of
@@ -97,10 +121,12 @@ def _solo_metrics(req):
     ctx = telemetry.TraceContext(req.trace_id, req.dispatch_span)
     try:
         inject.chaos_point("serve.solo_dispatch", req_id=req.req_id)
+        cfg = req.cfg
         with telemetry.span("serve.dispatch.operands", ctx=ctx,
-                            id=req.req_id):
-            keys, nc, nb = _operands([req])
-            args = (keys[0], nc[0], nb[0])
+                            id=req.req_id, device_programs=0):
+            args = (_key(req.seed),
+                    np.int32(cfg.faults.resolved_n_crashed(cfg.n)),
+                    np.int32(cfg.faults.n_byzantine))
             if req.probe is not None:
                 # the armed solo twin (consobs-solo registry entry) —
                 # same operands, final state bit-equal under the exact
@@ -112,16 +138,25 @@ def _solo_metrics(req):
                 sim = _solo_fn(req.canon)
         with telemetry.span("serve.dispatch.execute", ctx=ctx,
                             id=req.req_id):
-            out = jax.block_until_ready(sim(*args))
+            out = sim(*args)
+            # while the device runs: which leaves the readback will fetch,
+            # for its span's attrs (shapes alone, no value is touched), so
+            # that nothing stands between the two spans
+            final, probes = out if req.probe is not None else (out, None)
+            picked = base_model.metric_leaves(cfg, final)
+            leaves = jax.tree.leaves(picked)
+            fetched = {"leaves": len(leaves), "fetches": 1,
+                       "bytes": sum(x.nbytes for x in leaves)}
+            jax.block_until_ready(out)
         with telemetry.span("serve.dispatch.readback", ctx=ctx,
-                            id=req.req_id):
+                            id=req.req_id, **fetched):
+            m = base_model.sim_metrics(
+                cfg, base_model.host_final(cfg, final, picked))
             if req.probe is None:
-                return sim_metrics(req.cfg, out)
+                return m
             from blockchain_simulator_tpu.obsim import host as obsh
             from blockchain_simulator_tpu.obsim import schema as obs_schema
 
-            final, probes = out
-            m = sim_metrics(req.cfg, final)
             m["probe"] = obs_schema.summarize(req.canon, req.probe, probes)
             obsh.note_violations(m["probe"], req.cfg, req.seed)
             return m

@@ -304,6 +304,136 @@ def test_solo_dispatch_failure_is_typed_not_fatal(monkeypatch):
     assert "dispatch failed" in resp["error"]
 
 
+# ------------------------------------------- the lone flush's host link ----
+
+def _serve_spans(obj):
+    """One lone request through a fresh server: ``(response, span records)``."""
+    from blockchain_simulator_tpu.utils import telemetry
+
+    with telemetry.capture() as spans:
+        with ScenarioServer(max_batch=2, max_wait_ms=1.0) as srv:
+            resp = srv.request(obj, wait_s=300)
+    assert resp["status"] == "ok", resp
+    return resp, list(spans)
+
+
+@pytest.mark.parametrize("n_byzantine", [0, 1])
+@pytest.mark.parametrize("protocol,sim_ms", [
+    ("pbft", 200), ("raft", 600), ("paxos", 600)])
+def test_lone_flush_equals_static_solo_from_one_fetch(protocol, sim_ms,
+                                                      n_byzantine):
+    """A lone served request builds its operands on the host (the key from
+    host key data, the fault counts as numpy scalars) and reads the final
+    state's metric leaves in one fetch: the answer is still
+    ``sim_metrics(cfg, make_sim_fn(cfg)(jax.random.key(seed)))`` key for
+    key, and ``base.host_final`` hands ``metrics`` the device state's own
+    leaves in ``METRIC_FIELDS`` and None elsewhere."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from blockchain_simulator_tpu.models import base
+
+    seed = 2_147_483_659  # above int32: the key's low word, not a wrap
+    tpl = dict(TPL, protocol=protocol, sim_ms=sim_ms)
+    cfg = SimConfig(**tpl, faults=FaultConfig(n_byzantine=n_byzantine))
+    resp, spans = _serve_spans(
+        dict(tpl, seed=seed, faults={"n_byzantine": n_byzantine}))
+    assert resp["batch"]["mode"] == "solo"
+    static = base.sim_metrics(
+        cfg, runner.make_sim_fn(cfg)(jax.random.key(seed)))
+    assert resp["metrics"] == static
+
+    by_name = {s["name"]: s["attrs"] for s in spans}
+    assert by_name["serve.dispatch.operands"]["device_programs"] == 0
+    fields = base.get_protocol(protocol).METRIC_FIELDS
+    readback = by_name["serve.dispatch.readback"]
+    assert readback["fetches"] == 1 and readback["bytes"] > 0
+
+    final = serve_dispatch._solo_fn(canonical_fault_cfg(cfg))(
+        serve_dispatch._key(seed), np.int32(0), np.int32(n_byzantine))
+    picked = base.metric_leaves(cfg, final)
+    assert set(picked) <= set(fields)
+    assert readback["leaves"] == len(jax.tree.leaves(picked))
+    host = base.host_final(cfg, final, picked)
+    assert type(host) is type(final)
+    for f in dataclasses.fields(final):
+        got = getattr(host, f.name)
+        if f.name not in picked:
+            assert got is None, f.name
+            continue
+        for a, b in zip(jax.tree.leaves(got),
+                        jax.tree.leaves(getattr(final, f.name))):
+            assert isinstance(a, np.ndarray)
+            np.testing.assert_array_equal(a, np.asarray(b))
+    assert base.sim_metrics(cfg, host) == static
+
+
+def test_lone_committee_flush_keeps_the_stack_own_readback():
+    """A committee final is a stacked pytree that topo/committee.metrics
+    fetches once itself: the lone flush leaves it to that readback (its
+    span nests under ``serve.dispatch.readback``) and still answers as the
+    static run does."""
+    import jax
+
+    from blockchain_simulator_tpu.models import base
+
+    tpl = dict(TPL, n=16, topology="committee", committees=2)
+    resp, spans = _serve_spans(dict(tpl, seed=3))
+    cfg = SimConfig(**tpl)
+    assert resp["metrics"] == base.sim_metrics(
+        cfg, runner.make_sim_fn(cfg)(jax.random.key(3)))
+    outer = next(s for s in spans if s["name"] == "serve.dispatch.readback")
+    inner = next(s for s in spans if s["name"] == "topo.committee.readback")
+    assert inner["parent"] == outer["id"]
+    assert outer["attrs"]["fetches"] == 1
+    assert outer["attrs"]["leaves"] == inner["attrs"]["leaves"]
+    assert outer["attrs"]["bytes"] == inner["attrs"]["bytes"]
+
+
+def test_lone_flush_refuses_a_prng_whose_key_it_cannot_build():
+    """The served key is threefry's seeding done on the host.  Under another
+    default PRNG implementation those words would be another key than the
+    static run's, so the dispatch fails, typed, and runs nothing."""
+    import jax
+
+    with jax.default_prng_impl("rbg"):
+        with pytest.raises(NotImplementedError, match="threefry2x32"):
+            serve_dispatch._key(3)
+        (_, resp), = serve_dispatch.run_batch(
+            [parse_request(dict(TPL, seed=3), "r")], max_batch=1)
+    assert resp["status"] == "error" and resp["code"] == 500
+    assert "dispatch failed" in resp["error"]
+    assert "threefry2x32" in resp["error"]
+
+
+def test_lone_flush_runs_no_device_program_but_the_solo_executable():
+    """The build log's view of a lone flush: on a shape the process has not
+    seen, every backend compile it causes is the solo executable's (no
+    eager one-primitive program for a key, an upload or a slice); on a
+    warmed server, one more lone request builds nothing at all."""
+    def fresh(before):
+        return [r for r in aotcache.registry.builds()
+                if r["id"] not in before]
+
+    tpl = dict(TPL, n=12, sim_ms=170)  # a canon no other test compiles
+    with ScenarioServer(max_batch=1, max_wait_ms=1.0) as srv:
+        seen = {r["id"] for r in aotcache.registry.builds()}
+        cold = srv.request(dict(tpl, seed=5, faults={"n_byzantine": 1}), 300)
+        built = fresh(seen)
+        seen |= {r["id"] for r in built}
+        warm = srv.request(dict(tpl, seed=6, faults={"n_crashed": 2}), 300)
+        late = fresh(seen)
+    assert cold["status"] == warm["status"] == "ok"
+    compiled = [r["attrs"]["fun"] for r in built
+                if r["name"] == "build.compile"]
+    assert compiled == ["jit(sim)"]
+    assert [r["attrs"]["factory"] for r in built
+            if r["name"] == "build.factory"] == ["serve-solo"]
+    assert late == []
+
+
 # ----------------------------------------------------- stats / registry ----
 
 def test_registry_stats_snapshot():

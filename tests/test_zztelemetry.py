@@ -411,7 +411,7 @@ def _host_events(trace_dir):
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """ONE short profiler session (tens of ms of work, every program warmed
+    """ONE short profiler session (some 0.15 s of work, every program warmed
     before it) over: two recorded spans and an annotation-only one, two lone
     requests through a ScenarioServer, one run_seed_sweep.  Returns the span
     records, the flight ring's new entries and the trace's host events."""
@@ -426,8 +426,14 @@ def traced(tmp_path_factory):
     cfg = SimConfig(protocol="pbft", n=8, sim_ms=200, stat_sampler="exact")
     seeds = [11, 12, 13]
     trace_dir = tmp_path_factory.mktemp("profile")
+    # a lone flush long enough (some 55 ms at sim_ms=400) that the batcher
+    # loop's own lines between two states, 0.4-0.5 ms a request whatever a
+    # flush costs, stay under 1% of its thread: at sim_ms=200 a flush is
+    # 11-17 ms and the states cover 96.7-97.6% of the stretch, parent and
+    # PR 52 alike (test_batcher_states_tile_the_batcher_thread asks for 97%)
+    served = dict(TPL, sim_ms=400)
     with ScenarioServer(max_batch=2, max_wait_ms=2.0) as srv:
-        srv.request(dict(TPL, seed=1), wait_s=300)  # warm: the solo program
+        srv.request(dict(served, seed=1), wait_s=300)  # warm: the solo program
         run_seed_sweep(cfg, seeds)                  # warm: the 3-lane program
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -443,7 +449,7 @@ def traced(tmp_path_factory):
                 with telemetry.span("t.second"):
                     time.sleep(0.006)
                 for i in (2, 3):
-                    r = srv.request(dict(TPL, seed=i, id=f"tr-{i}"),
+                    r = srv.request(dict(served, seed=i, id=f"tr-{i}"),
                                     wait_s=300)
                     assert r["status"] == "ok"
                 rows = run_seed_sweep(cfg, seeds)
@@ -506,6 +512,33 @@ def test_solo_dispatch_children_tile_their_parent(traced):
         # tiling is untouched
         root = next(s for s in spans if s["id"] == p["parent"])
         assert root["name"] == "serve.request"
+
+
+def test_solo_dispatch_children_carry_the_round_trip_counts(traced):
+    """A lone flush's host link, counted on its spans: the operands ran no
+    device program, the readback was ONE fetch of pbft's nine metric leaves.
+    The attrs ride the records and the trace's twins alike."""
+    spans = traced["spans"]
+    for p in (s for s in spans if s["name"] == "serve.dispatch"
+              and s["attrs"]["id"].startswith("tr-")):
+        kids = sorted((s for s in spans if s.get("parent") == p["id"]),
+                      key=lambda s: s["ts"])
+        assert [k["name"] for k in kids] == [
+            "serve.dispatch.operands", "serve.dispatch.execute",
+            "serve.dispatch.readback"]
+        operands, execute, readback = (k["attrs"] for k in kids)
+        assert operands == {"id": p["attrs"]["id"], "device_programs": 0}
+        assert execute == {"id": p["attrs"]["id"]}
+        assert readback["leaves"] == 9 and readback["fetches"] == 1
+        assert readback["bytes"] > 0
+        # one after another inside the parent (how much of it they cover:
+        # test_solo_dispatch_children_tile_their_parent)
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur_ms"] / 1e3 <= b["ts"] + 1e-4
+    twins = [e[3] for e in traced["events"]
+             if e[0] == "serve.dispatch.readback"]
+    assert len(twins) == 2
+    assert all(t["leaves"] == 9 and t["fetches"] == 1 for t in twins)
 
 
 def test_batcher_states_tile_the_batcher_thread(traced):
